@@ -7,6 +7,13 @@ CRC for checksums.  This module is the equivalent seam in this framework: a
 small C++ library exposing a C ABI, compiled on first use with the in-repo
 Makefile and loaded via ctypes (pybind11 is not in the image).
 
+The Makefile compiles with -march=native and the source picks its AVX2 /
+SSE4.2 / AES paths at compile time, so a build is only valid on a CPU with
+the features of the one that built it.  The artefact's file name therefore
+carries a key over source + Makefile + compiler flags + this host's CPU
+features: a library built elsewhere (the chip tool copies the tree as it
+stands on disk) has another name and is rebuilt, never dlopened.
+
 Falls back gracefully: `available()` is False when no compiler is present,
 and callers (ops.codec registry, utils.cipher) keep a pure-Python/numpy path.
 """
@@ -14,7 +21,10 @@ and callers (ops.codec registry, utils.cipher) keep a pure-Python/numpy path.
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -22,7 +32,32 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _NATIVE_DIR = os.path.join(_HERE, "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libweedtpu_native.so")
+
+
+def _cpu_features() -> str:
+    """This host's CPU feature list (what -march=native compiles for)."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return " ".join(sorted(line.split(":", 1)[1].split()))
+    except OSError:
+        pass
+    return platform.processor()
+
+
+def _so_name() -> str:
+    """File name of the build that is valid here: keyed on source,
+    Makefile, compiler overrides and this host's CPU features."""
+    h = hashlib.sha256()
+    for name in ("weedtpu_native.cc", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    for part in (os.environ.get("CXX", ""), os.environ.get("CXXFLAGS", ""),
+                 platform.machine(), _cpu_features()):
+        h.update(b"\0" + part.encode())
+    return f"libweedtpu_native-{h.hexdigest()[:16]}.so"
+
 
 _lib = None
 _lib_err: str | None = None
@@ -35,28 +70,31 @@ class NativeUnavailable(RuntimeError):
     pass
 
 
-def _build() -> None:
-    src = os.path.join(_NATIVE_DIR, "weedtpu_native.cc")
-
-    def up_to_date() -> bool:
-        return os.path.exists(_SO_PATH) and \
-            os.path.getmtime(_SO_PATH) >= os.path.getmtime(src)
-
-    if up_to_date():
-        return
-    # serialize concurrent first-use builds across processes so nobody
-    # dlopens a half-written .so
+def _build() -> str:
+    """Path of the library for this source on this CPU, built if absent."""
+    so_name = _so_name()
+    so_path = os.path.join(_NATIVE_DIR, so_name)
+    if os.path.exists(so_path):
+        return so_path
+    # serialize concurrent first-use builds across processes (the
+    # Makefile renames into place, so nobody dlopens a half-written .so)
     import fcntl
     lock_path = os.path.join(_NATIVE_DIR, ".build.lock")
     with open(lock_path, "w") as lock:
         fcntl.flock(lock, fcntl.LOCK_EX)
         try:
-            if up_to_date():  # another process built it while we waited
-                return
-            subprocess.run(["make", "-C", _NATIVE_DIR, "libweedtpu_native.so"],
-                           check=True, capture_output=True)
+            if not os.path.exists(so_path):  # else: built while we waited
+                subprocess.run(
+                    ["make", "-C", _NATIVE_DIR, f"OUT={so_name}"],
+                    check=True, capture_output=True)
+                # builds for other sources/flags/CPUs are dead weight now
+                for old in glob.glob(os.path.join(
+                        _NATIVE_DIR, "libweedtpu_native*.so")):
+                    if old != so_path:
+                        os.remove(old)
         finally:
             fcntl.flock(lock, fcntl.LOCK_UN)
+    return so_path
 
 
 def _load():
@@ -65,8 +103,7 @@ def _load():
         if _lib is not None or _lib_err is not None:
             return _lib
         try:
-            _build()
-            lib = ctypes.CDLL(_SO_PATH)
+            lib = ctypes.CDLL(_build())
         except (OSError, subprocess.CalledProcessError) as e:
             _lib_err = str(e)
             return None
@@ -107,8 +144,8 @@ def _require():
     lib = _load()
     if lib is None:
         raise NativeUnavailable(
-            f"native library unavailable (need g++/make or a prebuilt "
-            f"{_SO_PATH}): {_lib_err}")
+            f"native library unavailable (need g++/make to build "
+            f"{_NATIVE_DIR}): {_lib_err}")
     return lib
 
 

@@ -49,6 +49,41 @@ def _is_numpy_ref(codec) -> bool:
     return hasattr(codec, "encode_numpy") and not hasattr(codec, "_factory")
 
 
+def backend_name(codec) -> str:
+    """Class of the shell that runs a codec's matrix applies: the
+    answer to "which backend ran" on metric labels and job stats
+    (MSRFileCodec wraps the shell that does the work)."""
+    return type(getattr(codec, "inner", codec)).__name__
+
+
+def describe(codec, jax_live: bool = False) -> dict:
+    """What a codec object runs on: its shell class and, for a device
+    shell, the JAX backend its matrices already live on (platform,
+    device_kind, device count), interpret flag and tile.  Asks JAX
+    nothing for a host codec unless the caller says a backend already
+    exists (`jax_live`) — reporting must never initialise one."""
+    shell = getattr(codec, "inner", codec)
+    info: dict = {"codec": backend_name(codec)}
+    host = _is_host(codec) or _is_numpy_ref(codec)
+    if host and not jax_live:
+        return info
+    import jax
+    devs = jax.devices()
+    info.update(platform=devs[0].platform, device_kind=devs[0].device_kind,
+                device_count=len(devs))
+    if host:
+        return info
+    kernel = getattr(shell, "kernel", None)  # mesh encoders: _ApplyKernel
+    if kernel is not None:
+        info.update(body=kernel.kind, mesh_devices=int(shell.mesh.size))
+        if kernel.kind == "pallas":  # always compiled inside shard_map
+            info.update(interpret=False, tile=kernel.tile)
+    else:  # the Pallas shell carries both; the XLA shell has neither
+        info.update({f: getattr(shell, f) for f in ("interpret", "tile")
+                     if hasattr(shell, f)})
+    return info
+
+
 def dispatch_parity(codec, batch: np.ndarray):
     """Dispatch [k, B] -> [m, B] parity. JAX backends return the device
     array WITHOUT materialising it; host backends compute eagerly."""

@@ -44,7 +44,8 @@ import time
 
 import numpy as np
 
-from seaweedfs_tpu.ops.dispatch import (dispatch_parity_batch,
+from seaweedfs_tpu.ops.dispatch import (backend_name,
+                                        dispatch_parity_batch,
                                         unit_parity_shards)
 from seaweedfs_tpu.stats import netflow as _netflow
 from seaweedfs_tpu.stats import pipeline as _pipeline
@@ -70,25 +71,26 @@ def _fleet_unit_encoder(k: int, m: int):
 
 
 def fleet_codec(kind: str | None = None):
-    """The codec a fleet conversion rides: with more than one attached
-    device (a real slice, or the virtual CPU mesh in tests) the
-    unit-sharded FleetUnitEncoder; otherwise whatever WEEDTPU_EC_CODEC
-    resolves to — every backend now takes `dispatch_parity_batch`."""
-    from seaweedfs_tpu.storage.ec.ec_files import _get_codec
-    kind = kind or os.environ.get("WEEDTPU_CONVERT_CODEC")
-    if kind:
-        if kind in ("mesh", "fleet"):
-            return _fleet_unit_encoder(layout.DATA_SHARDS,
-                                       layout.PARITY_SHARDS)
+    """The codec a fleet conversion rides.  An explicit choice
+    (WEEDTPU_CONVERT_CODEC, else WEEDTPU_EC_CODEC) is honoured before
+    JAX is asked anything: a host-codec process must not initialise a
+    backend on a machine whose chip belongs to the volume server.  Under
+    `auto`, more than one attached device (a real slice, or the virtual
+    CPU mesh in tests) selects the unit-sharded FleetUnitEncoder, one
+    device whatever `_get_codec` resolves to — every backend takes
+    `dispatch_parity_batch`.  A backend that fails to initialise raises."""
+    from seaweedfs_tpu.storage.ec.ec_files import _get_codec, note_resolved
+    kind = kind or os.environ.get("WEEDTPU_CONVERT_CODEC") or \
+        os.environ.get("WEEDTPU_EC_CODEC", "auto")
+    if kind not in ("auto", "mesh", "fleet"):
         return _get_codec(kind)
-    try:
+    if kind == "auto":
         import jax
-        if len(jax.devices()) > 1:
-            return _fleet_unit_encoder(layout.DATA_SHARDS,
-                                       layout.PARITY_SHARDS)
-    except Exception:
-        pass
-    return _get_codec()
+        if len(jax.devices()) == 1:
+            return _get_codec(kind)
+    codec = _fleet_unit_encoder(layout.DATA_SHARDS, layout.PARITY_SHARDS)
+    note_resolved(kind, None, codec)
+    return codec
 
 
 class _VolumeJob:
@@ -243,6 +245,7 @@ def convert_volumes(bases: list[str], *,
 
     stats = stats if stats is not None else {}
     stats["mode"] = "fleet"
+    stats["backend"] = backend_name(codec)
     stats["unit_batch"] = U
     # class=convert on THIS thread and (contextvars are per-thread) re-
     # stamped inside each pipeline thread, so any hop made on the
@@ -404,6 +407,13 @@ def convert_volumes(bases: list[str], *,
             try:
                 with _Timer(stats, "encode_s"):
                     parity = dispatch_parity_batch(codec, buf)
+                # how many devices the unit batch's parity lives on (0:
+                # a host codec returned numpy) — a mesh that silently ran
+                # on its first chip must show
+                sharding = getattr(parity, "sharding", None)
+                if sharding is not None:
+                    stats["devices"] = max(stats.get("devices", 0),
+                                           len(sharding.device_set))
                 q_disp.put((buf, metas, parity))
             except BaseException as e:
                 errors.append(e)
@@ -458,4 +468,5 @@ def convert_volumes(bases: list[str], *,
                                  "shard_size": j.shard_size}
                         for j in jobs},
             "bytes": stats["bytes"], "units": stats["units"],
+            "devices": stats.get("devices", 0),
             "wall_s": round(stats["wall_s"], 4)}

@@ -6,20 +6,20 @@ unpacks bit-planes in VMEM, runs one int8 MXU dot against the pre-lifted
 coding matrix, folds parity-mask + repack into the epilogue, and writes only
 the [m, TN] output bytes — HBM traffic is the information-theoretic minimum.
 
-Measured on v5e-1 (RS(10,4), 640MB/iter, BENCH_r04): 336.5 GB/s of data
-encoded vs ~90 GB/s for the XLA path and ~6.4 GB/s for the AVX2 CPU kernel
-(klauspost/reedsolomon scheme driven by weed/storage/erasure_coding/
-ec_encoder.go; ~0.84 GB/s in the reference's full file-I/O shape).
+Throughput: not measured on current code (PERF.md keeps what the chip
+has shown).  The CPU comparison point is the klauspost/reedsolomon AVX2
+scheme driven by weed/storage/erasure_coding/ec_encoder.go.
 
 Kernel-shape notes (why it looks the way it does):
 - Bit extraction is `(x & (1<<s)) != 0`: Mosaic has no 8-bit shifts
   (`arith.shrui` on i8 fails to legalize) but and/cmp/select are native and
   uint8 lanes are 4x-packed, so this is the cheapest unpack.
 - Bit-planes are *plane-major* (all of bit s for every shard, then bit s+1)
-  and each plane is padded to KPAD=16 sublanes: concatenation then happens on
-  16-sublane-aligned int8 blocks, which Mosaic lays out without relayout
-  copies. The coding bit-matrix gets matching zero columns (free MXU work —
-  the MXU is nowhere near the bottleneck; the VPU unpack is).
+  and each plane is padded to PLANE_PAD=16 sublanes — half of int8's
+  native (32, 128) tile.  Mosaic (libtpu 0.0.34) accepts the 8-way
+  concatenation of those blocks; tests/test_tpu_aot.py compiles it for a
+  v5e on every tier-1 run.  The coding bit-matrix gets matching zero
+  columns (extra MXU work on zeros).
 - The dot is int8 x int8 -> int32: 0/1 operands, sums bounded by 8k <= 128,
   exact. preferred_element_type=int8 trips a Mosaic verifier bug; int32 also
   keeps the <<r repack shifts legal (no 8-bit shifts, see above).
@@ -40,13 +40,12 @@ from jax.experimental.pallas import tpu as pltpu
 from seaweedfs_tpu.ops import codec_base, gf
 
 DEFAULT_TILE = 32768  # interpreter/CPU default: small pads for small inputs
-TPU_TILE = 131072  # measured best on v5e (round-5 sweep: ~+25% over 32K;
-#                    256K regresses — xbits VMEM block passes 16MB)
+TPU_TILE = 131072  # the served tile on a chip; which candidate is fastest
+#                    is not measured on current code
 # candidate byte-column tiles for the bench re-tune sweep
-# (bench._bench_tile_sweep): the r04->r05 swing (336 -> 108 GB/s) showed
-# the best tile is a property of the chip + runtime, not the repo, so
-# every TPU bench run re-measures and records its choice instead of
-# trusting a constant picked under different weather
+# (bench._bench_tile_sweep): the best tile is a property of the chip +
+# runtime, not the repo, so a TPU bench run re-measures and records its
+# choice
 SWEEP_TILES = (32768, 65536, 131072, 262144)
 PLANE_PAD = 16  # sublane alignment for each bit-plane block
 
@@ -79,12 +78,11 @@ def resolved_tile(tile: int | None = None) -> int:
 
 # -- tile pin: the bench sweep's winner, persisted with provenance --------
 #
-# The r04->r05 collapse (336 -> 108 GB/s) was a pinned tile constant
-# nobody re-measured.  The sweep now records its winner + the measured
-# sweep table + a backend/chip fingerprint; resolved_tile() honours a
-# matching pin, and the tile-drift sentinel (stats/pipeline.py)
-# re-validates it in the background so a pin that stops winning fires
-# an alert instead of shipping a silent 3x loss.
+# The sweep records its winner + the measured sweep table + a
+# backend/chip fingerprint; resolved_tile() honours a matching pin, and
+# the tile-drift sentinel (stats/pipeline.py) re-validates it in the
+# background so a pin that stops winning fires an alert.  Whether the
+# tile explains any throughput swing is not measured on current code.
 
 _fingerprint: str | None = None
 
@@ -173,13 +171,15 @@ def load_tile_pin(path: str | None = None) -> dict | None:
 
 def micro_sweep(k: int = 10, m: int = 4, n: int | None = None,
                 iters: int = 3,
-                ensure_tile: int | None = None) -> dict[int, float]:
+                ensure_tile: int | None = None) -> dict[int, float | str]:
     """Cheap re-measure of every SWEEP_TILES candidate on this chip:
-    {tile: GB/s}.  One LCM-of-tiles column width (~256K columns, a few
-    MB per candidate) and a handful of iterations — enough to rank
-    tiles, deliberately far from bench depth; the sentinel compares
-    candidates against each other under identical conditions, so the
-    absolute numbers need not match the bench's."""
+    {tile: GB/s}, or {tile: "failed: <error>"} for a candidate that did
+    not compile or run (off-TPU that is every candidate: the kernels
+    only compile for a chip).  One LCM-of-tiles column width (~256K
+    columns, a few MB per candidate) and a handful of iterations —
+    enough to rank tiles, deliberately far from bench depth; the
+    sentinel compares candidates against each other under identical
+    conditions, so the absolute numbers need not match the bench's."""
     from seaweedfs_tpu.models import rs
     code = rs.get_code(k, m)
     # the sentinel passes its pinned tile: a pin outside SWEEP_TILES
@@ -191,8 +191,6 @@ def micro_sweep(k: int = 10, m: int = 4, n: int | None = None,
                    ({int(ensure_tile)} if ensure_tile else set()))
     if n is None:
         n = max(SWEEP_TILES)
-        if jax.default_backend() != "tpu":
-            n = min(SWEEP_TILES)  # the interpreter is the emulator: tiny
         if ensure_tile:
             t = int(ensure_tile)
             if t > n:
@@ -201,7 +199,7 @@ def micro_sweep(k: int = 10, m: int = 4, n: int | None = None,
                 n = (n // t) * t  # other candidates may drop out
     rng = np.random.default_rng(0)
     data = jnp.asarray(rng.integers(0, 256, (k, n), dtype=np.uint8))
-    out: dict[int, float] = {}
+    out: dict[int, float | str] = {}
     for t in tiles:
         if n % t:
             continue
@@ -212,11 +210,19 @@ def micro_sweep(k: int = 10, m: int = 4, n: int | None = None,
             for _ in range(iters):
                 codec.encode_parity(data).block_until_ready()
             el = (time.perf_counter() - t0) / iters
-        except Exception:
-            continue  # a tile whose VMEM blocks don't fit just drops out
+        except Exception as e:
+            # e.g. a tile whose VMEM blocks don't fit: it drops out of
+            # the ranking, with the compiler's reason kept beside it
+            out[t] = sweep_failure(e)
+            continue
         if el > 0:
             out[t] = k * n / 1e9 / el
     return out
+
+
+def sweep_failure(e: BaseException) -> str:
+    """The table entry of a sweep candidate that raised."""
+    return f"failed: {type(e).__name__}: {str(e)[:300]}"
 
 
 def gf_matrix_to_bitmatrix_planemajor(C: np.ndarray, kpad: int | None = None) -> np.ndarray:
@@ -273,9 +279,11 @@ def _gf_apply(bitmat: jax.Array, data: jax.Array, k: int, m: int, kpad: int,
             pl.BlockSpec((k, tile), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((m, tile), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        # vma: inside the mesh encoders' shard_map the output varies over
+        # the same mesh axes as the data block (empty outside one)
+        out_shape=jax.ShapeDtypeStruct((m, n), jnp.uint8,
+                                       vma=jax.typeof(data).vma),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(bitmat, data)
@@ -309,9 +317,9 @@ def _gf_apply_batch(bitmat: jax.Array, data: jax.Array, k: int, m: int,
             pl.BlockSpec((1, k, tile), lambda u, i: (u, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, m, tile), lambda u, i: (u, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((U, m, n), jnp.uint8),
-        compiler_params=getattr(pltpu, "CompilerParams",
-                                getattr(pltpu, "TPUCompilerParams", None))(
+        out_shape=jax.ShapeDtypeStruct((U, m, n), jnp.uint8,
+                                       vma=jax.typeof(data).vma),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(bitmat, data)
@@ -324,17 +332,25 @@ class PallasGFMatrix:
     work callers should feed tile-aligned spans (the EC block sizes — 1GB/1MB,
     reference weed/storage/erasure_coding/ec_encoder.go:21-22 — are all
     tile-multiples).
+
+    The kernel is compiled for the chip.  `interpret=True` runs it under
+    the Pallas interpreter instead and is for tests only: off-TPU nothing
+    selects it implicitly, so a host with no chip raises here rather than
+    serving from the emulator.
     """
 
     def __init__(self, C: np.ndarray, tile: int | None = None,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
+        if not interpret and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                "the Pallas GF(2^8) kernel compiles only for a TPU and the "
+                f"JAX backend found is {jax.default_backend()!r}; use "
+                "WEEDTPU_EC_CODEC=auto|cpp|jax on this host")
         self.C = np.asarray(C, dtype=np.uint8)
         self.m, self.k = self.C.shape
         self.kpad = max(PLANE_PAD, -(-self.k // PLANE_PAD) * PLANE_PAD)
         self.tile = resolved_tile(tile)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
-        self.interpret = interpret
+        self.interpret = bool(interpret)
         self.bitmat = jnp.asarray(
             gf_matrix_to_bitmatrix_planemajor(self.C, self.kpad), dtype=jnp.int8)
 
@@ -365,7 +381,7 @@ class PallasRSCodec(codec_base.RSCodecBase):
     """Fused-kernel RS codec: `RSCodecBase` over `PallasGFMatrix` applies."""
 
     def __init__(self, code, tile: int | None = None,
-                 interpret: bool | None = None):
+                 interpret: bool = False):
         super().__init__(
             code, lambda C: PallasGFMatrix(C, tile, interpret))
         self.tile = self._parity.tile

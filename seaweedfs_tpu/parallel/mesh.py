@@ -14,8 +14,7 @@ balancedEcDistribution). The TPU-native analogue has three axes:
   sharding there is no per-chip tile-width loss: every chip runs the fused
   kernel at its preferred tile on whole units.  `FleetUnitEncoder` keeps
   in/out shardings matched call-to-call so device-resident outputs never
-  reshard between unit batches, and donates the input buffer on real chips
-  so XLA reuses it instead of copying.
+  reshard between unit batches.
 - **volume/shard placement** ("data parallel" + all-to-all): a batch of
   volumes [V, k, n] shards over devices on V; after local encode, one
   `all_to_all` over ICI re-distributes so device d holds shard-group d of
@@ -23,11 +22,9 @@ balancedEcDistribution). The TPU-native analogue has three axes:
   instead of 14 gRPC copies.
 
 Per-device compute dispatches through ONE body seam (`_ApplyKernel`):
-the fused Pallas kernel on real TPU chips (ops/pallas_gf — the 336 GB/s
-r04 path), the XLA bit-sliced matmul everywhere else (CPU test meshes,
-interpreters).  Before round 6 the mesh paths always used the XLA body,
-which is why `ec_encode_rs10_4_mesh` trailed the single-chip Pallas
-number even before any sharding overhead.
+the fused Pallas kernel on real TPU chips (ops/pallas_gf), the XLA
+bit-sliced matmul everywhere else (CPU test meshes).  Mesh throughput
+against the single-chip kernel: not measured on current code.
 
 Everything is `shard_map` over a `jax.sharding.Mesh`, so it runs identically
 on a real multi-chip slice and on the virtual CPU mesh used in tests.
@@ -40,10 +37,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map
-except ImportError:  # older jax: not yet re-exported at top level
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from seaweedfs_tpu.ops import gf, gfmat_jax
@@ -142,13 +136,6 @@ class _ApplyKernel:
         return out[:, :, :n] if pad else out
 
 
-def _donate_argnums() -> tuple[int, ...]:
-    """Donate the data operand on real chips (XLA aliases the buffer, the
-    copy disappears); CPU backends don't implement donation and would
-    just log a warning per call."""
-    return (1,) if jax.default_backend() == "tpu" else ()
-
-
 class ShardedRSEncoder:
     """RS(k, m) encode/rebuild over a device mesh.
 
@@ -193,15 +180,14 @@ class ShardedRSEncoder:
 
             def _enc_place(bm, vols):  # vols: [Vl, k, nl]
                 # ONE batched kernel launch for all local volumes (the
-                # fused Pallas grid on TPU) — half the r05 batch4
-                # regression was a vmap of the slower XLA body here
+                # fused Pallas grid on TPU), not a vmap of the XLA body
                 par = batch_body(bm, vols)
                 shards = jnp.concatenate([vols, par], axis=1)  # [Vl, k+m, nl]
                 if D == 1:
                     # degenerate placement (1-way vol axis): every shard
                     # group already lives here, and the row pad +
                     # all_to_all below would be pure whole-batch HBM
-                    # copies — the other half of the r05 regression
+                    # copies
                     return shards
                 if pad_rows:
                     shards = jnp.pad(shards, ((0, 0), (0, pad_rows), (0, 0)))
@@ -211,14 +197,12 @@ class ShardedRSEncoder:
                 return jax.lax.all_to_all(
                     shards, vol_axis, split_axis=1, concat_axis=0, tiled=True)
 
-            # donated volume batch: the concat+all_to_all reuses the input
-            # buffer instead of holding both alive (fleet batches are
-            # ~160MB per depth step on the production config)
+            # no donation: input [V, k, n] and output [V, S_pad, n] differ
+            # in shape, so XLA could never alias the donated buffer
             self._encode_place = jax.jit(shard_map(
                 _enc_place,
                 mesh=mesh, in_specs=(P(), P(vol_axis, None, col_axis)),
-                out_specs=P(None, vol_axis, col_axis)),
-                donate_argnums=_donate_argnums())
+                out_specs=P(None, vol_axis, col_axis)))
 
     # -- column-parallel single volume ---------------------------------
 
@@ -297,9 +281,10 @@ class FleetUnitEncoder:
     cross-chip bytes, so 8 chips process 8x the units of 1 at equal unit
     size.  The jitted shard_map is built once; its in/out shardings are
     both P(unit_axis), so a device-resident output (or a staging buffer
-    placed by `place`) feeds the next call without any reshard, and on
-    real chips the input batch is DONATED — XLA writes parity into
-    recycled memory instead of growing the footprint per in-flight batch.
+    placed by `place`) feeds the next call without any reshard.  The
+    input batch is not donated: [U, k, B] in and [U, m, B] out differ in
+    shape, and the executable compiled for a v5e carries no input/output
+    alias when it is.
 
     D2H is per-device: `unit_shards(parity)` yields each device's local
     [U/D, m, B] block the moment it is fetched, so the conversion drain
@@ -324,8 +309,7 @@ class FleetUnitEncoder:
         self._encode = jax.jit(shard_map(
             batch_body,
             mesh=mesh, in_specs=(P(), P(unit_axis)),
-            out_specs=P(unit_axis)),
-            donate_argnums=_donate_argnums())
+            out_specs=P(unit_axis)))
 
     def unit_slots(self, min_units: int) -> int:
         """Round a desired in-flight unit count up to an even per-device

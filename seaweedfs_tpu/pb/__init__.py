@@ -2,15 +2,20 @@
 
 Reference: weed/pb/*.proto + generated code.  The schema (weedtpu.proto)
 is compiled with protoc on first use (same build-on-demand discipline as
-native/).  `available()` is False when protoc and a prebuilt module are
-both absent — every endpoint keeps its JSON framing, so protobuf is an
-upgrade, not a dependency.
+native/, and the same rule for a generated file that came with a copied
+tree: its first line carries a key over the schema and the installed
+protobuf runtime, and a module with another key is regenerated, not
+imported).  `available()` is False when the module is stale or absent
+and protoc is missing — every endpoint keeps its JSON framing, so
+protobuf is an upgrade, not a dependency.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -24,18 +29,46 @@ _err: str | None = None
 CONTENT_TYPE = "application/x-protobuf"
 
 
+def _gen_key() -> str:
+    import google.protobuf
+    with open(_PROTO, "rb") as f:
+        h = hashlib.sha256(f.read())
+    h.update(google.protobuf.__version__.encode())
+    return f"# weedtpu-key: {h.hexdigest()[:16]}\n"
+
+
+def _generate() -> None:
+    """(Re)generate weedtpu_pb2.py unless the one on disk carries this
+    schema's and this runtime's key."""
+    key = _gen_key()
+    try:
+        with open(_GEN) as f:
+            if f.readline() == key:
+                return
+    except OSError:
+        pass
+    # protoc writes into a scratch directory and the keyed module is
+    # renamed into place, so no other process imports a half-written file
+    with tempfile.TemporaryDirectory(dir=_HERE) as tmp:
+        subprocess.run(
+            ["protoc", f"--python_out={tmp}",
+             f"--proto_path={_HERE}", "weedtpu.proto"],
+            check=True, capture_output=True)
+        out = os.path.join(tmp, os.path.basename(_GEN))
+        with open(out) as f:
+            body = f.read()
+        with open(out, "w") as f:
+            f.write(key + body)
+        os.replace(out, _GEN)
+
+
 def _load():
     global _mod, _err
     with _lock:
         if _mod is not None or _err is not None:
             return _mod
         try:
-            if not os.path.exists(_GEN) or \
-                    os.path.getmtime(_GEN) < os.path.getmtime(_PROTO):
-                subprocess.run(
-                    ["protoc", f"--python_out={_HERE}",
-                     f"--proto_path={_HERE}", "weedtpu.proto"],
-                    check=True, capture_output=True)
+            _generate()
             from seaweedfs_tpu.pb import weedtpu_pb2  # noqa: PLC0415
             _mod = weedtpu_pb2
         except (OSError, subprocess.CalledProcessError, ImportError) as e:

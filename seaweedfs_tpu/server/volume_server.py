@@ -1146,7 +1146,8 @@ class VolumeServer:
                 cancel=lambda: job["cancel"],
                 stats=stages, codec_tag=spec.tag)
             ec_files.write_sorted_ecx(base + ".idx")
-            metrics.EC_ENCODE_BYTES.labels("tpu").inc(job["total"])
+            metrics.EC_ENCODE_BYTES.labels(
+                stages.get("backend", "unknown")).inc(job["total"])
 
         try:
             await asyncio.to_thread(gen)
@@ -1231,7 +1232,8 @@ class VolumeServer:
                 stats=stages)
             for _, v in vols:
                 ec_files.write_sorted_ecx(v._base + ".idx")
-            metrics.EC_ENCODE_BYTES.labels("fleet").inc(total)
+            metrics.EC_ENCODE_BYTES.labels(
+                stages.get("backend", "unknown")).inc(total)
             return rep
 
         def settle_failed():
@@ -1267,7 +1269,7 @@ class VolumeServer:
         return web.json_response(
             {"converted": [vid for vid, _ in vols], "skipped": skipped,
              "bytes": report["bytes"], "units": report["units"],
-             "wall_s": report["wall_s"]})
+             "devices": report["devices"], "wall_s": report["wall_s"]})
 
     async def handle_ec_progress(self, req: web.Request) -> web.Response:
         """Observability for a long-running encode (weak spot the reference

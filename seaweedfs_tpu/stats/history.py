@@ -597,8 +597,8 @@ _DEFAULT_ALERT_RULES = (
     "interference_high=threshold,series=weedtpu_interference_index,"
     "agg=max,window=120,op=gt,value=0.5,for=30;"
     # tile-drift sentinel (stats/pipeline.py): the pinned Pallas tile no
-    # longer wins its own micro-sweep by >10% — the r05 failure mode
-    # (336 -> 108 GB/s off a stale pin) pages instead of shipping.  The
+    # longer wins its own micro-sweep by >10% — a stale pin pages
+    # instead of shipping.  The
     # rule watches the EXCESS series (best/pinned - 1) rather than the
     # companion ratio gauge: federated gauges sum across nodes, and a
     # healthy fleet must sum to zero at any size

@@ -5,10 +5,10 @@ Every overlapped pipeline in the data path (the EC encode/rebuild
 engines in storage/ec/ec_files.py, the multi-volume fleet conversion in
 ops/fleet_convert.py, the EC degraded-read engine) already accumulated
 ad-hoc per-stage wall-second dicts for bench.py — visible only on bench
-day.  The r05 regression (336 -> 108 GB/s, a stale pinned Pallas tile
-nobody re-measured) shipped precisely because production paths had no
-always-on answer to "which stage bounds throughput and how far from the
-hardware roofline are we?".  This module is that answer:
+day: production paths had no always-on answer to "which stage bounds
+throughput and how far from the hardware roofline are we?".  This
+module is that answer (none of its device numbers is measured on
+current code — PERF.md):
 
 - **PipelineJob** — the shared stage-accounting primitive: per-stage
   busy seconds (doing work), blocked seconds (backpressured on a
@@ -31,9 +31,8 @@ hardware roofline are we?".  This module is that answer:
   ops/pallas_gf.save_tile_pin) with a cheap background micro-sweep on
   codec-hosting servers.  ``weedtpu_tile_drift`` reports the fractional
   advantage of the best candidate over the pin (0 = pin still wins);
-  the default ``tile_pin_stale`` alert rule fires past 0.1 — the r05
-  failure mode becomes a page carrying the sweep table instead of a
-  silent 3x loss.  (The alert watches the *excess* series rather than
+  the default ``tile_pin_stale`` alert rule fires past 0.1 — a pin
+  that stops winning becomes a page carrying the sweep table.  (The alert watches the *excess* series rather than
   the companion ``weedtpu_tile_drift_ratio`` because federated gauges
   sum across nodes: a healthy fleet sums zeros at any size.)
 
@@ -612,6 +611,8 @@ class TileDriftSentinel:
         pinned_tile = int(pin["tile"])
         pinned_now = sweep.get(pinned_tile) or \
             sweep.get(str(pinned_tile)) or 0.0
+        if not isinstance(pinned_now, (int, float)):
+            pinned_now = 0.0  # "failed: <error>": shown in the table below
         best_tile, best = pinned_tile, pinned_now
         for t, v in sweep.items():
             if isinstance(v, (int, float)) and v > best:
@@ -705,7 +706,10 @@ def local_snapshot(limit: int = 16) -> dict:
     from seaweedfs_tpu.stats import profile as _profile
     out = {"id": TRACKER_ID, "enabled": perf_obs_enabled(),
            "jobs": jobs_snapshot(limit),
-           "roofline": _profile.roofline_snapshot()}
+           "roofline": _profile.roofline_snapshot(),
+           # what each codec selection resolved to (backend, device,
+           # class, interpret, tile); empty until a codec was built
+           "codecs": _profile.codecs_snapshot()}
     tile = sentinel_status()
     if tile is not None:
         out["tile"] = tile

@@ -332,11 +332,12 @@ def ceilings() -> dict[str, float]:
                     continue
                 if gbps > 0:
                     out[k.strip()] = gbps
-        # only consult the pin where jax is already resident: importing
-        # pallas_gf would otherwise drag the whole jax runtime into
-        # processes that deliberately never load it (the cpu-native
-        # bench path, lean host-codec servers)
-        if "device" not in out and "jax" in sys.modules:
+        # only consult the pin in a process whose codec selection already
+        # initialised a backend: chip_fingerprint() would, and a
+        # host-codec process sharing the machine with the volume server
+        # that owns the chip must never do that (`jax` being imported
+        # says nothing — ops.native_codec imports it)
+        if "device" not in out and jax_backend_noted():
             try:
                 from seaweedfs_tpu.ops import pallas_gf
                 pin = pallas_gf.load_tile_pin()
@@ -349,6 +350,41 @@ def ceilings() -> dict[str, float]:
         out.update(_ceilings_set)
         _ceilings_cache = (now, out)
         return dict(out)
+
+
+# -- what the codec selection resolved to ---------------------------------
+
+_codecs_noted: dict[tuple, dict] = {}
+_codecs_lock = threading.Lock()
+
+
+def note_codec(key: tuple, make_info) -> None:
+    """Record, and log the first time, what one codec selection resolved
+    to in this process (ec_files.note_resolved builds the block with
+    ops/dispatch.describe — lazily, `key` is checked first).  /perf
+    carries the blocks, so "which backend is this volume server really
+    encoding on" has an answer that does not depend on reading the
+    environment."""
+    if key in _codecs_noted:  # the per-read path: one dict probe
+        return
+    with _codecs_lock:
+        if key in _codecs_noted:
+            return
+        info = _codecs_noted[key] = make_info()
+    import logging
+    logging.getLogger("ec").info("ec codec resolved: %s", info)
+
+
+def codecs_snapshot() -> list[dict]:
+    with _codecs_lock:
+        return [dict(v) for v in _codecs_noted.values()]
+
+
+def jax_backend_noted() -> bool:
+    """True once a codec selection in this process has initialised a JAX
+    backend — the only processes allowed to ask JAX about its devices."""
+    with _codecs_lock:
+        return any("platform" in v for v in _codecs_noted.values())
 
 
 # which (resource, seconds-field, bytes-field) pairs a kernel row feeds:
@@ -381,7 +417,8 @@ def roofline_snapshot() -> dict:
                 continue
             gbps = nbytes / 1e9 / secs
             row = {"kernel": kernel, "backend": backend,
-                   "resource": resource, "busy_s": round(secs, 4),
+                   "resource": resource, "calls": int(r["calls"]),
+                   "busy_s": round(secs, 4),
                    "gbytes": round(nbytes / 1e9, 4),
                    "achieved_gbps": round(gbps, 3)}
             c = ceil.get(resource)

@@ -47,11 +47,33 @@ def _get_codec(kind: str | None = None, tag: str | None = None):
 
     auto (default): Pallas on TPU, native C++ AVX2 on CPU hosts, XLA
     bit-sliced otherwise.  Override with WEEDTPU_EC_CODEC=tpu|jax|cpp|numpy.
+    `tpu` means the compiled kernel: on a host with no chip it raises.
 
     `tag` picks the CODE (ops/codecs grammar: rs_10_4 / lrc_10_2_2 /
     msr_9_16); non-RS families build through the codec registry, which
-    reuses the same backend kinds over their matrices."""
+    reuses the same backend kinds over their matrices.
+
+    What each selection resolved to is logged once and rides /perf
+    (stats/profile.note_codec)."""
     kind = kind or os.environ.get("WEEDTPU_EC_CODEC", "auto")
+    codec = _select_codec(kind, tag)
+    note_resolved(kind, tag, codec)
+    return codec
+
+
+def note_resolved(kind: str, tag: str | None, codec) -> None:
+    """Report one selection (this module's and fleet_codec's).  Keyed so
+    the describe() behind it runs once per distinct resolution, not per
+    degraded-read batch; `auto` has asked JAX for its backend already,
+    so its block may name the platform even when a host codec won."""
+    tag = tag or f"rs_{layout.DATA_SHARDS}_{layout.PARITY_SHARDS}"
+    _profile.note_codec(
+        (kind, tag, _backend_name(codec)),
+        lambda: {"asked": kind, "tag": tag,
+                 **_describe(codec, jax_live=kind == "auto")})
+
+
+def _select_codec(kind: str, tag: str | None):
     if tag is not None:
         from seaweedfs_tpu.ops import codecs as _codecs
         spec = _codecs.parse_tag(tag)
@@ -93,6 +115,8 @@ from seaweedfs_tpu.stats import netflow as _netflow  # noqa: E402
 from seaweedfs_tpu.stats import pipeline as _pipeline  # noqa: E402
 from seaweedfs_tpu.stats import profile as _profile  # noqa: E402
 from seaweedfs_tpu.ops.dispatch import (  # noqa: E402
+    backend_name as _backend_name,
+    describe as _describe,
     dispatch_parity as _dispatch_parity,
     materialize as _materialize,
     reconstruct_batch as _reconstruct_batch,
@@ -356,6 +380,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     # on a 2-core host, and wider hosts only widen the gap
     use_serial = native_host and pipe == "serial"
     stats["mode"] = "host-serial" if use_serial else "pipelined"
+    stats["backend"] = _backend_name(codec)
 
     t_wall = time.perf_counter()
     import mmap as mmap_mod

@@ -125,3 +125,25 @@ def test_ec_files_cpp_codec_roundtrip(tmp_path, monkeypatch):
         with open(base + layout.to_ext(10 + pi), "rb") as f:
             got = np.frombuffer(f.read(10_000), dtype=np.uint8)
         assert (got == parity[pi]).all(), pi
+
+
+def test_library_is_tied_to_the_cpu_that_built_it(tmp_path, monkeypatch):
+    """-march=native makes a build valid only on the CPU that made it, and
+    the chip tool copies the tree (artefacts included) to another machine:
+    the file name carries a key over source + flags + CPU features, so a
+    library from elsewhere is never the one dlopened — it gets rebuilt."""
+    import os
+    import shutil
+    here = native._so_name()
+    assert os.path.basename(native._build()) == here
+    monkeypatch.setattr(native, "_cpu_features", lambda: "another cpu")
+    foreign = native._so_name()
+    assert foreign != here
+    # on "the other machine" the copied library is ignored and replaced
+    ndir = tmp_path / "native"
+    shutil.copytree(native._NATIVE_DIR, ndir)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(ndir))
+    assert (ndir / here).exists()
+    built = native._build()
+    assert os.path.basename(built) == foreign and os.path.exists(built)
+    assert not (ndir / here).exists()

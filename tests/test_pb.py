@@ -72,3 +72,23 @@ def test_json_fallback_when_forced(tmp_path, monkeypatch):
         assert c.master.topo.to_dict()["nodes"]
     finally:
         c.stop()
+
+
+def test_generated_module_from_another_tree_is_regenerated():
+    """The chip tool copies the tree as it stands, generated module
+    included: one whose first line does not carry this schema's and this
+    protobuf runtime's key is regenerated, never imported."""
+    key = pb._gen_key()
+    with open(pb._GEN) as f:
+        assert f.readline() == key
+        body = f.read()
+    try:
+        with open(pb._GEN, "w") as f:
+            f.write("# weedtpu-key: 0000000000000000\nraise ImportError\n")
+        pb._generate()
+        with open(pb._GEN) as f:
+            assert f.readline() == key
+            assert f.read() == body
+    finally:
+        with open(pb._GEN, "w") as f:
+            f.write(key + body)

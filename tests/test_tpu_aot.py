@@ -1,0 +1,149 @@
+"""The served kernels compile for a TPU v5e — checked without a chip.
+
+`jax.experimental.topologies` describes a `v5e:2x2` host to the installed
+libtpu, and `jit(...).lower(...).compile()` against its devices runs the
+real Mosaic + XLA:TPU pipeline.  Tier-1 otherwise only ever sees the XLA
+body (CPU `auto`) or the Pallas interpreter, so a kernel the compiler
+refuses, or a mesh wrapper that fails to trace around the Pallas body,
+would first show on the chip.  Compilation is all this proves: execution
+and numerics at real size are `chip_smoke.py`'s.
+
+Plus the two selection rules a CPU run can check: `WEEDTPU_EC_CODEC=tpu`
+never means the interpreter, and a host-codec process never initialises a
+JAX backend.
+"""
+
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from seaweedfs_tpu.models import rs
+from seaweedfs_tpu.ops import pallas_gf
+from seaweedfs_tpu.parallel import mesh as pmesh
+
+MIB = 1024 * 1024
+TILE = pallas_gf.TPU_TILE
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a v5e:2x2 topology (no chip needed)."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"no TPU compiler here: {e}")
+    assert len(topo.devices) == 4
+    return topo.devices
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _lifted(C, sharding):
+    """(spec of C's plane-major lift, kpad), by the mesh seam's own rule."""
+    seam = pmesh._ApplyKernel("pallas", TILE)
+    bm = seam.lift(C)
+    return _spec(bm.shape, bm.dtype, sharding), seam._kpad(C.shape[1])
+
+
+@pytest.mark.parametrize("wanted", [None, [3]],
+                         ids=["encode_10_4", "decode_1_row"])
+def test_gf_apply_compiles_for_v5e(v5e, wanted):
+    """The served single-chip shape, [10, 1 MiB] at TPU_TILE: the parity
+    matrix and a one-row decode matrix (a degraded read of one shard)."""
+    code = rs.get_code(10, 4)
+    C = code.parity_matrix if wanted is None else code.decode_matrix(
+        [i for i in range(14) if i not in wanted][:10], wanted)
+    one = SingleDeviceSharding(v5e[0])
+    bm, kpad = _lifted(C, one)
+    m, k = C.shape
+    compiled = pallas_gf._gf_apply.lower(
+        bm, _spec((k, MIB), jnp.uint8, one),
+        k=k, m=m, kpad=kpad, tile=TILE, interpret=False).compile()
+    assert compiled is not None
+
+
+def test_gf_apply_batch_compiles_for_v5e(v5e):
+    """The fleet-conversion unit batch, U = 4."""
+    code = rs.get_code(10, 4)
+    one = SingleDeviceSharding(v5e[0])
+    bm, kpad = _lifted(code.parity_matrix, one)
+    pallas_gf._gf_apply_batch.lower(
+        bm, _spec((4, 10, MIB), jnp.uint8, one),
+        k=10, m=4, kpad=kpad, tile=TILE, interpret=False).compile()
+
+
+def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
+    """The mesh wrappers around the Pallas body, over all four devices:
+    shard_map checks varying-manual-axes, and a pallas_call whose
+    out_shape carries no vma fails that check at trace time."""
+    code = rs.get_code(10, 4)
+    fleet_mesh = Mesh(np.array(v5e), ("unit",))
+    enc = pmesh.FleetUnitEncoder(code, fleet_mesh, kernel="pallas",
+                                 tile=TILE)
+    assert enc.kernel.kind == "pallas" and enc.n_devices == 4
+    bm = _spec(enc.parity_bits.shape, jnp.int8,
+               NamedSharding(fleet_mesh, P()))
+    compiled = enc._encode.lower(
+        bm, _spec((8, 10, MIB), jnp.uint8, enc.in_sharding)).compile()
+    # [U, k, B] in and [U, m, B] out differ in shape: nothing to alias,
+    # which is why the encoder donates nothing
+    assert "input_output_alias" not in compiled.as_text()
+
+    col_mesh = Mesh(np.array(v5e), ("data",))
+    col = pmesh.ShardedRSEncoder(code, col_mesh, kernel="pallas", tile=TILE)
+    col._apply_cols.lower(
+        _spec(col.parity_bits.shape, jnp.int8,
+              NamedSharding(col_mesh, P())),
+        _spec((10, 4 * MIB), jnp.uint8,
+              NamedSharding(col_mesh, P(None, "data")))).compile()
+
+
+def test_tpu_codec_off_tpu_raises_instead_of_interpreting(monkeypatch):
+    from seaweedfs_tpu.storage.ec import ec_files
+    assert jax.default_backend() == "cpu"
+    pallas_gf._get_codec_cached.cache_clear()
+    monkeypatch.setenv("WEEDTPU_EC_CODEC", "tpu")
+    with pytest.raises(RuntimeError, match="backend found is 'cpu'"):
+        ec_files._get_codec()
+    # the interpreter stays reachable, by name only
+    assert pallas_gf.PallasRSCodec(rs.get_code(10, 4), tile=256,
+                                   interpret=True).interpret is True
+
+
+def test_host_codec_process_initialises_no_jax_backend():
+    """fleet_codec() under WEEDTPU_EC_CODEC=cpp, the /perf snapshot and
+    the roofline ceilings, in a fresh process: jax gets imported
+    (ops.native_codec does) but no backend may come up — on a chip host
+    that process would take the chip from the volume server."""
+    code = (
+        "import os\n"
+        "os.environ['WEEDTPU_EC_CODEC'] = 'cpp'\n"
+        "os.environ.pop('WEEDTPU_CONVERT_CODEC', None)\n"
+        "from seaweedfs_tpu.ops import fleet_convert\n"
+        "from seaweedfs_tpu.stats import pipeline, profile\n"
+        "codec = fleet_convert.fleet_codec()\n"
+        "assert type(codec).__name__ == 'NativeRSCodec', codec\n"
+        "snap = pipeline.local_snapshot()\n"
+        "assert snap['codecs'] == [{'asked': 'cpp', 'tag': 'rs_10_4',\n"
+        "                           'codec': 'NativeRSCodec'}], snap\n"
+        "profile.ceilings()\n"
+        "import sys\n"
+        "from jax._src import xla_bridge\n"
+        "assert 'jax' in sys.modules\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    from seaweedfs_tpu import native
+    if not native.available():
+        pytest.skip("no native codec here")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
