@@ -17,10 +17,24 @@ survivors — are the default when the hook is absent).
 from __future__ import annotations
 
 import collections
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+
+
+def named_jit(name: str, **jit_kwargs):
+    """`jax.jit` of a function under an explicit name: the XLA module —
+    and its event on a device trace's `XLA Modules` line — is
+    `jit_<name>` whatever the Python function is called."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def named(*args, **kwargs):
+            return fn(*args, **kwargs)
+        named.__name__ = named.__qualname__ = name
+        return jax.jit(named, **jit_kwargs)
+    return deco
 
 
 def decode_cache_cap() -> int:

@@ -10,21 +10,82 @@ whose d2h transfer is the sync point.  Centralising the isinstance
 fan-out here keeps the storage layer free of backend imports and gives
 the overlapped pipeline one seam to time the sync point through.
 
-Every call also feeds the per-kernel profile (stats/profile.KERNELS):
-host wall, H2D conversion, `block_until_ready` device time, and D2H
-transfer are recorded separately per entry point, so a 225 ms `encode`
-span finally decomposes into matmul vs transfer vs host codec time at
-/debug/pprof?format=table.
+This is also the one place that knows H2D from enqueue from wait from
+copy, so it is where the time is cut.  Round every device dispatch the
+seam opens four stages (stats/pipeline.Stage), booked to the calling
+engine's job and named `codec.<stage>` on spans and profiler annotations:
+
+  h2d          the calling thread's time in `jnp.asarray` / the mesh
+               `place`: staging and the put.  Not the transfer: the DMA
+               runs on after the call returns and shows in a profiler
+               trace inside the `codec.h2d` annotation
+  dispatch     the enqueue (and, for a shape seen first, tracing and
+               compilation); a host codec's whole computation
+  device_wait  blocked in `block_until_ready`: the queued transfers and
+               the kernel
+  d2h_copy     `np.asarray` once the array is ready
+
+No synchronisation is added for the sake of measurement: a
+`block_until_ready` stands only directly before a copy of the same array,
+which would block on it anyway.  The same four figures feed the
+per-kernel profile (stats/profile.KERNELS: `h2d_s`, `wall_s`, `device_s`,
+`d2h_s` per entry point, at /debug/pprof?format=table), and each entry
+point marks itself on its thread so that compilations are counted against
+it (stats/profile.codec_entry).
 """
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
-from seaweedfs_tpu.stats import trace
-from seaweedfs_tpu.stats.profile import KERNELS
+from seaweedfs_tpu.stats import pipeline as _pipeline
+from seaweedfs_tpu.stats.profile import KERNELS, codec_entry
+
+
+def _stage(job, name: str, unit=None, **attrs):
+    """One seam stage, booked to the calling engine's `job` (to nothing
+    for a caller that runs none)."""
+    return (job or _pipeline.UNTRACKED).stage(
+        name, unit=unit, span="codec." + name, **attrs)
+
+
+def _host_call(job, unit, kernel: str, nbytes: int, fn, *args, **kwargs):
+    """A host codec computes where a device codec enqueues: its whole
+    call is the `dispatch` stage."""
+    with _stage(job, "dispatch", unit, backend="host", kernel=kernel,
+                bytes=nbytes) as st:
+        out = fn(*args, **kwargs)
+    KERNELS.record(kernel, "host", wall_s=st.seconds, nbytes=nbytes)
+    return out
+
+
+def _device_call(job, unit, kernel: str, nbytes: int, put, run, **attrs):
+    """The first two stages of a device dispatch: `put()` sends the inputs
+    up (`h2d`), `run(placed)` enqueues the work on them (`dispatch`).  The
+    result comes back un-materialised; `_to_host` holds the other two."""
+    with _stage(job, "h2d", unit, bytes=nbytes) as h2d:
+        placed = put()
+    with _stage(job, "dispatch", unit, backend="device", kernel=kernel,
+                bytes=nbytes, **attrs) as disp:
+        out = run(placed)
+    KERNELS.record(kernel, "device", wall_s=disp.seconds,
+                   h2d_s=h2d.seconds, h2d_bytes=nbytes, nbytes=nbytes)
+    return out
+
+
+def _to_host(arrays: list, job, unit, kernel: str) -> list[np.ndarray]:
+    """The sync point of a device dispatch: wait for `arrays`, then copy
+    them back."""
+    with _stage(job, "device_wait", unit, kernel=kernel) as wait:
+        for a in arrays:
+            if not isinstance(a, np.ndarray):  # MSRFileCodec re-interleaves
+                a.block_until_ready()          # on the host already
+    with _stage(job, "d2h_copy", unit, kernel=kernel) as copy:
+        host = [np.asarray(a) for a in arrays]
+    KERNELS.record(kernel, "device", calls=0, device_s=wait.seconds,
+                   d2h_s=copy.seconds,
+                   d2h_bytes=sum(h.nbytes for h in host))
+    return host
 
 
 def _host_classes():
@@ -84,136 +145,86 @@ def describe(codec, jax_live: bool = False) -> dict:
     return info
 
 
-def dispatch_parity(codec, batch: np.ndarray):
+@codec_entry("encode_parity")
+def dispatch_parity(codec, batch: np.ndarray, job=None, unit=None):
     """Dispatch [k, B] -> [m, B] parity. JAX backends return the device
     array WITHOUT materialising it; host backends compute eagerly."""
+    nbytes = batch.nbytes
     if _is_host(codec):
-        with trace.span("codec.dispatch_parity", backend="host",
-                        bytes=batch.nbytes), \
-                KERNELS.timed("encode_parity", nbytes=batch.nbytes):
-            return codec.encode_parity(batch)
+        return _host_call(job, unit, "encode_parity", nbytes,
+                          codec.encode_parity, batch)
     if _is_numpy_ref(codec):
-        with trace.span("codec.dispatch_parity", backend="host",
-                        bytes=batch.nbytes), \
-                KERNELS.timed("encode_parity", nbytes=batch.nbytes):
-            return codec.encode_numpy(batch)[codec.k:]
+        return _host_call(job, unit, "encode_parity", nbytes,
+                          lambda: codec.encode_numpy(batch)[codec.k:])
     import jax.numpy as jnp
-    # a device dispatch returns un-materialised: this span times only the
-    # h2d + async enqueue — the sync cost shows up under codec.d2h
-    with trace.span("codec.dispatch_parity", backend="device",
-                    bytes=batch.nbytes):
-        t0 = time.perf_counter()
-        dev = jnp.asarray(batch)
-        t1 = time.perf_counter()
-        out = codec.encode_parity(dev)
-        KERNELS.record("encode_parity", "device",
-                       wall_s=time.perf_counter() - t1,
-                       h2d_s=t1 - t0, h2d_bytes=batch.nbytes,
-                       nbytes=batch.nbytes)
-        return out
+    # returns un-materialised: the sync cost shows up in materialize()
+    return _device_call(job, unit, "encode_parity", nbytes,
+                        lambda: jnp.asarray(batch), codec.encode_parity)
 
 
-def materialize(parity, kernel: str = "encode_parity") -> np.ndarray:
+def materialize(parity, kernel: str = "encode_parity", job=None,
+                unit=None) -> np.ndarray:
     """Sync point of an async dispatch: host backends already returned
-    numpy; device arrays `block_until_ready` (device time, attributed to
-    `kernel`) and then transfer d2h here."""
+    numpy; device arrays are waited for and then copied back here, both
+    attributed to `kernel`."""
     if isinstance(parity, np.ndarray):
         return parity
-    nbytes = getattr(parity, "nbytes", 0)
-    with trace.span("codec.d2h", bytes=nbytes):
-        t0 = time.perf_counter()
-        if hasattr(parity, "block_until_ready"):
-            parity.block_until_ready()
-        t1 = time.perf_counter()
-        out = np.asarray(parity)
-        KERNELS.record(kernel, "device", calls=0,
-                       device_s=t1 - t0,
-                       d2h_s=time.perf_counter() - t1, d2h_bytes=nbytes)
-        return out
+    return _to_host([parity], job, unit, kernel)[0]
 
 
-def dispatch_parity_batch(codec, units, placed=None):
+@codec_entry("fleet_encode")
+def dispatch_parity_batch(codec, units, job=None, unit=None):
     """Dispatch a [U, k, B] unit batch -> [U, m, B] parity in ONE kernel
     launch — the fleet-conversion hot path (ops/fleet_convert.py).
 
-    `placed`, when given, is the already-device-resident (and, on a mesh,
-    unit-sharded) twin of the host batch `units`: the pipeline H2Ds
-    through the encoder's matched in_sharding up front so the dispatch
-    never reshards.  Host backends loop eagerly per unit (they have no
-    batch geometry to win; the pipeline's value there is the interleaved
-    I/O).  Device dispatches return un-materialised; `unit_parity_shards`
-    is the streaming sync point."""
+    A mesh encoder H2Ds through its matched in_sharding (`place`: each
+    chip pulls exactly its U/D units) so the dispatch never reshards.
+    Host backends loop eagerly per unit (they have no batch geometry to
+    win; the pipeline's value there is the interleaved I/O).  Device
+    dispatches return un-materialised; `unit_parity_shards` is the
+    streaming sync point."""
     nbytes = units.nbytes
-    if _is_host(codec) or _is_numpy_ref(codec):
-        with trace.span("codec.dispatch_parity_batch", backend="host",
-                        bytes=nbytes), \
-                KERNELS.timed("fleet_encode", nbytes=nbytes):
-            if _is_numpy_ref(codec):
-                return np.stack([codec.encode_numpy(units[u])[codec.k:]
-                                 for u in range(units.shape[0])], axis=0)
-            batched = getattr(codec, "encode_parity_batch", None)
-            if batched is not None:
-                return batched(units)
-            return np.stack([codec.encode_parity(units[u])
-                             for u in range(units.shape[0])], axis=0)
-    import jax.numpy as jnp
-    with trace.span("codec.dispatch_parity_batch", backend="device",
-                    bytes=nbytes):
-        t0 = time.perf_counter()
-        # the H2D is booked exactly once: by the mesh place() seam when
-        # one exists (whether the caller pre-placed or we place here),
-        # else by this record — double-booking would inflate the
-        # fleet_encode h2d roofline row 2x
-        booked_by_place = placed is not None
-        if placed is None:
-            place = getattr(codec, "place", None)
-            if place is not None:
-                placed = place(units)
-                booked_by_place = True
-            else:
-                placed = jnp.asarray(units)
-        t1 = time.perf_counter()
-        out = codec.encode_parity_batch(placed)
-        KERNELS.record("fleet_encode", "device",
-                       wall_s=time.perf_counter() - t1,
-                       h2d_s=0.0 if booked_by_place else t1 - t0,
-                       h2d_bytes=0.0 if booked_by_place else nbytes,
-                       nbytes=nbytes)
-        return out
+    if _is_numpy_ref(codec):
+        def batched(us):
+            return np.stack([codec.encode_numpy(u)[codec.k:] for u in us])
+    elif _is_host(codec):
+        batched = getattr(codec, "encode_parity_batch", None) or (
+            lambda us: np.stack([codec.encode_parity(u) for u in us]))
+    else:
+        import jax.numpy as jnp
+        return _device_call(job, unit, "fleet_encode", nbytes,
+                            lambda: getattr(codec, "place",
+                                            jnp.asarray)(units),
+                            codec.encode_parity_batch)
+    return _host_call(job, unit, "fleet_encode", nbytes, batched, units)
 
 
-def unit_parity_shards(parity, kernel: str = "fleet_encode"):
+def unit_parity_shards(parity, kernel: str = "fleet_encode", job=None,
+                       unit=None):
     """Streaming sync point of a batched dispatch: yield
     (unit_start, unit_stop, np.ndarray) per device-local block as each
     block's D2H completes — on a mesh the drain hands shards to their
     writers as they come off each chip instead of waiting for a full
-    gather.  Host arrays yield one block immediately."""
+    gather.  Host arrays yield one block immediately.  No stage stays
+    open across a yield: what the consumer does with a block is its own."""
     if isinstance(parity, np.ndarray):
         yield 0, parity.shape[0], parity
         return
-    nbytes = getattr(parity, "nbytes", 0)
-    with trace.span("codec.d2h", bytes=nbytes, streamed=True):
-        t0 = time.perf_counter()
-        if hasattr(parity, "block_until_ready"):
-            parity.block_until_ready()
-        t1 = time.perf_counter()
-        KERNELS.record(kernel, "device", calls=0, device_s=t1 - t0)
-        shards = getattr(parity, "addressable_shards", None)
-        if not shards:
-            out = np.asarray(parity)
-            KERNELS.record(kernel, "device", calls=0,
-                           d2h_s=time.perf_counter() - t1,
-                           d2h_bytes=out.nbytes)
-            yield 0, out.shape[0], out
-            return
-        for sh in sorted(shards, key=lambda s: s.index[0].start or 0):
-            start = sh.index[0].start or 0
-            t2 = time.perf_counter()
+    shards = getattr(parity, "addressable_shards", None)
+    if not shards:
+        out, = _to_host([parity], job, unit, kernel)
+        yield 0, out.shape[0], out
+        return
+    with _stage(job, "device_wait", unit, kernel=kernel) as wait:
+        parity.block_until_ready()
+    KERNELS.record(kernel, "device", calls=0, device_s=wait.seconds)
+    for sh in sorted(shards, key=lambda s: s.index[0].start or 0):
+        start = int(sh.index[0].start or 0)
+        with _stage(job, "d2h_copy", unit, kernel=kernel) as copy:
             data = np.asarray(sh.data)
-            KERNELS.record(kernel, "device", calls=0,
-                           d2h_s=time.perf_counter() - t2,
-                           d2h_bytes=data.nbytes)
-            yield int(start), int(start) + data.shape[0], data
+        KERNELS.record(kernel, "device", calls=0, d2h_s=copy.seconds,
+                       d2h_bytes=data.nbytes)
+        yield start, start + data.shape[0], data
 
 
 def parity_mismatch(codec, data: np.ndarray,
@@ -243,28 +254,25 @@ _APPLY_CACHE: dict = {}
 _APPLY_CACHE_MAX = 64
 
 
-def apply_matrix(codec, C: np.ndarray, stack: np.ndarray) -> np.ndarray:
+@codec_entry("repair_partial")
+def apply_matrix(codec, C: np.ndarray, stack: np.ndarray, job=None,
+                 unit=None) -> np.ndarray:
     """out[r, n] = C[r, j] @ stack[j, n] over GF(2^8) through the same
     backend seam as encode/reconstruct — the partial-sum kernel of the
     reduced-read repair path (profiled as `repair_partial`)."""
     C = np.ascontiguousarray(C, dtype=np.uint8)
     nbytes = stack.nbytes
+    factory = getattr(codec, "_factory", None)
     if _is_host(codec):
         from seaweedfs_tpu import native
-        with trace.span("codec.apply_matrix", backend="host",
-                        bytes=nbytes), \
-                KERNELS.timed("repair_partial", nbytes=nbytes):
-            if native.available():
-                return native.gf_matmul(C, np.ascontiguousarray(stack))
-            from seaweedfs_tpu.ops import gf
-            return gf.gf_matmul(C, stack)
-    factory = getattr(codec, "_factory", None)
-    if _is_numpy_ref(codec) or factory is None:
+        if native.available():
+            return _host_call(job, unit, "repair_partial", nbytes,
+                              native.gf_matmul, C,
+                              np.ascontiguousarray(stack))
+    if _is_host(codec) or _is_numpy_ref(codec) or factory is None:
         from seaweedfs_tpu.ops import gf
-        with trace.span("codec.apply_matrix", backend="host",
-                        bytes=nbytes), \
-                KERNELS.timed("repair_partial", nbytes=nbytes):
-            return gf.gf_matmul(C, stack)
+        return _host_call(job, unit, "repair_partial", nbytes,
+                          gf.gf_matmul, C, stack)
     key = (id(codec), C.shape, C.tobytes())
     mat = _APPLY_CACHE.get(key)
     if mat is None:
@@ -272,47 +280,30 @@ def apply_matrix(codec, C: np.ndarray, stack: np.ndarray) -> np.ndarray:
             _APPLY_CACHE.clear()
         mat = _APPLY_CACHE[key] = factory(C)
     import jax.numpy as jnp
-    with trace.span("codec.apply_matrix", backend="device", bytes=nbytes):
-        t0 = time.perf_counter()
-        dev = jnp.asarray(stack)
-        t1 = time.perf_counter()
-        out = mat(dev)
-        t2 = time.perf_counter()
-        host = np.asarray(out)
-        KERNELS.record("repair_partial", "device",
-                       wall_s=t2 - t1, h2d_s=t1 - t0, h2d_bytes=nbytes,
-                       d2h_s=time.perf_counter() - t2,
-                       d2h_bytes=host.nbytes, nbytes=nbytes)
-        return host
+    out = _device_call(job, unit, "repair_partial", nbytes,
+                       lambda: jnp.asarray(stack), mat)
+    return _to_host([out], job, unit, "repair_partial")[0]
 
 
+@codec_entry("reconstruct")
 def reconstruct_batch(codec, shards: dict[int, np.ndarray],
-                      wanted: list[int]) -> dict[int, np.ndarray]:
+                      wanted: list[int], job=None,
+                      unit=None) -> dict[int, np.ndarray]:
     """Rebuild `wanted` shard rows from >=k survivor rows (host bytes
     in/out)."""
     nbytes = sum(v.nbytes for v in shards.values())
     if _is_host(codec):
-        with trace.span("codec.reconstruct", backend="host",
-                        bytes=nbytes, wanted=len(wanted)), \
-                KERNELS.timed("reconstruct", nbytes=nbytes):
-            return codec.reconstruct(shards, wanted=wanted)
+        return _host_call(job, unit, "reconstruct", nbytes,
+                          codec.reconstruct, shards, wanted=wanted)
     if _is_numpy_ref(codec):
-        with trace.span("codec.reconstruct", backend="host",
-                        bytes=nbytes, wanted=len(wanted)), \
-                KERNELS.timed("reconstruct", nbytes=nbytes):
-            return codec.reconstruct_numpy(shards, wanted=wanted)
+        return _host_call(job, unit, "reconstruct", nbytes,
+                          codec.reconstruct_numpy, shards, wanted=wanted)
     import jax.numpy as jnp
-    with trace.span("codec.reconstruct", backend="device",
-                    bytes=nbytes, wanted=len(wanted)):
-        t0 = time.perf_counter()
-        dev = {i: jnp.asarray(v) for i, v in shards.items()}
-        t1 = time.perf_counter()
-        out = codec.reconstruct(dev, wanted=wanted)
-        t2 = time.perf_counter()
-        host = {i: np.asarray(v) for i, v in out.items()}
-        KERNELS.record("reconstruct", "device",
-                       wall_s=t2 - t1, h2d_s=t1 - t0, h2d_bytes=nbytes,
-                       d2h_s=time.perf_counter() - t2,
-                       d2h_bytes=sum(v.nbytes for v in host.values()),
-                       nbytes=nbytes)
-        return host
+    out = _device_call(
+        job, unit, "reconstruct", nbytes,
+        lambda: {i: jnp.asarray(v) for i, v in shards.items()},
+        lambda dev: codec.reconstruct(dev, wanted=wanted),
+        wanted=len(wanted))
+    ids = list(out)
+    return dict(zip(ids, _to_host([out[i] for i in ids], job, unit,
+                                  "reconstruct")))

@@ -51,8 +51,8 @@ from seaweedfs_tpu.stats import netflow as _netflow
 from seaweedfs_tpu.stats import pipeline as _pipeline
 from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
-    DEFAULT_BATCH, EncodeCancelled, _book_stage_bytes, _iter_units,
-    _map_readonly, _ShardFlusher, _ShardWriterPool, _Timer,
+    DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
+    _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
     _unit_coverage, _unit_steps, overlap_fraction, write_vif)
 
 
@@ -98,7 +98,7 @@ class _VolumeJob:
     its writer pool, and completion accounting."""
 
     def __init__(self, base: str, dat_path: str | None, large_block: int,
-                 small_block: int, batch_size: int, stats: dict | None):
+                 small_block: int, batch_size: int, pjob):
         self.base = base
         self.dat_path = dat_path or base + ".dat"
         self.dat_size = os.path.getsize(self.dat_path)
@@ -119,9 +119,8 @@ class _VolumeJob:
             self.view = np.frombuffer(self.mm, dtype=np.uint8)
         k = layout.DATA_SHARDS
         self.writers = _ShardWriterPool(
-            self.out_fds, self.highwater, stats,
-            stage_key=lambda i: "write_data_s" if i < k
-            else "write_parity_s")
+            self.out_fds, self.highwater, pjob,
+            stage_of=lambda i: "write_data" if i < k else "write_parity")
         # two submission batchers, one per producer thread: the reader
         # ships data-shard copies, the drain ships parity rows — a
         # _ShardFlusher is single-producer (its per-shard job lists and
@@ -137,7 +136,7 @@ class _VolumeJob:
         self.units_skipped = 0   # written by the reader thread only
         self.done_bytes = 0
         self.committed = False
-        self._stats = stats
+        self._stats = pjob.stats
 
     def next_unit(self):
         try:
@@ -174,11 +173,9 @@ class _VolumeJob:
         for i, p in enumerate(self.tmp_paths):
             os.replace(p, self.base + layout.to_ext(i))
         self.committed = True
-        if self._stats is not None:
-            # callers that must react per-volume (the volume server's
-            # freeze bookkeeping) see commits even when a LATER volume
-            # fails the run
-            self._stats.setdefault("committed_bases", []).append(self.base)
+        # callers that must react per-volume (the volume server's freeze
+        # bookkeeping) see commits even when a LATER volume fails the run
+        self._stats.setdefault("committed_bases", []).append(self.base)
 
     def abort(self) -> None:
         """Failure path: drop fds and every .tmp so no partial shard set
@@ -253,8 +250,19 @@ def convert_volumes(bases: list[str], *,
     flow_cls = _netflow.current_class() or "convert"
     _flow_token = _netflow.set_class(flow_cls)
     t_wall = time.perf_counter()
-    jobs = [_VolumeJob(b, None, large_block, small_block, batch_size,
-                       stats) for b in bases]
+    # stages as the single-volume encode names them: the reader's `read`
+    # and `stall`, the writers' `write_data` and `write_parity`, and the
+    # dispatch seam's four, which add up to `encode` and `d2h`; a unit
+    # batch's index rides each as `unit`
+    pjob = _pipeline.track("fleet_convert", stats,
+                           meta={"volumes": len(bases), "unit_batch": U},
+                           span="ec.fleet", sums=ENCODE_SUMS)
+    try:
+        jobs = [_VolumeJob(b, None, large_block, small_block, batch_size,
+                           pjob) for b in bases]
+    except BaseException as e:  # a volume that cannot be opened: no run
+        pjob.finish(e)
+        raise
     stats["bytes"] = sum(j.dat_size for j in jobs)
 
     # one staging width covers every job (ragged tails zero-fill): pooled
@@ -275,14 +283,15 @@ def convert_volumes(bases: list[str], *,
         nonlocal done_total
         active = list(jobs)
         _netflow.set_class(flow_cls)
+        batch = 0
         try:
             while active and not errors:
                 if cancel is not None and cancel():
                     raise EncodeCancelled("fleet conversion cancelled")
-                with _Timer(stats, "stall_s"):
+                with pjob.blocked("stall", unit=batch):
                     buf = pool.get()
                 metas = []
-                with _Timer(stats, "read_s"):
+                with pjob.stage("read", unit=batch):
                     while len(metas) < U and active:
                         job = active[len(metas) % len(active)]
                         unit = job.next_unit()
@@ -321,7 +330,8 @@ def convert_volumes(bases: list[str], *,
                     if progress is not None:
                         progress(done_total)
                 if metas:
-                    q_read.put((buf, metas))
+                    q_read.put((batch, buf, metas))
+                    batch += 1
                 else:
                     pool.put(buf)
         except BaseException as e:
@@ -338,7 +348,7 @@ def convert_volumes(bases: list[str], *,
             item = q_disp.get()
             if item is None:
                 return
-            buf, metas, parity = item
+            batch, buf, metas, parity = item
             if failed or errors:
                 pool.put(buf)
                 continue
@@ -347,13 +357,9 @@ def convert_volumes(bases: list[str], *,
                 # submit) the moment its d2h lands, instead of waiting
                 # for a full gather — write_parity overlaps the d2h of
                 # the blocks still in flight
-                blocks = unit_parity_shards(parity)
                 released = False
-                while True:
-                    with _Timer(stats, "d2h_s"):
-                        item_blk = next(blocks, None)
-                    if item_blk is None:
-                        break
+                for a, b, block in unit_parity_shards(parity, job=pjob,
+                                                      unit=batch):
                     if not released:
                         # the first yield implies block_until_ready has
                         # returned: the device is done with the staging
@@ -361,7 +367,6 @@ def convert_volumes(bases: list[str], *,
                         # transferring
                         pool.put(buf)
                         released = True
-                    a, b, block = item_blk
                     touched = []
                     for u in range(a, min(b, len(metas))):
                         job, shard_off, step = metas[u]
@@ -386,8 +391,6 @@ def convert_volumes(bases: list[str], *,
 
     t_r = threading.Thread(target=reader, name="fleet-reader", daemon=True)
     t_d = threading.Thread(target=drain, name="fleet-drain", daemon=True)
-    pjob = _pipeline.track("fleet_convert", stats, stats["bytes"],
-                           meta={"volumes": len(jobs), "unit_batch": U})
     t_r.start()
     t_d.start()
     try:
@@ -400,13 +403,13 @@ def convert_volumes(bases: list[str], *,
             # deep q_disp means the drain/writers are
             pjob.queue("q_read", q_read.qsize(), depth)
             pjob.queue("q_disp", q_disp.qsize())
-            buf, metas = item
+            batch, buf, metas = item
             if errors:
                 pool.put(buf)
                 continue
             try:
-                with _Timer(stats, "encode_s"):
-                    parity = dispatch_parity_batch(codec, buf)
+                parity = dispatch_parity_batch(codec, buf, job=pjob,
+                                               unit=batch)
                 # how many devices the unit batch's parity lives on (0:
                 # a host codec returned numpy) — a mesh that silently ran
                 # on its first chip must show
@@ -414,7 +417,7 @@ def convert_volumes(bases: list[str], *,
                 if sharding is not None:
                     stats["devices"] = max(stats.get("devices", 0),
                                            len(sharding.device_set))
-                q_disp.put((buf, metas, parity))
+                q_disp.put((batch, buf, metas, parity))
             except BaseException as e:
                 errors.append(e)
                 pool.put(buf)
@@ -427,7 +430,7 @@ def convert_volumes(bases: list[str], *,
             except queue.Empty:
                 continue
             if item is not None:
-                pool.put(item[0])
+                pool.put(item[1])
         t_r.join()
         # empty volumes never enter the stream; commit them here, and on
         # any error roll every uncommitted volume back

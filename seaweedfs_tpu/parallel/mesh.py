@@ -40,17 +40,15 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from seaweedfs_tpu.ops import gf, gfmat_jax
+from seaweedfs_tpu.ops import codec_base, gf, gfmat_jax
 
 
-def _book_h2d(nbytes: float, secs: float,
-              kernel: str = "encode_parity") -> None:
-    """Book a mesh place() H2D into the kernel profile.  The pre-placed
-    paths bypass ops/dispatch's single-dispatch seam (which deliberately
-    skips re-booking a placed batch), so without this the device-link
-    totals — and the h2d roofline row — understate fleet traffic."""
+def _book_h2d(nbytes: float, secs: float) -> None:
+    """Book a place_columns() H2D into the kernel profile: the
+    column-placed path bypasses ops/dispatch, whose `h2d` stage books
+    every other put (FleetUnitEncoder.place among them)."""
     from seaweedfs_tpu.stats.profile import KERNELS
-    KERNELS.record(kernel, "device", calls=0,
+    KERNELS.record("encode_parity", "device", calls=0,
                    h2d_s=secs, h2d_bytes=nbytes)
 
 
@@ -305,9 +303,9 @@ class FleetUnitEncoder:
         self.kernel = _ApplyKernel(kernel, tile)
         self.parity_bits = self.kernel.lift(code.parity_matrix)
         self.in_sharding = NamedSharding(mesh, P(unit_axis))
-        batch_body = self.kernel.batch_body
-        self._encode = jax.jit(shard_map(
-            batch_body,
+        # the fleet program's name on a device trace: `jit_batch_body`
+        self._encode = codec_base.named_jit("batch_body")(shard_map(
+            self.kernel.batch_body,
             mesh=mesh, in_specs=(P(), P(unit_axis)),
             out_specs=P(unit_axis)))
 
@@ -323,11 +321,8 @@ class FleetUnitEncoder:
         no later reshard (this IS the encode's in_sharding)."""
         assert host_units.shape[0] % self.n_devices == 0, \
             (host_units.shape, self.n_devices)
-        t0 = time.perf_counter()
-        out = jax.device_put(host_units, self.in_sharding)
-        _book_h2d(host_units.nbytes, time.perf_counter() - t0,
-                  kernel="fleet_encode")
-        return out
+        # timed and booked by the caller: ops/dispatch's `h2d` stage
+        return jax.device_put(host_units, self.in_sharding)
 
     def encode_parity_batch(self, units: jax.Array) -> jax.Array:
         """[U, k, B] (device-resident, unit-sharded) -> [U, m, B] parity,

@@ -10,15 +10,26 @@ throughput and how far from the hardware roofline are we?".  This
 module is that answer (none of its device numbers is measured on
 current code — PERF.md):
 
-- **PipelineJob** — the shared stage-accounting primitive: per-stage
-  busy seconds (doing work), blocked seconds (backpressured on a
-  downstream ring/queue), bytes, items, and queue-depth high-water
-  marks, wrapped around the existing stats-dict contract so bench.py and
-  /admin/ec/progress keep their keys.  Finished jobs land in a bounded
-  ring; running jobs are observable live.  ``bottleneck()`` attributes
-  the run to the stage whose busy fraction bounds throughput and — when
-  a hardware ceiling for that stage's resource is known
-  (stats/profile.py ceilings) — how close to it the stage ran.
+- **Stage** (``job.stage(name)`` / ``job.blocked(name)``) — the EC
+  plane's ONE timing primitive.  One enter/exit books the stage's
+  seconds, bytes and items to its job (and the ``<stage>_s`` key of the
+  job's stats dict, the engines' published output), records a
+  stats/trace.py span when the ambient request is sampled, and opens a
+  ``jax.profiler.TraceAnnotation`` of the same name while — and only
+  while — a profiler session is open, so the program's stages land on
+  the host plane of the profiler's trace, on the device planes' clock.
+  Span names are dotted and stable: ``<job's span prefix>.<stage>``
+  (``ec.encode.read``), or ``codec.<stage>`` for the four stages the
+  dispatch seam (ops/dispatch.py) books to the calling job.
+
+- **PipelineJob** — stage accounting for one run: per-stage busy
+  seconds (doing work), blocked seconds (backpressured on a downstream
+  ring/queue), bytes, items, and queue-depth high-water marks.
+  Finished jobs land in a bounded ring; running jobs are observable
+  live.  ``bottleneck()`` attributes the run to the stage whose busy
+  fraction bounds throughput and — when a hardware ceiling for that
+  stage's resource is known (stats/profile.py ceilings) — how close to
+  it the stage ran.
 
 - **FlowAccount** — the continuous twin for long-lived engines (the EC
   read path): cumulative per-stage busy seconds/bytes whose counter
@@ -47,12 +58,14 @@ both.  ``WEEDTPU_PERF_OBS=0`` turns the whole plane off (the
 from __future__ import annotations
 
 import collections
-import contextlib
 import itertools
 import os
+import sys
 import threading
 import time
 import uuid
+
+from seaweedfs_tpu.stats import trace as _trace
 
 # -- knobs ----------------------------------------------------------------
 
@@ -109,41 +122,95 @@ STAGE_RESOURCE = {
 }
 
 
-class _StageTimer:
-    __slots__ = ("_job", "_stage", "_nbytes", "_items", "_blocked", "_t0")
+_trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
-    def __init__(self, job, stage, nbytes, items, blocked):
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is open
+    in this process, else None.  The session is the only switch.  Never
+    imports jax (a process that has not loaded it has no session) and
+    asks it nothing that could initialise a backend."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        if profiler is None:
+            return None
+        cls = _trace_annotation = profiler.TraceAnnotation
+    return cls if cls.is_enabled() else None
+
+
+class Stage:
+    """One enter/exit of a stage: see the module docstring.  ``seconds``
+    holds the stage's wall time after exit (the dispatch seam feeds the
+    kernel profile from it); ``set(**attrs)`` adds attributes to the
+    trace span, as a ``trace.span`` does."""
+
+    __slots__ = ("_job", "_stage", "_span_name", "_nbytes", "_items",
+                 "_blocked", "_unit", "_attrs", "_t0", "_span", "_ann",
+                 "seconds")
+
+    def __init__(self, job, stage, span_name, nbytes, items, blocked, unit,
+                 attrs):
         self._job = job
         self._stage = stage
+        self._span_name = span_name
         self._nbytes = nbytes
         self._items = items
         self._blocked = blocked
+        self._unit = unit
+        self._attrs = attrs
+        self.seconds = 0.0
+
+    def set(self, **attrs) -> None:
+        self._span.set(**attrs)
 
     def __enter__(self):
+        self._span = _trace.span(self._span_name, **self._attrs)
+        self._span.__enter__()
+        ann = _profiler_annotation()
+        if ann is not None:
+            ids = self._job.annotation_ids()
+            if self._unit is not None:
+                ids["unit"] = self._unit
+            ann = ann(self._span_name, **ids)
+            ann.__enter__()
+        self._ann = ann
         self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *exc):
-        self._job._book(self._stage, time.perf_counter() - self._t0,
-                        self._nbytes, self._items, self._blocked)
+    def __exit__(self, etype, exc, tb):
+        self.seconds = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(etype, exc, tb)
+        self._span.__exit__(etype, exc, tb)
+        self._job._book(self._stage, self.seconds, self._nbytes,
+                        self._items, self._blocked)
         return False
 
 
 class PipelineJob:
     """Stage accounting for ONE pipeline run (an encode, a rebuild, a
-    fleet conversion).  Wraps the pipeline's existing stats dict — the
-    ``<stage>_s`` wall-second keys bench.py and /admin/ec/progress
-    already read stay the source of truth for stage TIME (including the
-    writer-pool seconds folded in at close()); this object adds the
-    dimensions a dict of floats can't carry: bytes and items per stage,
-    queue-depth high-water marks, blocked time, liveness, and the
+    fleet conversion).  Its stages book their seconds here AND into the
+    ``<stage>_s`` keys of the wrapped stats dict — the engines' published
+    output, which /admin/ec/progress and bench.py read.  The job adds what a
+    dict of floats can't carry: busy apart from blocked time, bytes and
+    items per stage, queue-depth high-water marks, liveness, and the
     registry that makes the run observable at /debug/pipeline while it
-    is still running."""
+    is still running.  ``span`` prefixes the job's span and annotation
+    names (``ec.encode``; the kind when not given).  ``sums`` names the
+    lumps the engine publishes beside their parts, ``{"d2h":
+    ("device_wait", "d2h_copy")}``: a part's seconds book to its lump
+    too, so the lump IS the sum at every moment of the run."""
 
     def __init__(self, kind: str, stats: dict | None = None,
                  total_bytes: int = 0, meta: dict | None = None,
-                 register: bool = True):
+                 register: bool = True, span: str | None = None,
+                 sums: dict[str, tuple] | None = None):
         self.kind = kind
+        self.span = span or kind
+        self._lump_of = {part: lump for lump, parts in (sums or {}).items()
+                         for part in parts}
         self.stats = stats if stats is not None else {}
         self.total_bytes = total_bytes
         self.meta = meta or {}
@@ -165,25 +232,45 @@ class PipelineJob:
 
     # -- accounting ------------------------------------------------------
 
-    def stage(self, name: str, nbytes: float = 0.0,
-              items: float = 1.0) -> _StageTimer:
-        """CM bracketing productive work attributed to `name`."""
-        return _StageTimer(self, name, nbytes, items, False)
+    def stage(self, name: str, nbytes: float = 0.0, items: float = 1.0,
+              unit: int | None = None, span: str | None = None,
+              **attrs) -> Stage:
+        """CM bracketing productive work attributed to `name`.  `unit`
+        rides the profiler annotation, `attrs` the trace span; `span`
+        overrides the span name (the dispatch seam's ``codec.*``)."""
+        return Stage(self, name, span or f"{self.span}.{name}", nbytes,
+                     items, False, unit, attrs)
 
-    def blocked(self, name: str) -> _StageTimer:
-        """CM bracketing time `name` spent backpressured on a
-        downstream queue/ring — never counted as busy."""
-        return _StageTimer(self, name, 0.0, 0.0, True)
+    def blocked(self, name: str, unit: int | None = None) -> Stage:
+        """CM bracketing time spent backpressured on a downstream
+        queue/ring — never counted as busy.  The engines book theirs
+        under the wait stage `stall`."""
+        return Stage(self, name, f"{self.span}.{name}", 0.0, 0.0, True,
+                     unit, {})
+
+    def annotation_ids(self) -> dict:
+        """What ties a profiler annotation back to this run."""
+        return {"job": self.job_id}
 
     def _book(self, name: str, secs: float, nbytes: float, items: float,
               blocked: bool) -> None:
         with self._lock:
-            row = self._stages.get(name)
-            if row is None:
-                row = self._stages[name] = [0.0, 0.0, 0.0, 0.0]
-            row[1 if blocked else 0] += secs
-            row[2] += nbytes
-            row[3] += items
+            self._add(name, secs, nbytes, items, blocked)
+            lump = self._lump_of.get(name)
+            if lump is not None:
+                self._add(lump, secs, 0.0, 0.0, blocked)
+
+    def _add(self, name, secs, nbytes, items, blocked) -> None:
+        row = self._stages.get(name)
+        if row is None:
+            row = self._stages[name] = [0.0, 0.0, 0.0, 0.0]
+        row[1 if blocked else 0] += secs
+        row[2] += nbytes
+        row[3] += items
+        if secs:
+            # a work stage's busy seconds, a wait stage's blocked ones
+            key = name + "_s"
+            self.stats[key] = self.stats.get(key, 0.0) + secs
 
     def add_bytes(self, name: str, nbytes: float,
                   items: float = 0.0) -> None:
@@ -245,8 +332,7 @@ class PipelineJob:
 
     def _stats_stage_seconds(self) -> dict[str, float]:
         """Stage wall-seconds from the wrapped stats dict (`encode_s`,
-        `write_parity_s`, ... — the writer pool folds its busy seconds
-        there at close()).  `wall_s` is the clock, `stall_s` idle."""
+        `write_parity_s`, ...).  `wall_s` is the clock, not a stage."""
         out: dict[str, float] = {}
         for key, v in list(self.stats.items()):
             if key.endswith("_s") and key != "wall_s" and \
@@ -258,29 +344,27 @@ class PipelineJob:
         with self._lock:
             stages_own = {k: list(v) for k, v in self._stages.items()}
             queues = {k: list(v) for k, v in self._queues.items()}
-            # the stats dict's wall_s (the bench/_Timer contract) is the
-            # canonical clock when the pipeline stamped one — the job's
-            # own bracket includes setup/teardown outside it
+            # the stats dict's wall_s is the canonical clock when the
+            # pipeline stamped one — the job's own bracket includes
+            # setup/teardown outside it
             wall = self.stats.get("wall_s")
             if not isinstance(wall, (int, float)) or wall <= 0:
                 wall = self.wall_s if self.wall_s is not None \
                     else time.perf_counter() - self._t0
             state, error = self.state, self.error
-        merged: dict[str, dict] = {}
+        merged: dict[str, dict] = {
+            name: {"busy_s": busy, "blocked_s": blocked, "bytes": nbytes,
+                   "items": items}
+            for name, (busy, blocked, nbytes, items) in stages_own.items()}
+        # seconds no Stage timed: the write engines' submit / complete
+        # cut of the write stages, which the writer pool folds straight
+        # into the stats dict at close()
         for name, secs in self._stats_stage_seconds().items():
-            merged[name] = {"busy_s": secs, "blocked_s": 0.0,
-                            "bytes": 0.0, "items": 0.0}
-        for name, (busy, blocked, nbytes, items) in stages_own.items():
             row = merged.setdefault(
                 name, {"busy_s": 0.0, "blocked_s": 0.0, "bytes": 0.0,
                        "items": 0.0})
-            # stats-dict seconds win when both booked the same stage
-            # (they are the same measurement, taken by _Timer)
-            if row["busy_s"] == 0.0:
-                row["busy_s"] = busy
-            row["blocked_s"] += blocked
-            row["bytes"] += nbytes
-            row["items"] += items
+            if not (row["busy_s"] or row["blocked_s"]):
+                row["busy_s"] = secs
         # the stall stage is idle/backpressure time, not work
         for name in list(merged):
             if name in IDLE_STAGES:
@@ -339,8 +423,8 @@ class FlowAccount(PipelineJob):
     so the counter RATE is live stage occupancy.  Registered once per
     (process, kind)."""
 
-    def __init__(self, kind: str):
-        super().__init__(kind, register=False)
+    def __init__(self, kind: str, span: str | None = None):
+        super().__init__(kind, register=False, span=span)
         self.state = "flow"
         # per-stage (seconds-counter, bytes-counter) children, resolved
         # once: a labels() registry lookup per read is measurable tax on
@@ -377,32 +461,49 @@ class FlowAccount(PipelineJob):
         if nbytes:
             pair[1].inc(nbytes)
 
-    def stage(self, name, nbytes=0.0, items=1.0):
-        if not perf_obs_enabled():
-            return contextlib.nullcontext()
-        return super().stage(name, nbytes, items)
+    def annotation_ids(self) -> dict:
+        t = _trace.current()
+        return {"trace": t.trace_id} if t is not None else {}
 
 
 def track(kind: str, stats: dict | None = None, total_bytes: int = 0,
-          meta: dict | None = None) -> PipelineJob:
+          meta: dict | None = None, span: str | None = None,
+          sums: dict[str, tuple] | None = None) -> PipelineJob:
     """The one-liner pipelines wrap themselves in::
 
-        with pipeline.track("ec_encode", stats, dat_size) as job:
+        with pipeline.track("ec_encode", stats, dat_size,
+                            span="ec.encode") as job:
+            with job.stage("read", unit=i): ...
             ... job.queue("read", q.qsize()) ...
 
-    Returns an unregistered no-op-ish job when the observatory is off
-    (stage CMs still time into the stats dict contract holders, but
-    nothing is retained or exported)."""
-    return PipelineJob(kind, stats, total_bytes, meta)
+    With the observatory off the job is unregistered: its stages still
+    time into the stats dict, record spans and annotate the profiler's
+    trace, but nothing is retained or exported."""
+    return PipelineJob(kind, stats, total_bytes, meta, span=span, sums=sums)
 
 
-def flow(kind: str) -> FlowAccount:
+class _Untracked(PipelineJob):
+    """Where the dispatch seam books the stages of a caller that runs no
+    job (the scrubber, the repair plane, a bare codec call): the spans
+    and annotations are recorded, the seconds kept nowhere."""
+
+    def _book(self, name, secs, nbytes, items, blocked):
+        pass
+
+    def annotation_ids(self) -> dict:
+        return {}
+
+
+UNTRACKED = _Untracked("untracked", register=False)
+
+
+def flow(kind: str, span: str | None = None) -> FlowAccount:
     # lock-free fast path: dict.get is atomic under the GIL, and this
     # rides per-needle-read hot paths (the EC read engine)
     acct = _flows.get(kind)
     if acct is not None:
         return acct
-    FlowAccount(kind)  # registers itself (first registration wins)
+    FlowAccount(kind, span)  # registers itself (first registration wins)
     return _flows[kind]
 
 
@@ -709,7 +810,10 @@ def local_snapshot(limit: int = 16) -> dict:
            "roofline": _profile.roofline_snapshot(),
            # what each codec selection resolved to (backend, device,
            # class, interpret, tile); empty until a codec was built
-           "codecs": _profile.codecs_snapshot()}
+           "codecs": _profile.codecs_snapshot(),
+           # backend compilations by the codec entry point open on the
+           # compiling thread: {entry: {count, seconds}}
+           "compiles": _profile.compiles_snapshot()}
     tile = sentinel_status()
     if tile is not None:
         out["tile"] = tile

@@ -14,12 +14,20 @@ debug surface):
   pools, the scrubber) unlike signal-based profilers which only ever see
   the main thread.
 
-- **Kernel profile** — the device-side twin fed by ops/dispatch.py: per
-  codec entry point (encode_parity / reconstruct / parity_mismatch) the
-  host wall time of the dispatch, the ``block_until_ready`` device time,
-  and H2D/D2H transfer time + bytes.  A span can say ``encode`` took
-  225 ms; this table says how much of that was the matmul vs the
-  transfers around it.
+- **Kernel profile** — the device-side twin fed by ops/dispatch.py from
+  the four stages it opens round every device dispatch: per codec entry
+  point (encode_parity / reconstruct / fleet_encode / repair_partial)
+  the host wall time of the enqueue (``wall_s``), the time blocked in
+  ``block_until_ready`` (``device_s``: the device's work plus the
+  transfers queued before it), what the calling thread spent in
+  ``jnp.asarray`` / ``place`` (``h2d_s``: staging and put, not the DMA
+  itself) and in ``np.asarray`` once ready (``d2h_s``), plus the bytes
+  each way.
+
+- **Compile counter** — backend compilations counted inside the program
+  by a ``jax.monitoring`` listener (the events ``JAX_LOG_COMPILES``
+  logs), booked to the codec entry point open on the compiling thread;
+  ``/perf`` -> ``compiles``.
 
 Default off: ``WEEDTPU_PROFILE_HZ`` unset/0 starts nothing, and
 ``/debug/pprof?seconds=N`` spins up an on-demand window sampler that is
@@ -29,6 +37,7 @@ leave zero threads behind.
 
 from __future__ import annotations
 
+import functools
 import os
 import sys
 import threading
@@ -212,10 +221,9 @@ class KernelProfile:
     """Per-kernel host/device time + transfer accounting.
 
     One row per codec entry point, accumulating: calls, host-side
-    dispatch wall (`wall_s`), `block_until_ready` device time
-    (`device_s`), H2D/D2H transfer seconds and bytes, and payload bytes.
-    The rows separate ``encode`` (device_s) from ``write_parity``-side
-    stalls (d2h_s) that a span lumps together."""
+    dispatch wall (`wall_s`), seconds blocked in `block_until_ready`
+    (`device_s`), the calling thread's seconds in the H2D put and the
+    D2H copy with their bytes, and payload bytes (module docstring)."""
 
     _FIELDS = ("calls", "wall_s", "device_s", "h2d_s", "d2h_s",
                "bytes", "h2d_bytes", "d2h_bytes")
@@ -239,23 +247,6 @@ class KernelProfile:
             for f, v in zip(self._FIELDS, add):
                 if v:
                     row[f] += v
-
-    def timed(self, kernel: str, backend: str = "host", *,
-              nbytes: float = 0.0):
-        """Context manager for the common case — bracket one call's wall
-        time into `kernel`'s row.  Device paths with split h2d/device/d2h
-        phases call record() directly."""
-        import contextlib
-
-        @contextlib.contextmanager
-        def cm():
-            t0 = time.perf_counter()
-            try:
-                yield
-            finally:
-                self.record(kernel, backend,
-                            wall_s=time.perf_counter() - t0, nbytes=nbytes)
-        return cm()
 
     def snapshot(self) -> dict[str, dict[str, float]]:
         with self._lock:
@@ -364,13 +355,16 @@ def note_codec(key: tuple, make_info) -> None:
     ops/dispatch.describe — lazily, `key` is checked first).  /perf
     carries the blocks, so "which backend is this volume server really
     encoding on" has an answer that does not depend on reading the
-    environment."""
+    environment.  The first block that names a JAX platform also starts
+    the compile counter: from here on this process may compile."""
     if key in _codecs_noted:  # the per-read path: one dict probe
         return
     with _codecs_lock:
         if key in _codecs_noted:
             return
         info = _codecs_noted[key] = make_info()
+    if "platform" in info:
+        _count_compiles()
     import logging
     logging.getLogger("ec").info("ec codec resolved: %s", info)
 
@@ -385,6 +379,67 @@ def jax_backend_noted() -> bool:
     backend — the only processes allowed to ask JAX about its devices."""
     with _codecs_lock:
         return any("platform" in v for v in _codecs_noted.values())
+
+
+# -- compilations, counted inside the program -----------------------------
+#
+# jax.monitoring hands every listener the duration events JAX records;
+# "/jax/core/compile/backend_compile_duration" is recorded by the same
+# context manager that writes JAX_LOG_COMPILES's "Finished XLA
+# compilation of ..." line (jax 0.9.0: _src/dispatch.log_elapsed_time
+# from pxla._cached_compilation), synchronously on the compiling thread,
+# so this count and a grep of that log agree.  Always on once a codec
+# resolved to a JAX backend: it costs nothing unless something compiles.
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_entry = threading.local()
+_compiles: dict[str, list[float]] = {}  # entry -> [count, seconds]
+_compiles_lock = threading.Lock()
+_compiles_counted = False
+
+
+def codec_entry(name: str):
+    """Decorator of a dispatch-seam entry point: compilations on the
+    calling thread book to `name` while the call is open."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def entry(*args, **kwargs):
+            prev = getattr(_compile_entry, "name", None)
+            _compile_entry.name = name
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                _compile_entry.name = prev
+        return entry
+    return deco
+
+
+def _on_jax_duration(event: str, duration: float, **_kw) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    entry = getattr(_compile_entry, "name", None) or "other"
+    with _compiles_lock:
+        row = _compiles.setdefault(entry, [0, 0.0])
+        row[0] += 1
+        row[1] += duration
+
+
+def _count_compiles() -> None:
+    global _compiles_counted
+    with _compiles_lock:
+        if _compiles_counted:
+            return
+        _compiles_counted = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def compiles_snapshot() -> dict[str, dict]:
+    """{entry point: {count, seconds}} of the backend compilations so
+    far; entry `other` is whatever compiled outside the dispatch seam."""
+    with _compiles_lock:
+        return {k: {"count": int(c), "seconds": round(secs, 6)}
+                for k, (c, secs) in _compiles.items()}
 
 
 # which (resource, seconds-field, bytes-field) pairs a kernel row feeds:
@@ -497,3 +552,51 @@ async def handle_debug_pprof(req):
     else:
         text = prof.collapsed() + "\n"
     return web.Response(text=text, content_type="text/plain")
+
+
+async def handle_debug_jax_profile(req):
+    """``/debug/jax_profile?seconds=N[&dir=D]``: open the JAX profiler
+    on this running server for N seconds (default 5, at most 120) and
+    answer when the trace is written — an operator's window, where
+    ``--jax-profile`` covers a whole process life.  The EC plane's
+    stages are in it as ``ec.*`` / ``codec.*`` annotations beside the
+    device's operations.  One session at a time (utils/grace.py holds
+    it): 400 while one is open, and 400 in a process whose codecs run on
+    no JAX backend, because a profiler session would initialise one."""
+    import asyncio
+    import glob
+    import tempfile
+
+    from aiohttp import web
+
+    from seaweedfs_tpu.utils import grace
+
+    if not jax_backend_noted():
+        return web.json_response(
+            {"error": "no codec of this process runs on a JAX backend; "
+                      "a profiler session would initialise one"},
+            status=400)
+    try:
+        seconds = float(req.query.get("seconds", "5"))
+    except ValueError:
+        seconds = 5.0
+    seconds = max(0.0, min(seconds, 120.0))
+    trace_dir = req.query.get("dir") or tempfile.mkdtemp(
+        prefix="weedtpu-jax-profile-")
+    # a SIGTERM inside the window still writes the trace (registered
+    # here, on the server's loop thread: signal handlers install only
+    # from the main thread); start and stop run on a worker thread so
+    # that handler never finds the session's lock held by its own thread
+    grace.on_interrupt(grace.stop_jax_profile)
+    if not await asyncio.to_thread(grace.start_jax_profile, trace_dir):
+        return web.json_response(
+            {"error": "a JAX profiler session is open already"},
+            status=400)
+    try:
+        await asyncio.sleep(seconds)
+    finally:
+        await asyncio.to_thread(grace.stop_jax_profile)
+    return web.json_response({
+        "dir": trace_dir, "seconds": seconds,
+        "xplane": sorted(glob.glob(os.path.join(
+            trace_dir, "plugins", "profile", "*", "*.xplane.pb")))})

@@ -748,7 +748,7 @@ def debug_guard(handler):
 def debug_routes():
     """Routes every server mounts (before any catch-all), loopback-gated
     as one unit: /debug/traces, /debug/requests, /debug/pprof,
-    /debug/pipeline."""
+    /debug/jax_profile, /debug/pipeline."""
     from aiohttp import web
 
     from seaweedfs_tpu.stats import pipeline as _pipeline
@@ -757,5 +757,7 @@ def debug_routes():
             web.get("/debug/requests", debug_guard(handle_debug_requests)),
             web.get("/debug/pprof",
                     debug_guard(_profile.handle_debug_pprof)),
+            web.get("/debug/jax_profile",
+                    debug_guard(_profile.handle_debug_jax_profile)),
             web.get("/debug/pipeline",
                     debug_guard(_pipeline.handle_debug_pipeline))]

@@ -301,22 +301,15 @@ def _copy_range(src_fd: int, dst_fd: int, src_off: int, dst_off: int,
         _pwrite_all(dst_fd, src_view[src_off:src_off + count], dst_off)
 
 
-class _Timer:
-    """Accumulates wall seconds into stats[key]; no-op when stats is None."""
-
-    def __init__(self, stats, key):
-        self.stats, self.key = stats, key
-
-    def __enter__(self):
-        if self.stats is not None:
-            self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        if self.stats is not None:
-            self.stats[self.key] = self.stats.get(self.key, 0.0) + \
-                (time.perf_counter() - self.t0)
-        return False
+# the lumps /admin/ec/progress has always shown, and the stages of the
+# dispatch seam (ops/dispatch.py) and of the rebuild loop that add up to
+# each (stats/pipeline.PipelineJob `sums`)
+ENCODE_SUMS = {"encode": ("h2d", "dispatch"),
+               "d2h": ("device_wait", "d2h_copy")}
+REBUILD_SUMS = {"reconstruct": ("stage", "h2d", "dispatch", "device_wait",
+                                "d2h_copy", "unstage")}
+_PART_KEYS = {part + "_s" for sums in (ENCODE_SUMS, REBUILD_SUMS)
+              for parts in sums.values() for part in parts}
 
 
 def _finalize_shards(out_fds, highwater, shard_size: int) -> None:
@@ -385,7 +378,8 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     t_wall = time.perf_counter()
     import mmap as mmap_mod
     with _pipeline.track("ec_encode", stats, dat_size,
-                         meta={"mode": stats["mode"]}) as pjob, \
+                         meta={"mode": stats["mode"]}, span="ec.encode",
+                         sums=ENCODE_SUMS) as pjob, \
             open(dat_path, "rb") as datf:
         dat_fd = datf.fileno()
         mm = _map_readonly(dat_fd, dat_size)
@@ -394,13 +388,13 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
             if use_serial:
                 _encode_serial_host(codec, dat_fd, dat_view, dat_size,
                                     large_block, small_block, batch_size,
-                                    out_fds, highwater, progress, cancel,
-                                    stats)
+                                    out_fds, highwater, pjob, progress,
+                                    cancel)
             else:
                 _encode_pipelined(codec, dat_fd, dat_view, dat_size,
                                   large_block, small_block, batch_size,
-                                  out_fds, highwater, progress, cancel,
-                                  stats, pjob)
+                                  out_fds, highwater, pjob, progress,
+                                  cancel)
         finally:
             del dat_view
             try:
@@ -516,10 +510,11 @@ class _ShardWriterPool:
     Workers never die: after the first error they drain remaining items
     without touching the fds (still firing release hooks) so producers
     can never deadlock on a full queue; the first error surfaces via
-    `.errors` after close().  Busy seconds accumulate per SHARD (not per
-    worker) and close() folds them into the stats dict under
-    stage_key(shard_index), preserving the write_data_s/write_parity_s
-    attribution bench.py reports.
+    `.errors` after close().  Each batch a worker writes is one stage of
+    the run's `job`, named by stage_of(shard_index) (`write_data`,
+    `write_parity`, `write`): its seconds book to the job and its stats
+    dict as the write happens, summed over the threads, and close() adds
+    the thread capacity behind each stage (`<stage>_workers`).
 
     The actual byte-moving rides the host async-I/O engine
     (storage/aio.py): each worker owns a WriteEngine (io_uring ring with
@@ -531,13 +526,15 @@ class _ShardWriterPool:
     WRITE_FIXED.  close() folds the engines' submit/complete seconds
     into stats next to the write stages."""
 
-    def __init__(self, fds, highwater=None, stats=None, stage_key=None,
+    def __init__(self, fds, highwater=None, job=None, stage_of=None,
                  depth: int | None = None, workers: int | None = None,
                  reg_bufs=None):
         self._fds = list(fds)
         self._hw = highwater
-        self._stats = stats
-        self._stage_key = stage_key or (lambda i: "write_s")
+        # a bare pool (bench.py) times its batches for no run
+        self._job = job if job is not None else _pipeline.UNTRACKED
+        self._stats = job.stats if job is not None else None
+        self._stage_of = stage_of or (lambda i: "write")
         self._mode = _aio.engine_mode()
         self._reg = list(reg_bufs) if reg_bufs else None
         self._engines: list = []
@@ -593,66 +590,14 @@ class _ShardWriterPool:
                 if batch is None:
                     return
                 shard, item = batch
-                fd = self._fds[shard]
-                t0 = time.perf_counter()
                 # releases fire only after the batch DRAINS: with an
                 # async ring the kernel may still be reading a buffer
                 # long after submission returned, and a recycled parity
                 # buffer mid-read is silent corruption
                 releases: list = []
-                ends: list[tuple[int, int]] = []
-                idx = 0
-                while idx < len(item):
-                    data, cfr, off, release = item[idx]
-                    if release is not None:
-                        releases.append(release)
-                    idx += 1
-                    try:
-                        if self.errors:
-                            continue  # drain without touching the fd
-                        if cfr is not None:
-                            src_fd, src_off, count, src_view = cfr
-                            # in-kernel copies want plain buffered fd
-                            # semantics: barrier the ring, drop O_DIRECT
-                            eng.ensure_buffered(fd)
-                            _copy_range(src_fd, fd, src_off, off, count,
-                                        src_view=src_view)
-                            end = off + count
-                            self._wbytes[shard] += count
-                            if self._hw is not None and \
-                                    end > self._hw[shard]:
-                                self._hw[shard] = end
-                        else:
-                            # merge the run of pwrites targeting
-                            # contiguous offsets into one submission
-                            bufs = [np.ascontiguousarray(data)]
-                            end = off + bufs[0].nbytes
-                            while (idx < len(item)
-                                   and len(bufs) < self._IOV_RUN
-                                   and item[idx][1] is None
-                                   and item[idx][2] == end):
-                                nxt = np.ascontiguousarray(item[idx][0])
-                                bufs.append(nxt)
-                                end += nxt.nbytes
-                                if item[idx][3] is not None:
-                                    releases.append(item[idx][3])
-                                idx += 1
-                            eng.writev(fd, bufs, off)
-                            ends.append((end, end - off))
-                    except BaseException as e:  # surfaced after close
-                        self.errors.append(e)
-                try:
-                    eng.drain()
-                except BaseException as e:
-                    self.errors.append(e)
-                else:
-                    if not self.errors:
-                        for end, n in ends:
-                            self._wbytes[shard] += n
-                            if self._hw is not None and \
-                                    end > self._hw[shard]:
-                                self._hw[shard] = end
-                self._busy[shard] += time.perf_counter() - t0
+                with self._job.stage(self._stage_of(shard)) as st:
+                    self._write_batch(eng, shard, item, releases)
+                self._busy[shard] += st.seconds
                 for rel in releases:
                     rel()
         finally:
@@ -660,6 +605,64 @@ class _ShardWriterPool:
                 eng.close()
             except BaseException as e:
                 self.errors.append(e)
+
+    def _write_batch(self, eng, shard: int, item: list,
+                     releases: list) -> None:
+        """Submit one queue item's jobs to the engine and drain it; every
+        error is kept for close(), none raised."""
+        fd = self._fds[shard]
+        ends: list[tuple[int, int]] = []
+        idx = 0
+        while idx < len(item):
+            data, cfr, off, release = item[idx]
+            if release is not None:
+                releases.append(release)
+            idx += 1
+            try:
+                if self.errors:
+                    continue  # drain without touching the fd
+                if cfr is not None:
+                    src_fd, src_off, count, src_view = cfr
+                    # in-kernel copies want plain buffered fd
+                    # semantics: barrier the ring, drop O_DIRECT
+                    eng.ensure_buffered(fd)
+                    _copy_range(src_fd, fd, src_off, off, count,
+                                src_view=src_view)
+                    end = off + count
+                    self._wbytes[shard] += count
+                    if self._hw is not None and \
+                            end > self._hw[shard]:
+                        self._hw[shard] = end
+                else:
+                    # merge the run of pwrites targeting
+                    # contiguous offsets into one submission
+                    bufs = [np.ascontiguousarray(data)]
+                    end = off + bufs[0].nbytes
+                    while (idx < len(item)
+                           and len(bufs) < self._IOV_RUN
+                           and item[idx][1] is None
+                           and item[idx][2] == end):
+                        nxt = np.ascontiguousarray(item[idx][0])
+                        bufs.append(nxt)
+                        end += nxt.nbytes
+                        if item[idx][3] is not None:
+                            releases.append(item[idx][3])
+                        idx += 1
+                    eng.writev(fd, bufs, off)
+                    ends.append((end, end - off))
+            except BaseException as e:  # surfaced after close
+                self.errors.append(e)
+        try:
+            eng.drain()
+        except BaseException as e:
+            self.errors.append(e)
+        else:
+            if not self.errors:
+                for end, n in ends:
+                    self._wbytes[shard] += n
+                    if self._hw is not None and \
+                            end > self._hw[shard]:
+                        self._hw[shard] = end
 
     # a bare pool quacks like a _ShardFlusher so producers can submit
     # DIRECTLY when units are big enough that per-job queue hops are
@@ -671,10 +674,10 @@ class _ShardWriterPool:
         pass
 
     def close(self) -> None:
-        """Drain every queue, join the workers, fold busy seconds into
-        stats.  Idempotent, and does not raise — callers inspect
-        `.errors`, letting a producer-side exception win over a writer
-        one."""
+        """Drain every queue, join the workers, fold the engines' seconds
+        and the thread capacity behind each stage into stats.  Idempotent,
+        and does not raise — callers inspect `.errors`, letting a
+        producer-side exception win over a writer one."""
         if getattr(self, "_closed", False):
             return
         self._closed = True
@@ -721,12 +724,11 @@ class _ShardWriterPool:
                 self._stats["aio_degraded_engines"] = \
                     self._stats.get("aio_degraded_engines", 0) + degraded
         if self._stats is not None:
-            key_busy: dict[str, float] = {}
+            stage_busy: dict[str, float] = {}
             for i, busy in enumerate(self._busy):
-                key = self._stage_key(i)
-                self._stats[key] = self._stats.get(key, 0.0) + busy
-                key_busy[key] = key_busy.get(key, 0.0) + busy
-            # stage seconds above are summed across N parallel shard
+                stage = self._stage_of(i)
+                stage_busy[stage] = stage_busy.get(stage, 0.0) + busy
+            # the stages' seconds are summed across N parallel shard
             # slots: publish the capacity backing them so occupancy math
             # (stats/pipeline busy_frac) divides by it instead of
             # reading a 4-worker 30%-busy pool as a 120%-saturated
@@ -738,12 +740,12 @@ class _ShardWriterPool:
             # the wrong stage.  ACCUMULATED, not first-wins —
             # fleet_convert's per-volume pools all fold into one shared
             # stats dict, and their concurrent workers are all capacity
-            total_busy = sum(key_busy.values())
-            for key, busy_k in key_busy.items():
-                if key.endswith("_s") and total_busy > 0:
-                    wkey = key[:-2] + "_workers"
+            total_busy = sum(stage_busy.values())
+            for stage, busy in stage_busy.items():
+                if total_busy > 0:
+                    wkey = stage + "_workers"
                     self._stats[wkey] = self._stats.get(wkey, 0.0) + \
-                        self._nworkers * (busy_k / total_busy)
+                        self._nworkers * (busy / total_busy)
         # the disk-side roofline row: shard writes vs the measured disk
         # ceiling (stats/profile.roofline_snapshot special-cases this
         # kernel onto the wall/bytes columns)
@@ -842,43 +844,48 @@ def overlap_fraction(stats: dict) -> float | None:
                 if key.endswith("_s")
                 and key not in ("wall_s", "stall_s", "submit_s",
                                 "complete_s")
+                and key not in _PART_KEYS  # their lumps carry them
                 and isinstance(v, float))
     if not wall or total <= 0:
         return None
     return round(max(0.0, 1.0 - wall / total), 3)
 
 
-def _host_parity_unit(codec, dat_view: np.ndarray, tailbuf: np.ndarray,
-                      pbuf: np.ndarray, row_start: int, block: int,
-                      col: int, step: int, nz: int, tail: int) -> None:
-    """Parity for one column unit of a stripe row: gf_matmul_ptrs straight
-    off the .dat mmap into pbuf's m rows.  A partial tail row is staged
-    into the zeroed tailbuf first; a stripe with nz < k populated rows
-    uses a column-truncated generator.  This is the ONE copy of the
-    zero-copy host encode — both the serial and pipelined strategies call
-    it, so they stay byte-identical by construction."""
+def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
+                      tailbuf: np.ndarray, pbuf: np.ndarray, row_start: int,
+                      block: int, col: int, step: int, nz: int,
+                      tail: int) -> None:
+    """Parity for one column unit of a stripe row — the job's `encode`
+    stage: gf_matmul_ptrs straight off the .dat mmap into pbuf's m rows.
+    A partial tail row is staged into the zeroed tailbuf first; a stripe
+    with nz < k populated rows uses a column-truncated generator.  This
+    is the ONE copy of the zero-copy host encode — both the serial and
+    pipelined strategies call it, so they stay byte-identical by
+    construction."""
     from seaweedfs_tpu import native
-    rows = [dat_view[row_start + j * block + col:
-                     row_start + j * block + col + step]
-            for j in range(nz)]
-    if tail < step:
-        tailbuf[:tail] = rows[nz - 1][:tail]
-        tailbuf[tail:step] = 0
-        rows[nz - 1] = tailbuf
-    code = codec.code
-    mat = code.parity_matrix if nz == code.k else \
-        np.ascontiguousarray(code.parity_matrix[:, :nz])
+    with pjob.stage("encode", unit=unit) as st:
+        rows = [dat_view[row_start + j * block + col:
+                         row_start + j * block + col + step]
+                for j in range(nz)]
+        if tail < step:
+            tailbuf[:tail] = rows[nz - 1][:tail]
+            tailbuf[tail:step] = 0
+            rows[nz - 1] = tailbuf
+        code = codec.code
+        mat = code.parity_matrix if nz == code.k else \
+            np.ascontiguousarray(code.parity_matrix[:, :nz])
+        native.gf_matmul_ptrs(mat, rows, list(pbuf), step)
     # the zero-copy path bypasses ops/dispatch, so it feeds the kernel
     # profile itself — otherwise host-encode time vanishes from
     # /debug/pprof?format=table
-    with _profile.KERNELS.timed("encode_parity", nbytes=nz * step):
-        native.gf_matmul_ptrs(mat, rows, list(pbuf), step)
+    _profile.KERNELS.record("encode_parity", wall_s=st.seconds,
+                            nbytes=nz * step)
 
 
 def _encode_serial_host(codec, dat_fd: int, dat_view: np.ndarray,
                         dat_size: int, large_block: int, small_block: int,
-                        batch_size: int, out_fds, highwater,
-                        progress=None, cancel=None, stats=None) -> None:
+                        batch_size: int, out_fds, highwater, pjob,
+                        progress=None, cancel=None) -> None:
     """Native-codec encode with overlapped shard I/O: the GF matmul runs
     on the calling thread straight off the .dat mmap (zero staging copy),
     while all 14 shard files are written by the per-shard writer pool —
@@ -898,15 +905,15 @@ def _encode_serial_host(codec, dat_fd: int, dat_view: np.ndarray,
         pbuf_pool.put(b)
     tailbuf = np.zeros(max_step, dtype=np.uint8)
     writers = _ShardWriterPool(
-        out_fds, highwater, stats,
-        stage_key=lambda i: "write_data_s" if i < k else "write_parity_s",
+        out_fds, highwater, pjob,
+        stage_of=lambda i: "write_data" if i < k else "write_parity",
         reg_bufs=pbufs)
     sink = _make_sink(writers, codec.k + codec.m, min_step)
     done = 0
     try:
-        for row_start, block, col, step, shard_off in _iter_units(
-                dat_size, large_block, small_block, batch_size,
-                data_shards=k):
+        for unit, (row_start, block, col, step, shard_off) in enumerate(
+                _iter_units(dat_size, large_block, small_block, batch_size,
+                            data_shards=k)):
             if cancel is not None and cancel():
                 raise EncodeCancelled("ec encode cancelled")
             if writers.failed:
@@ -928,11 +935,10 @@ def _encode_serial_host(codec, dat_fd: int, dat_view: np.ndarray,
                 # ship the pending batches first: their releases are what
                 # refill the ring (blocking before the flush would deadlock)
                 sink.flush()
-                with _Timer(stats, "stall_s"):
+                with pjob.blocked("stall", unit=unit):
                     pbuf = pbuf_pool.get()
-            with _Timer(stats, "encode_s"):
-                _host_parity_unit(codec, dat_view, tailbuf, pbuf,
-                                  row_start, block, col, step, nz, tail)
+            _host_parity_unit(pjob, unit, codec, dat_view, tailbuf, pbuf,
+                              row_start, block, col, step, nz, tail)
             release = _countdown(
                 m, lambda b=pbuf: pbuf_pool.put(b))
             for i in range(m):
@@ -951,9 +957,8 @@ def _encode_serial_host(codec, dat_fd: int, dat_view: np.ndarray,
 
 def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                       dat_size: int, large_block: int, small_block: int,
-                      batch_size: int, out_fds, highwater,
-                      progress=None, cancel=None, stats=None,
-                      pjob=None) -> None:
+                      batch_size: int, out_fds, highwater, pjob,
+                      progress=None, cancel=None) -> None:
     """Overlapped reader -> dispatch -> drain -> shard-writer pipeline.
 
     Stages, each on its own thread(s), all behind bounded queues so a
@@ -964,18 +969,20 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                their shard writers by in-kernel copy_file_range on the
                way (they never round-trip the device).  For DEVICE
                codecs it also stages the stripe from the mmap into a
-               pooled buffer (read_s) — the device needs a stable host
-               buffer to transfer from.  HOST codecs skip the staging
+               pooled buffer (`read`; waiting for one is `stall`) — the
+               device needs a stable host buffer to transfer from.  HOST codecs skip the staging
                copy entirely: the dispatch stage encodes straight off
                the mmap, so forcing a host codec through this machinery
                (WEEDTPU_EC_PIPELINE=pipelined) costs no extra memory
                traffic vs the serial strategy.
       dispatch (caller's thread) launches the parity matmul for stripe N
-               — asynchronous on JAX backends, eager (ptr-matmul off the
-               mmap into a pooled parity ring) for native host codecs
-      drain    materialises stripe N-1's parity (d2h_s: the device sync
-               point, which the old writer buried inside write_parity_s)
-               and fans its m rows out to the shard writers
+               — asynchronous on JAX backends (the seam's `h2d` and
+               `dispatch`, which add up to `encode`), eager (ptr-matmul
+               off the mmap into a pooled parity ring: `encode`) for
+               native host codecs
+      drain    materialises stripe N-1's parity (the seam's `device_wait`
+               and `d2h_copy`, which add up to `d2h`: the device sync
+               point) and fans its m rows out to the shard writers
       writers  striped pwrite workers over the 14 shard fds
                (_ShardWriterPool), so parity files land concurrently
                instead of serially
@@ -1019,8 +1026,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     FLUSH = object()
     errors: list[BaseException] = []
     writers = _ShardWriterPool(
-        out_fds, highwater, stats,
-        stage_key=lambda i: "write_data_s" if i < k else "write_parity_s",
+        out_fds, highwater, pjob,
+        stage_of=lambda i: "write_data" if i < k else "write_parity",
         reg_bufs=reg_bufs)
     done = 0
 
@@ -1028,9 +1035,9 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
         nonlocal done
         flusher = _ShardFlusher(writers, k)  # data shards only
         try:
-            for row_start, block, col, step, shard_off in _iter_units(
-                    dat_size, large_block, small_block, batch_size,
-                    data_shards=k):
+            for unit, (row_start, block, col, step, shard_off) in enumerate(
+                    _iter_units(dat_size, large_block, small_block,
+                                batch_size, data_shards=k)):
                 if errors or writers.failed:  # downstream died: stop
                     break
                 if cancel is not None and cancel():
@@ -1046,12 +1053,12 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                                  src_view=dat_view)
                 if native_host:
                     # zero-copy: dispatch encodes off the mmap directly
-                    q_read.put((None, step, shard_off,
+                    q_read.put((unit, None, step, shard_off,
                                 (row_start, block, col, nz, tail)))
                 else:
-                    with _Timer(stats, "stall_s"):
+                    with pjob.blocked("stall", unit=unit):
                         buf = pool.get()
-                    with _Timer(stats, "read_s"):
+                    with pjob.stage("read", unit=unit):
                         batch = buf[:, :step]
                         for j in range(k):
                             off = row_start + j * block + col
@@ -1061,7 +1068,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                                           dat_view[off:off + n])
                             if n < step:
                                 batch[j, max(n, 0):] = 0
-                    q_read.put((buf, step, shard_off, None))
+                    q_read.put((unit, buf, step, shard_off, None))
                 done += (nz - 1) * step + tail
                 flusher.account(step)
                 if progress is not None:
@@ -1089,7 +1096,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
             if item is FLUSH:
                 flusher.flush()
                 continue
-            buf, step, shard_off, parity, release = item
+            unit, buf, step, shard_off, parity, release = item
             if failed or errors or writers.failed:
                 if release is not None:
                     for _ in range(m):
@@ -1104,8 +1111,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 flusher.account(step)
                 continue
             try:
-                with _Timer(stats, "d2h_s"):
-                    pnp = _materialize(parity)
+                pnp = _materialize(parity, job=pjob, unit=unit)
             except BaseException as e:
                 errors.append(e)
                 failed = True  # keep draining so nothing deadlocks
@@ -1125,9 +1131,9 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
             item = q_read.get()
             if item is None:
                 break
-            if pjob is not None:  # stage-queue depth at the consume site
-                pjob.queue("q_read", q_read.qsize(), PIPELINE_DEPTH)
-            buf, step, shard_off, coverage = item
+            # stage-queue depth at the consume site
+            pjob.queue("q_read", q_read.qsize(), PIPELINE_DEPTH)
+            unit, buf, step, shard_off, coverage = item
             if errors or writers.failed:  # stop dispatching, surface below
                 if buf is not None:
                     pool.put(buf)
@@ -1138,18 +1144,16 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                     pbuf = pool.get_nowait()
                 except queue.Empty:
                     q_disp.put(FLUSH)  # see FLUSH above: avoid deadlock
-                    with _Timer(stats, "stall_s"):
+                    with pjob.blocked("stall", unit=unit):
                         pbuf = pool.get()
-                with _Timer(stats, "encode_s"):
-                    _host_parity_unit(codec, dat_view, tailbuf, pbuf,
-                                      row_start, block, col, step, nz,
-                                      tail)
+                _host_parity_unit(pjob, unit, codec, dat_view, tailbuf, pbuf,
+                                  row_start, block, col, step, nz, tail)
                 release = _countdown(m, lambda b=pbuf: pool.put(b))
-                q_disp.put((None, step, shard_off, pbuf, release))
+                q_disp.put((unit, None, step, shard_off, pbuf, release))
             else:
-                with _Timer(stats, "encode_s"):
-                    parity = _dispatch_parity(codec, buf[:, :step])
-                q_disp.put((buf, step, shard_off, parity, None))
+                parity = _dispatch_parity(codec, buf[:, :step], job=pjob,
+                                          unit=unit)
+                q_disp.put((unit, buf, step, shard_off, parity, None))
     finally:
         q_disp.put(None)
         t_d.join()
@@ -1158,8 +1162,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 item = q_read.get(timeout=0.05)
             except queue.Empty:
                 continue
-            if item is not None and item[0] is not None:
-                pool.put(item[0])  # keep the pool whole or the reader starves
+            if item is not None and item[1] is not None:
+                pool.put(item[1])  # keep the pool whole or the reader starves
         t_r.join()
         writers.close()  # after the producers: no submission can block now
     if errors:
@@ -1241,7 +1245,8 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     pjob = _pipeline.track("ec_rebuild", stats,
                            shard_size * len(use),
                            meta={"missing": len(missing),
-                                 "codec": spec.tag})
+                                 "codec": spec.tag},
+                           span="ec.rebuild", sums=REBUILD_SUMS)
     t_wall = time.perf_counter()
     import mmap as mmap_mod
     ins: dict[int, object] = {}
@@ -1274,8 +1279,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
             (len(missing), min(batch_size, max(shard_size, 1))))
             for _ in range(PIPELINE_DEPTH)]
         writers = _ShardWriterPool([out_fds[i] for i in missing], None,
-                                   stats, stage_key=lambda i: "write_s",
-                                   reg_bufs=obufs)
+                                   pjob, reg_bufs=obufs)
         opool: queue.Queue = queue.Queue()
         for b in obufs:
             opool.put(b)
@@ -1285,32 +1289,38 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                 maps[i] = mm
                 views[i] = np.frombuffer(mm, dtype=np.uint8)
         done = 0
-        for off in range(0, shard_size, batch_size):
+        # a batch's time is `reconstruct`: the native matmul booked whole,
+        # or for a device codec the sum of this loop's `stage` (survivor
+        # memcpy) and `unstage` (copy into the output ring) and the four
+        # stages the dispatch seam books between them (REBUILD_SUMS)
+        for unit, off in enumerate(range(0, shard_size, batch_size)):
             if cancel is not None and cancel():
                 raise EncodeCancelled("ec rebuild cancelled")
             if writers.failed:
                 break
             n = min(batch_size, shard_size - off)
-            with _Timer(stats, "stall_s"):
+            with pjob.blocked("stall", unit=unit):
                 obuf = opool.get()
-            with _Timer(stats, "reconstruct_s"):
-                if native_host:
+            if native_host:
+                with pjob.stage("reconstruct", unit=unit) as st:
                     rows = [views[i][off:off + n] for i in use]
                     outs = [obuf[r, :n] for r in range(len(missing))]
-                    with _profile.KERNELS.timed("reconstruct",
-                                                nbytes=len(use) * n):
-                        native.gf_matmul_ptrs(dec_mat, rows, outs, n)
-                else:
+                    native.gf_matmul_ptrs(dec_mat, rows, outs, n)
+                _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
+                                        nbytes=len(use) * n)
+            else:
+                with pjob.stage("stage", unit=unit):
                     if stage is None:
                         stage = np.empty((len(use),
                                           min(batch_size, shard_size)),
                                          dtype=np.uint8)
                     for row, i in enumerate(use):
                         np.copyto(stage[row, :n], views[i][off:off + n])
-                    rebuilt = _reconstruct_batch(
-                        codec,
-                        {i: stage[row, :n] for row, i in enumerate(use)},
-                        missing)
+                rebuilt = _reconstruct_batch(
+                    codec,
+                    {i: stage[row, :n] for row, i in enumerate(use)},
+                    missing, job=pjob, unit=unit)
+                with pjob.stage("unstage", unit=unit):
                     for r, i in enumerate(missing):
                         np.copyto(obuf[r, :n], rebuilt[i])
             release = _countdown(len(missing),
@@ -1412,6 +1422,10 @@ def rebuild_ec_reduced(base: str, lost: list[int], groups: list[dict],
     local_fds: dict[int, int] = {}
     stats = stats if stats is not None else {}
     stats.setdefault("mode", "reduced")
+    # unregistered: the planner's own accounting (helper bytes, replans)
+    # is what a reduced rebuild reports; the job times `reconstruct`
+    pjob = _pipeline.PipelineJob("ec_rebuild", stats, register=False,
+                                 span="ec.rebuild")
     _flow_token = _netflow.set_class(_netflow.current_class() or "repair")
     t_wall = time.perf_counter()
     try:
@@ -1514,7 +1528,7 @@ def rebuild_ec_reduced(base: str, lost: list[int], groups: list[dict],
 
                 local_group = regen.HelperGroup(
                     node="", shards=tuple(sorted(local_fds)), locality=0)
-                with _Timer(stats, "reconstruct_s"):
+                with pjob.stage("reconstruct", unit=sid):
                     plan = regen.repair_shard(
                         plan_code, codec, sid,
                         [local_group] + remote_groups, shard_size,

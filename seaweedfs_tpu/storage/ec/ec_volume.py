@@ -47,6 +47,12 @@ from seaweedfs_tpu.storage.ec import ec_files, layout
 
 ShardReader = Callable[[int, int, int], "bytes | None"]
 
+
+def _read_flow() -> _pipeline.FlowAccount:
+    """The degraded-read engine's stage account: `ec_read` on /perf, its
+    spans and annotations `ec.read.<stage>`."""
+    return _pipeline.flow("ec_read", span="ec.read")
+
 # bytes of reconstructed ranges kept per EcVolume so hot degraded needles
 # don't re-reconstruct (0 disables)
 RECONSTRUCT_CACHE_BYTES = int(os.environ.get(
@@ -447,11 +453,10 @@ class EcVolume:
                     list(wanted)))
             except (ValueError, TypeError):
                 basis = None
-        with trace.span("ec.gather_survivors", shards_lost=len(wanted),
-                        segs=len(gsegs)), \
-                _pipeline.flow("ec_read").stage(
-                    "gather_survivors",
-                    nbytes=self.spec.k * sum(s for _, s in gsegs)):
+        with _read_flow().stage(
+                "gather_survivors",
+                nbytes=self.spec.k * sum(s for _, s in gsegs),
+                shards_lost=len(wanted), segs=len(gsegs)):
             try:
                 rows = self._gather_survivors(set(wanted), gsegs,
                                               shard_reader, want=basis)
@@ -470,12 +475,11 @@ class EcVolume:
         # (f-1)/f of the matmul OUTPUT (microseconds at KB batch sizes),
         # while splitting into per-shard dispatches multiplies the
         # per-call orchestration cost this engine exists to amortize
-        with trace.span("ec.reconstruct_batch", intervals=len(todo),
-                        shards=len(wanted),
-                        bytes=sum(s for _, s in gsegs)), \
-                _pipeline.flow("ec_read").stage(
-                    "reconstruct", nbytes=sum(s for _, s in gsegs)):
-            rebuilt = ec_files._reconstruct_batch(codec, rows, wanted)
+        flow = _read_flow()
+        with flow.stage("reconstruct", nbytes=sum(s for _, s in gsegs),
+                        intervals=len(todo), shards=len(wanted)):
+            rebuilt = ec_files._reconstruct_batch(codec, rows, wanted,
+                                                  job=flow)
         self._bump("reconstruct_batches")
         self._bump("reconstruct_intervals", len(todo))
         if heat.ambient_is_data():
@@ -538,10 +542,9 @@ class EcVolume:
             else:
                 probe.append(ri)
         # local reads, concurrent when there is anything to overlap
-        with trace.span("ec.local_pread", reads=len(probe)) as lsp, \
-                _pipeline.flow("ec_read").stage(
-                    "local_pread",
-                    nbytes=sum(reads[ri][2] for ri in probe)):
+        with _read_flow().stage(
+                "local_pread", nbytes=sum(reads[ri][2] for ri in probe),
+                reads=len(probe)) as lsp:
             if len(probe) == 1:
                 ri = probe[0]
                 sid, off, size, _ = reads[ri]
@@ -600,12 +603,12 @@ class EcVolume:
                 else:
                     still.append(ri)
 
-            with trace.span("ec.remote_fetch", reads=len(failed),
-                            hedge_ms=None if hedge_s is None else
-                            round(hedge_s * 1000.0, 1)) as rsp, \
-                    _pipeline.flow("ec_read").stage(
-                        "remote_fetch",
-                        nbytes=sum(reads[ri][2] for ri in failed)):
+            with _read_flow().stage(
+                    "remote_fetch",
+                    nbytes=sum(reads[ri][2] for ri in failed),
+                    reads=len(failed),
+                    hedge_ms=None if hedge_s is None else
+                    round(hedge_s * 1000.0, 1)) as rsp:
                 rpool = ThreadPoolExecutor(max_workers=min(8, len(failed)))
                 futs = {rpool.submit(timed_fetch, *reads[ri][:3]): ri
                         for ri in failed}

@@ -11,6 +11,7 @@ process in cProfile, hooks run on termination signals.
 from __future__ import annotations
 
 import atexit
+import contextlib
 import cProfile
 import faulthandler
 import logging
@@ -27,8 +28,9 @@ _profiler: cProfile.Profile | None = None
 
 def on_interrupt(hook) -> None:
     """Register a cleanup hook to run on SIGINT/SIGTERM (reference:
-    grace.OnInterrupt)."""
-    _hooks.append(hook)
+    grace.OnInterrupt).  Registering a hook twice runs it once."""
+    if hook not in _hooks:
+        _hooks.append(hook)
     _install()
 
 
@@ -85,17 +87,68 @@ def setup_profiling(cpu_profile_path: str | None) -> None:
     on_interrupt(dump)
 
 
-def jax_profile(trace_dir: str | None):
-    """Context manager capturing a JAX profiler (xprof) trace into
-    trace_dir — the TPU build's answer to the reference's pprof CPU
-    profiles (SURVEY §5: 'JAX profiler + xprof traces fill this role').
-    No-op when trace_dir is falsy, so call sites can pass the flag
-    straight through.  View with tensorboard or xprof."""
-    import contextlib
-    if not trace_dir:
-        return contextlib.nullcontext()
+# -- the JAX profiler: one session a process -------------------------------
+#
+# While a session is open the EC plane's stages (stats/pipeline.Stage)
+# land on the trace's host plane as `ec.*` / `codec.*` annotations, on the
+# device planes' clock.  Two things open one: `--jax-profile DIR` for the
+# process's lifetime, and `GET /debug/jax_profile?seconds=N` for a window
+# on a running server (stats/profile.handle_debug_jax_profile).  Both come
+# through here, and SIGTERM closes whichever is open before the process
+# exits, so the .xplane.pb is written.
+
+_jax_profile_lock = threading.Lock()
+_jax_profile_dir: str | None = None
+
+
+def start_jax_profile(trace_dir: str) -> bool:
+    """Open the session on `trace_dir`; False when one is open already.
+    The Python tracer stays off: the program names its own stages, and a
+    trace of two 1 GB encode calls is then half a megabyte.  Initialises
+    the JAX backend, as any profiler session does."""
+    global _jax_profile_dir
     import jax
-    return jax.profiler.trace(trace_dir)
+    with _jax_profile_lock:
+        if _jax_profile_dir is not None:
+            return False
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        try:
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        except RuntimeError:  # opened behind this module's back
+            return False
+        _jax_profile_dir = trace_dir
+    log.info("jax profiler trace started -> %s", trace_dir)
+    return True
+
+
+def stop_jax_profile() -> str | None:
+    """Close the open session -> its directory, the trace written; None
+    when none is open."""
+    global _jax_profile_dir
+    with _jax_profile_lock:
+        trace_dir, _jax_profile_dir = _jax_profile_dir, None
+        if trace_dir is None:
+            return None
+        import jax
+        try:
+            jax.profiler.stop_trace()
+        except RuntimeError:
+            return None  # already stopped
+    log.info("jax profiler trace written to %s", trace_dir)
+    return trace_dir
+
+
+@contextlib.contextmanager
+def jax_profile(trace_dir: str | None):
+    """Context-manager variant (bench.py); a no-op when trace_dir is
+    falsy, so call sites can pass the flag straight through."""
+    started = bool(trace_dir) and start_jax_profile(trace_dir)
+    try:
+        yield
+    finally:
+        if started:
+            stop_jax_profile()
 
 
 def setup_jax_profile(trace_dir: str | None) -> None:
@@ -103,14 +156,5 @@ def setup_jax_profile(trace_dir: str | None) -> None:
     now, stop it at exit/interrupt."""
     if not trace_dir:
         return
-    import jax
-    jax.profiler.start_trace(trace_dir)
-    log.info("jax profiler trace started -> %s", trace_dir)
-
-    def stop():
-        try:
-            jax.profiler.stop_trace()
-            log.info("jax profiler trace written to %s", trace_dir)
-        except RuntimeError:
-            pass  # already stopped
-    on_interrupt(stop)
+    on_interrupt(stop_jax_profile)
+    start_jax_profile(trace_dir)

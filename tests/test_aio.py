@@ -354,11 +354,11 @@ def test_fleet_convert_crash_safety_tmp_rename_under_uring(tmp_path,
     orig = fleet_convert.dispatch_parity_batch
     calls = {"n": 0}
 
-    def failing(codec, units, placed=None):
+    def failing(codec, units, **kw):
         calls["n"] += 1
         if calls["n"] >= 2:
             raise boom
-        return orig(codec, units, placed)
+        return orig(codec, units, **kw)
 
     monkeypatch.setattr(fleet_convert, "dispatch_parity_batch", failing)
     with pytest.raises(RuntimeError, match="injected"):

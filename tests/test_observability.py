@@ -906,7 +906,9 @@ def test_trace_propagation_degraded_filer_read(tmp_path, monkeypatch):
         assert "filer.chunk_fetch" in names
         assert "volume.request" in names
         # EC engine stages from the worker thread
-        assert "ec.plan" in names and "ec.reconstruct_batch" in names
+        assert "ec.plan" in names and "ec.read.reconstruct" in names
+        # ... and the dispatch seam's stage under it (a host codec here)
+        assert "codec.dispatch" in names
         # peer shard spans: the fetch on the serving server AND the
         # peer's handling of /admin/ec/shard_read in the same trace
         assert "volume.shard_fetch" in names

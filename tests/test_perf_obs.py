@@ -58,20 +58,46 @@ def test_stage_accounting_busy_blocked_invariants():
     assert snap["state"] == "done" and snap["bytes"] == 100
 
 
-def test_stats_dict_seconds_win_and_stall_maps_to_blocked():
-    # the wrapped stats dict (the _Timer contract bench.py reads) is the
-    # source of truth for stage TIME; stall_s is idle, never a stage
-    stats = {"encode_s": 2.0, "write_parity_s": 1.0, "stall_s": 0.5}
+def test_stage_seconds_write_through_and_stall_maps_to_blocked():
+    # a Stage books its seconds to the job AND to the `<stage>_s` key of
+    # the wrapped stats dict (what /admin/ec/progress and bench.py read);
+    # seconds folded into the dict alone (the write engines' submit and
+    # complete) still make a stage; stall_s is idle, never a stage
+    stats = {"write_parity_s": 1.0}
     job = pipeline.PipelineJob("t", stats)
     with job.stage("encode", nbytes=10):
-        pass  # own timer booked ~0s: the stats seconds must win
+        time.sleep(0.002)
+    with job.blocked("stall"):
+        time.sleep(0.002)
     job.add_bytes("encode", 90)
+    job.add_bytes("write_parity", 5)
     job.finish()
     snap = job.snapshot()
-    assert snap["stages"]["encode"]["busy_s"] == 2.0
+    assert stats["encode_s"] >= 0.002
+    assert snap["stages"]["encode"]["busy_s"] == \
+        pytest.approx(stats["encode_s"], abs=1e-6)
     assert snap["stages"]["encode"]["bytes"] == 100
+    assert snap["stages"]["write_parity"]["busy_s"] == 1.0
+    assert snap["stages"]["write_parity"]["bytes"] == 5
     assert "stall" not in snap["stages"]
-    assert snap["blocked_s"] == 0.5
+    assert snap["blocked_s"] == pytest.approx(stats["stall_s"], abs=1e-4)
+
+
+def test_lump_is_the_sum_of_its_parts_at_every_moment():
+    stats: dict = {}
+    job = pipeline.PipelineJob(
+        "t", stats, sums={"d2h": ("device_wait", "d2h_copy")})
+    for part in ("device_wait", "d2h_copy", "device_wait"):
+        with job.stage(part):
+            time.sleep(0.001)
+        assert stats["d2h_s"] == pytest.approx(
+            stats.get("device_wait_s", 0.0) + stats.get("d2h_copy_s", 0.0),
+            rel=1e-9)
+    job.finish()
+    stages = job.snapshot()["stages"]
+    assert stages["d2h"]["busy_s"] == pytest.approx(
+        stages["device_wait"]["busy_s"] + stages["d2h_copy"]["busy_s"],
+        abs=2e-6)
 
 
 def test_queue_depth_bounds_and_averages():
@@ -128,7 +154,7 @@ def test_writer_pool_worker_counts_accumulate_across_pools(tmp_path):
                       os.O_RDWR | os.O_CREAT, 0o644) for i in range(3)]
         fds += fs
         pool = ec_files._ShardWriterPool(
-            fs, None, stats, stage_key=lambda i: "write_s")
+            fs, None, pipeline.PipelineJob("t", stats, register=False))
         for i in range(3):
             pool.put(i, np.ones(1024, dtype=np.uint8), 0)
         pools.append(pool)

@@ -1,0 +1,432 @@
+"""The EC plane's one stage primitive (stats/pipeline.Stage), driven
+through the four engines on the `jax` codec: what it leaves on the
+profiler's trace, in the engines' stats dicts, in the compile counter and
+on /perf; plus the two ways to open a profiler session on a server
+(`--jax-profile`, `/debug/jax_profile`) and the SIGTERM that flushes."""
+
+import asyncio
+import glob
+import json
+import logging
+import os
+import signal
+import subprocess
+import sys
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from seaweedfs_tpu.ops import dispatch, fleet_convert
+from seaweedfs_tpu.stats import pipeline, profile, trace
+from seaweedfs_tpu.storage import needle as ndl
+from seaweedfs_tpu.storage.ec import ec_files, ec_volume, layout
+from seaweedfs_tpu.storage.volume import Volume
+from seaweedfs_tpu.utils import grace
+from tests.test_observability import _mock_req
+
+LARGE, SMALL, BATCH = 10000, 100, 1000
+KINDS = ["encode", "rebuild", "fleet", "degraded_read"]
+SEAM = {"codec.h2d", "codec.dispatch", "codec.device_wait",
+        "codec.d2h_copy"}
+# the engine's own stages that a tiny run on a device codec must show
+# (`stall` is there too whenever a pooled buffer was waited for)
+ENGINE = {"encode": {"ec.encode.read", "ec.encode.write_data",
+                     "ec.encode.write_parity"},
+          "rebuild": {"ec.rebuild.stage", "ec.rebuild.unstage",
+                      "ec.rebuild.write"},
+          "fleet": {"ec.fleet.read", "ec.fleet.write_data",
+                    "ec.fleet.write_parity"},
+          "degraded_read": {"ec.read.local_pread",
+                            "ec.read.gather_survivors",
+                            "ec.read.reconstruct"}}
+# every key /admin/ec/progress `stages` carried before the seam's cut
+# (the parent commit's stats dicts of the same tiny runs)
+OLD_KEYS = {
+    "encode": {"aio_mode", "backend", "bytes", "d2h_s", "encode_s", "mode",
+               "overlap_frac", "read_s", "stall_s", "wall_s",
+               "write_data_s", "write_data_workers", "write_parity_s",
+               "write_parity_workers"},
+    "rebuild": {"aio_mode", "bytes", "codec", "mode", "overlap_frac",
+                "reconstruct_s", "stall_s", "wall_s", "write_s",
+                "write_workers"},
+    "fleet": {"aio_mode", "backend", "bytes", "committed_bases", "d2h_s",
+              "devices", "encode_s", "mode", "overlap_frac", "read_s",
+              "stall_s", "unit_batch", "units", "volumes", "wall_s",
+              "write_data_s", "write_data_workers", "write_parity_s",
+              "write_parity_workers"},
+    "degraded_read": {"gather_survivors", "local_pread", "reconstruct"},
+}
+
+
+@pytest.fixture(autouse=True)
+def _jax_codec(monkeypatch):
+    monkeypatch.setenv("WEEDTPU_EC_CODEC", "jax")
+    monkeypatch.delenv("WEEDTPU_CONVERT_CODEC", raising=False)
+    pipeline.reset()
+    yield
+    pipeline.reset()
+
+
+def _make_ec(tmp_path, n=30):
+    vol = Volume(str(tmp_path), "", 3)
+    rng = np.random.default_rng(5)
+    blobs = {}
+    for i in range(1, n + 1):
+        data = rng.integers(0, 256, int(rng.integers(1, 4000)),
+                            dtype=np.uint8).tobytes()
+        vol.append_needle(ndl.Needle(cookie=0x9, id=i, data=data))
+        blobs[i] = data
+    vol.close()
+    base = str(tmp_path / "3")
+    ec_files.write_ec_files(base, large_block=LARGE, small_block=SMALL,
+                            batch_size=BATCH)
+    ec_files.write_sorted_ecx(base + ".idx")
+    return base, blobs
+
+
+def prepare(kind: str, tmp_path):
+    """Untimed set-up of one tiny run -> op(); op() runs it and returns
+    (what carries the stage seconds, the id its annotations carry)."""
+    tmp_path.mkdir(exist_ok=True)
+    rng = np.random.default_rng(11)
+    if kind == "encode":
+        base = str(tmp_path / "1")
+        rng.integers(0, 256, 230_000, dtype=np.uint8).tofile(base + ".dat")
+
+        def op():
+            stats: dict = {}
+            ec_files.write_ec_files(base, large_block=LARGE,
+                                    small_block=SMALL, batch_size=BATCH,
+                                    stats=stats)
+            return stats, ("job", _last_job("ec_encode")["id"])
+    elif kind == "rebuild":
+        base, _ = _make_ec(tmp_path)
+        os.remove(base + layout.to_ext(3))
+
+        def op():
+            stats: dict = {}
+            assert ec_files.rebuild_ec_files(base, batch_size=BATCH,
+                                             stats=stats) == [3]
+            return stats, ("job", _last_job("ec_rebuild")["id"])
+    elif kind == "fleet":
+        bases = []
+        for i, size in enumerate((150_000, 99_777)):
+            bases.append(str(tmp_path / f"f{i}"))
+            rng.integers(0, 256, size, dtype=np.uint8).tofile(
+                bases[-1] + ".dat")
+
+        def op():
+            stats: dict = {}
+            fleet_convert.convert_volumes(
+                bases, large_block=LARGE, small_block=SMALL,
+                batch_size=BATCH, stats=stats)
+            assert stats["backend"] == "JaxRSCodec"
+            return stats, ("job", _last_job("fleet_convert")["id"])
+    else:
+        base, blobs = _make_ec(tmp_path)
+        for sid in (0, 1):
+            os.remove(base + layout.to_ext(sid))
+
+        def op():
+            root = trace.new_root()
+            token = trace._current.set(root)
+            ev = ec_volume.EcVolume(base, LARGE, SMALL)
+            try:
+                for nid, data in blobs.items():
+                    assert ev.read_needle(nid).data == data
+            finally:
+                ev.close()
+                trace._current.reset(token)
+            return _last_job("ec_read")["stages"], ("trace", root.trace_id)
+    return op
+
+
+def _last_job(kind: str) -> dict:
+    return next(j for j in pipeline.jobs_snapshot() if j["kind"] == kind)
+
+
+def _annotations(trace_dir: str) -> dict[str, list[dict]]:
+    """{event name: [its stats]} of the program's annotations in the
+    newest .xplane.pb under `trace_dir`."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    found: dict[str, list[dict]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        assert not plane.name.startswith("/device:") or not any(
+            e.name.startswith(("ec.", "codec."))
+            for ln in plane.lines for e in ln.events)
+        for ln in plane.lines:
+            for e in ln.events:
+                if e.name.startswith(("ec.", "codec.")):
+                    found.setdefault(e.name, []).append(
+                        {k: v for k, v in e.stats})
+    return found
+
+
+# -- (a) the profiler session is the switch ---------------------------------
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_stages_annotate_the_profilers_trace(kind, tmp_path):
+    op = prepare(kind, tmp_path / "data")
+    assert pipeline._profiler_annotation() is None  # no session: nothing
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        assert pipeline._profiler_annotation() is jax.profiler.TraceAnnotation
+        _, (id_key, id_value) = op()
+    finally:
+        jax.profiler.stop_trace()
+    assert pipeline._profiler_annotation() is None
+    found = _annotations(str(tmp_path / "trace"))
+    assert SEAM | ENGINE[kind] <= set(found), sorted(found)
+    for name in SEAM | ENGINE[kind]:
+        mine = [s for s in found[name] if str(s.get(id_key)) ==
+                str(id_value)]
+        assert mine, (name, id_key, id_value, found[name][:3])
+        if kind != "degraded_read" and ".write" not in name:
+            # bulk stages say which unit (a writer's batch spans several)
+            assert {int(s["unit"]) for s in mine} >= {0}
+
+
+def test_a_session_opened_after_the_run_holds_no_annotation(tmp_path):
+    prepare("encode", tmp_path / "data")()
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    jax.profiler.stop_trace()
+    assert _annotations(str(tmp_path / "trace")) == {}
+
+
+def test_host_codec_process_stages_initialise_no_jax_backend(tmp_path):
+    """All four engines on the native codec in a fresh process: the stage
+    primitive looks for a profiler session (jax is loaded:
+    ops.native_codec imports it) and must bring up no backend doing so."""
+    from seaweedfs_tpu import native
+    if not native.available():
+        pytest.skip("no native codec here")
+    code = (
+        "import os, pathlib, sys\n"
+        "os.environ['WEEDTPU_EC_CODEC'] = 'cpp'\n"
+        "os.environ.pop('WEEDTPU_CONVERT_CODEC', None)\n"
+        "from tests import test_stage_tracing as t\n"
+        "from seaweedfs_tpu.stats import pipeline\n"
+        "for kind in t.KINDS:\n"
+        "    if kind != 'fleet':  # its op asserts the jax codec: below\n"
+        "        t.prepare(kind, pathlib.Path(sys.argv[1]) / kind)()\n"
+        "import numpy as np\n"
+        "from seaweedfs_tpu.ops import fleet_convert\n"
+        "b = str(pathlib.Path(sys.argv[1]) / 'v')\n"
+        "np.zeros(50_000, np.uint8).tofile(b + '.dat')\n"
+        "fleet_convert.convert_volumes([b], large_block=10000,\n"
+        "                              small_block=100, batch_size=1000)\n"
+        "assert pipeline._profiler_annotation() is None\n"
+        "assert pipeline.local_snapshot()['compiles'] == {}\n"
+        "from jax._src import xla_bridge\n"
+        "assert 'jax' in sys.modules\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    env = {k: v for k, v in os.environ.items() if k != "WEEDTPU_EC_CODEC"}
+    r = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                       capture_output=True, text=True, timeout=180,
+                       cwd=os.path.dirname(os.path.dirname(__file__)),
+                       env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+
+
+# -- (b) the lumps are the sums of their parts ------------------------------
+
+def _sum(stats, *keys):
+    return sum(stats[k] for k in keys)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_sum_identities_and_every_old_key(kind, tmp_path):
+    stats, _ = prepare(kind, tmp_path)()
+    assert OLD_KEYS[kind] <= set(stats), OLD_KEYS[kind] - set(stats)
+    if kind in ("encode", "fleet"):
+        assert stats["encode_s"] == pytest.approx(
+            _sum(stats, "h2d_s", "dispatch_s"), rel=1e-9)
+        assert stats["d2h_s"] == pytest.approx(
+            _sum(stats, "device_wait_s", "d2h_copy_s"), rel=1e-9)
+    elif kind == "rebuild":
+        assert stats["reconstruct_s"] == pytest.approx(
+            _sum(stats, "stage_s", "h2d_s", "dispatch_s", "device_wait_s",
+                 "d2h_copy_s", "unstage_s"), rel=1e-9)
+    else:
+        # the read engine nests: `reconstruct` holds the seam's four
+        parts = sum(stats[s]["busy_s"] for s in
+                    ("h2d", "dispatch", "device_wait", "d2h_copy"))
+        assert 0 < parts <= stats["reconstruct"]["busy_s"] + 1e-4
+        assert stats["reconstruct"]["items"] == stats["dispatch"]["items"]
+    if kind != "degraded_read":
+        # a part is never counted beside its lump
+        stage_sum = sum(v for k, v in stats.items() if k.endswith("_s")
+                        and k not in ("wall_s", "stall_s", "submit_s",
+                                      "complete_s")
+                        and k not in ec_files._PART_KEYS)
+        assert stats["overlap_frac"] == round(
+            max(0.0, 1.0 - stats["wall_s"] / stage_sum), 3)
+
+
+def test_reconstruct_books_device_seconds():
+    """PERF.md's verdict table (PR 23): `reconstruct` had no device row."""
+    codec = ec_files._get_codec("jax")
+    before = profile.KERNELS.snapshot().get("reconstruct[device]", {})
+    rng = np.random.default_rng(2)
+    rows = {i: rng.integers(0, 256, 70_000, dtype=np.uint8)
+            for i in range(1, 11)}
+    out = dispatch.reconstruct_batch(codec, rows, [0])
+    assert out[0].shape == (70_000,)
+    after = profile.KERNELS.snapshot()["reconstruct[device]"]
+    assert after["device_s"] > before.get("device_s", 0.0)
+    assert after["d2h_bytes"] - before.get("d2h_bytes", 0.0) == 70_000
+    assert after["h2d_bytes"] - before.get("h2d_bytes", 0.0) == 700_000
+
+
+# -- (c) compilations, counted inside the program ---------------------------
+
+def _compiles(entry: str) -> int:
+    return profile.compiles_snapshot().get(entry, {}).get("count", 0)
+
+
+def test_compile_counter_by_entry_point_agrees_with_the_log(caplog):
+    codec = ec_files._get_codec("jax")  # notes the codec: counting is on
+    rng = np.random.default_rng(3)
+
+    def degraded_read(length: int) -> None:
+        rows = {i: rng.integers(0, 256, length, dtype=np.uint8)
+                for i in range(1, 11)}
+        dispatch.reconstruct_batch(codec, rows, [0])
+
+    def total() -> int:
+        return sum(r["count"] for r in profile.compiles_snapshot().values())
+
+    jax.config.update("jax_log_compiles", True)
+    try:
+        with caplog.at_level(logging.WARNING, logger="jax"):
+            t0 = total()
+            r0, e0 = _compiles("reconstruct"), _compiles("encode_parity")
+            degraded_read(31_337)  # a needle length not seen before
+            r1 = _compiles("reconstruct")
+            assert r1 > r0 and _compiles("encode_parity") == e0
+            degraded_read(31_337)  # the same length again: nothing new
+            assert _compiles("reconstruct") == r1
+            degraded_read(31_339)
+            assert _compiles("reconstruct") > r1
+            dispatch.materialize(dispatch.dispatch_parity(
+                codec, rng.integers(0, 256, (10, 7_777), dtype=np.uint8)))
+            assert _compiles("encode_parity") == e0 + 1
+            jax.jit(lambda x: x * 3 + 1)(np.arange(5))  # outside the seam
+            logged = [r for r in caplog.records
+                      if "Finished XLA compilation of" in r.getMessage()]
+            assert total() - t0 == len(logged) > 3
+    finally:
+        jax.config.update("jax_log_compiles", False)
+    snap = profile.compiles_snapshot()
+    assert snap["other"]["count"] >= 1
+    assert all(r["seconds"] > 0 for r in snap.values())
+
+
+# -- (d) /perf --------------------------------------------------------------
+
+def test_perf_keeps_every_old_key_and_carries_compiles(tmp_path):
+    prepare("degraded_read", tmp_path)()
+    resp = asyncio.run(pipeline.handle_perf(_mock_req("/perf", "10.0.0.9")))
+    body = json.loads(resp.text)
+    assert {"id", "enabled", "jobs", "roofline", "codecs",
+            "compiles"} <= set(body)
+    assert body["compiles"]["reconstruct"]["count"] >= 1
+    assert set(body["compiles"]["reconstruct"]) == {"count", "seconds"}
+    for row in body["roofline"]["rows"]:
+        assert {"kernel", "backend", "resource", "calls", "gbytes",
+                "busy_s", "achieved_gbps"} <= set(row)
+    flow = next(j for j in body["jobs"] if j["kind"] == "ec_read")
+    assert flow["state"] == "flow"
+    assert {"busy_s", "blocked_s", "bytes", "items", "busy_frac"} <= \
+        set(flow["stages"]["reconstruct"])
+    assert any(b["codec"] == "JaxRSCodec" for b in body["codecs"])
+
+
+# -- the cost of a stage with the profiler closed ---------------------------
+
+def test_a_closed_profiler_stage_costs_microseconds():
+    job = pipeline.PipelineJob("t", register=False)
+    n = 20_000
+    t0 = time.perf_counter()
+    for i in range(n):
+        with job.stage("s", unit=i):
+            pass
+    per_stage = (time.perf_counter() - t0) / n
+    # a bulk call opens about a thousand: 1 ms of a 0.44 s call at 1 us
+    assert per_stage < 50e-6, per_stage
+    assert job.stats["s_s"] > 0 and job.snapshot()["stages"]["s"]["items"] == n
+
+
+# -- opening a session on a server: --jax-profile, SIGTERM, the route --------
+
+def test_sigterm_closes_the_jax_profile(tmp_path):
+    """What `--jax-profile DIR` does (grace.setup_jax_profile), in a
+    child on the CPU: SIGTERM must leave the .xplane.pb, with the stage
+    annotations of the encode that ran inside the session."""
+    code = (
+        "import os, sys, time\n"
+        "import numpy as np\n"
+        "from seaweedfs_tpu.utils import grace\n"
+        "from seaweedfs_tpu.storage.ec import ec_files\n"
+        "base = os.path.join(sys.argv[1], '1')\n"
+        "np.arange(200_000, dtype=np.uint8).tofile(base + '.dat')\n"
+        "grace.setup_jax_profile(os.path.join(sys.argv[1], 'trace'))\n"
+        "ec_files.write_ec_files(base, large_block=10000, small_block=100,\n"
+        "                        batch_size=1000)\n"
+        "print('ready', flush=True)\n"
+        "time.sleep(120)\n")
+    env = dict(os.environ, WEEDTPU_EC_CODEC="jax", JAX_PLATFORMS="cpu")
+    p = subprocess.Popen([sys.executable, "-c", code, str(tmp_path)],
+                         stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                         text=True, env=env,
+                         cwd=os.path.dirname(os.path.dirname(__file__)))
+    try:
+        assert p.stdout.readline().strip() == "ready", p.stderr.read()[-2000:]
+        p.send_signal(signal.SIGTERM)
+        assert p.wait(60) == 128 + signal.SIGTERM
+    finally:
+        if p.poll() is None:
+            p.kill()
+    found = _annotations(str(tmp_path / "trace"))
+    assert "ec.encode.read" in found and SEAM <= set(found), sorted(found)
+
+
+def test_debug_jax_profile_opens_one_window_at_a_time(tmp_path):
+    handler = profile.handle_debug_jax_profile
+    ec_files._get_codec("jax")  # a codec of this process runs on JAX
+    op = prepare("encode", tmp_path / "data")
+
+    async def window_with_an_encode_in_it():
+        first = asyncio.ensure_future(handler(_mock_req(
+            f"/debug/jax_profile?seconds=3&dir={tmp_path}/w",
+            "127.0.0.1")))
+        await asyncio.sleep(0.3)
+        second = await handler(_mock_req(
+            "/debug/jax_profile?seconds=0.1", "127.0.0.1"))
+        await asyncio.to_thread(op)
+        return await first, second
+
+    first, second = asyncio.run(window_with_an_encode_in_it())
+    assert second.status == 400 and "open already" in second.text
+    assert first.status == 200, first.text
+    body = json.loads(first.text)
+    assert body["dir"] == f"{tmp_path}/w" and len(body["xplane"]) == 1
+    assert "ec.encode.read" in _annotations(body["dir"])
+    assert grace.stop_jax_profile() is None  # the window closed itself
+    # mounted beside /debug/pprof, behind the same loopback guard
+    routes = {r.path: r.handler for r in trace.debug_routes()}
+    resp = asyncio.run(routes["/debug/jax_profile"](
+        _mock_req("/debug/jax_profile?seconds=0.1", "10.0.0.9")))
+    assert resp.status == 403
+
+
+def test_debug_jax_profile_refuses_a_process_with_no_jax_backend(
+        monkeypatch):
+    monkeypatch.setattr(profile, "_codecs_noted", {})
+    resp = asyncio.run(profile.handle_debug_jax_profile(
+        _mock_req("/debug/jax_profile?seconds=0.1", "127.0.0.1")))
+    assert resp.status == 400 and "initialise" in resp.text

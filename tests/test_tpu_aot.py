@@ -13,6 +13,9 @@ never means the interpreter, and a host-codec process never initialises a
 JAX backend.
 """
 
+import json
+import pathlib
+import re
 import subprocess
 import sys
 
@@ -48,6 +51,28 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _assert_trace_names(compiled, kernel: str, module: str) -> None:
+    """The compiled program carries the names the benchmark sums device
+    time by: a device trace names an `XLA Ops` event by the op's whole HLO
+    line and an `XLA Modules` event by the module, and
+    benchmark/kernels.json matches the former.  Pinned by `name=` on the
+    pallas_call and codec_base.named_jit, not derived from what the Python
+    functions are called."""
+    table = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                        / "kernels.json").read_text())
+    patterns = [re.compile(p) for p in table["kernels"][kernel]["patterns"]]
+    text = compiled.as_text()
+    assert text.startswith(f"HloModule {module},"), text[:80]
+    calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1, calls
+    assert any(p.search(calls[0]) for p in patterns), calls[0][:120]
+    others = [k for k, spec in table["kernels"].items()
+              if spec["patterns"] != table["kernels"][kernel]["patterns"]
+              and any(re.search(p, calls[0]) for p in spec["patterns"])]
+    assert not others, (calls[0][:120], others)
+
+
 def _lifted(C, sharding):
     """(spec of C's plane-major lift, kpad), by the mesh seam's own rule."""
     seam = pmesh._ApplyKernel("pallas", TILE)
@@ -69,7 +94,9 @@ def test_gf_apply_compiles_for_v5e(v5e, wanted):
     compiled = pallas_gf._gf_apply.lower(
         bm, _spec((k, MIB), jnp.uint8, one),
         k=k, m=m, kpad=kpad, tile=TILE, interpret=False).compile()
-    assert compiled is not None
+    _assert_trace_names(compiled,
+                        "gf_apply" if wanted is None else "gf_reconstruct",
+                        "jit__gf_apply")
 
 
 def test_gf_apply_batch_compiles_for_v5e(v5e):
@@ -77,9 +104,10 @@ def test_gf_apply_batch_compiles_for_v5e(v5e):
     code = rs.get_code(10, 4)
     one = SingleDeviceSharding(v5e[0])
     bm, kpad = _lifted(code.parity_matrix, one)
-    pallas_gf._gf_apply_batch.lower(
+    compiled = pallas_gf._gf_apply_batch.lower(
         bm, _spec((4, 10, MIB), jnp.uint8, one),
         k=10, m=4, kpad=kpad, tile=TILE, interpret=False).compile()
+    _assert_trace_names(compiled, "gf_apply_batch", "jit__gf_apply_batch")
 
 
 def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
@@ -98,6 +126,7 @@ def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
     # [U, k, B] in and [U, m, B] out differ in shape: nothing to alias,
     # which is why the encoder donates nothing
     assert "input_output_alias" not in compiled.as_text()
+    _assert_trace_names(compiled, "gf_apply_batch", "jit_batch_body")
 
     col_mesh = Mesh(np.array(v5e), ("data",))
     col = pmesh.ShardedRSEncoder(code, col_mesh, kernel="pallas", tile=TILE)
