@@ -22,6 +22,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def named_jit(name: str, **jit_kwargs):
@@ -48,6 +49,25 @@ def decode_cache_cap() -> int:
         return 64
 
 
+# A reconstruct runs at one of a few widths, not at its row length: XLA and
+# Mosaic build a program per shape, and a degraded read's length is its
+# needle's.  The widths are the tile times 1, 2, 4 ... 2^(BUCKETS-1), and
+# past the largest its multiples (a rebuild batch is one, so it is put up
+# as it is).  The columns added are zero and cost nothing but the bytes:
+# a GF(2^8) matrix apply is column-local.
+BUCKETS = 8
+
+
+def bucket(n: int, tile: int) -> int:
+    """The width a reconstruct of `n` byte columns runs at under a codec
+    of this `tile`: >= n, a tile multiple, under 2 x max(n, tile), and one
+    of BUCKETS values up to tile << (BUCKETS - 1)."""
+    top = tile << (BUCKETS - 1)
+    if n > top:
+        return -(-n // top) * top
+    return tile << max(0, -(-n // tile) - 1).bit_length()
+
+
 def select_survivors(code, present: tuple, wanted: list[int]) -> tuple:
     """The survivor basis a decode matrix is built against: the code's
     `decode_select` when it has one, else the MDS default of the first
@@ -58,11 +78,28 @@ def select_survivors(code, present: tuple, wanted: list[int]) -> tuple:
     return tuple(present[: code.k])
 
 
+def stacked(data, k: int) -> jax.Array:
+    """Inside a program: the [k, W] stack of an input in *linear* form,
+    which is the k rows as a tuple of [W] arrays or one after the other
+    in one [k * W] array.  The runtime moves a 1-D array between host and
+    device as it lies; a 2-D uint8 one goes through a relayout on the
+    host, in both directions, that costs more than the transfer (a
+    `[10, 16 MiB]` put 48 ms against 16 for its ten rows, a `[1, 16 MiB]`
+    copy back 88 ms against 6; PERF.md, PR 26).  So the reconstruct seam
+    moves only 1-D arrays, and the program that applies the matrix lays
+    them out: `linear=True` on a matrix apply is this on the way in and
+    the product's rows one after the other in one array on the way out."""
+    if isinstance(data, (tuple, list)):
+        return jnp.stack(data, axis=0)
+    return data.reshape(k, -1)
+
+
 class RSCodecBase:
     """Encode / reconstruct for one fixed-matrix GF(2^8) code.
 
-    `matrix_apply_factory(C) -> callable([k, n] bytes) -> [m, n] bytes`
-    supplies the device kernel for a fixed GF(2^8) matrix C.
+    `matrix_apply_factory(C) -> callable([k, n] bytes, linear=False) ->
+    [m, n] bytes` (`linear`: 1-D in and out, see `stacked`) supplies the
+    device kernel for a fixed GF(2^8) matrix C.
     """
 
     def __init__(self, code, matrix_apply_factory):
@@ -109,20 +146,38 @@ class RSCodecBase:
         """[k, n] data -> [k+m, n] shards."""
         return jnp.concatenate([data, self.encode_parity(data)], axis=0)
 
+    def decode_basis(self, present, wanted: list[int]) -> tuple:
+        """The survivors whose rows `reconstruct_stack` takes, in the
+        order it takes them (first k sorted for MDS codes, the
+        decode_select choice otherwise)."""
+        return select_survivors(self.code, tuple(sorted(present)),
+                                list(wanted))
+
+    def reconstruct_stack(self, stack, present, wanted: list[int],
+                          linear: bool = False) -> jax.Array:
+        """[len(basis), W] survivor rows in `decode_basis(present, wanted)`
+        order -> the [len(wanted), W] rebuilt rows: the cached decode
+        matrix applied to the stack as it is, one program and nothing
+        else on the device.  The matrix is cached per (basis, wanted)
+        pattern since failure patterns are few in practice; W is the
+        caller's to keep to a few values (`bucket`).
+
+        `linear`: the stack comes, and the rows go back, as 1-D arrays
+        (`stacked`)."""
+        _, mat = self._cached_decode(tuple(sorted(present)), tuple(wanted))
+        return mat(stack, linear)
+
     def reconstruct(self, shards: dict[int, jax.Array],
                     wanted: list[int] | None = None) -> dict[int, jax.Array]:
-        """Rebuild missing shards from sufficient survivors.
-
-        The code's survivor basis (first k sorted for MDS codes, the
-        decode_select choice otherwise) feeds the decode matrix; the
-        matrix is cached per (basis, wanted) pattern since failure
-        patterns are few in practice."""
-        present = tuple(sorted(shards))
+        """Rebuild missing shards from a dict of sufficient survivor rows:
+        `reconstruct_stack` for callers that hold rows one by one (the
+        MSR file codec's virtual rows, tests).  The stack is built on the
+        side the rows live on."""
         if wanted is None:
             wanted = [i for i in range(self.n) if i not in shards]
         if not wanted:
             return {}
-        basis, mat = self._cached_decode(present, tuple(wanted))
-        stack = jnp.stack([shards[i] for i in basis], axis=0)
-        out = mat(stack)
+        rows = [shards[i] for i in self.decode_basis(shards, wanted)]
+        xp = np if all(isinstance(r, np.ndarray) for r in rows) else jnp
+        out = self.reconstruct_stack(xp.stack(rows, axis=0), shards, wanted)
         return {w: out[i] for i, w in enumerate(wanted)}

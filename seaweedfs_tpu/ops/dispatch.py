@@ -59,17 +59,21 @@ def _host_call(job, unit, kernel: str, nbytes: int, fn, *args, **kwargs):
     return out
 
 
-def _device_call(job, unit, kernel: str, nbytes: int, put, run, **attrs):
+def _device_call(job, unit, kernel: str, nbytes: int, put, run,
+                 h2d_bytes: int | None = None, **attrs):
     """The first two stages of a device dispatch: `put()` sends the inputs
     up (`h2d`), `run(placed)` enqueues the work on them (`dispatch`).  The
-    result comes back un-materialised; `_to_host` holds the other two."""
+    result comes back un-materialised; `_to_host` holds the other two.
+    `nbytes` are the caller's useful bytes, `h2d_bytes` what crosses where
+    that is more (a padded put)."""
     with _stage(job, "h2d", unit, bytes=nbytes) as h2d:
         placed = put()
     with _stage(job, "dispatch", unit, backend="device", kernel=kernel,
                 bytes=nbytes, **attrs) as disp:
         out = run(placed)
     KERNELS.record(kernel, "device", wall_s=disp.seconds,
-                   h2d_s=h2d.seconds, h2d_bytes=nbytes, nbytes=nbytes)
+                   h2d_s=h2d.seconds, nbytes=nbytes,
+                   h2d_bytes=nbytes if h2d_bytes is None else h2d_bytes)
     return out
 
 
@@ -139,7 +143,7 @@ def describe(codec, jax_live: bool = False) -> dict:
         info.update(body=kernel.kind, mesh_devices=int(shell.mesh.size))
         if kernel.kind == "pallas":  # always compiled inside shard_map
             info.update(interpret=False, tile=kernel.tile)
-    else:  # the Pallas shell carries both; the XLA shell has neither
+    else:  # the Pallas shell carries both; the XLA shell its bucket tile
         info.update({f: getattr(shell, f) for f in ("interpret", "tile")
                      if hasattr(shell, f)})
     return info
@@ -285,25 +289,74 @@ def apply_matrix(codec, C: np.ndarray, stack: np.ndarray, job=None,
     return _to_host([out], job, unit, "repair_partial")[0]
 
 
+# A put costs its thread about 0.2 ms whatever it carries, and one array
+# in flight moves at half the rate of ten (TPU v5e: a 160 MiB put 30 ms,
+# its ten rows 16; at 1 MiB a row the two ways tie; PERF.md, PR 26): rows
+# this wide and wider go up one by one, narrower ones in one array.
+ROW_PUTS_FROM = 2 << 20
+
+
+def _staged(rows, order: list[int], width: int) -> np.ndarray:
+    """Rows `order` of `rows`, one under the other and `width` wide with
+    zeros past their end: `rows` itself where it is that already, else a
+    copy (the one host copy of a degraded read)."""
+    n = len(rows[0])
+    if (width == n and isinstance(rows, np.ndarray)
+            and order == list(range(len(rows)))):
+        return rows
+    buf = np.zeros((len(order), width), dtype=np.uint8)
+    for r, src in enumerate(order):
+        buf[r, :n] = rows[src]
+    return buf
+
+
 @codec_entry("reconstruct")
-def reconstruct_batch(codec, shards: dict[int, np.ndarray],
-                      wanted: list[int], job=None,
-                      unit=None) -> dict[int, np.ndarray]:
-    """Rebuild `wanted` shard rows from >=k survivor rows (host bytes
-    in/out)."""
-    nbytes = sum(v.nbytes for v in shards.values())
-    if _is_host(codec):
-        return _host_call(job, unit, "reconstruct", nbytes,
-                          codec.reconstruct, shards, wanted=wanted)
-    if _is_numpy_ref(codec):
-        return _host_call(job, unit, "reconstruct", nbytes,
-                          codec.reconstruct_numpy, shards, wanted=wanted)
+def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
+                      job=None, unit=None) -> dict[int, np.ndarray]:
+    """Rebuild `wanted` shard rows (host bytes in and out) from the
+    survivors `ids`, whose rows of n bytes each `rows` holds in that
+    order: a `[len(ids), n]` array or a sequence of rows.
+
+    A device codec is handed the rows its decode matrix wants, in its
+    order, stacked on the host and W wide, W the bucket of n
+    (`codec_base.bucket`): one program per (rows wanted, W) and not per n,
+    with nothing but 1-D arrays crossing (`codec_base.stacked`), and the
+    cut back to n is a view on the host.  A caller that stages a whole
+    bucket in basis order (a rebuild batch) is put up as it is."""
+    ids = list(ids)
+    n = len(rows[0])
+    nbytes = len(ids) * n
+    if _is_host(codec) or _is_numpy_ref(codec):
+        fn = codec.reconstruct if _is_host(codec) else \
+            codec.reconstruct_numpy
+        return _host_call(job, unit, "reconstruct", nbytes, fn,
+                          dict(zip(ids, rows)), wanted=wanted)
     import jax.numpy as jnp
+    if not hasattr(codec, "reconstruct_stack"):
+        # a dict of rows at their own length is all MSRFileCodec (which
+        # interleaves whole files round its shell, and brings its rows
+        # back itself) and the column-sharded mesh encoder take
+        out = _device_call(
+            job, unit, "reconstruct", nbytes,
+            lambda: {i: jnp.asarray(r) for i, r in zip(ids, rows)},
+            lambda dev: codec.reconstruct(dev, wanted=wanted),
+            wanted=len(wanted))
+        return dict(zip(out, _to_host(list(out.values()), job, unit,
+                                      "reconstruct")))
+    from seaweedfs_tpu.ops.codec_base import bucket
+    order = [ids.index(i) for i in codec.decode_basis(ids, wanted)]
+    width = bucket(n, codec.tile)
+
+    def put():
+        stack = _staged(rows, order, width)
+        if width >= ROW_PUTS_FROM:
+            return tuple(jnp.asarray(row) for row in stack)
+        return jnp.asarray(stack.reshape(-1))
+
     out = _device_call(
-        job, unit, "reconstruct", nbytes,
-        lambda: {i: jnp.asarray(v) for i, v in shards.items()},
-        lambda dev: codec.reconstruct(dev, wanted=wanted),
-        wanted=len(wanted))
-    ids = list(out)
-    return dict(zip(ids, _to_host([out[i] for i in ids], job, unit,
-                                  "reconstruct")))
+        job, unit, "reconstruct", nbytes, put,
+        lambda dev: codec.reconstruct_stack(dev, ids, wanted, linear=True),
+        h2d_bytes=len(order) * width, wanted=len(wanted))
+    host, = _to_host([out], job, unit, "reconstruct")
+    host = host.reshape(len(wanted), width)
+    return {w: host[r, :n] for r, w in enumerate(wanted)}

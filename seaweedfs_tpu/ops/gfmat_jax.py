@@ -66,7 +66,15 @@ def bitsliced_apply_body(bitmat: jax.Array, data: jax.Array) -> jax.Array:
     return pack_bits(ybits)
 
 
-_bitsliced_apply = jax.jit(bitsliced_apply_body)
+@functools.partial(jax.jit, static_argnames="linear")
+def _bitsliced_apply(bitmat: jax.Array, data, linear: bool = False
+                     ) -> jax.Array:
+    """`linear`: 1-D in and out, laid out in this program
+    (codec_base.stacked)."""
+    if linear:
+        data = codec_base.stacked(data, bitmat.shape[1] // 8)
+    out = bitsliced_apply_body(bitmat, data)
+    return out.reshape(-1) if linear else out
 
 
 def bitsliced_apply_batch_body(bitmat: jax.Array, data: jax.Array
@@ -87,11 +95,14 @@ class JaxGFMatrix:
     def __init__(self, C: np.ndarray):
         self.C = np.asarray(C, dtype=np.uint8)
         self.m, self.k = self.C.shape
-        self.bitmat = jnp.asarray(gf.gf_matrix_to_bitmatrix(self.C), dtype=jnp.int8)
+        # cast on the host: `jnp.asarray(..., dtype=)` builds a program
+        # per matrix shape, on the first degraded read of each pattern
+        self.bitmat = jnp.asarray(
+            gf.gf_matrix_to_bitmatrix(self.C).astype(np.int8))
 
-    def __call__(self, data: jax.Array) -> jax.Array:
+    def __call__(self, data, linear: bool = False) -> jax.Array:
         """data [k, n] uint8 -> [m, n] uint8 product over GF(2^8)."""
-        return _bitsliced_apply(self.bitmat, data)
+        return _bitsliced_apply(self.bitmat, data, linear)
 
     def apply_batch(self, data: jax.Array) -> jax.Array:
         """data [U, k, n] -> [U, m, n] in one dispatch."""
@@ -100,6 +111,10 @@ class JaxGFMatrix:
 
 class JaxRSCodec(codec_base.RSCodecBase):
     """XLA bit-sliced RS codec: `RSCodecBase` over `JaxGFMatrix` applies."""
+
+    # XLA takes any width; this only sets how coarse the reconstruct
+    # seam's width buckets are (codec_base.bucket)
+    tile = 32768
 
     def __init__(self, code):
         super().__init__(code, JaxGFMatrix)

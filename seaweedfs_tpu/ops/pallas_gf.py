@@ -278,13 +278,18 @@ GF_APPLY = "_gf_apply"
 GF_APPLY_BATCH = "_gf_apply_batch"
 
 
-@codec_base.named_jit(GF_APPLY, static_argnames=("k", "m", "kpad", "tile", "interpret"))
-def _gf_apply(bitmat: jax.Array, data: jax.Array, k: int, m: int, kpad: int,
-              tile: int, interpret: bool) -> jax.Array:
+@codec_base.named_jit(GF_APPLY, static_argnames=("k", "m", "kpad", "tile",
+                                                 "interpret", "linear"))
+def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
+              tile: int, interpret: bool, linear: bool = False) -> jax.Array:
+    """`linear`: 1-D in and out, laid out in this program
+    (codec_base.stacked)."""
+    if linear:
+        data = codec_base.stacked(data, k)
     _, n = data.shape
     assert n % tile == 0, (n, tile)
     kernel = functools.partial(_gf_apply_kernel, k=k, m=m, kpad=kpad)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(n // tile,),
         in_specs=[
@@ -301,6 +306,7 @@ def _gf_apply(bitmat: jax.Array, data: jax.Array, k: int, m: int, kpad: int,
         interpret=interpret,
         name=GF_APPLY,
     )(bitmat, data)
+    return out.reshape(-1) if linear else out
 
 
 def _gf_apply_batch_kernel(bitmat_ref, x_ref, o_ref, *, k: int, m: int,
@@ -343,10 +349,13 @@ def _gf_apply_batch(bitmat: jax.Array, data: jax.Array, k: int, m: int,
 class PallasGFMatrix:
     """Fixed GF(2^8) matrix applied via the fused kernel.
 
-    Pads the byte-column count up to the tile size internally; for bulk EC
-    work callers should feed tile-aligned spans (the EC block sizes — 1GB/1MB,
-    reference weed/storage/erasure_coding/ec_encoder.go:21-22 — are all
-    tile-multiples).
+    Pads the byte-column count up to the tile size internally, at the
+    price of a pad and a slice program per width: served callers feed
+    tile-aligned spans (the EC block sizes — 1GB/1MB, reference
+    weed/storage/erasure_coding/ec_encoder.go:21-22 — are all
+    tile-multiples, and the reconstruct seam pads on the host to one of
+    a few widths, codec_base.bucket); regen's small applies and tests
+    come unaligned.
 
     The kernel is compiled for the chip.  `interpret=True` runs it under
     the Pallas interpreter instead and is for tests only: off-TPU nothing
@@ -366,10 +375,15 @@ class PallasGFMatrix:
         self.kpad = max(PLANE_PAD, -(-self.k // PLANE_PAD) * PLANE_PAD)
         self.tile = resolved_tile(tile)
         self.interpret = bool(interpret)
-        self.bitmat = jnp.asarray(
-            gf_matrix_to_bitmatrix_planemajor(self.C, self.kpad), dtype=jnp.int8)
+        # cast on the host: `jnp.asarray(..., dtype=)` builds a program
+        # per matrix shape, on the first degraded read of each pattern
+        self.bitmat = jnp.asarray(gf_matrix_to_bitmatrix_planemajor(
+            self.C, self.kpad).astype(np.int8))
 
-    def __call__(self, data: jax.Array) -> jax.Array:
+    def __call__(self, data, linear: bool = False) -> jax.Array:
+        if linear:  # the reconstruct seam's: a tile multiple wide already
+            return _gf_apply(self.bitmat, data, self.k, self.m, self.kpad,
+                             self.tile, self.interpret, True)
         k, n = data.shape
         assert k == self.k, (k, self.k)
         pad = (-n) % self.tile

@@ -1316,10 +1316,9 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                                          dtype=np.uint8)
                     for row, i in enumerate(use):
                         np.copyto(stage[row, :n], views[i][off:off + n])
-                rebuilt = _reconstruct_batch(
-                    codec,
-                    {i: stage[row, :n] for row, i in enumerate(use)},
-                    missing, job=pjob, unit=unit)
+                # `use` is the basis order: a full batch goes up as staged
+                rebuilt = _reconstruct_batch(codec, stage[:, :n], use,
+                                             missing, job=pjob, unit=unit)
                 with pjob.stage("unstage", unit=unit):
                     for r, i in enumerate(missing):
                         np.copyto(obuf[r, :n], rebuilt[i])

@@ -478,8 +478,8 @@ class EcVolume:
         flow = _read_flow()
         with flow.stage("reconstruct", nbytes=sum(s for _, s in gsegs),
                         intervals=len(todo), shards=len(wanted)):
-            rebuilt = ec_files._reconstruct_batch(codec, rows, wanted,
-                                                  job=flow)
+            rebuilt = ec_files._reconstruct_batch(
+                codec, list(rows.values()), list(rows), wanted, job=flow)
         self._bump("reconstruct_batches")
         self._bump("reconstruct_intervals", len(todo))
         if heat.ambient_is_data():

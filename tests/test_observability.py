@@ -516,9 +516,9 @@ def test_kernel_profile_accumulates_from_dispatch():
     batch = np.arange(10 * 64, dtype=np.uint8).reshape(10, 64)
     parity = dispatch.materialize(dispatch.dispatch_parity(codec, batch))
     assert parity.shape == (4, 64)
-    shards = {i: batch[i] for i in range(2, 10)}
-    shards.update({10 + r: parity[r] for r in range(2)})
-    out = dispatch.reconstruct_batch(codec, shards, wanted=[0, 1])
+    out = dispatch.reconstruct_batch(
+        codec, np.concatenate([batch[2:], parity[:2]]), range(2, 12),
+        wanted=[0, 1])
     assert np.array_equal(out[0], batch[0])
     snap = profile.KERNELS.snapshot()
     assert snap["encode_parity[host]"]["calls"] == 1

@@ -133,9 +133,9 @@ def test_coalesced_intervals_one_dispatch(tmp_path, monkeypatch):
     calls = []
     real = ec_files._reconstruct_batch
 
-    def counting(codec, shards, wanted, **kw):
+    def counting(codec, rows, ids, wanted, **kw):
         calls.append(list(wanted))
-        return real(codec, shards, wanted, **kw)
+        return real(codec, rows, ids, wanted, **kw)
 
     monkeypatch.setattr(ec_files, "_reconstruct_batch", counting)
     ev = ec_volume.EcVolume(base, LARGE, SMALL)

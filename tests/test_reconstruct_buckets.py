@@ -1,0 +1,236 @@
+"""The reconstruct seam runs at a few widths, not at every row length:
+the bucket rule, byte identity at the buckets' edges, and the count of
+programs a device shell builds for degraded reads and for a rebuild."""
+
+import os
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu.models import rs
+from seaweedfs_tpu.ops import codec_base, dispatch, gfmat_jax, pallas_gf
+from seaweedfs_tpu.stats import pipeline, profile
+from seaweedfs_tpu.storage import needle as ndl
+from seaweedfs_tpu.storage.ec import ec_files, ec_volume, layout
+from seaweedfs_tpu.storage.volume import Volume
+
+TILE = 256
+LARGE, SMALL = 200, 100  # blocks this small: every needle is on every shard
+TOP = TILE << (codec_base.BUCKETS - 1)
+SHELLS = ["jax", "pallas"]
+
+
+def _shell(kind: str, tile: int = TILE):
+    """A device shell of its own at a tile tiny volumes can cross several
+    buckets of.  The tests that count programs take a tile each: a
+    program built for another test's width is not built again."""
+    code = rs.get_code(10, 4)
+    if kind == "pallas":
+        return pallas_gf.PallasRSCodec(code, tile=tile, interpret=True)
+    codec = gfmat_jax.JaxRSCodec(code)
+    codec.tile = tile
+    return codec
+
+
+@pytest.fixture
+def serve(monkeypatch):
+    """serve(codec): every engine's codec selection resolves to `codec`,
+    with the compile counter on."""
+    ec_files._get_codec("jax")  # notes a JAX backend: counting is on
+
+    def use(codec):
+        monkeypatch.setattr(ec_files, "_get_codec",
+                            lambda kind=None, tag=None: codec)
+        return codec
+    return use
+
+
+def _programs() -> int:
+    return profile.compiles_snapshot().get("reconstruct", {}).get("count", 0)
+
+
+# ---- the rule ----------------------------------------------------------
+
+@pytest.mark.parametrize("tile", [256, 32768, pallas_gf.TPU_TILE])
+def test_bucket_rule(tile):
+    top = tile << (codec_base.BUCKETS - 1)
+    edges = {1, 2, tile - 1, tile, tile + 1, 2 * tile, 2 * tile + 1,
+             3 * tile, top - 1, top, top + 1, 2 * top, 2 * top + 1,
+             5 * top - 3}
+    edges |= {int(x) for x in np.geomspace(1, 3 * top, 400)}
+    widths = [codec_base.bucket(n, tile) for n in sorted(edges)]
+    for n, w in zip(sorted(edges), widths):
+        assert w >= n and w % tile == 0 and w <= 2 * max(n, tile), (n, w)
+    assert widths == sorted(widths)  # monotone
+    upto = {w for n, w in zip(sorted(edges), widths) if n <= top}
+    assert upto == {tile << j for j in range(codec_base.BUCKETS)}
+    if tile == pallas_gf.TPU_TILE:  # the chip: 128 KiB ... a rebuild batch
+        assert top == ec_files.DEFAULT_BATCH == 16 << 20
+        assert len(upto) <= 8
+    assert codec_base.bucket(top + 1, tile) == 2 * top
+
+
+# ---- byte identity at the edges ----------------------------------------
+
+@pytest.mark.parametrize("kind", SHELLS)
+@pytest.mark.parametrize("row_puts", [False, True],
+                         ids=["one_put", "row_puts"])
+@pytest.mark.parametrize("wanted", [[1], [0, 12], [0, 1, 5, 13]],
+                         ids=lambda w: f"{len(w)}rows")
+@pytest.mark.parametrize("n", [1, TILE - 1, TILE, TILE + 1, 2 * TILE + 1,
+                               TOP + 1])
+def test_reconstruct_matches_numpy_at_bucket_edges(kind, row_puts, wanted, n,
+                                                   monkeypatch):
+    """Both ways up (one array of the rows one after the other, or a put
+    a row where rows are wide) at every edge of the width buckets."""
+    if row_puts:
+        monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", 0)
+    code = rs.get_code(10, 4)
+    codec = _shell(kind)
+    rng = np.random.default_rng(n * 31 + len(wanted))
+    shards = code.encode_numpy(rng.integers(0, 256, (10, n), dtype=np.uint8))
+    ids = [i for i in range(14) if i not in wanted][:10]
+    wide = rng.integers(0, 256, (10, n + 5), dtype=np.uint8)
+    wide[:, :n] = shards[ids]
+    rows = wide[:, :n]  # stacked, and not contiguous
+    assert not rows.flags["C_CONTIGUOUS"] or n == 1
+    want = code.reconstruct_numpy(dict(zip(ids, rows)), wanted=wanted)
+    got = dispatch.reconstruct_batch(codec, rows, ids, wanted)
+    assert sorted(got) == sorted(wanted)
+    for w in wanted:
+        assert got[w].shape == (n,) and got[w].dtype == np.uint8
+        assert np.array_equal(got[w], want[w]), (w, n)
+        assert np.array_equal(got[w], shards[w])
+    # the dict wrapper (MSRFileCodec's inner shell, older callers)
+    out = codec.reconstruct({i: rows[r] for r, i in enumerate(ids)}, wanted)
+    assert all(np.array_equal(np.asarray(out[w]), shards[w]) for w in wanted)
+
+
+def test_a_staged_bucket_goes_up_as_it_is():
+    stage = np.arange(10 * 2 * TILE, dtype=np.uint8).reshape(10, 2 * TILE)
+    assert dispatch._staged(stage, list(range(10)), 2 * TILE) is stage
+    part = dispatch._staged(stage[:, :TILE + 1], list(range(10)), 2 * TILE)
+    assert part is not stage and part.shape == stage.shape
+    assert np.array_equal(part[:, :TILE + 1], stage[:, :TILE + 1])
+    assert not part[:, TILE + 1:].any()
+    picked = dispatch._staged(list(stage), [9, 0], 2 * TILE)
+    assert np.array_equal(picked, stage[[9, 0]])
+
+
+# ---- programs built by degraded reads ----------------------------------
+
+def _volume(tmp_path, sizes, seed):
+    vol = Volume(str(tmp_path), "", 3)
+    rng = np.random.default_rng(seed)
+    blobs = {}
+    for i, size in enumerate(sizes, start=1):
+        data = rng.integers(0, 256, size, dtype=np.uint8).tobytes()
+        vol.append_needle(ndl.Needle(cookie=0x9, id=i, data=data))
+        blobs[i] = data
+    vol.close()
+    base = str(tmp_path / "3")
+    ec_files.write_ec_files(base, large_block=LARGE, small_block=SMALL,
+                            batch_size=1000)
+    ec_files.write_sorted_ecx(base + ".idx")
+    return base, blobs
+
+
+@pytest.mark.parametrize("kind", SHELLS)
+def test_degraded_reads_build_a_program_a_bucket_not_a_length(
+        kind, tmp_path, serve, monkeypatch):
+    """Shards 0 and 1 lost: 48 needles of 48 lengths reconstruct through
+    EcVolume on a device shell and build no more programs than (width
+    buckets) x (counts of rows wanted); 48 other lengths then build none."""
+    monkeypatch.setenv("WEEDTPU_EC_RECONSTRUCT_CACHE", "0")
+    first = [397 * i + 2011 for i in range(48)]   # 2,011 .. 20,670 bytes
+    second = [397 * i + 2023 for i in range(48)]
+    assert len(set(first) | set(second)) == 96
+    base, blobs = _volume(tmp_path, first + second, seed=21)
+    for sid in (0, 1):
+        os.remove(base + layout.to_ext(sid))
+    tile = 384
+    codec = serve(_shell(kind, tile))
+    shapes: set = set()
+    lengths: set = set()
+    real = dispatch._staged
+
+    def staged(rows, order, width):
+        lengths.add(len(rows[0]))
+        return real(rows, order, width)
+
+    monkeypatch.setattr(dispatch, "_staged", staged)
+    real_stack = codec.reconstruct_stack
+
+    def stack_spy(stack, present, wanted, linear):
+        assert linear and stack.ndim == 1 and stack.size % 10 == 0
+        shapes.add((len(wanted), 10, stack.size // 10))
+        return real_stack(stack, present, wanted, linear)
+
+    codec.reconstruct_stack = stack_spy
+    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    try:
+        p0 = _programs()
+        for nid in range(1, 49):
+            assert ev.read_needle(nid).data == blobs[nid], nid
+        built = _programs() - p0
+        assert ev.read_stats_snapshot()["reconstruct_batches"] >= 40
+        assert len(lengths) >= 30  # the rows really came in many lengths
+        widths = {s[2] for s in shapes}
+        assert widths <= {tile << j for j in range(codec_base.BUCKETS)}
+        assert 1 <= built <= len(widths) * len({s[0] for s in shapes})
+        assert built == len(shapes) < len(lengths) / 3
+        seen = set(shapes)
+        for nid in range(49, 97):
+            assert ev.read_needle(nid).data == blobs[nid], nid
+        assert shapes == seen  # the same buckets ...
+        assert _programs() - p0 == built  # ... and nothing new built
+    finally:
+        ev.close()
+
+
+# ---- rebuild: a batch is its own bucket --------------------------------
+
+@pytest.mark.parametrize("kind", SHELLS)
+def test_rebuild_puts_a_batch_once_and_builds_one_program(
+        kind, tmp_path, serve, monkeypatch):
+    batch = 4 * 640  # a bucket of the tile
+    base = str(tmp_path / "1")
+    rng = np.random.default_rng(4)
+    rng.integers(0, 256, 230_000, dtype=np.uint8).tofile(base + ".dat")
+    serve(_shell(kind, 640))
+    ec_files.write_ec_files(base, large_block=8 * batch, small_block=batch,
+                            batch_size=batch)
+    shard_size = os.path.getsize(base + layout.to_ext(3))
+    batches, rest = divmod(shard_size, batch)
+    assert batches > 3 and rest == 0
+    want = open(base + layout.to_ext(3), "rb").read()
+    os.remove(base + layout.to_ext(3))
+
+    puts = []
+    real = dispatch._staged
+
+    def staged(rows, order, width):
+        out = real(rows, order, width)
+        puts.append(out is rows)
+        return out
+
+    monkeypatch.setattr(dispatch, "_staged", staged)
+    pipeline.reset()
+    before = profile.KERNELS.snapshot().get("reconstruct[device]", {})
+    p0 = _programs()
+    assert ec_files.rebuild_ec_files(base, batch_size=batch) == [3]
+    assert open(base + layout.to_ext(3), "rb").read() == want
+    assert _programs() - p0 == 1
+    assert puts == [True] * batches  # no host copy: staged in basis order
+    job = next(j for j in pipeline.jobs_snapshot()
+               if j["kind"] == "ec_rebuild")
+    assert {job["stages"][s]["items"] for s in
+            ("stage", "h2d", "dispatch", "device_wait", "d2h_copy",
+             "unstage")} == {batches}
+    after = profile.KERNELS.snapshot()["reconstruct[device]"]
+    moved = {f: after[f] - before.get(f, 0.0)
+             for f in ("calls", "bytes", "h2d_bytes", "d2h_bytes")}
+    # ten rows up and the missing row back, a dispatch a batch: what /perf
+    # roofline.rows (its `gbytes`, `calls`) said of a rebuild before
+    assert moved == {"calls": batches, "bytes": 10 * shard_size,
+                     "h2d_bytes": 10 * shard_size, "d2h_bytes": shard_size}

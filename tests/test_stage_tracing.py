@@ -18,7 +18,7 @@ import jax
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import dispatch, fleet_convert
+from seaweedfs_tpu.ops import codec_base, dispatch, fleet_convert
 from seaweedfs_tpu.stats import pipeline, profile, trace
 from seaweedfs_tpu.storage import needle as ndl
 from seaweedfs_tpu.storage.ec import ec_files, ec_volume, layout
@@ -272,14 +272,16 @@ def test_reconstruct_books_device_seconds():
     codec = ec_files._get_codec("jax")
     before = profile.KERNELS.snapshot().get("reconstruct[device]", {})
     rng = np.random.default_rng(2)
-    rows = {i: rng.integers(0, 256, 70_000, dtype=np.uint8)
-            for i in range(1, 11)}
-    out = dispatch.reconstruct_batch(codec, rows, [0])
+    rows = rng.integers(0, 256, (10, 70_000), dtype=np.uint8)
+    out = dispatch.reconstruct_batch(codec, rows, range(1, 11), [0])
     assert out[0].shape == (70_000,)
     after = profile.KERNELS.snapshot()["reconstruct[device]"]
     assert after["device_s"] > before.get("device_s", 0.0)
-    assert after["d2h_bytes"] - before.get("d2h_bytes", 0.0) == 70_000
-    assert after["h2d_bytes"] - before.get("h2d_bytes", 0.0) == 700_000
+    # what crosses is the bucket's width, what was asked for the needle's
+    width = codec_base.bucket(70_000, codec.tile)
+    assert after["d2h_bytes"] - before.get("d2h_bytes", 0.0) == width
+    assert after["h2d_bytes"] - before.get("h2d_bytes", 0.0) == 10 * width
+    assert after["bytes"] - before.get("bytes", 0.0) == 700_000
 
 
 # -- (c) compilations, counted inside the program ---------------------------
@@ -293,9 +295,8 @@ def test_compile_counter_by_entry_point_agrees_with_the_log(caplog):
     rng = np.random.default_rng(3)
 
     def degraded_read(length: int) -> None:
-        rows = {i: rng.integers(0, 256, length, dtype=np.uint8)
-                for i in range(1, 11)}
-        dispatch.reconstruct_batch(codec, rows, [0])
+        rows = rng.integers(0, 256, (10, length), dtype=np.uint8)
+        dispatch.reconstruct_batch(codec, rows, range(1, 11), [0])
 
     def total() -> int:
         return sum(r["count"] for r in profile.compiles_snapshot().values())
@@ -305,12 +306,12 @@ def test_compile_counter_by_entry_point_agrees_with_the_log(caplog):
         with caplog.at_level(logging.WARNING, logger="jax"):
             t0 = total()
             r0, e0 = _compiles("reconstruct"), _compiles("encode_parity")
-            degraded_read(31_337)  # a needle length not seen before
+            degraded_read(131_337)  # a width bucket not seen before
             r1 = _compiles("reconstruct")
             assert r1 > r0 and _compiles("encode_parity") == e0
-            degraded_read(31_337)  # the same length again: nothing new
+            degraded_read(131_339)  # another length of the bucket: nothing
             assert _compiles("reconstruct") == r1
-            degraded_read(31_339)
+            degraded_read(331_339)  # the next bucket
             assert _compiles("reconstruct") > r1
             dispatch.materialize(dispatch.dispatch_parity(
                 codec, rng.integers(0, 256, (10, 7_777), dtype=np.uint8)))

@@ -27,7 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from seaweedfs_tpu.models import rs
-from seaweedfs_tpu.ops import pallas_gf
+from seaweedfs_tpu.ops import dispatch, pallas_gf
 from seaweedfs_tpu.parallel import mesh as pmesh
 
 MIB = 1024 * 1024
@@ -80,20 +80,35 @@ def _lifted(C, sharding):
     return _spec(bm.shape, bm.dtype, sharding), seam._kpad(C.shape[1])
 
 
-@pytest.mark.parametrize("wanted", [None, [3]],
-                         ids=["encode_10_4", "decode_1_row"])
-def test_gf_apply_compiles_for_v5e(v5e, wanted):
-    """The served single-chip shape, [10, 1 MiB] at TPU_TILE: the parity
-    matrix and a one-row decode matrix (a degraded read of one shard)."""
+@pytest.mark.parametrize("wanted, width",
+                         [(None, MIB), ([3], 16 * MIB), ([0, 1], TILE)],
+                         ids=["encode_10_4", "rebuild_batch_1_row",
+                              "read_2_rows_smallest_bucket"])
+def test_gf_apply_compiles_for_v5e(v5e, wanted, width):
+    """The served single-chip programs at TPU_TILE: [10, 1 MiB] under the
+    parity matrix, and the two ends of what the reconstruct seam runs
+    (ops/dispatch.reconstruct_batch): a rebuild batch, the widest bucket,
+    under a one-row decode matrix, and a degraded read of two shards at
+    the narrowest."""
     code = rs.get_code(10, 4)
     C = code.parity_matrix if wanted is None else code.decode_matrix(
         [i for i in range(14) if i not in wanted][:10], wanted)
     one = SingleDeviceSharding(v5e[0])
     bm, kpad = _lifted(C, one)
     m, k = C.shape
+    # a decode as the seam runs it, 1-D in and out (codec_base.stacked):
+    # a wide stack as its ten rows, a narrow one as one array
+    if wanted is None:
+        data = _spec((k, width), jnp.uint8, one)
+    elif width >= dispatch.ROW_PUTS_FROM:
+        data = tuple(_spec((width,), jnp.uint8, one) for _ in range(k))
+    else:
+        data = _spec((k * width,), jnp.uint8, one)
     compiled = pallas_gf._gf_apply.lower(
-        bm, _spec((k, MIB), jnp.uint8, one),
-        k=k, m=m, kpad=kpad, tile=TILE, interpret=False).compile()
+        bm, data, k=k, m=m, kpad=kpad, tile=TILE, interpret=False,
+        linear=wanted is not None).compile()
+    assert compiled.out_info.shape == (
+        (m, width) if wanted is None else (m * width,))
     _assert_trace_names(compiled,
                         "gf_apply" if wanted is None else "gf_reconstruct",
                         "jit__gf_apply")
