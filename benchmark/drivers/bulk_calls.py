@@ -7,7 +7,9 @@ untimed checks after it
 reference).  Each operation's client wall gives one sample of
 bytes / wall in GB/s, where bytes are the `.dat` bytes of the volumes the
 call works on.  After each operation the job's stages are read from
-/admin/ec/progress (untimed) for the per-layer readers.
+/admin/ec/progress (untimed) for the per-layer readers.  `compared` counts
+what the run held against the reference, [wrong, of]: calls by their
+answer, shard files by sha256 and size.
 """
 
 from __future__ import annotations
@@ -15,8 +17,7 @@ from __future__ import annotations
 import json
 import time
 
-from harness import (check, compare_shards, fill, http_call, http_json,
-                     post_steps)
+from harness import check, compare_shards, fill, http_call, http_json
 import stats
 
 
@@ -31,10 +32,17 @@ def run(cell) -> dict:
     nbytes = sum(v["dat_bytes"] for v in vols)
     op = p["op"]
     failures: list[str] = []
+    files = [0, 0]  # shard files that differ from the reference, compared
+
+    def compare(v: dict, only=None) -> list[str]:
+        wrong = compare_shards(srv.base(v["collection"], v["vid"]), v, only)
+        files[0] += len(wrong)
+        files[1] += len(v["shards_sha256"] if only is None else only)
+        return wrong
 
     def one() -> dict:
-        names = {"vid": vids[0], "vids": vids}
-        post_steps(srv, op["before"], vids)
+        names = {"vid": vids[0], "vids": vids, "codec": cell.codec["tag"]}
+        cell.post_steps(op["before"], vids)
         timed = op["timed"]
         body = fill(timed["body"], **names)
         t_wall = time.time()
@@ -42,7 +50,7 @@ def run(cell) -> dict:
         status, raw = http_call(srv.volume, "POST", timed["path"], body,
                                 timeout=600)
         wall = time.perf_counter() - t0
-        why = None
+        why, answered = None, False
         if cell.device is None:  # known once the first operation has run
             cell.check_device(srv.perf())
         names["device_count"] = cell.device["count"]
@@ -51,14 +59,15 @@ def run(cell) -> dict:
         elif not _matches(json.loads(raw), fill(op["expect"], **names)):
             why = f"{timed['path']} answered {raw[:300]!r}"
         else:
+            answered = True
             for v in vols if op["check_shards"] else ():
-                wrong = compare_shards(srv.base(v["collection"], v["vid"]),
-                                       v, only=op["check_shards"])
+                wrong = compare(v, op["check_shards"])
                 why = why or (wrong[0] if wrong else None)
         job = http_json(srv.volume, "GET",
                         f"/admin/ec/progress?volumeId={vids[0]}")
         return {"t0": t_wall, "t1": t_wall + wall, "wall_s": wall,
                 "bytes": nbytes, "ok": why is None, "why": why,
+                "answered": answered,
                 "kind": job.get("kind"), "stages": job.get("stages", {})}
 
     for _ in range(p["warmup_ops"]):
@@ -80,11 +89,15 @@ def run(cell) -> dict:
     cell.window_ended()
     if p["final_check_shards"] == "all":
         for v in vols:
-            failures += compare_shards(srv.base(v["collection"], v["vid"]), v)
+            failures += compare(v)
     good = [o for o in ops if o["ok"]]
     check(good, f"no operation of the window succeeded: {failures[:3]}")
     m = p["metric"]
     return {"attempted": len(ops), "failed": len(ops) - len(good),
             "failures": failures, "ops": ops,
+            "compared": {
+                "calls_answered_wrong": [sum(not o["answered"] for o in ops),
+                                         len(ops)],
+                "shard_files_wrong": files},
             "metrics": {m["name"]: stats.stat(
                 [o["bytes"] / 1e9 / o["wall_s"] for o in good], m["stat"])}}

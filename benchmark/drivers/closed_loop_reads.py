@@ -6,8 +6,9 @@ needles, in an order fixed by the seed; the first `warmup_reads` of the
 draw warm up and are not read again.  Latency is taken client side, from
 the request sent to the last byte of the body read; the sha256 check comes
 after the clock stops.  Each read is classed `degraded` when its record
-touches a shard listed in `lost_shards` (by the reference's copy of the
-layout rule) and `healthy` otherwise.  Should the window outlast the
+touches a shard file listed in `lost_shards` (by the layout rule of the
+configuration's reference module, under its `codec` block) and `healthy`
+otherwise.  Should the window outlast the
 draw, the order is played again and the run says so.
 """
 
@@ -18,21 +19,29 @@ import threading
 import time
 
 from harness import check, make_rng, needle_ok, read_needle, say
-import reference
 import stats
+
+
+def classifier(ref, codec: dict, volume: dict, lost_shards: list[int]):
+    """-> needle -> `degraded` or `healthy`, for the needles of `volume`
+    (a manifest entry) with the shard files `lost_shards` gone."""
+    lost = set(lost_shards)
+
+    def klass(n: list) -> str:
+        held_by = ref.shards_touched(codec, volume["dat_bytes"], n[3], n[4])
+        return "degraded" if held_by & lost else "healthy"
+    return klass
 
 
 def run(cell) -> dict:
     p, srv = cell.traffic, cell.srv
     needles = cell.volumes[0]["needles"]
-    lost = set(p["lost_shards"])
+    klass = classifier(cell.ref, cell.codec, cell.volumes[0],
+                       p["lost_shards"])
+    klass_of = {n[0]: klass(n) for n in needles}  # none of it in the window
     order = [int(i) for i in make_rng(cell.seed, 2).permutation(len(needles))]
     warm, draw = order[:p["warmup_reads"]], order[p["warmup_reads"]:]
     check(draw, "the volume holds no more needles than the warm-up reads")
-
-    def klass(n: list) -> str:
-        return "degraded" if reference.shards_touched(n[3], n[4]) & lost \
-            else "healthy"
 
     conn = http.client.HTTPConnection(srv.volume, timeout=600)
     try:
@@ -65,7 +74,7 @@ def run(cell) -> dict:
                 ok = needle_ok(status, body, n)
                 with lock:
                     ops.append({"t0": t_wall, "ms": ms, "ok": ok,
-                                "bytes": n[2], "klass": klass(n),
+                                "bytes": n[2], "klass": klass_of[n[0]],
                                 "why": None if ok else
                                 f"GET {n[0]} -> {status}, {len(body)} bytes"})
         except BaseException as e:  # read by the main thread
@@ -106,5 +115,6 @@ def run(cell) -> dict:
     ms = [o["ms"] for o in good]
     return {"attempted": len(ops), "failed": len(ops) - len(good),
             "failures": [o["why"] for o in ops if not o["ok"]], "ops": ops,
+            "compared": {"reads_wrong": [len(ops) - len(good), len(ops)]},
             "metrics": {m["name"]: stats.stat(ms, m["stat"])
                         for m in p["metrics"]}}

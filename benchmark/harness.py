@@ -1,12 +1,16 @@
 """What every cell of the benchmark shares: the server child, HTTP, the
 master's admin lock, the volume cache and the checks on what a run leaves
-on disk.  Nothing here imports JAX or the program under test."""
+on disk.  Nothing here imports JAX or the program under test, and nothing
+here knows a code: what a shard set is comes from the configuration's
+`codec` block and the reference module it names (`reference_of`)."""
 
 from __future__ import annotations
 
 import concurrent.futures
+import glob
 import hashlib
 import http.client
+import importlib
 import json
 import os
 import re
@@ -20,8 +24,6 @@ import threading
 import time
 
 import numpy as np
-
-import reference
 
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
@@ -54,6 +56,36 @@ def check(cond, msg: str) -> None:
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def reference_of(codec: dict):
+    """The plain reference a configuration's `codec` block names: the
+    module `benchmark/<reference>.py`, held to its own word on the tag."""
+    name = codec["reference"]
+    check(re.fullmatch(r"\w+", name) and
+          os.path.exists(os.path.join(BENCH, name + ".py")),
+          f"no reference module {name!r}: {BENCH}/{name}.py is missing")
+    ref = importlib.import_module(name)
+    have = (codec["data_shards"], codec["parity_shards"])
+    says = ref.set_of(codec["tag"])
+    check(says == have,
+          f"codec block: {have[0]} + {have[1]} shards under the tag "
+          f"{codec['tag']!r}, of which {name}.py says {says}")
+    return ref
+
+
+def kernel_table() -> dict:
+    """kernels.json, with every kernel that is a file of its own,
+    `kernels/<kernel>.json`, added to its `kernels` by the file's name."""
+    table = load_json(os.path.join(BENCH, "kernels.json"))
+    for path in sorted(glob.glob(os.path.join(BENCH, "kernels", "*.json"))):
+        spec = load_json(path)
+        name = spec.pop("name", None)
+        check(name == os.path.basename(path)[:-len(".json")] and
+              name not in table["kernels"],
+              f"{path} names the kernel {name!r}")
+        table["kernels"][name] = spec
+    return table
 
 
 def free_port() -> int:
@@ -391,21 +423,29 @@ class VolumeCache:
     """`benchmark/.cache/<config>-seed<n>/`: the sealed `.dat` / `.idx` of
     a configuration's volumes, the sha256 of every acknowledged write and
     the reference's shard hashes, left by the first run of a seed in a
-    checkout.  Later runs start the server on a copy, which is what a
-    restarted volume server does.  An entry whose checksum does not match
-    is removed and rebuilt; the oldest entries go when the cache would
-    pass CACHE_BUDGET."""
+    checkout, with the `codec` block (the reference's name in it) they
+    were computed under.  Later runs start the server on a copy, which is
+    what a restarted volume server does.  An entry whose checksum does not
+    match, or that was built under another block, is removed and rebuilt;
+    the oldest entries go when the cache would pass CACHE_BUDGET."""
 
-    def __init__(self, config_name: str, seed: int, rehearsal: bool):
+    def __init__(self, config_name: str, codec: dict, seed: int,
+                 rehearsal: bool):
         tag = f"{config_name}-seed{seed}" + ("-rehearsal" if rehearsal else "")
         self.dir = os.path.join(CACHE_DIR, tag)
         self.manifest_path = os.path.join(self.dir, "manifest.json")
+        self.codec = codec
 
     def lookup(self) -> list[dict] | None:
         if not os.path.exists(self.manifest_path):
             return None
         try:
             m = load_json(self.manifest_path)
+            if m["codec"] != self.codec:
+                say(f"volume cache {self.dir}: built under the codec block "
+                    f"{m['codec']}, rebuilding")
+                shutil.rmtree(self.dir, ignore_errors=True)
+                return None
             ok = m["digest"] == _manifest_digest(m["volumes"])
             for v in m["volumes"] if ok else ():
                 base = os.path.join(self.dir, f"{v['collection']}_{v['vid']}")
@@ -444,7 +484,7 @@ class VolumeCache:
                                 os.path.join(tmp, name + ext))
         with open(os.path.join(tmp, "manifest.json"), "w") as f:
             json.dump({"digest": _manifest_digest(volumes),
-                       "volumes": volumes}, f)
+                       "codec": self.codec, "volumes": volumes}, f)
         os.rename(tmp, self.dir)
 
     @staticmethod
@@ -467,21 +507,24 @@ class VolumeCache:
             total -= size
 
 
-def describe_volume(srv: Server, loaded: dict) -> dict:
-    """Manifest entry of one sealed volume: sizes, checksums, the
-    reference's shard hashes, and for every needle its fid, the sha256 and
-    size acknowledged at write time, and where its record lies."""
+def describe_volume(srv: Server, loaded: dict, ref, codec: dict) -> dict:
+    """Manifest entry of one sealed volume: sizes, checksums, the shard
+    hashes of the reference `ref` under the block `codec`, and for every
+    needle its fid, the sha256 and size acknowledged at write time, and
+    where its record lies."""
     base = srv.base(loaded["collection"], loaded["vid"])
-    idx = reference.read_idx(base + ".idx")
+    idx = ref.read_idx(base + ".idx")
     check(len(idx) == len(loaded["needles"]),
           f"{base}.idx lists {len(idx)} needles, {len(loaded['needles'])} "
           f"writes were acknowledged")
     needles = []
     for fid, digest, size in loaded["needles"]:
-        offset, body = idx[reference.needle_id_of(fid)]
-        needles.append([fid, digest, size, offset,
-                        reference.record_length(body)])
-    shards, shard_size = reference.reference_shards(base + ".dat")
+        offset, body = idx[ref.needle_id_of(fid)]
+        needles.append([fid, digest, size, offset, ref.record_length(body)])
+    shards, shard_size = ref.reference_shards(codec, base + ".dat")
+    check(len(shards) == ref.shard_count(codec),
+          f"the reference gave {len(shards)} shard hashes for a set of "
+          f"{ref.shard_count(codec)}")
     return {"collection": loaded["collection"], "vid": loaded["vid"],
             "dat_bytes": os.path.getsize(base + ".dat"),
             "dat_sample": _sample_digest(base + ".dat"),
@@ -492,8 +535,9 @@ def describe_volume(srv: Server, loaded: dict) -> dict:
 
 def compare_shards(base: str, volume: dict, only=None) -> list[str]:
     """-> what differs between the shard files at `base` and the
-    reference's (empty when all agree)."""
-    ids = list(range(reference.K + reference.M)) if only is None else only
+    reference's (empty when all agree): all of the set, which is as many
+    files as the reference gave hashes, or `only` the listed."""
+    ids = list(range(len(volume["shards_sha256"]))) if only is None else only
     paths = [f"{base}.ec{i:02d}" for i in ids]
     wrong = [f"{p} is missing" for p in paths if not os.path.exists(p)]
     if wrong:
@@ -503,7 +547,7 @@ def compare_shards(base: str, volume: dict, only=None) -> list[str]:
             wrong.append(f"{p}: {os.path.getsize(p)} bytes, reference "
                          f"{volume['shard_size']}")
         elif digest != volume["shards_sha256"][i]:
-            wrong.append(f"{p} differs from the numpy reference")
+            wrong.append(f"{p} differs from the reference")
     return wrong
 
 

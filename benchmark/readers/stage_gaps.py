@@ -107,8 +107,7 @@ def read(ev: dict, params: dict):
             os.path.getsize(found[1]) != sl.get("xplane_bytes"):
         return None
     cell, path = found
-    with open(os.path.join(harness.BENCH, "kernels.json")) as f:
-        result = table(trace_reduce.load_planes(path), json.load(f))
+    result = table(trace_reduce.load_planes(path), harness.kernel_table())
     if result is None:
         return None
     out_dir = os.path.join(harness.OUT_DIR, cell)

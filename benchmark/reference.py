@@ -1,14 +1,24 @@
 """The benchmark's plain reference: numpy only, and its own copy.
 
-What a `.dat` must encode to under RS(10,4) with the upstream layout
-(`ec_encoder.go`: rows of ten 1 MiB blocks while no more than ten 1 GB
-blocks remain, the last row zero-padded; shard j is block j of every row),
-and which shards a needle's record touches.  Nothing here imports the
-program under test; `selfcheck/` holds this file against
-`seaweedfs_tpu/models/rs.py` at a small size and against fixed vectors.
+Two things live here.  The volume's own format (`read_idx`,
+`needle_id_of`, `record_length`) and GF(2^8), which every reference module
+may import: a reference is independent of the program under test, not of
+its siblings.  And the reference of the Reed-Solomon family, written to
+the contract of a reference module (README.md, "A reference module"):
+everything about a shard set is a function of the configuration's `codec`
+block, so RS(10,4) at upstream's 1 GB / 1 MB blocks, RS(6,3) at 1 MiB or
+RS(10,4) with a small `large_block_bytes` are blocks, not code.
+
+What a `.dat` must encode to (`ec_encoder.go`'s loop: rows of k large
+blocks while MORE than one large row remains, then rows of k small blocks,
+the last zero-padded; shard j is block j of every row, large blocks
+first, and shards k.. hold the parity of each row), and which shard files
+hold a byte range of it.  Nothing here imports the program under test;
+`selfcheck/` holds this file against `seaweedfs_tpu/models/rs.py` and
+`storage/ec/layout.py` at small sizes and against fixed vectors.
 
 Field: GF(2^8), primitive polynomial x^8+x^4+x^3+x^2+1 (0x11D), generator
-2 — klauspost/reedsolomon's, which upstream SeaweedFS encodes with.  The
+2: klauspost/reedsolomon's, which upstream SeaweedFS encodes with.  The
 generator matrix is that library's default: a Vandermonde matrix
 vm[r, c] = r**c made systematic by vm @ inv(vm[:k]).
 """
@@ -18,14 +28,12 @@ from __future__ import annotations
 import concurrent.futures
 import hashlib
 import os
+import re
 import struct
 
 import numpy as np
 
-K, M = 10, 4
 MIB = 1024 * 1024
-SMALL_BLOCK = MIB
-LARGE_BLOCK = 1024 * MIB
 POLY = 0x11D
 
 # needle record on disk, version 3: [cookie 4][id 8][size 4] body[size]
@@ -96,46 +104,118 @@ def _gf_inv_matrix(a: np.ndarray) -> np.ndarray:
     return aug[:, n:]
 
 
-def parity_matrix(k: int = K, m: int = M) -> np.ndarray:
+def parity_matrix(k: int, m: int) -> np.ndarray:
     """The [m, k] parity rows of the systematic Vandermonde generator."""
     vm = np.array([[_gf_pow(r, c) for c in range(k)] for r in range(k + m)],
                   dtype=np.uint8)
     return gf_matmul(vm, _gf_inv_matrix(vm[:k]))[k:]
 
 
-def shard_file_size(dat_size: int) -> int:
-    if dat_size > K * LARGE_BLOCK:
-        raise ValueError(f"{dat_size} bytes: the large-block layout is not "
-                         f"part of this reference")
-    return -(-dat_size // (K * SMALL_BLOCK)) * SMALL_BLOCK
+# -- the Reed-Solomon family, by the contract of a reference module ------------
+
+def set_of(tag: str) -> tuple[int, int]:
+    """(data shards, parity shards) of the set the program's codec tag
+    `rs_<k>_<m>` names."""
+    found = re.fullmatch(r"rs_(\d+)_(\d+)", tag)
+    if not found:
+        raise ValueError(f"{tag!r} is no Reed-Solomon tag (rs_<k>_<m>): "
+                         f"another family brings a reference of its own")
+    return int(found.group(1)), int(found.group(2))
 
 
-def reference_shards(dat_path: str) -> tuple[list[str], int]:
-    """sha256 of each of the 14 shard files `dat_path` must encode to, and
-    the size of a shard file."""
+def _geometry(codec: dict) -> tuple[int, int, int, int]:
+    """-> (k, m, large block, small block) of a `codec` block."""
+    if codec["family"] != "rs":
+        raise ValueError(f"family {codec['family']!r}: this module is the "
+                         f"reference of `rs` alone")
+    k, m = codec["data_shards"], codec["parity_shards"]
+    large, small = codec["large_block_bytes"], codec["small_block_bytes"]
+    if not (k >= 1 and m >= 1 and k + m <= 256 and 0 < small <= large):
+        raise ValueError(f"no RS layout: {codec}")
+    return k, m, large, small
+
+
+def shard_count(codec: dict) -> int:
+    """How many shard files a set has."""
+    k, m, _large, _small = _geometry(codec)
+    return k + m
+
+
+def _rows(codec: dict, dat_size: int) -> tuple[int, int]:
+    """-> (large rows, small rows), as the encode loop cuts them: a large
+    row while more than one large row's bytes remain, small rows for the
+    rest."""
+    k, _m, large, small = _geometry(codec)
+    large_rows = max(0, (dat_size - 1) // (k * large))
+    rest = dat_size - large_rows * k * large
+    return large_rows, -(-rest // (k * small))
+
+
+def shard_file_size(codec: dict, dat_size: int) -> int:
+    _k, _m, large, small = _geometry(codec)
+    large_rows, small_rows = _rows(codec, dat_size)
+    return large_rows * large + small_rows * small
+
+
+def reference_shards(codec: dict, dat_path: str) -> tuple[list[str], int]:
+    """sha256 of each shard file `dat_path` must encode to, in shard
+    order, and the size of a shard file."""
+    k, m, large, small = _geometry(codec)
     size = os.path.getsize(dat_path)
-    shard_size = shard_file_size(size)
-    rows = shard_size // SMALL_BLOCK
-    row_bytes = K * SMALL_BLOCK
-    pm = parity_matrix()
+    large_rows, small_rows = _rows(codec, size)
+    pm = parity_matrix(k, m)
+    # a unit is one step of every shard's file: bytes [at, at + n) of each
+    # of a row's k blocks.  A small row is one unit; a large row goes in
+    # steps of the small block, so no unit holds more than k small blocks
+    units = [(r * k * large, large, at, min(small, large - at))
+             for r in range(large_rows) for at in range(0, large, small)]
+    small_from = large_rows * k * large
+    units += [(small_from + r * k * small, small, 0, small)
+              for r in range(small_rows)]
+    fd = os.open(dat_path, os.O_RDONLY)
 
-    def one(r: int):
-        with open(dat_path, "rb") as f:
-            f.seek(r * row_bytes)
-            raw = f.read(row_bytes)
-        block = np.zeros(row_bytes, dtype=np.uint8)
-        block[:len(raw)] = np.frombuffer(raw, dtype=np.uint8)
-        data = block.reshape(K, SMALL_BLOCK)
+    def one(unit: tuple[int, int, int, int]):
+        row_at, block, at, n = unit
+        data = np.zeros((k, n), dtype=np.uint8)
+        for j in range(k):
+            raw = os.pread(fd, n, row_at + j * block + at)
+            data[j, :len(raw)] = np.frombuffer(raw, dtype=np.uint8)
         return data, gf_matmul(pm, data)
 
-    hashers = [hashlib.sha256() for _ in range(K + M)]
-    with concurrent.futures.ThreadPoolExecutor(
-            max_workers=min(8, os.cpu_count() or 1)) as ex:
-        for data, parity in ex.map(one, range(rows)):
-            for h, block in zip(hashers, (*data, *parity)):
-                h.update(block)
-    return [h.hexdigest() for h in hashers], shard_size
+    hashers = [hashlib.sha256() for _ in range(k + m)]
+    try:
+        with concurrent.futures.ThreadPoolExecutor(
+                max_workers=min(8, os.cpu_count() or 1)) as ex:
+            for data, parity in ex.map(one, units):
+                for h, block in zip(hashers, (*data, *parity)):
+                    h.update(block)
+    finally:
+        os.close(fd)
+    return [h.hexdigest() for h in hashers], shard_file_size(codec, size)
 
+
+def shards_touched(codec: dict, dat_size: int, offset: int,
+                   length: int) -> set[int]:
+    """Shard files that hold bytes [offset, offset + length) of a `.dat`
+    of `dat_size` bytes: in the large rows byte b lives in shard
+    (b // large block) % k, past them in ((b - large rows' bytes) //
+    small block) % k."""
+    k, _m, large, small = _geometry(codec)
+    small_from = _rows(codec, dat_size)[0] * k * large
+    touched: set[int] = set()
+    at, end = offset, offset + length
+    while at < end and len(touched) < k:
+        if at < small_from:
+            block = at // large
+            at = (block + 1) * large
+        else:
+            block = (at - small_from) // small
+            at = small_from + (block + 1) * small
+        touched.add(block % k)
+    return touched
+
+
+# -- the volume's own format ------------------------------------------------------
 
 def record_length(size: int) -> int:
     """Bytes a needle of body size `size` (the .idx entry's) takes in the
@@ -157,16 +237,6 @@ def read_idx(idx_path: str) -> dict[int, tuple[int, int]]:
         else:
             out.pop(nid, None)
     return out
-
-
-def shards_touched(offset: int, length: int) -> set[int]:
-    """Data shards that hold bytes [offset, offset + length) of a `.dat`
-    under the small-block layout: byte b lives in shard (b // 1 MiB) % 10."""
-    first = offset // SMALL_BLOCK
-    last = (offset + length - 1) // SMALL_BLOCK
-    if last - first >= K - 1:
-        return set(range(K))
-    return {b % K for b in range(first, last + 1)}
 
 
 def needle_id_of(fid: str) -> int:

@@ -5,8 +5,9 @@
         --trace <0|1> [--rehearsal]
 
 Finds the cell in BENCHMARK.json, its configuration under
-`benchmark/configs/`, its traffic mix under `benchmark/traffic/`, the
-driver that plays the mix under `benchmark/drivers/` and, for `--trace 1`,
+`benchmark/configs/` with the plain reference its `codec` block names, its
+traffic mix under `benchmark/traffic/`, the driver that plays the mix
+under `benchmark/drivers/` and, for `--trace 1`,
 each of the cell's per-layer metrics under `benchmark/layer_metrics/` with
 its reader under `benchmark/readers/`.  Starts the configuration's server
 as a child through `serve.py` (the only process that touches JAX), on a
@@ -14,8 +15,10 @@ copy of the seed's sealed volumes from `benchmark/.cache/` or, on a seed's
 first run in a checkout, on volumes loaded through the served write path.
 
 Standard output carries one line, the last thing written, and only when
-the run reached its end: the contract's JSON object.  `--trace 0` gives the
-cell's end-to-end metrics, `--trace 1` its per-layer metrics.  A run that
+the run reached its end: the contract's JSON object, with `compared` as
+its last key (each count held against the reference, beside its limit:
+the same lines end standard error).  `--trace 0` gives the cell's
+end-to-end metrics, `--trace 1` its per-layer metrics.  A run that
 finds no accelerator, another codec than the configuration expects, or a
 host codec at work prints no line and exits nonzero.  Progress, sample
 counts and the server's log tail go to standard error; per-operation walls
@@ -64,12 +67,27 @@ def load_module(kind: str, name: str):
 
 
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
-                device: dict, breakdown: dict | None = None) -> str:
+                device: dict, breakdown: dict | None = None,
+                compared: dict | None = None) -> str:
+    """The contract's line; `compared`, each number held against the
+    reference beside its limit, comes last."""
     line = dict(zip(RESULT_KEYS, (bool(correct), int(attempted), int(failed),
                                   metrics, device)))
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["compared"] = compared or {}
     return json.dumps(line)
+
+
+def judge(result: dict) -> tuple[bool, dict]:
+    """-> (`correct`, `compared`) of what a driver returned: its
+    `compared` ([wrong, of] by name) as the line carries it, each count
+    beside its limit.  Every comparison here is exact (sha256, sizes, an
+    answer's fields), so every limit is 0."""
+    compared = {name: {"value": wrong, "limit": 0, "of": of}
+                for name, (wrong, of) in result["compared"].items()}
+    return not result["failures"] and all(
+        c["value"] <= c["limit"] for c in compared.values()), compared
 
 
 class Tracer:
@@ -109,6 +127,8 @@ class Cell:
         entry = next(c for c in bench["configs"]
                      if c["name"] == self.workload["config"])
         self.config = load_json(os.path.join(ROOT, entry["file"]))
+        self.codec = self.config["codec"]
+        self.ref = harness.reference_of(self.codec)
         self.traffic = load_json(os.path.join(
             BENCH, "traffic", self.workload["traffic"] + ".json"))
         self.sizes = dict(self.config, **(self.config["rehearsal"]
@@ -137,13 +157,21 @@ class Cell:
                                  harness.OUT_DIR, harness.compile_cache())
         say(f"{name}: {self.phases[name]} s")
 
+    def volume_cache(self):
+        return harness.VolumeCache(self.config["name"], self.codec,
+                                   self.seed, self.rehearsal)
+
+    def post_steps(self, steps: list[dict], vids: list[int]) -> None:
+        """The untimed POSTs a data file lists, the configuration's codec
+        tag filled in for `{codec}`."""
+        harness.post_steps(self.srv, steps, vids, codec=self.codec["tag"])
+
     def volumes_up(self) -> None:
         """The server running on a copy of the seed's sealed volumes, which
         is what a restarted volume server does.  A seed's first run in a
         checkout builds them first, with a server of its own, so that the
         measured server starts from the same state in every run."""
-        cache = harness.VolumeCache(self.config["name"], self.seed,
-                                    self.rehearsal)
+        cache = self.volume_cache()
         t0 = time.time()
         self.volumes = cache.lookup()
         if self.volumes is None:
@@ -174,11 +202,12 @@ class Cell:
                 harness.make_rng(self.seed, 1, i)))
         self.phase("build.load_volumes", t0)
         t0 = time.time()
-        harness.post_steps(self.srv, self.config["seal_call"]["steps"],
-                           [v["vid"] for v in loaded])
+        self.post_steps(self.config["seal_call"]["steps"],
+                        [v["vid"] for v in loaded])
         self.phase("build.seal_volumes", t0)
         t0 = time.time()
-        volumes = [harness.describe_volume(self.srv, v) for v in loaded]
+        volumes = [harness.describe_volume(self.srv, v, self.ref, self.codec)
+                   for v in loaded]
         self.phase("build.reference", t0)
         t0 = time.time()
         cache.store(volumes, self.srv.data_dir)
@@ -206,8 +235,8 @@ class Cell:
 
     def traffic_setup(self) -> None:
         t0 = time.time()
-        harness.post_steps(self.srv, self.traffic["setup"],
-                           [v["vid"] for v in self.volumes])
+        self.post_steps(self.traffic["setup"],
+                        [v["vid"] for v in self.volumes])
         self.phase("traffic_setup", t0)
 
     # -- the window's edges, called by the driver -----------------------------------
@@ -261,7 +290,7 @@ class Cell:
 def per_layer_metrics(cell: Cell, bench: dict, result: dict) -> tuple:
     """-> (metrics, evidence) of the traced run."""
     import trace_reduce
-    table = load_json(os.path.join(BENCH, "kernels.json"))
+    table = harness.kernel_table()
     peaks = load_json(os.path.join(BENCH, "peaks.json"))["devices"]
     sl = cell.tracer.slice
     if sl is not None and "t1" in sl:
@@ -352,8 +381,12 @@ def run(args, cell: Cell, bench: dict) -> str:
     with open(os.path.join(cell.out, "ops.json"), "w") as f:
         json.dump(result["ops"], f)
     shutil.rmtree(cell.srv.data_dir, ignore_errors=True)
-    return result_line(not result["failures"], result["attempted"],
-                       result["failed"], metrics, device, breakdown)
+    correct, compared = judge(result)
+    for name, c in compared.items():  # the last lines on standard error
+        say(f"compared {name}: {c['value']} of {c['of']}, limit {c['limit']}")
+    say(f"correct: {correct}")
+    return result_line(correct, result["attempted"], result["failed"],
+                       metrics, device, breakdown, compared)
 
 
 def main() -> int:
@@ -388,8 +421,7 @@ def main() -> int:
     cell = None
     try:
         cell = Cell(args, bench)
-        cached = os.path.exists(harness.VolumeCache(
-            cell.config["name"], args.seed, args.rehearsal).manifest_path)
+        cached = os.path.exists(cell.volume_cache().manifest_path)
         signal.alarm(DEADLINE_S if cached else FIRST_RUN_DEADLINE_S)
         line = run(args, cell, bench)
     except Exception as e:  # every failure, the alarm's included: exit 1

@@ -8,6 +8,7 @@ import re
 
 import pytest
 
+import harness
 from conftest import BENCH, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -62,6 +63,47 @@ def test_configs(bench):
         for group in ("env", "env_traced"):
             for var, spec in cfg[group].items():
                 assert spec["why"], f"{var} has no reason"
+
+
+REFERENCE_CONTRACT = ("set_of", "shard_count", "shard_file_size",
+                      "reference_shards", "shards_touched", "read_idx",
+                      "needle_id_of", "record_length")
+
+
+def test_codec_blocks(bench):
+    """Every configuration says what code its volumes are under and names
+    the plain reference that holds them to it."""
+    for c in bench["configs"]:
+        codec = load(ROOT, c["file"])["codec"]
+        assert set(codec) == {"reference", "tag", "family", "data_shards",
+                              "parity_shards", "large_block_bytes",
+                              "small_block_bytes"}
+        assert NAME.match(codec["tag"]) and NAME.match(codec["reference"])
+        ref = harness.reference_of(codec)  # the file is there, the tag's
+        # set is the block's
+        for name in REFERENCE_CONTRACT:
+            assert callable(getattr(ref, name, None)), \
+                f"{codec['reference']}.py has no {name}()"
+        assert ref.set_of(codec["tag"]) == (codec["data_shards"],
+                                            codec["parity_shards"])
+        assert ref.shard_count(codec) == codec["data_shards"] + \
+            codec["parity_shards"]
+        assert codec["tag"].startswith(codec["family"] + "_")
+        assert 0 < codec["small_block_bytes"] <= codec["large_block_bytes"]
+
+
+def test_no_module_but_the_reference_knows_a_code():
+    """`harness.py`, `run.py`, the drivers and the readers take the shard
+    set from the cell's module and block."""
+    knows = re.compile(r"^import reference|^from reference |reference\.[KM]\b"
+                       r"|\b14\b", re.M)
+    for sub in ("", "drivers", "readers"):
+        d = os.path.join(BENCH, sub)
+        for name in sorted(os.listdir(d)):
+            if name.endswith(".py") and name != "reference.py":
+                with open(os.path.join(d, name)) as f:
+                    found = knows.findall(f.read())
+                assert not found, f"{sub}/{name}: {found}"
 
 
 def test_workloads(bench):
@@ -123,11 +165,49 @@ def test_metrics(bench):
 def test_tables():
     peaks = load(BENCH, "peaks.json")
     assert peaks["source"] and "TPU v5 lite" in peaks["devices"]
-    table = load(BENCH, "kernels.json")
-    for spec in table["kernels"].values():
+    table = harness.kernel_table()
+    assert set(table) == {"why", "device_plane", "op_lines", "kernels"}
+    for pattern in (table["device_plane"], *table["op_lines"]):
+        re.compile(pattern)
+    # tests/test_tpu_aot.py reads these three from kernels.json itself
+    assert {"gf_apply", "gf_reconstruct", "gf_apply_batch"} <= \
+        set(load(BENCH, "kernels.json")["kernels"])
+    for name, spec in table["kernels"].items():
+        assert NAME.match(name)
+        assert set(spec) - {"what"} == {"line", "patterns", "bound",
+                                        "perf_kernels"}
         assert spec["bound"] in peaks["devices"]["TPU v5 lite"]
         for pattern in (spec["line"], *spec["patterns"]):
             re.compile(pattern)
+
+
+def test_a_kernel_can_be_a_file_of_its_own(tmp_path, monkeypatch):
+    shared = load(BENCH, "kernels.json")
+    with open(tmp_path / "kernels.json", "w") as f:
+        json.dump(shared, f)
+    os.mkdir(tmp_path / "kernels")
+    spec = dict(shared["kernels"]["gf_apply"], what="a kernel a PR brings",
+                patterns=["^%_lrc_repair(\\.\\d+)? = "])
+    with open(tmp_path / "kernels" / "lrc_repair.json", "w") as f:
+        json.dump(dict(spec, name="lrc_repair"), f)
+    monkeypatch.setattr(harness, "BENCH", str(tmp_path))
+    table = harness.kernel_table()
+    assert set(table["kernels"]) == set(shared["kernels"]) | {"lrc_repair"}
+    assert table["kernels"]["lrc_repair"] == spec
+    # a file may not take a name that is there, nor name another kernel
+    with open(tmp_path / "kernels" / "gf_apply.json", "w") as f:
+        json.dump(dict(spec, name="gf_apply"), f)
+    with pytest.raises(harness.BenchFailure, match="gf_apply"):
+        harness.kernel_table()
+
+
+def test_a_roofline_has_its_kernels_file(bench):
+    table = harness.kernel_table()["kernels"]
+    for m in bench["per_layer"]:
+        if m["name"].endswith("_roofline"):
+            spec = load(BENCH, "layer_metrics", m["name"] + ".json")
+            assert m["name"] == spec["params"]["kernel"] + "_roofline"
+            assert spec["params"]["kernel"] in table
 
 
 def test_file_names_under_paths():

@@ -10,7 +10,6 @@ import pytest
 import harness
 import run
 import trace_reduce
-from conftest import BENCH
 
 OP = "%_gf_apply.1 = u8[4,8]{1,0} custom-call(u8[10,8]{1,0} %data.1)"
 
@@ -20,9 +19,7 @@ def reader():
     return run.load_module("readers", "stage_gaps")
 
 
-def kernels_table():
-    with open(os.path.join(BENCH, "kernels.json")) as f:
-        return json.load(f)
+kernels_table = harness.kernel_table
 
 
 def planes(with_stages=True):

@@ -3,8 +3,8 @@ import os
 
 import pytest
 
+import harness
 import trace_reduce
-from conftest import BENCH
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 GF_APPLY = ("%_gf_apply.1 = u8[4,1048576]{1,0:T(4,128)(4,1)} custom-call("
@@ -12,9 +12,7 @@ GF_APPLY = ("%_gf_apply.1 = u8[4,1048576]{1,0:T(4,128)(4,1)} custom-call("
             "%data.1), custom_call_target=\"tpu_custom_call\"")
 
 
-def table():
-    with open(os.path.join(BENCH, "kernels.json")) as f:
-        return json.load(f)
+table = harness.kernel_table
 
 
 def test_reduce_on_hand_made_planes():
