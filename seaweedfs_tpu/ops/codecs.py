@@ -11,14 +11,22 @@ codec *tag*, and this module is the one place the tag grammar lives:
 `parse_tag(None)` and any unknown tag resolve to the RS default — old
 nodes that never heard of codec tags keep working with no flag-day.
 
-Backend builds go through `make_codec(tag, kind)`, the codec-family
-generalisation of ec_files._get_codec: the same WEEDTPU_EC_CODEC knob
-(auto|tpu|jax|cpp|numpy|mesh) picks the matrix-apply backend, and every
-family rides the RSCodecBase / NativeRSCodec shells unchanged — LRC is
-just another fixed matrix; MSR wraps the shell in its interleaving
-file codec.  The Pallas and mesh backends are RS-shaped (fixed 10x4
-tiling assumptions); non-RS families fall back to the XLA bit-sliced
-backend there rather than guessing at tile geometry.
+It is also the one place a tag becomes a codec object: `resolve(tag,
+kind)` is the only resolution the program has (`ec_files._get_codec`,
+`make_codec` and `fleet_convert.fleet_codec` call it and choose
+nothing themselves).  Which shell runs a code is a pure function of
+the tag, the WEEDTPU_EC_CODEC kind (auto|tpu|jax|cpp|numpy|mesh) and
+the platform (`backend_for`), and every shell is built over the tag's
+own code object, so the spec's k and m are what the files get: `rs_6_3`
+is 6 + 3 on every backend.  `rs` and `lrc` are fixed matrices with a
+`decode_matrix`, which is all the fused Pallas kernel
+(`pallas_gf.PallasGFMatrix`, generic in (m, k)) and the XLA and native
+shells ask for: on a TPU under `auto` / `tpu` both get `PallasRSCodec`.
+MSR wraps a shell in its interleaving file codec and stays on the XLA
+body there.  A tag whose family or geometry the chosen backend does
+not carry raises `CodecUnsupported` before any file is touched (the
+volume server answers 400 with the reason): the mesh encoder and the
+fleet conversion are Reed-Solomon only, the latter RS(10,4) only.
 
 Knobs: WEEDTPU_CODEC_DEFAULT (tag or family for untagged volumes),
 WEEDTPU_CODEC_LRC ("k,l,g" params behind the bare "lrc" family name),
@@ -137,7 +145,13 @@ def registered() -> list[CodecSpec]:
 
 
 # ---------------------------------------------------------------------------
-# backend builds
+# the one resolution: (tag, kind, platform) -> backend -> codec object
+
+
+class CodecUnsupported(ValueError):
+    """The chosen backend does not carry the tag's family or geometry.
+    Raised by `resolve` before any shard file or `.tmp` exists; the
+    volume server's EC handlers answer 400 with the message."""
 
 
 class _NumpyShell:
@@ -178,60 +192,151 @@ def _code_for(spec: CodecSpec):
     """The bare code object (matrix + decode protocol) behind a spec.
     For MSR this is the inner virtual-row code; the file surface is
     MSRFileCodec's."""
-    if spec.family == "lrc":
-        from seaweedfs_tpu.ops import lrc
-        return lrc.get_code(*spec.params)
-    if spec.family == "msr":
-        from seaweedfs_tpu.ops import msr
-        return msr.get_code(*spec.params)
-    from seaweedfs_tpu.models import rs
-    return rs.get_code(spec.k, spec.m)
+    try:
+        if spec.family == "lrc":
+            from seaweedfs_tpu.ops import lrc
+            return lrc.get_code(*spec.params)
+        if spec.family == "msr":
+            from seaweedfs_tpu.ops import msr
+            return msr.get_code(*spec.params)
+        from seaweedfs_tpu.models import rs
+        return rs.get_code(spec.k, spec.m)
+    except (ValueError, AssertionError) as e:
+        raise CodecUnsupported(f"{spec.tag}: no such code: {e}") from e
 
 
-def _shell_for(code, kind: str):
-    """An RSCodecBase-compatible shell over `code` for one backend
-    kind.  Pallas/mesh are RS-tiled; generic codes use the XLA
-    bit-sliced backend there."""
+def backend_for(spec: CodecSpec, kind: str, platform: str | None = None,
+                devices: int = 1, fleet: bool = False) -> str:
+    """Which shell runs `spec` under the WEEDTPU_EC_CODEC `kind`:
+    `pallas`, `xla`, `native`, `numpy`, `mesh` or `fleet`.  A pure
+    function of its arguments.  `platform` (`tpu`, `native`: a CPU host
+    with the C++ library, `cpu`) and `devices` matter under `auto`
+    alone, which is the only kind that asks JAX anything (`_platform`);
+    `fleet` is the multi-volume conversion's resolution, which under
+    `auto` takes the unit-sharded mesh encoder when there is more than
+    one device.  Raises CodecUnsupported where the backend does not
+    carry the tag."""
+    from seaweedfs_tpu.storage.ec import layout
+    if spec.k < 1 or spec.m < 1 or spec.n > layout.MAX_TOTAL_SHARDS:
+        raise CodecUnsupported(
+            f"{spec.tag}: {spec.k} + {spec.m} shard files; a shard set "
+            f"has at least 1 + 1 and at most {layout.MAX_TOTAL_SHARDS}")
+    if fleet and spec.tag != DEFAULT_TAG:
+        raise CodecUnsupported(
+            f"{spec.tag}: fleet conversion stripes and writes the "
+            f"{DEFAULT_TAG} layout only (ops/fleet_convert._VolumeJob); "
+            f"convert the volume with /admin/ec/generate")
+    if fleet and (kind in ("mesh", "fleet") or
+                  (kind == "auto" and devices > 1)):
+        return "fleet"
     if kind in ("cpp", "native"):
+        return "native"
+    if kind == "numpy":
+        return "numpy"
+    if kind == "mesh":
+        if spec.family != "rs":
+            raise CodecUnsupported(
+                f"{spec.tag}: the column-sharded mesh encoder rebuilds "
+                f"from the first k survivors, which only a Reed-Solomon "
+                f"code allows")
+        return "mesh"
+    if kind == "tpu" or (kind == "auto" and platform == "tpu"):
+        # the fused kernel takes any fixed matrix; the MSR file codec's
+        # interleave stays on the XLA body (ROADMAP R8)
+        return "xla" if spec.family == "msr" else "pallas"
+    if kind == "auto" and platform == "native":
+        return "native"
+    return "xla"
+
+
+def _platform(kind: str, fleet: bool) -> tuple[str | None, int]:
+    """(platform, device count) as `backend_for` wants them.  Only `auto`
+    asks: an explicit kind is honoured before JAX is asked anything, so
+    a host-codec process never initialises a backend on a machine whose
+    chip belongs to the volume server."""
+    if kind != "auto":
+        return None, 1
+    import jax
+    devices = len(jax.devices()) if fleet else 1
+    if jax.default_backend() == "tpu":
+        return "tpu", devices
+    from seaweedfs_tpu import native
+    return ("native" if native.available() else "cpu"), devices
+
+
+def _shell(code, backend: str, tile: int | None):
+    if backend == "pallas":
+        from seaweedfs_tpu.ops import pallas_gf
+        return pallas_gf.PallasRSCodec(code, tile)
+    if backend == "native":
         from seaweedfs_tpu.ops import native_codec
         return native_codec.NativeRSCodec(code)
-    if kind == "numpy":
-        return _NumpyShell(code)
-    if kind == "auto":
-        import jax
-        if jax.default_backend() == "tpu":
-            from seaweedfs_tpu.ops import gfmat_jax
-            return gfmat_jax.JaxRSCodec(code)
-        from seaweedfs_tpu import native
-        if native.available():
-            from seaweedfs_tpu.ops import native_codec
-            return native_codec.NativeRSCodec(code)
+    if backend == "mesh":
+        # multi-chip column-parallel codec: stripes shard over every
+        # attached device; built once, so its shard_maps compile once
+        from seaweedfs_tpu.parallel import mesh as pmesh
+        return pmesh.ShardedRSEncoder(code, pmesh.make_mesh())
+    if backend == "fleet":
+        from seaweedfs_tpu.parallel import mesh as pmesh
+        return pmesh.FleetUnitEncoder(code)
+    if backend == "numpy":
+        # a bare RS code is its own numpy reference (ops/dispatch)
+        return code if getattr(code, "family", "rs") == "rs" \
+            else _NumpyShell(code)
     from seaweedfs_tpu.ops import gfmat_jax
     return gfmat_jax.JaxRSCodec(code)
 
 
-@functools.lru_cache(maxsize=16)
-def _build(tag: str, kind: str):
+@functools.lru_cache(maxsize=32)
+def _build(tag: str, backend: str, tile: int | None):
+    """One object per (tag, backend[, Pallas tile]): decode matrices and
+    compiled programs are cached on it."""
     spec = parse_tag(tag)
-    if spec.family == "rs":
-        # RS keeps its existing per-backend registries (incl. Pallas
-        # fused kernels and the mesh codec) — delegate so behaviour and
-        # caches stay byte-identical with pre-family builds
-        from seaweedfs_tpu.storage.ec import ec_files
-        return ec_files._get_codec(kind if kind != "default" else None)
-    code = _code_for(spec)
+    codec = _shell(_code_for(spec), backend, tile)
     if spec.family == "msr":
         from seaweedfs_tpu.ops import msr
-        return msr.MSRFileCodec(_shell_for(code, kind))
-    return _shell_for(code, kind)
+        codec = msr.MSRFileCodec(codec)
+    if (codec.k, codec.m) != (spec.k, spec.m):
+        raise CodecUnsupported(
+            f"{tag}: the {backend} backend built {codec.k} + {codec.m} "
+            f"where the tag says {spec.k} + {spec.m}")
+    return codec
 
 
-def make_codec(tag: str | None, kind: str | None = None):
-    """Backend codec for a codec tag.  `kind` defaults to the
-    WEEDTPU_EC_CODEC knob, exactly like ec_files._get_codec."""
+def resolve(tag: str | None = None, kind: str | None = None,
+            fleet: bool = False):
+    """The backend codec for a codec tag: the program's one resolution.
+    `kind` defaults to the WEEDTPU_EC_CODEC knob (auto: Pallas on a TPU,
+    native C++ AVX2 on a CPU host that has it, XLA bit-sliced otherwise;
+    `tpu` means the compiled kernel, and on a host with no chip it
+    raises).  What each selection resolved to is logged once and rides
+    /perf `codecs` (stats/profile.note_codec)."""
     spec = parse_tag(tag)
     kind = kind or os.environ.get("WEEDTPU_EC_CODEC", "auto")
-    return _build(spec.tag, kind)
+    backend = backend_for(spec, kind, *_platform(kind, fleet), fleet=fleet)
+    tile = None
+    if backend == "pallas":  # WEEDTPU_EC_TILE / the tile pin, as of now
+        from seaweedfs_tpu.ops import pallas_gf
+        tile = pallas_gf.resolved_tile()
+    codec = _build(spec.tag, backend, tile)
+    _note(kind, spec.tag, codec)
+    return codec
+
+
+make_codec = resolve
+
+
+def _note(kind: str, tag: str, codec) -> None:
+    """Report one selection.  Keyed so the describe() behind it runs once
+    per distinct resolution, not per degraded-read batch; `auto` has
+    asked JAX for its backend already, so its block may name the platform
+    even when a host codec won."""
+    from seaweedfs_tpu.ops import dispatch
+    from seaweedfs_tpu.stats import profile
+    profile.note_codec(
+        (kind, tag, dispatch.backend_name(codec)),
+        lambda: {"asked": kind, "tag": tag,
+                 **dispatch.describe(codec, jax_live=kind == "auto")})
 
 
 def spec_of(codec) -> CodecSpec:

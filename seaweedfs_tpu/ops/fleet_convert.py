@@ -36,7 +36,6 @@ lives in maintenance/convert.py; this module is the data plane.
 
 from __future__ import annotations
 
-import functools
 import os
 import queue
 import threading
@@ -63,34 +62,22 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
-@functools.lru_cache(maxsize=4)
-def _fleet_unit_encoder(k: int, m: int):
-    from seaweedfs_tpu.models import rs
-    from seaweedfs_tpu.parallel import mesh as pmesh
-    return pmesh.FleetUnitEncoder(rs.get_code(k, m))
-
-
-def fleet_codec(kind: str | None = None):
-    """The codec a fleet conversion rides.  An explicit choice
-    (WEEDTPU_CONVERT_CODEC, else WEEDTPU_EC_CODEC) is honoured before
-    JAX is asked anything: a host-codec process must not initialise a
-    backend on a machine whose chip belongs to the volume server.  Under
-    `auto`, more than one attached device (a real slice, or the virtual
-    CPU mesh in tests) selects the unit-sharded FleetUnitEncoder, one
-    device whatever `_get_codec` resolves to — every backend takes
-    `dispatch_parity_batch`.  A backend that fails to initialise raises."""
-    from seaweedfs_tpu.storage.ec.ec_files import _get_codec, note_resolved
+def fleet_codec(kind: str | None = None, tag: str | None = None):
+    """The codec a fleet conversion rides: ops/codecs.resolve's fleet
+    rule.  An explicit choice (WEEDTPU_CONVERT_CODEC, else
+    WEEDTPU_EC_CODEC) is honoured before JAX is asked anything: a
+    host-codec process must not initialise a backend on a machine whose
+    chip belongs to the volume server.  Under `auto`, more than one
+    attached device (a real slice, or the virtual CPU mesh in tests)
+    selects the unit-sharded FleetUnitEncoder, one device whatever a
+    single volume's encode resolves to — every backend takes
+    `dispatch_parity_batch`.  The stream stripes and writes RS(10,4)
+    (`_VolumeJob`): any other `tag` raises codecs.CodecUnsupported.  A
+    backend that fails to initialise raises."""
+    from seaweedfs_tpu.ops import codecs
     kind = kind or os.environ.get("WEEDTPU_CONVERT_CODEC") or \
         os.environ.get("WEEDTPU_EC_CODEC", "auto")
-    if kind not in ("auto", "mesh", "fleet"):
-        return _get_codec(kind)
-    if kind == "auto":
-        import jax
-        if len(jax.devices()) == 1:
-            return _get_codec(kind)
-    codec = _fleet_unit_encoder(layout.DATA_SHARDS, layout.PARITY_SHARDS)
-    note_resolved(kind, None, codec)
-    return codec
+    return codecs.resolve(tag, kind, fleet=True)
 
 
 class _VolumeJob:
@@ -215,17 +202,19 @@ def convert_volumes(bases: list[str], *,
                     batch_size: int = DEFAULT_BATCH,
                     codec=None, unit_batch: int | None = None,
                     progress=None, cancel=None,
-                    stats: dict | None = None) -> dict:
+                    stats: dict | None = None,
+                    codec_tag: str | None = None) -> dict:
     """Convert `bases` (.dat volumes) into EC shard sets through one
     interleaved device-resident stream.  Returns per-volume accounting.
 
     `progress(bytes_done)` sees TOTAL volume bytes consumed across the
     fleet; `cancel()` aborts the whole run (uncommitted volumes roll
     back).  `stats` receives the usual per-stage wall-second attribution
-    plus units/volumes counters."""
+    plus units/volumes counters.  `codec_tag` is refused unless it names
+    the layout this stream writes (`fleet_codec`)."""
     if not bases:
         return {"volumes": {}, "bytes": 0}
-    codec = codec if codec is not None else fleet_codec()
+    codec = codec if codec is not None else fleet_codec(tag=codec_tag)
 
     # chaos hook: an armed shard_write_error fault fails the conversion
     # like a dying disk — before any tmp shard file exists

@@ -146,8 +146,10 @@ def heartbeat_from_bytes(raw: bytes) -> dict:
         "ec_shards": [{
             # empty codec = a pre-codec-family node: consumers default rs
             **({"codec": e.codec} if e.codec else {}),
+            # proto3 zero-default, as `version` above: a JSON beat that
+            # never carried a shard size round-trips without the key
+            **({"shard_size": e.shard_size} if e.shard_size else {}),
             "id": e.id, "collection": e.collection,
             "shard_ids": list(e.shards),
-            "shard_size": e.shard_size,
         } for e in hb.ec_shards],
     }

@@ -1157,6 +1157,11 @@ class VolumeServer:
             # set is untouched
             job["state"] = "cancelled"
             return web.json_response({"error": "cancelled"}, status=409)
+        except _codecs.CodecUnsupported as e:
+            # refused by the codec resolution, before any .tmp file
+            job["state"] = "failed"
+            job["error"] = str(e)
+            return web.json_response({"error": str(e)}, status=400)
         except Exception as e:
             job["state"] = "failed"
             job["error"] = str(e)
@@ -1203,6 +1208,15 @@ class VolumeServer:
             return web.json_response(
                 {"error": "no convertible volumes here",
                  "skipped": skipped}, status=404)
+        # the stream's codec, resolved before anything is frozen or
+        # opened: a tag it does not carry is refused here
+        from seaweedfs_tpu.ops import codecs as _codecs
+        from seaweedfs_tpu.ops import fleet_convert as _fleet
+        try:
+            codec = await asyncio.to_thread(_fleet.fleet_codec, None,
+                                            body.get("codec"))
+        except _codecs.CodecUnsupported as e:
+            return web.json_response({"error": str(e)}, status=400)
         # freeze writes for the duration (the same contract as shell
         # ec.encode's readonly step): a needle appended after the .dat
         # snapshot would be silently absent from the committed EC set.
@@ -1224,9 +1238,8 @@ class VolumeServer:
             for _, v in vols:
                 v.flush()  # buffered .dat AND .idx — the mmap'd snapshot
                 #            must hold every committed needle
-            from seaweedfs_tpu.ops import fleet_convert as _fleet
             rep = _fleet.convert_volumes(
-                [v._base for _, v in vols],
+                [v._base for _, v in vols], codec=codec,
                 progress=lambda n: shared.__setitem__("bytes_done", n),
                 cancel=lambda: shared["cancel"],
                 stats=stages)
@@ -1360,6 +1373,10 @@ class VolumeServer:
         except ec_files.EncodeCancelled:
             job["state"] = "cancelled"
             return web.json_response({"error": "cancelled"}, status=409)
+        except _codecs.CodecUnsupported as e:
+            job["state"] = "failed"
+            job["error"] = str(e)
+            return web.json_response({"error": str(e)}, status=400)
         except _regen.HelperDied as e:
             # re-planning exhausted its substitutes: the master retries /
             # falls back to naive copies, and needs to know how hard we
@@ -2467,7 +2484,9 @@ class VolumeServer:
         except Exception as e:
             job["state"] = "failed"
             job["error"] = str(e)
-            return web.json_response({"error": str(e)}, status=500)
+            refused = isinstance(e, _codecs.CodecUnsupported)
+            return web.json_response({"error": str(e)},
+                                     status=400 if refused else 500)
         finally:
             try:
                 os.remove(tmp_dat)

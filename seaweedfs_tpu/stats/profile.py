@@ -351,7 +351,7 @@ _codecs_lock = threading.Lock()
 
 def note_codec(key: tuple, make_info) -> None:
     """Record, and log the first time, what one codec selection resolved
-    to in this process (ec_files.note_resolved builds the block with
+    to in this process (ops/codecs.resolve builds the block with
     ops/dispatch.describe — lazily, `key` is checked first).  /perf
     carries the blocks, so "which backend is this volume server really
     encoding on" has an answer that does not depend on reading the

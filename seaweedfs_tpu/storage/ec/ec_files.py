@@ -19,7 +19,6 @@ Functions mirror the reference's capability surface:
 
 from __future__ import annotations
 
-import functools
 import os
 import queue
 import sys
@@ -35,78 +34,15 @@ from seaweedfs_tpu.storage.ec import layout
 DEFAULT_BATCH = 16 * 1024 * 1024  # bytes per shard per device round-trip
 
 
-@functools.lru_cache(maxsize=8)
-def _mesh_codec(k: int, m: int):
-    from seaweedfs_tpu.models import rs
-    from seaweedfs_tpu.parallel import mesh as pmesh
-    return pmesh.ShardedRSEncoder(rs.get_code(k, m), pmesh.make_mesh())
-
-
 def _get_codec(kind: str | None = None, tag: str | None = None):
-    """Select the EC codec backend: the `ec.codec` knob of this framework.
-
-    auto (default): Pallas on TPU, native C++ AVX2 on CPU hosts, XLA
-    bit-sliced otherwise.  Override with WEEDTPU_EC_CODEC=tpu|jax|cpp|numpy.
-    `tpu` means the compiled kernel: on a host with no chip it raises.
-
-    `tag` picks the CODE (ops/codecs grammar: rs_10_4 / lrc_10_2_2 /
-    msr_9_16); non-RS families build through the codec registry, which
-    reuses the same backend kinds over their matrices.
-
-    What each selection resolved to is logged once and rides /perf
-    (stats/profile.note_codec)."""
-    kind = kind or os.environ.get("WEEDTPU_EC_CODEC", "auto")
-    codec = _select_codec(kind, tag)
-    note_resolved(kind, tag, codec)
-    return codec
-
-
-def note_resolved(kind: str, tag: str | None, codec) -> None:
-    """Report one selection (this module's and fleet_codec's).  Keyed so
-    the describe() behind it runs once per distinct resolution, not per
-    degraded-read batch; `auto` has asked JAX for its backend already,
-    so its block may name the platform even when a host codec won."""
-    tag = tag or f"rs_{layout.DATA_SHARDS}_{layout.PARITY_SHARDS}"
-    _profile.note_codec(
-        (kind, tag, _backend_name(codec)),
-        lambda: {"asked": kind, "tag": tag,
-                 **_describe(codec, jax_live=kind == "auto")})
-
-
-def _select_codec(kind: str, tag: str | None):
-    if tag is not None:
-        from seaweedfs_tpu.ops import codecs as _codecs
-        spec = _codecs.parse_tag(tag)
-        if spec.family != "rs":
-            return _codecs.make_codec(spec.tag, kind)
-    k, m = layout.DATA_SHARDS, layout.PARITY_SHARDS
-    if kind in ("cpp", "native"):
-        from seaweedfs_tpu.ops import native_codec
-        return native_codec.get_codec(k, m)
-    if kind == "numpy":
-        from seaweedfs_tpu.models import rs
-        return rs.get_code(k, m)
-    if kind == "mesh":
-        # multi-chip column-parallel codec (parallel/mesh.py): stripes
-        # shard over every attached device; memoized so the jitted
-        # shard_maps compile once per (k, m)
-        return _mesh_codec(k, m)
-    if kind == "auto":
-        import jax
-        if jax.default_backend() == "tpu":
-            from seaweedfs_tpu.ops import pallas_gf
-            return pallas_gf.get_codec(k, m)
-        from seaweedfs_tpu import native
-        if native.available():
-            from seaweedfs_tpu.ops import native_codec
-            return native_codec.get_codec(k, m)
-        from seaweedfs_tpu.ops import gfmat_jax
-        return gfmat_jax.get_codec(k, m)
-    if kind == "tpu":
-        from seaweedfs_tpu.ops import pallas_gf
-        return pallas_gf.get_codec(k, m)
-    from seaweedfs_tpu.ops import gfmat_jax
-    return gfmat_jax.get_codec(k, m)
+    """The codec the EC file engines run a volume's code on: the `ec.codec`
+    knob of this framework.  `tag` picks the CODE (ops/codecs grammar:
+    rs_10_4 / lrc_12_2_2 / msr_9_16; none: the RS default), `kind` the
+    backend (default: WEEDTPU_EC_CODEC).  The choice itself is
+    ops/codecs.resolve's, the program's one resolution; a tag the backend
+    does not carry raises codecs.CodecUnsupported."""
+    from seaweedfs_tpu.ops import codecs as _codecs
+    return _codecs.resolve(tag, kind)
 
 
 # backend seam (ops/dispatch.py): parity dispatch, the d2h sync point,
@@ -114,9 +50,9 @@ def _select_codec(kind: str, tag: str | None):
 from seaweedfs_tpu.stats import netflow as _netflow  # noqa: E402
 from seaweedfs_tpu.stats import pipeline as _pipeline  # noqa: E402
 from seaweedfs_tpu.stats import profile as _profile  # noqa: E402
+from seaweedfs_tpu.stats import trace as _trace  # noqa: E402
 from seaweedfs_tpu.ops.dispatch import (  # noqa: E402
     backend_name as _backend_name,
-    describe as _describe,
     dispatch_parity as _dispatch_parity,
     materialize as _materialize,
     reconstruct_batch as _reconstruct_batch,
@@ -182,9 +118,10 @@ def write_ec_files(base: str, dat_path: str | None = None,
                    batch_size: int = DEFAULT_BATCH,
                    progress=None, cancel=None, stats=None,
                    codec_tag: str | None = None) -> None:
-    """Encode `<base>.dat` (or dat_path) into `<base>.ec00` .. `.ec13`,
-    plus a `<base>.vif` volume-info sidecar recording the encode-time dat
-    size and version (the reference's .vif, volume_info.go:16-40, as JSON):
+    """Encode `<base>.dat` (or dat_path) into the shard files of the
+    volume's code (`codec_tag`: `.ec00` .. `.ec13` under rs_10_4, `.ec15`
+    under lrc_12_2_2), plus a `<base>.vif` volume-info sidecar recording
+    the codec tag, the encode-time dat size and version (the reference's .vif, volume_info.go:16-40, as JSON):
     the layout was cut from the FILE size, which later lookups cannot
     reliably re-derive from the index once tail needles get deleted.
 
@@ -245,8 +182,8 @@ def _iter_units(dat_size: int, large_block: int, small_block: int,
     units in shard file order: N full rows of k large blocks, then
     small-block rows.  shard_off is the unit's byte offset inside every
     shard file (all n shard files are parallel arrays of blocks).
-    `data_shards` is the codec's stripe width k (10 for RS/LRC, 9 for
-    MSR volumes)."""
+    `data_shards` is the codec's stripe width k (10 for rs_10_4, 12 for
+    lrc_12_2_2, 9 for MSR volumes)."""
     k = data_shards
     processed = 0
     remaining = dat_size
@@ -597,7 +534,8 @@ class _ShardWriterPool:
                 releases: list = []
                 with self._job.stage(self._stage_of(shard)) as st:
                     self._write_batch(eng, shard, item, releases)
-                self._busy[shard] += st.seconds
+                if not self.errors:  # a failed run's seconds mean nothing
+                    self._busy[shard] += st.seconds
                 for rel in releases:
                     rel()
         finally:
@@ -610,7 +548,6 @@ class _ShardWriterPool:
                      releases: list) -> None:
         """Submit one queue item's jobs to the engine and drain it; every
         error is kept for close(), none raised."""
-        fd = self._fds[shard]
         ends: list[tuple[int, int]] = []
         idx = 0
         while idx < len(item):
@@ -621,6 +558,10 @@ class _ShardWriterPool:
             try:
                 if self.errors:
                     continue  # drain without touching the fd
+                # inside the try: a shard the pool has no file for (a
+                # codec wider than the set) is an error of the run, not
+                # the death of this worker with its queue still filling
+                fd = self._fds[shard]
                 if cfr is not None:
                     src_fd, src_off, count, src_view = cfr
                     # in-kernel copies want plain buffered fd
@@ -1187,11 +1128,27 @@ def _survivor_basis(codec, present: list[int],
                                      list(wanted)))
 
 
+def basis_kind(codec, use: list[int]) -> str:
+    """`local` when the survivors a decode reads all lie in one local
+    group of the code (an LRC's one-lost repair: r files, not k), else
+    `global` (any MDS code, and an LRC's fallback over the whole set)."""
+    code = getattr(codec, "code", codec)
+    group_of = getattr(code, "group_of", None)
+    if group_of is None:
+        return "global"
+    groups = {group_of(i) for i in use}
+    return "local" if len(groups) == 1 and None not in groups else "global"
+
+
 def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                      progress=None, cancel=None, stats=None,
                      codec_tag: str | None = None) -> list[int]:
-    """Regenerate whichever `.ecXX` files are missing from the >=10 present
-    ones. Returns the rebuilt shard ids.
+    """Regenerate whichever `.ecXX` files are missing from the present
+    ones, under the code the `.vif` names. Returns the rebuilt shard ids.
+    Only the survivors of the code's basis are opened and staged
+    (`_survivor_basis`): any k for an MDS code, the r of one local group
+    for an LRC's one-lost repair; `stats` says how many and which kind
+    (`survivors`, `basis`).
 
     Same zero-copy and overlap discipline as the encode path (and the same
     observability: `progress(bytes_done)` per batch over survivor bytes,
@@ -1221,6 +1178,10 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     stats = stats if stats is not None else {}
     stats["bytes"] = shard_size * len(use)
     stats["codec"] = spec.tag
+    # what the rebuild reads: how many survivor files it stages and
+    # whether they are one local group (/admin/ec/progress `stages`)
+    stats["survivors"] = len(use)
+    stats["basis"] = basis_kind(codec, use)
     # MSR sub-packetization: every chunk a codec's interleave must see is
     # an alpha multiple (shard files themselves are block-multiples)
     if spec.alpha > 1:
@@ -1247,6 +1208,12 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                            meta={"missing": len(missing),
                                  "codec": spec.tag},
                            span="ec.rebuild", sums=REBUILD_SUMS)
+    # the job's own span, round the stages named after it
+    job_span = _trace.span("ec.rebuild", codec=spec.tag,
+                           missing=len(missing),
+                           survivors=stats["survivors"],
+                           basis=stats["basis"])
+    job_span.__enter__()
     t_wall = time.perf_counter()
     import mmap as mmap_mod
     ins: dict[int, object] = {}
@@ -1354,6 +1321,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
         # error operators triage from /debug/pipeline, not a generic tag
         pjob.finish(None if ok else
                     (sys.exc_info()[1] or "rebuild failed"))
+        job_span.__exit__(*sys.exc_info())
         for f in ins.values():
             f.close()
         for i in list(views):

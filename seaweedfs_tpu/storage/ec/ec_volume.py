@@ -456,7 +456,7 @@ class EcVolume:
         with _read_flow().stage(
                 "gather_survivors",
                 nbytes=self.spec.k * sum(s for _, s in gsegs),
-                shards_lost=len(wanted), segs=len(gsegs)):
+                shards_lost=len(wanted), segs=len(gsegs)) as gather:
             try:
                 rows = self._gather_survivors(set(wanted), gsegs,
                                               shard_reader, want=basis)
@@ -469,6 +469,10 @@ class EcVolume:
                 rows = self._gather_survivors(
                     set(wanted), gsegs, shard_reader,
                     need=self.spec.k + extra)
+            # what the read gathered: 6 of one local group under
+            # lrc_12_2_2 with one shard of the group lost, k otherwise
+            gather.set(survivors=len(rows),
+                       basis=ec_files.basis_kind(codec, list(rows)))
         # one dispatch decodes every wanted shard over the WHOLE
         # concatenation even though each segment only consumes its own
         # shard's slice — deliberately: with f lost shards that wastes

@@ -1,14 +1,20 @@
-"""EC striping layout: how a volume .dat maps onto 14 shard files.
+"""EC striping layout: how a volume .dat maps onto its shard files.
 
-Semantics match the reference exactly (weed/storage/erasure_coding/
-ec_locate.go, ec_encoder.go:17-23, encodeDatFile loop at :198-235) so shard
-files interoperate:
+The width of a stripe is k of the volume's codec (ops/codecs: 10 for
+rs_10_4, 12 for lrc_12_2_2, 9 for msr_9_16, 6 for rs_6_3) and the set has
+k + m files (14, 16, 18, 9).  Every function here takes `data_shards`;
+DATA_SHARDS / PARITY_SHARDS are the RS(10,4) default an untagged volume
+gets, and what the fleet conversion writes.  For RS(10,4) the semantics
+match the reference exactly (weed/storage/erasure_coding/ec_locate.go,
+ec_encoder.go:17-23, encodeDatFile loop at :198-235) so shard files
+interoperate:
 
 - The .dat is consumed row-major. While more than one large row
-  (10 x 1GB) remains, a large row is cut into 10 large blocks; the rest is
-  cut into rows of 10 small (1MB) blocks, the final row zero-padded.
+  (k x 1GB) remains, a large row is cut into k large blocks; the rest is
+  cut into rows of k small (1MB) blocks, the final row zero-padded.
 - Shard j's file = its large blocks in row order, then its small blocks.
-- Parity shards 10..13 hold the RS parity of each row, same block sizes.
+- Parity shards k..k+m-1 hold the code's parity of each row, same block
+  sizes.
 
 This is the system's "sequence sharding": a needle read touches only the
 block(s) its byte range lands in, while encode streams sequentially.
@@ -40,8 +46,8 @@ class Interval:
     size: int
     is_large_block: bool
     large_block_rows: int
-    # stripe width: k of the volume's codec (RS default; LRC shares the
-    # same 10-wide geometry, MSR volumes stripe 9-wide)
+    # stripe width: k of the volume's codec (10 by default; lrc_12_2_2
+    # stripes 12 wide, MSR volumes 9)
     data_shards: int = DATA_SHARDS
 
     def to_shard_id_and_offset(self, large_block: int = LARGE_BLOCK_SIZE,
@@ -59,7 +65,7 @@ class Interval:
 def n_large_rows(dat_size: int, large_block: int = LARGE_BLOCK_SIZE,
                  small_block: int = SMALL_BLOCK_SIZE,
                  data_shards: int = DATA_SHARDS) -> int:
-    """Number of 10-wide large-block rows for a volume of dat_size bytes.
+    """Number of k-wide large-block rows for a volume of dat_size bytes.
 
     Exactly matches the encode loop's strict `remaining > 10*large`
     condition: rows are cut while MORE than one large row remains.

@@ -24,6 +24,9 @@ def test_heartbeat_roundtrip_preserves_fields():
         ],
         "ec_shards": [
             {"id": 7, "collection": "", "shard_ids": [0, 3, 13]},
+            # the shard report is where a volume's codec tag travels
+            {"id": 9, "collection": "warm", "shard_ids": [3, 12, 15],
+             "shard_size": 80 << 20, "codec": "lrc_12_2_2"},
         ],
     }
     back = pb.heartbeat_from_bytes(pb.heartbeat_to_bytes(beat))

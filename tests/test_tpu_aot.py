@@ -80,19 +80,35 @@ def _lifted(C, sharding):
     return _spec(bm.shape, bm.dtype, sharding), seam._kpad(C.shape[1])
 
 
-@pytest.mark.parametrize("wanted, width",
-                         [(None, MIB), ([3], 16 * MIB), ([0, 1], TILE)],
-                         ids=["encode_10_4", "rebuild_batch_1_row",
-                              "read_2_rows_smallest_bucket"])
-def test_gf_apply_compiles_for_v5e(v5e, wanted, width):
-    """The served single-chip programs at TPU_TILE: [10, 1 MiB] under the
+@pytest.mark.parametrize("tag, wanted, width, shape", [
+    ("rs_10_4", None, MIB, (4, 10)),
+    ("rs_10_4", [3], 16 * MIB, (1, 10)),
+    ("rs_10_4", [0, 1], TILE, (2, 10)),
+    ("lrc_12_2_2", None, MIB, (4, 12)),
+    ("lrc_12_2_2", [3], 16 * MIB, (1, 6)),
+    ("lrc_12_2_2", [0], TILE, (1, 6)),
+    ("lrc_12_2_2", [0, 1], 16 * MIB, (2, 12)),
+    ("lrc_12_2_2", [0, 6], TILE, (2, 12)),
+], ids=["encode_10_4", "rebuild_batch_1_row", "read_2_rows_smallest_bucket",
+        "lrc_encode_4_12", "lrc_local_rebuild_batch_1_6",
+        "lrc_local_read_smallest_bucket_1_6", "lrc_global_rebuild_2_12",
+        "lrc_read_one_lost_in_each_group_2_12"])
+def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
+    """The served single-chip programs at TPU_TILE: [k, 1 MiB] under the
     parity matrix, and the two ends of what the reconstruct seam runs
     (ops/dispatch.reconstruct_batch): a rebuild batch, the widest bucket,
-    under a one-row decode matrix, and a degraded read of two shards at
-    the narrowest."""
-    code = rs.get_code(10, 4)
-    C = code.parity_matrix if wanted is None else code.decode_matrix(
-        [i for i in range(14) if i not in wanted][:10], wanted)
+    and a degraded read at the narrowest.  RS(10,4), and Azure LRC(12,2,2)
+    (`lrc_12_2_2`): its [4, 12] parity, the [1, 6] of ones that rebuilds
+    one lost data shard from its local group (k = 6 under PLANE_PAD 16)
+    and the [2, 12] of the global fallback, each as its basis stages it."""
+    from seaweedfs_tpu.ops import codecs
+    code = codecs._code_for(codecs.parse_tag(tag))
+    if wanted is None:
+        C = code.parity_matrix
+    else:
+        have = [i for i in range(code.n) if i not in wanted]
+        C = code.decode_matrix(have, wanted)
+    assert C.shape == shape
     one = SingleDeviceSharding(v5e[0])
     bm, kpad = _lifted(C, one)
     m, k = C.shape
