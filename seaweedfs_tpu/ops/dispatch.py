@@ -292,7 +292,10 @@ def apply_matrix(codec, C: np.ndarray, stack: np.ndarray, job=None,
 # A put costs its thread about 0.2 ms whatever it carries, and one array
 # in flight moves at half the rate of ten (TPU v5e: a 160 MiB put 30 ms,
 # its ten rows 16; at 1 MiB a row the two ways tie; PERF.md, PR 26): rows
-# this wide and wider go up one by one, narrower ones in one array.
+# this wide and wider go up one by one, narrower ones in one array.  A row
+# that goes up on its own needs no place in a stacked array: where it is
+# as wide as its bucket too it is put from where it lies, uncopied (a view
+# of a shard file's map in a rebuild; PERF.md, PR 29).
 ROW_PUTS_FROM = 2 << 20
 
 
@@ -318,11 +321,16 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
     order: a `[len(ids), n]` array or a sequence of rows.
 
     A device codec is handed the rows its decode matrix wants, in its
-    order, stacked on the host and W wide, W the bucket of n
-    (`codec_base.bucket`): one program per (rows wanted, W) and not per n,
-    with nothing but 1-D arrays crossing (`codec_base.stacked`), and the
-    cut back to n is a view on the host.  A caller that stages a whole
-    bucket in basis order (a rebuild batch) is put up as it is."""
+    order and W wide, W the bucket of n (`codec_base.bucket`): one program
+    per (rows wanted, W) and not per n, with nothing but 1-D arrays
+    crossing (`codec_base.stacked`), and the cut back to n is a view on
+    the host.  How they go up rests on n and W, not on what `rows` is:
+    rows as wide as their bucket and at least `ROW_PUTS_FROM` (a rebuild
+    batch) are put one by one from where they lie, and the runtime reads
+    them after the put returns, so they stay alive and unchanged until
+    this does (it waits for the result); any others are stacked on the
+    host first (`_staged`: the one host copy of a degraded read, or of a
+    rebuild's short last batch), which the job counts as `rows_staged`."""
     ids = list(ids)
     n = len(rows[0])
     nbytes = len(ids) * n
@@ -348,7 +356,11 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
     width = bucket(n, codec.tile)
 
     def put():
+        if width == n and width >= ROW_PUTS_FROM:
+            return tuple(jnp.asarray(rows[src]) for src in order)
         stack = _staged(rows, order, width)
+        if job is not None and stack is not rows:
+            job.count("rows_staged", len(order))
         if width >= ROW_PUTS_FROM:
             return tuple(jnp.asarray(row) for row in stack)
         return jnp.asarray(stack.reshape(-1))

@@ -276,6 +276,12 @@ class PipelineJob:
                   items: float = 0.0) -> None:
         self._book(name, 0.0, nbytes, items, False)
 
+    def count(self, name: str, n: int) -> None:
+        """Add to a plain count the run states beside its stage seconds
+        (``stats[name]``: a value of /admin/ec/progress `stages`)."""
+        with self._lock:
+            self.stats[name] = self.stats.get(name, 0) + n
+
     def queue(self, name: str, depth: int, bound: int = 0) -> None:
         """Sample a queue's depth (producers call at put/get sites)."""
         with self._lock:

@@ -1145,19 +1145,31 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                      codec_tag: str | None = None) -> list[int]:
     """Regenerate whichever `.ecXX` files are missing from the present
     ones, under the code the `.vif` names. Returns the rebuilt shard ids.
-    Only the survivors of the code's basis are opened and staged
+    Only the survivors of the code's basis are opened and mapped
     (`_survivor_basis`): any k for an MDS code, the r of one local group
     for an LRC's one-lost repair; `stats` says how many and which kind
     (`survivors`, `basis`).
 
-    Same zero-copy and overlap discipline as the encode path (and the same
-    observability: `progress(bytes_done)` per batch over survivor bytes,
-    `cancel()` aborts, `stats` gets per-stage seconds + overlap_frac):
-    survivor shards are mmap'd and fed to the native decode matmul by row
-    pointer, rebuilt shards land in a countdown-released buffer ring and
-    stream to per-shard writer workers (the decode of batch N overlaps the
-    writes of batch N-1) into recycled `.tmp` inodes, committed by rename
-    only on success (reference: RebuildEcFiles, ec_encoder.go:237-291)."""
+    The encode path's observability (`progress(bytes_done)` per batch over
+    survivor bytes, `cancel()` aborts, `stats` gets per-stage seconds +
+    overlap_frac) and its zero-copy reads: survivor shards are mmap'd and a
+    batch is its rows where they lie in the maps, handed to the native
+    decode matmul by row pointer or to the dispatch seam as views, which a
+    device codec puts up uncopied where they are a whole bucket wide
+    (`ops/dispatch.ROW_PUTS_FROM`; `stats["rows_staged"]` counts the rows
+    copied first: a short last batch).  Of its overlap, the writes alone:
+    rebuilt shards land in a countdown-released buffer ring and stream to
+    per-shard writer workers (the decode of batch N overlaps the writes of
+    batch N-1) into recycled `.tmp` inodes, committed by rename only on
+    success (reference: RebuildEcFiles, ec_encoder.go:237-291); a device
+    batch's put, kernel, copy back and `unstage` run one after the other.
+
+    The runtime reads a row after its put returns, so a batch's views and
+    device arrays must be dead before the maps close: the seam waits for
+    each batch's result and frees its device arrays before the next is
+    taken, and where a reference outlives the loop (a traceback, the CPU
+    backend aliasing an aligned view of the read-only maps) `mm.close()`
+    raises `BufferError`, let pass: the mapping goes with its last view."""
     from seaweedfs_tpu.ops import codecs as _codecs
     spec = _codecs.parse_tag(codec_tag or
                              (read_vif(base) or {}).get("codec"))
@@ -1182,6 +1194,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     # whether they are one local group (/admin/ec/progress `stages`)
     stats["survivors"] = len(use)
     stats["basis"] = basis_kind(codec, use)
+    stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
     # MSR sub-packetization: every chunk a codec's interleave must see is
     # an alpha multiple (shard files themselves are block-multiples)
     if spec.alpha > 1:
@@ -1222,7 +1235,6 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     tmp_paths = {i: base + layout.to_ext(i) + ".tmp" for i in missing}
     out_fds: dict[int, int] = {}
     writers = None
-    stage = None
     ok = False
     # setup runs under the same finally that seals the job: a survivor
     # deleted between the present-list and open (a racing repair), or
@@ -1257,9 +1269,10 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                 views[i] = np.frombuffer(mm, dtype=np.uint8)
         done = 0
         # a batch's time is `reconstruct`: the native matmul booked whole,
-        # or for a device codec the sum of this loop's `stage` (survivor
-        # memcpy) and `unstage` (copy into the output ring) and the four
-        # stages the dispatch seam books between them (REBUILD_SUMS)
+        # or for a device codec the sum of this loop's `stage` (the rows
+        # selected in the maps: no byte moves) and `unstage` (copy into the
+        # output ring) and the four stages the dispatch seam books between
+        # them (REBUILD_SUMS)
         for unit, off in enumerate(range(0, shard_size, batch_size)):
             if cancel is not None and cancel():
                 raise EncodeCancelled("ec rebuild cancelled")
@@ -1277,15 +1290,10 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                                         nbytes=len(use) * n)
             else:
                 with pjob.stage("stage", unit=unit):
-                    if stage is None:
-                        stage = np.empty((len(use),
-                                          min(batch_size, shard_size)),
-                                         dtype=np.uint8)
-                    for row, i in enumerate(use):
-                        np.copyto(stage[row, :n], views[i][off:off + n])
-                # `use` is the basis order: a full batch goes up as staged
-                rebuilt = _reconstruct_batch(codec, stage[:, :n], use,
-                                             missing, job=pjob, unit=unit)
+                    rows = [views[i][off:off + n] for i in use]
+                rebuilt = _reconstruct_batch(codec, rows, use, missing,
+                                             job=pjob, unit=unit)
+                del rows  # no view of a map outlives its batch
                 with pjob.stage("unstage", unit=unit):
                     for r, i in enumerate(missing):
                         np.copyto(obuf[r, :n], rebuilt[i])

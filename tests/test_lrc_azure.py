@@ -458,6 +458,8 @@ def test_generate_and_rebuild_under_the_tag(server):
     assert status == 200 and job["kind"] == "rebuild" and job["codec"] == TAG
     assert job["stages"]["survivors"] == 6
     assert job["stages"]["basis"] == "local"
+    # one batch, narrower than a row goes up alone: stacked on the host
+    assert job["stages"]["rows_staged"] == 6
     _no_leftovers(base)
 
 

@@ -7,8 +7,9 @@ import os
 import numpy as np
 import pytest
 
+from seaweedfs_tpu.models import lrc as lrc_ref
 from seaweedfs_tpu.models import rs
-from seaweedfs_tpu.ops import codec_base, dispatch, gfmat_jax, pallas_gf
+from seaweedfs_tpu.ops import codec_base, dispatch, gfmat_jax, lrc, pallas_gf
 from seaweedfs_tpu.stats import pipeline, profile
 from seaweedfs_tpu.storage import needle as ndl
 from seaweedfs_tpu.storage.ec import ec_files, ec_volume, layout
@@ -20,11 +21,11 @@ TOP = TILE << (codec_base.BUCKETS - 1)
 SHELLS = ["jax", "pallas"]
 
 
-def _shell(kind: str, tile: int = TILE):
+def _shell(kind: str, tile: int = TILE, code=None):
     """A device shell of its own at a tile tiny volumes can cross several
     buckets of.  The tests that count programs take a tile each: a
     program built for another test's width is not built again."""
-    code = rs.get_code(10, 4)
+    code = code or rs.get_code(10, 4)
     if kind == "pallas":
         return pallas_gf.PallasRSCodec(code, tile=tile, interpret=True)
     codec = gfmat_jax.JaxRSCodec(code)
@@ -218,10 +219,15 @@ def test_rebuild_puts_a_batch_once_and_builds_one_program(
     pipeline.reset()
     before = profile.KERNELS.snapshot().get("reconstruct[device]", {})
     p0 = _programs()
-    assert ec_files.rebuild_ec_files(base, batch_size=batch) == [3]
+    stats: dict = {}
+    assert ec_files.rebuild_ec_files(base, batch_size=batch,
+                                     stats=stats) == [3]
     assert open(base + layout.to_ext(3), "rb").read() == want
     assert _programs() - p0 == 1
-    assert puts == [True] * batches  # no host copy: staged in basis order
+    # rows narrower than `ROW_PUTS_FROM` go up in one array: the one host
+    # copy of a batch is the seam's, out of the maps
+    assert puts == [False] * batches
+    assert stats["rows_staged"] == 10 * batches
     job = next(j for j in pipeline.jobs_snapshot()
                if j["kind"] == "ec_rebuild")
     assert {job["stages"][s]["items"] for s in
@@ -234,3 +240,138 @@ def test_rebuild_puts_a_batch_once_and_builds_one_program(
     # roofline.rows (its `gbytes`, `calls`) said of a rebuild before
     assert moved == {"calls": batches, "bytes": 10 * shard_size,
                      "h2d_bytes": 10 * shard_size, "d2h_bytes": shard_size}
+
+
+# ---- rebuild: rows a bucket wide go up from the shard files' maps -------
+
+BATCH = 4 * 640  # a bucket of the tile 640
+
+
+def _code(tag: str):
+    return lrc.get_code(12, 2, 2) if tag == "lrc_12_2_2" else \
+        rs.get_code(10, 4)
+
+
+def _shard_set(tmp_path, tag: str, shard_size: int, lost: list[int]):
+    """The plain reference's shard files of seeded data under `tag`
+    (`models/lrc.py`, `models/rs.py`), the `lost` ones removed: (base, the
+    whole set's bytes)."""
+    data = np.random.default_rng(shard_size).integers(
+        0, 256, (_code(tag).k, shard_size), dtype=np.uint8)
+    want = lrc_ref.encode(data) if tag == "lrc_12_2_2" else \
+        rs.get_code(10, 4).encode_numpy(data)
+    base = str(tmp_path / "7")
+    for i, row in enumerate(want):
+        if i not in lost:
+            row.tofile(base + layout.to_ext(i))
+    return base, want
+
+
+def _spied_rebuild(monkeypatch, base, tag, lost):
+    """`rebuild_ec_files` at `ROW_PUTS_FROM` = one batch, with what the
+    seam was handed: per call the rows' length and whether every row
+    shares memory with a map the engine made, and the lengths `_staged`
+    was entered with."""
+    monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", BATCH)
+    maps, calls, staged = [], [], []
+    real_map, real_seam, real_staged = (
+        ec_files._map_readonly, ec_files._reconstruct_batch,
+        dispatch._staged)
+
+    def map_spy(fd, size):
+        maps.append(real_map(fd, size))
+        return maps[-1]
+
+    def seam_spy(codec, rows, ids, wanted, **kw):
+        views = [np.frombuffer(m, dtype=np.uint8) for m in maps]
+        calls.append((len(rows[0]), all(
+            r.ndim == 1 and any(np.shares_memory(r, v) for v in views)
+            for r in rows)))
+        del views
+        return real_seam(codec, rows, ids, wanted, **kw)
+
+    def staged_spy(rows, order, width):
+        staged.append(len(rows[0]))
+        return real_staged(rows, order, width)
+
+    monkeypatch.setattr(ec_files, "_map_readonly", map_spy)
+    monkeypatch.setattr(ec_files, "_reconstruct_batch", seam_spy)
+    monkeypatch.setattr(dispatch, "_staged", staged_spy)
+    stats: dict = {}
+    assert ec_files.rebuild_ec_files(base, batch_size=BATCH, stats=stats,
+                                     codec_tag=tag) == lost
+    return stats, calls, staged
+
+
+@pytest.mark.parametrize("kind", SHELLS)
+@pytest.mark.parametrize("tag, lost, survivors", [
+    ("rs_10_4", [3], 10), ("rs_10_4", [0, 5, 11, 13], 10),
+    ("lrc_12_2_2", [3], 6)], ids=["rs_1lost", "rs_4lost", "lrc_1lost"])
+def test_rebuild_puts_whole_buckets_from_the_maps(
+        kind, tag, lost, survivors, tmp_path, serve, monkeypatch):
+    """Every batch a bucket wide and wide enough to go up row by row:
+    the seam is handed views of the maps, copies none of them on the
+    host, and the files are the plain reference's."""
+    base, want = _shard_set(tmp_path, tag, 4 * BATCH, lost)
+    serve(_shell(kind, 640, _code(tag)))
+    stats, calls, staged = _spied_rebuild(monkeypatch, base, tag, lost)
+    assert calls == [(BATCH, True)] * 4
+    assert staged == []
+    assert (stats["rows_staged"], stats["survivors"]) == (0, survivors)
+    assert stats["stage_s"] > 0 and stats["unstage_s"] > 0
+    for i in lost:
+        assert open(base + layout.to_ext(i), "rb").read() == \
+            want[i].tobytes(), i
+
+
+@pytest.mark.parametrize("kind", SHELLS)
+def test_rebuild_stages_only_a_last_batch_short_of_its_bucket(
+        kind, tmp_path, serve, monkeypatch):
+    base, want = _shard_set(tmp_path, "rs_10_4", 3 * BATCH + 700, [3])
+    serve(_shell(kind, 640))
+    stats, calls, staged = _spied_rebuild(monkeypatch, base, "rs_10_4", [3])
+    assert calls == [(BATCH, True)] * 3 + [(700, True)]
+    assert staged == [700]  # that batch alone: 700 bytes in a 1,280 bucket
+    assert stats["rows_staged"] == 10
+    assert open(base + layout.to_ext(3), "rb").read() == want[3].tobytes()
+
+
+@pytest.mark.parametrize("kind", SHELLS)
+@pytest.mark.parametrize("in_place", [True, False],
+                         ids=["from_where_they_lie", "staged"])
+def test_seam_takes_rows_or_an_array_alike(kind, in_place, monkeypatch):
+    """A list of 1-D rows and the same rows as one array: the same bytes
+    out of the same program, whichever way the width sends them up."""
+    tile = 896
+    n = 2 * tile
+    monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", n if in_place else n + 1)
+    staged = []
+    real = dispatch._staged
+
+    def staged_spy(rows, order, width):
+        staged.append(type(rows))
+        return real(rows, order, width)
+
+    monkeypatch.setattr(dispatch, "_staged", staged_spy)
+    code = rs.get_code(10, 4)
+    codec = _shell(kind, tile)
+    ec_files._get_codec("jax")  # the compile counter is on
+    shards = code.encode_numpy(np.random.default_rng(29).integers(
+        0, 256, (10, n), dtype=np.uint8))
+    ids, wanted = [0, 1, 2, 4, 5, 6, 7, 8, 9, 13], [3, 12]
+    stack = np.ascontiguousarray(shards[ids])
+    job = pipeline.PipelineJob("seam", register=False)
+    p0 = _programs()
+    got_rows = dispatch.reconstruct_batch(codec, list(stack), ids, wanted,
+                                          job=job)
+    built = _programs() - p0
+    got_array = dispatch.reconstruct_batch(codec, stack, ids, wanted,
+                                           job=job)
+    assert _programs() - p0 == built <= 1
+    for w in wanted:
+        assert np.array_equal(got_rows[w], shards[w])
+        assert np.array_equal(got_array[w], shards[w])
+    # in place nothing is stacked; else the list is, once, and the array
+    # is a staged bucket already
+    assert staged == ([] if in_place else [list, np.ndarray])
+    assert job.stats.get("rows_staged", 0) == (0 if in_place else 10)
