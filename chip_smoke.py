@@ -247,9 +247,6 @@ class Server:
             # on on the CPU, and `auto` would quietly hand out a host codec
             JAX_PLATFORMS="cpu" if rehearsal else "tpu",
             JAX_LOG_COMPILES="1",  # compile counts per phase, from the log
-            # the default pin lives in $HOME: state outside the checkout
-            # that changes the kernel's tile
-            WEEDTPU_TILE_PIN=os.path.join(work, "tile_pin.json"),
             WEEDTPU_CANARY_INTERVAL="0",
             PYTHONUNBUFFERED="1")
         if rehearsal:

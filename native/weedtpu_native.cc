@@ -341,8 +341,7 @@ static int detect_gfni(void) {
 
 // 0 = auto (best available), 1 = force AVX2 split-table, 2 = force scalar,
 // 3 = force GFNI (falls back to auto-best when the host lacks it).  The AVX2
-// force keeps the klauspost-equivalent baseline measurable on GFNI hosts
-// (bench.py benchmarks both and reports the ratio).
+// force keeps the klauspost-equivalent baseline testable on GFNI hosts.
 static int gf_impl_force = 0;
 
 void wn_gf_set_impl(int impl) { gf_impl_force = impl; }

@@ -25,7 +25,7 @@ __version__ = "0.1.0"
 # JAX's persistent compilation cache.  JAX reads the variable when it is
 # first imported, and the directory is part of every cache key, so the
 # place is decided here — the one module every entry point (server,
-# shell, bench.py, chip_smoke.py) imports before any `import jax`.  An
+# shell, chip_smoke.py) imports before any `import jax`.  An
 # operator's JAX_COMPILATION_CACHE_DIR wins; otherwise one fixed,
 # git-ignored directory at the root of the checkout.
 COMPILE_CACHE_DIR = _os.environ.setdefault(

@@ -13,5 +13,5 @@ Three cooperating parts (see README "Self-healing"):
              concurrency caps, exponential backoff, trace spans).
   faults.py  test-only fault injection (WEEDTPU_FAULTS / /admin/faults):
              flip bits, delete shards, delay peers — the heal loop is
-             provable end-to-end in tests and bench.py.
+             provable end-to-end in tests.
 """

@@ -3,8 +3,8 @@
 The pieces met one at a time in single-scenario tests — faults.py
 injection, the scrub/repair loop, tracing, SLO burn rates, the
 resilience layer — but nothing proved the cluster survives *mixed
-workloads under compound failures*.  This module is the shared driver
-behind ``tests/test_chaos.py`` and the ``bench.py`` chaos section:
+workloads under compound failures*.  This module is the driver
+behind ``tests/test_chaos.py``:
 
 - :class:`ChaosCluster` — an in-process cluster (master(s) + volume
   servers + optional filer/s3/MQ brokers on one background asyncio

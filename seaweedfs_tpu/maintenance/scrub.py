@@ -25,8 +25,7 @@ planner (maintenance/repair.py), which deletes the corrupt shard and
 rebuilds it through the normal EC machinery.
 
 The rate limit exists because scrub I/O competes with foreground reads on
-the same spindles: bench.py gates foreground blob_read_rps at >= 0.95x
-with the scrubber running.
+the same spindles.
 """
 
 from __future__ import annotations

@@ -162,7 +162,7 @@ def gf_impl() -> int:
 
 
 def set_gf_impl(impl: int) -> None:
-    """Force a kernel (GF_IMPL_*): lets bench.py measure the AVX2 path (the
+    """Force a kernel (GF_IMPL_*): lets tests run the AVX2 path (the
     klauspost-equivalent baseline) on GFNI hosts. GF_IMPL_AUTO restores
     best-available dispatch."""
     _require().wn_gf_set_impl(int(impl))

@@ -264,10 +264,10 @@ def _platform(kind: str, fleet: bool) -> tuple[str | None, int]:
     return ("native" if native.available() else "cpu"), devices
 
 
-def _shell(code, backend: str, tile: int | None):
+def _shell(code, backend: str):
     if backend == "pallas":
         from seaweedfs_tpu.ops import pallas_gf
-        return pallas_gf.PallasRSCodec(code, tile)
+        return pallas_gf.PallasRSCodec(code)  # the platform's one tile
     if backend == "native":
         from seaweedfs_tpu.ops import native_codec
         return native_codec.NativeRSCodec(code)
@@ -288,11 +288,11 @@ def _shell(code, backend: str, tile: int | None):
 
 
 @functools.lru_cache(maxsize=32)
-def _build(tag: str, backend: str, tile: int | None):
-    """One object per (tag, backend[, Pallas tile]): decode matrices and
-    compiled programs are cached on it."""
+def _build(tag: str, backend: str):
+    """One object per (tag, backend): decode matrices and compiled
+    programs are cached on it."""
     spec = parse_tag(tag)
-    codec = _shell(_code_for(spec), backend, tile)
+    codec = _shell(_code_for(spec), backend)
     if spec.family == "msr":
         from seaweedfs_tpu.ops import msr
         codec = msr.MSRFileCodec(codec)
@@ -314,11 +314,7 @@ def resolve(tag: str | None = None, kind: str | None = None,
     spec = parse_tag(tag)
     kind = kind or os.environ.get("WEEDTPU_EC_CODEC", "auto")
     backend = backend_for(spec, kind, *_platform(kind, fleet), fleet=fleet)
-    tile = None
-    if backend == "pallas":  # WEEDTPU_EC_TILE / the tile pin, as of now
-        from seaweedfs_tpu.ops import pallas_gf
-        tile = pallas_gf.resolved_tile()
-    codec = _build(spec.tag, backend, tile)
+    codec = _build(spec.tag, backend)
     _note(kind, spec.tag, codec)
     return codec
 

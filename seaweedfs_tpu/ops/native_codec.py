@@ -5,7 +5,7 @@ encode/reconstruct surface but numpy arrays in and out.  Fills the role
 klauspost/reedsolomon's SIMD assembly plays in the reference (invoked from
 weed/storage/erasure_coding/ec_encoder.go:214 enc.Encode and
 weed/storage/store_ec.go:374 enc.ReconstructData): the fast path when no
-TPU is attached, and the honest CPU baseline for bench.py.
+TPU is attached.
 
 Code-generic like codec_base: anything with k/m/n, `parity_matrix` and
 `decode_matrix` plugs in; non-MDS codes steer survivor choice through

@@ -28,7 +28,6 @@ Kernel-shape notes (why it looks the way it does):
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -42,187 +41,15 @@ from seaweedfs_tpu.ops import codec_base, gf
 DEFAULT_TILE = 32768  # interpreter/CPU default: small pads for small inputs
 TPU_TILE = 131072  # the served tile on a chip; which candidate is fastest
 #                    is not measured on current code
-# candidate byte-column tiles for the bench re-tune sweep
-# (bench._bench_tile_sweep): the best tile is a property of the chip +
-# runtime, not the repo, so a TPU bench run re-measures and records its
-# choice
-SWEEP_TILES = (32768, 65536, 131072, 262144)
 PLANE_PAD = 16  # sublane alignment for each bit-plane block
 
 
 def resolved_tile(tile: int | None = None) -> int:
-    """The tile a codec will actually use: explicit argument, else the
-    WEEDTPU_EC_TILE env override (how the bench sweep's winning config —
-    and an operator pinning a known-good shape — reaches every codec
-    constructed afterwards), else the persisted tile pin from the last
-    bench sweep when its backend/chip fingerprint matches THIS runtime
-    (a pin measured on different hardware must not leak in), else the
-    backend default."""
+    """The tile a codec will actually use: the explicit argument, else
+    one constant a platform."""
     if tile is not None:
         return tile
-    import os
-    env = os.environ.get("WEEDTPU_EC_TILE")
-    if env:
-        try:
-            t = int(env)
-            if t > 0:
-                return t
-        except ValueError:
-            pass
-    pin = load_tile_pin()
-    if pin and pin.get("tile") and \
-            pin.get("fingerprint") == chip_fingerprint():
-        return int(pin["tile"])
     return TPU_TILE if jax.default_backend() == "tpu" else DEFAULT_TILE
-
-
-# -- tile pin: the bench sweep's winner, persisted with provenance --------
-#
-# The sweep records its winner + the measured sweep table + a
-# backend/chip fingerprint; resolved_tile() honours a matching pin, and
-# the tile-drift sentinel (stats/pipeline.py) re-validates it in the
-# background so a pin that stops winning fires an alert.  Whether the
-# tile explains any throughput swing is not measured on current code.
-
-_fingerprint: str | None = None
-
-
-def chip_fingerprint() -> str:
-    """backend:device-kind:device-count — what a tile measurement is a
-    property of.  A pin recorded under a different fingerprint is
-    provenance-only (never applied, never alerted against).  Memoized:
-    the device set is fixed per process, and resolved_tile() consults
-    this from codec-lookup paths."""
-    global _fingerprint
-    if _fingerprint is not None:
-        return _fingerprint
-    try:
-        devs = jax.devices()
-        kind = devs[0].device_kind if devs else "none"
-        _fingerprint = f"{jax.default_backend()}:{kind}:{len(devs)}"
-        return _fingerprint
-    except Exception:
-        return "unknown"
-
-
-def pin_path(path: str | None = None) -> str:
-    import os
-    return path or os.environ.get("WEEDTPU_TILE_PIN") or \
-        os.path.join(os.path.expanduser("~"), ".weedtpu_tile_pin.json")
-
-
-def save_tile_pin(tile: int, gbps: float, sweep: dict | None = None,
-                  path: str | None = None) -> str:
-    """Persist the sweep winner (atomically: tmp + rename) for
-    resolved_tile() and the drift sentinel.  Returns the path written."""
-    import json
-    import os
-    p = pin_path(path)
-    rec = {"tile": int(tile), "gbps": round(float(gbps), 3),
-           "fingerprint": chip_fingerprint(),
-           "ts": time.time()}
-    if sweep:
-        rec["sweep"] = {str(k): v for k, v in sweep.items()}
-    tmp = p + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(rec, f)
-    os.replace(tmp, p)
-    return p
-
-
-_pin_cache: dict[str, tuple[tuple, dict | None]] = {}
-
-
-def load_tile_pin(path: str | None = None) -> dict | None:
-    """Read the persisted pin, cached by (mtime, size, inode) — this
-    rides resolved_tile() and therefore codec-lookup hot paths (the
-    degraded-read engine constructs codecs per reconstruct batch), so
-    a stat() must be the steady-state cost, not open+json.load.  A
-    save_tile_pin/direct rewrite changes the stat key and refreshes."""
-    import json
-    import os
-    p = pin_path(path)
-    try:
-        st = os.stat(p)
-    except OSError:
-        _pin_cache.pop(p, None)
-        return None
-    key = (st.st_mtime_ns, st.st_size, st.st_ino)
-    hit = _pin_cache.get(p)
-    if hit is not None and hit[0] == key:
-        rec = hit[1]
-        return dict(rec) if rec is not None else None
-    try:
-        with open(p) as f:
-            rec = json.load(f)
-    except OSError:
-        # raced away between stat and open: don't cache, re-stat next
-        return None
-    except ValueError:
-        # a corrupt pin caches as None under its stat key — hot-path
-        # callers must not re-parse the same broken bytes per lookup
-        rec = None
-    rec = rec if isinstance(rec, dict) and rec.get("tile") else None
-    _pin_cache[p] = (key, rec)
-    # callers may annotate/mutate the verdict they build from this —
-    # hand out a copy so the cache stays pristine
-    return dict(rec) if rec is not None else None
-
-
-def micro_sweep(k: int = 10, m: int = 4, n: int | None = None,
-                iters: int = 3,
-                ensure_tile: int | None = None) -> dict[int, float | str]:
-    """Cheap re-measure of every SWEEP_TILES candidate on this chip:
-    {tile: GB/s}, or {tile: "failed: <error>"} for a candidate that did
-    not compile or run (off-TPU that is every candidate: the kernels
-    only compile for a chip).  One LCM-of-tiles column width (~256K
-    columns, a few MB per candidate) and a handful of iterations —
-    enough to rank tiles, deliberately far from bench depth; the
-    sentinel compares candidates against each other under identical
-    conditions, so the absolute numbers need not match the bench's."""
-    from seaweedfs_tpu.models import rs
-    code = rs.get_code(k, m)
-    # the sentinel passes its pinned tile: a pin outside SWEEP_TILES
-    # (tiny CPU sweeps, a later-release re-tune of the candidate set,
-    # an operator pin) must still be a measured candidate with n a
-    # multiple of it, or the sweep can never validate the very pin it
-    # watches — permanent sweep_failed silently disarms tile_pin_stale
-    tiles = sorted(set(SWEEP_TILES) |
-                   ({int(ensure_tile)} if ensure_tile else set()))
-    if n is None:
-        n = max(SWEEP_TILES)
-        if ensure_tile:
-            t = int(ensure_tile)
-            if t > n:
-                n = t
-            elif n % t:
-                n = (n // t) * t  # other candidates may drop out
-    rng = np.random.default_rng(0)
-    data = jnp.asarray(rng.integers(0, 256, (k, n), dtype=np.uint8))
-    out: dict[int, float | str] = {}
-    for t in tiles:
-        if n % t:
-            continue
-        try:
-            codec = PallasRSCodec(code, tile=t)
-            codec.encode_parity(data).block_until_ready()  # compile/warm
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                codec.encode_parity(data).block_until_ready()
-            el = (time.perf_counter() - t0) / iters
-        except Exception as e:
-            # e.g. a tile whose VMEM blocks don't fit: it drops out of
-            # the ranking, with the compiler's reason kept beside it
-            out[t] = sweep_failure(e)
-            continue
-        if el > 0:
-            out[t] = k * n / 1e9 / el
-    return out
-
-
-def sweep_failure(e: BaseException) -> str:
-    """The table entry of a sweep candidate that raised."""
-    return f"failed: {type(e).__name__}: {str(e)[:300]}"
 
 
 def gf_matrix_to_bitmatrix_planemajor(C: np.ndarray, kpad: int | None = None) -> np.ndarray:
@@ -426,8 +253,7 @@ def _get_codec_cached(k: int, m: int, construction: str,
 
 def get_codec(k: int, m: int, construction: str = "vandermonde",
               tile: int | None = None) -> PallasRSCodec:
-    """tile=None resolves via WEEDTPU_EC_TILE (the bench sweep's recorded
-    winner) and then per backend: the big TPU tile for real chips, the
-    small default under the (CPU) interpreter where column padding to
+    """tile=None resolves per backend: the big TPU tile for real chips,
+    the small default under the (CPU) interpreter where column padding to
     the tile width is pure waste."""
     return _get_codec_cached(k, m, construction, resolved_tile(tile))

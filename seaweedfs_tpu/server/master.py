@@ -966,11 +966,10 @@ class MasterServer:
 
     def collect_perf(self) -> dict:
         """Fleet performance observatory: every node's /debug/pipeline
-        payload (per-job stage timelines, roofline rows, tile-sentinel
-        verdict) merged into fleet occupancy per (kind, stage), the
-        worst bottleneck verdict per pipeline kind, the fleet's worst
-        roofline offenders, and per-node tile-drift state.  Thread-safe
-        sync function: the handler calls it via to_thread."""
+        payload (per-job stage timelines, roofline rows) merged into
+        fleet occupancy per (kind, stage), the worst bottleneck verdict
+        per pipeline kind, and the fleet's worst roofline offenders.
+        Thread-safe sync function: the handler calls it via to_thread."""
         import json as _json
 
         from seaweedfs_tpu.stats import pipeline as _pipeline
@@ -1071,9 +1070,9 @@ class MasterServer:
 
     async def handle_cluster_perf(self, req: web.Request) -> web.Response:
         """/cluster/perf: fleet pipeline occupancy + bottleneck verdicts
-        + roofline offenders + tile-drift state (loopback-gated like the
-        rest of the debug-derived surface — it carries file paths and
-        kernel internals)."""
+        + roofline offenders (loopback-gated like the rest of the
+        debug-derived surface — it carries file paths and kernel
+        internals)."""
         err = trace.loopback_error(req)
         if err is not None:
             return err
@@ -1335,8 +1334,8 @@ class MasterServer:
     async def handle_maintenance_tick(self, req: web.Request
                                       ) -> web.Response:
         """Force one planner tick; {"wait": true} blocks until every
-        launched repair finishes — the deterministic hook tests and
-        bench.py drive instead of sleeping on the background loop."""
+        launched repair finishes — the deterministic hook tests
+        drive instead of sleeping on the background loop."""
         if not self.is_leader:
             return self._not_leader_response()
         try:

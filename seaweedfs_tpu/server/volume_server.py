@@ -239,12 +239,6 @@ class VolumeServer:
                     (i + 1) % len(self.master_urls)]
         self._hb_task = asyncio.create_task(self._heartbeat_loop())
         profile.ensure_started()  # WEEDTPU_PROFILE_HZ, process-wide
-        # tile-drift sentinel (stats/pipeline.py): codec-hosting servers
-        # re-validate the pinned Pallas tile in the background when
-        # WEEDTPU_TILE_SENTINEL_INTERVAL asks for it (process-wide, so
-        # co-hosted servers share one)
-        from seaweedfs_tpu.stats import pipeline as _pipeline
-        _pipeline.ensure_sentinel()
         # test-only fault plan from the environment (maintenance/faults.py)
         from seaweedfs_tpu.maintenance import faults as _faults
         _faults.register_node(self.url, "volume")
@@ -2407,7 +2401,8 @@ class VolumeServer:
         try:
             with trace.span("volume.probe_read", vid=vid, skip=skip):
                 n = await asyncio.to_thread(
-                    ev.read_needle, nid, reader, None, frozenset({skip}))
+                    ev.read_needle, nid, reader,
+                    skip_shards=frozenset({skip}))
         except (KeyError, IOError, ValueError) as e:
             return web.json_response(
                 {"error": f"degraded probe read failed: {e}"}, status=503)

@@ -910,11 +910,11 @@ def cmd_cluster_perf(env: CommandEnv, args, out):
     """Fleet performance observatory (/cluster/perf): per-pipeline stage
     occupancy, the bottleneck verdict per pipeline kind (the stage whose
     busy fraction bounds throughput, with its achieved-vs-ceiling
-    fraction when the resource's roofline is measured), the worst
-    roofline offenders fleet-wide, and every node's tile-drift verdict.
+    fraction when the resource's roofline is measured), and the worst
+    roofline offenders fleet-wide.
     -top N offender rows (default 5); -json dumps the raw merge.
-    Runbook: a bench trajectory regression names WHAT got slower —
-    this names WHERE (stage + node + distance from the hardware)."""
+    Runbook: a benchmark regression names WHAT got slower — this names
+    WHERE (stage + node + distance from the hardware)."""
     flags = parse_flags(args)
     st = env.master_get("/cluster/perf")
     if "json" in flags:
@@ -958,13 +958,6 @@ def cmd_cluster_perf(env: CommandEnv, args, out):
                   f"{r['resource']:6s} {r['achieved_gbps']:9.3f} GB/s "
                   f"= {r['ceiling_frac']:.0%} of "
                   f"{r.get('ceiling_gbps', 0):.3f}", file=out)
-    for node, tile in sorted((st.get("tiles") or {}).items()):
-        line = f"tile {node}: {tile.get('state')}"
-        if tile.get("pinned_tile") is not None:
-            line += (f" pinned={tile['pinned_tile']} "
-                     f"best={tile.get('best_tile')} "
-                     f"drift={tile.get('drift', 0):+.1%}")
-        print(line, file=out)
     cx = st.get("codecs") or {}
     if cx.get("mix"):
         print("codecs: " + " ".join(

@@ -596,14 +596,6 @@ _DEFAULT_ALERT_RULES = (
     # then cluster.trace of the latest retune decision)
     "interference_high=threshold,series=weedtpu_interference_index,"
     "agg=max,window=120,op=gt,value=0.5,for=30;"
-    # tile-drift sentinel (stats/pipeline.py): the pinned Pallas tile no
-    # longer wins its own micro-sweep by >10% — a stale pin pages
-    # instead of shipping.  The
-    # rule watches the EXCESS series (best/pinned - 1) rather than the
-    # companion ratio gauge: federated gauges sum across nodes, and a
-    # healthy fleet must sum to zero at any size
-    "tile_pin_stale=threshold,series=weedtpu_tile_drift,"
-    "agg=max,window=120,op=gt,value=0.1,for=30;"
     # control-plane observatory (stats/loops.py): a master loop whose
     # tick wall time exceeds its own interval can no longer hold its
     # cadence — the scrape/repair/alert plane is silently falling
@@ -1154,9 +1146,6 @@ alerts: <span class="badge {badge.get(alerts.get('state', ''), '')}">{_h(alerts.
 {sect("Roofline fraction (achieved / measured ceiling by resource)",
       "<table>" + _spark_row(
           store, "roofline", "weedtpu_roofline_frac", None, "last",
-          rng, step) + "</table>"
-      "<table>" + _spark_row(
-          store, "tile drift", "weedtpu_tile_drift", None, "last",
           rng, step) + "</table>")}
 {sect("Interference (foreground p99 inflation by class / governed rates)",
       "<table>" + _spark_row(

@@ -3,8 +3,8 @@
 Rounds 6-12 built the senses (netflow byte ledger, latency histograms,
 the TSDB, alerts) but background pacing stayed open-loop: repair,
 conversion, and scrub ran on STATIC token buckets plus a binary
-alert-pause, while interference was only ever measured offline in
-bench.py.  The SSD-array study (PAPERS.md, arXiv 1709.05365) shows the
+alert-pause, while interference was only ever measured offline.
+The SSD-array study (PAPERS.md, arXiv 1709.05365) shows the
 foreground cost of background byte-flow is nonlinear and device-local,
 and the warehouse study (arXiv 1309.0186) shows it concentrates on
 exactly the hot nodes — so the throttle must be a live, per-node

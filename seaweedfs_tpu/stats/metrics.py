@@ -642,13 +642,8 @@ CANARY_LATENCY = REGISTRY.gauge(
     ("path", "quantile"))
 # performance observatory (stats/pipeline.py, stats/profile.py
 # rooflines): per-stage busy seconds whose RATE is stage occupancy
-# (1 busy-second/second == a saturated stage), bytes moved per stage,
-# per-kernel achieved-vs-ceiling fractions, and the tile-drift
-# sentinel's verdict.  weedtpu_tile_drift is the fractional advantage
-# of the best candidate tile over the pinned one (0 = pin still wins)
-# — the default tile_pin_stale alert rule watches IT rather than the
-# ratio because federated gauges sum across nodes, and a healthy fleet
-# must sum to zero at any size.
+# (1 busy-second/second == a saturated stage), bytes moved per stage, and
+# per-kernel achieved-vs-ceiling fractions.
 PIPELINE_STAGE_SECONDS = REGISTRY.counter(
     "weedtpu_pipeline_stage_seconds_total",
     "busy seconds per data-plane pipeline stage (rate == occupancy)",
@@ -661,15 +656,6 @@ ROOFLINE_FRAC = REGISTRY.gauge(
     "achieved throughput of a kernel as a fraction of the measured "
     "hardware ceiling of the resource it exercises",
     ("resource", "kernel"))
-TILE_DRIFT = REGISTRY.gauge(
-    "weedtpu_tile_drift",
-    "fractional throughput advantage of the best candidate Pallas tile "
-    "over the pinned one (0 = pin still optimal; >0.1 fires "
-    "tile_pin_stale)")
-TILE_DRIFT_RATIO = REGISTRY.gauge(
-    "weedtpu_tile_drift_ratio",
-    "best candidate tile throughput / pinned tile throughput from the "
-    "drift sentinel's last micro-sweep")
 # interference observatory + governor (stats/interference.py): the
 # foreground-impact index per node and background traffic class, the
 # governed rate per background-work target, and the retune event

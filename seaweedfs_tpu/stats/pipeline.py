@@ -1,14 +1,12 @@
-"""Pipeline occupancy accounting + the tile-drift sentinel: the data
-plane's performance observatory.
+"""Pipeline occupancy accounting: the data plane's performance
+observatory.
 
 Every overlapped pipeline in the data path (the EC encode/rebuild
 engines in storage/ec/ec_files.py, the multi-volume fleet conversion in
-ops/fleet_convert.py, the EC degraded-read engine) already accumulated
-ad-hoc per-stage wall-second dicts for bench.py — visible only on bench
-day: production paths had no always-on answer to "which stage bounds
-throughput and how far from the hardware roofline are we?".  This
-module is that answer (none of its device numbers is measured on
-current code — PERF.md):
+ops/fleet_convert.py, the EC degraded-read engine) accumulates
+per-stage wall seconds; this module is the always-on answer to "which
+stage bounds throughput and how far from the hardware roofline are we?"
+(none of its device numbers is measured on current code — PERF.md):
 
 - **Stage** (``job.stage(name)`` / ``job.blocked(name)``) — the EC
   plane's ONE timing primitive.  One enter/exit books the stage's
@@ -37,22 +35,11 @@ current code — PERF.md):
   1 busy-second per second == a saturated stage), so "degraded reads
   went remote-fetch-bound at 14:05" is a /cluster/history query.
 
-- **TileDriftSentinel** — re-validates the pinned Pallas tile (the
-  bench sweep's winner, persisted with a backend+chip fingerprint by
-  ops/pallas_gf.save_tile_pin) with a cheap background micro-sweep on
-  codec-hosting servers.  ``weedtpu_tile_drift`` reports the fractional
-  advantage of the best candidate over the pin (0 = pin still wins);
-  the default ``tile_pin_stale`` alert rule fires past 0.1 — a pin
-  that stops winning becomes a page carrying the sweep table.  (The alert watches the *excess* series rather than
-  the companion ``weedtpu_tile_drift_ratio`` because federated gauges
-  sum across nodes: a healthy fleet sums zeros at any size.)
-
 Surfaces: ``/debug/pipeline`` on every server (loopback-gated, mounted
 by trace.debug_routes) renders per-job timelines; master
 ``/cluster/perf`` fans it out and aggregates fleet occupancy; the
 ``cluster.perf`` shell command and a /cluster/dashboard panel render
-both.  ``WEEDTPU_PERF_OBS=0`` turns the whole plane off (the
-``perf_obs_overhead`` bench gate holds it under 3% of hot-path cost).
+both.  ``WEEDTPU_PERF_OBS=0`` turns the whole plane off.
 """
 
 from __future__ import annotations
@@ -75,7 +62,7 @@ _enabled_cache: tuple[float, bool] = (0.0, True)
 def perf_obs_enabled() -> bool:
     """WEEDTPU_PERF_OBS != "0" (default on), cached ~0.5s so hot-path
     checks cost a tuple compare while flipping the env retargets live
-    servers (the perf_obs_overhead bench relies on that)."""
+    servers."""
     global _enabled_cache
     now = time.monotonic()
     ts, val = _enabled_cache
@@ -115,9 +102,6 @@ STAGE_RESOURCE = {
     "encode": "device", "reconstruct": "device", "d2h": "d2h",
     "read": "disk", "local_pread": "disk",
     "write": "disk", "write_data": "disk", "write_parity": "disk",
-    # the aio engine's finer cut of the write stages: ring submission vs
-    # completion reaping (storage/aio.py) — same disk resource
-    "submit": "disk", "complete": "disk",
     "remote_fetch": "net",
 }
 
@@ -193,7 +177,7 @@ class PipelineJob:
     """Stage accounting for ONE pipeline run (an encode, a rebuild, a
     fleet conversion).  Its stages book their seconds here AND into the
     ``<stage>_s`` keys of the wrapped stats dict — the engines' published
-    output, which /admin/ec/progress and bench.py read.  The job adds what a
+    output, which /admin/ec/progress reads.  The job adds what a
     dict of floats can't carry: busy apart from blocked time, bytes and
     items per stage, queue-depth high-water marks, liveness, and the
     registry that makes the run observable at /debug/pipeline while it
@@ -362,9 +346,8 @@ class PipelineJob:
             name: {"busy_s": busy, "blocked_s": blocked, "bytes": nbytes,
                    "items": items}
             for name, (busy, blocked, nbytes, items) in stages_own.items()}
-        # seconds no Stage timed: the write engines' submit / complete
-        # cut of the write stages, which the writer pool folds straight
-        # into the stats dict at close()
+        # seconds no Stage timed: `<stage>_s` keys an engine folds
+        # straight into the stats dict
         for name, secs in self._stats_stage_seconds().items():
             row = merged.setdefault(
                 name, {"busy_s": 0.0, "blocked_s": 0.0, "bytes": 0.0,
@@ -581,15 +564,14 @@ def bottleneck(snap: dict) -> dict | None:
 def aggregate_fleet(per_node: list[tuple[str, dict]]) -> dict:
     """Merge per-node /debug/pipeline payloads into fleet occupancy:
     per (kind, stage) busy seconds / bytes / max busy fraction across
-    every reporting node, the currently-running jobs, the worst
-    bottleneck verdict per kind, and every node's tile-drift verdict.
+    every reporting node, the currently-running jobs, and the worst
+    bottleneck verdict per kind.
     Payloads from nodes sharing one process (the all-in-one binary,
     in-process test clusters) carry the same tracker ``id`` and are
     merged once, not once per node."""
     occupancy: dict[str, dict[str, dict]] = {}
     running: list[dict] = []
     verdicts: dict[str, dict] = {}
-    tiles: dict[str, dict] = {}
     seen: set[str] = set()
     nodes: list[str] = []
     for node, payload in per_node:
@@ -599,9 +581,6 @@ def aggregate_fleet(per_node: list[tuple[str, dict]]) -> dict:
         if tid is not None:
             seen.add(tid)
         nodes.append(node)
-        tile = payload.get("tile")
-        if tile:
-            tiles[node] = tile
         for job in payload.get("jobs", []):
             kind = job.get("kind", "?")
             krow = occupancy.setdefault(kind, {})
@@ -623,7 +602,7 @@ def aggregate_fleet(per_node: list[tuple[str, dict]]) -> dict:
                         prev.get("busy_frac", 0.0):
                     verdicts[kind] = {"node": node, **bn}
     return {"nodes": nodes, "occupancy": occupancy,
-            "bottlenecks": verdicts, "running": running, "tiles": tiles}
+            "bottlenecks": verdicts, "running": running}
 
 
 def roofline_offenders(roofline: dict, limit: int = 5) -> list[dict]:
@@ -635,203 +614,29 @@ def roofline_offenders(roofline: dict, limit: int = 5) -> list[dict]:
     return rows[:limit]
 
 
-# -- tile-drift sentinel --------------------------------------------------
-
-class TileDriftSentinel:
-    """Background micro-sweep re-validating the pinned Pallas tile on
-    THIS chip + runtime.  Loads the bench sweep's persisted pin
-    (ops/pallas_gf.load_tile_pin: winning tile + backend/chip
-    fingerprint + the full sweep table), re-measures every candidate
-    cheaply, and reports how much the best candidate now beats the pin:
-
-        weedtpu_tile_drift        best/pinned - 1 (0 = pin still wins)
-        weedtpu_tile_drift_ratio  best/pinned     (the human number)
-
-    The default ``tile_pin_stale`` alert rule (stats/history.py) fires
-    past 10% drift with the sweep table attached to the sentinel status
-    (/debug/pipeline, /cluster/perf).  A pin recorded on a DIFFERENT
-    backend/chip is reported as ``fingerprint_mismatch`` and never
-    measured against — a CPU-fallback host must not page about a TPU
-    pin.  ``measure`` is injectable for tests (and anything that wants
-    a different probe): it returns {tile: gbps}."""
-
-    def __init__(self, interval: float | None = None, measure=None,
-                 pin_path: str | None = None):
-        if interval is None:
-            try:
-                interval = float(os.environ.get(
-                    "WEEDTPU_TILE_SENTINEL_INTERVAL", "0"))
-            except ValueError:
-                interval = 0.0
-        self.interval = interval
-        self.pin_path = pin_path
-        self._measure = measure
-        self._stop = threading.Event()
-        self._thread: threading.Thread | None = None
-        self._lock = threading.Lock()
-        self._status: dict = {"state": "idle"}
-
-    # -- one verdict -----------------------------------------------------
-
-    def run_once(self) -> dict:
-        from seaweedfs_tpu.ops import pallas_gf
-        from seaweedfs_tpu.stats import metrics
-        ts = time.time()
-        pin = pallas_gf.load_tile_pin(self.pin_path)
-        if pin is None:
-            st = {"state": "no_pin", "ts": ts}
-        elif pin.get("fingerprint") != pallas_gf.chip_fingerprint():
-            st = {"state": "fingerprint_mismatch", "ts": ts,
-                  "pin": {k: pin.get(k) for k in
-                          ("tile", "gbps", "fingerprint")},
-                  "fingerprint": pallas_gf.chip_fingerprint()}
-        else:
-            try:
-                # the default sweep must size its input so the PINNED
-                # tile measures (CPU sweeps are tiny), else the verdict
-                # degenerates to sweep_failed on the pin it watches
-                measure = self._measure or (
-                    lambda: pallas_gf.micro_sweep(
-                        ensure_tile=int(pin["tile"])))
-                sweep = measure()
-            except Exception as e:
-                st = {"state": "sweep_failed", "ts": ts,
-                      "error": str(e) or type(e).__name__}
-            else:
-                st = self._verdict(pin, sweep, ts)
-        if "drift" in st:
-            metrics.TILE_DRIFT.labels().set(st["drift"])
-            metrics.TILE_DRIFT_RATIO.labels().set(st["ratio"])
-        else:
-            # no measurable verdict (pin deleted, re-swept on other
-            # hardware, sweep failed): zero the gauges so a previously
-            # firing tile_pin_stale can clear instead of latching on
-            # the last stale value until process restart
-            metrics.TILE_DRIFT.labels().set(0.0)
-            metrics.TILE_DRIFT_RATIO.labels().set(1.0)
-        with self._lock:
-            self._status = st
-        return st
-
-    @staticmethod
-    def _verdict(pin: dict, sweep: dict, ts: float) -> dict:
-        pinned_tile = int(pin["tile"])
-        pinned_now = sweep.get(pinned_tile) or \
-            sweep.get(str(pinned_tile)) or 0.0
-        if not isinstance(pinned_now, (int, float)):
-            pinned_now = 0.0  # "failed: <error>": shown in the table below
-        best_tile, best = pinned_tile, pinned_now
-        for t, v in sweep.items():
-            if isinstance(v, (int, float)) and v > best:
-                best_tile, best = int(t), float(v)
-        if pinned_now <= 0:
-            return {"state": "sweep_failed", "ts": ts,
-                    "error": "pinned tile did not measure",
-                    "sweep": {str(k): v for k, v in sweep.items()}}
-        ratio = best / pinned_now
-        drift = max(0.0, ratio - 1.0)
-        return {"state": "stale" if drift > 0.1 else "ok", "ts": ts,
-                "pinned_tile": pinned_tile, "best_tile": best_tile,
-                "pinned_gbps": round(pinned_now, 3),
-                "best_gbps": round(best, 3),
-                "ratio": round(ratio, 4), "drift": round(drift, 4),
-                "pin": {"tile": pin.get("tile"), "gbps": pin.get("gbps"),
-                        "ts": pin.get("ts")},
-                "sweep": {str(k): round(v, 3) if isinstance(v, float)
-                          else v for k, v in sweep.items()}}
-
-    def status(self) -> dict:
-        with self._lock:
-            return dict(self._status)
-
-    # -- lifecycle -------------------------------------------------------
-
-    def start(self) -> "TileDriftSentinel":
-        if self.interval <= 0 or self._thread is not None:
-            return self
-        self._stop.clear()
-        self._thread = threading.Thread(
-            target=self._run, name="weedtpu-tile-sentinel", daemon=True)
-        self._thread.start()
-        return self
-
-    def _run(self) -> None:
-        while not self._stop.wait(self.interval):
-            try:
-                self.run_once()
-            except Exception:
-                from seaweedfs_tpu.utils import weedlog
-                weedlog.V(1, "pipeline").infof("tile sentinel tick failed")
-
-    def stop(self, timeout: float = 5.0) -> None:
-        t = self._thread
-        if t is None:
-            return
-        self._stop.set()
-        t.join(timeout)
-        self._thread = None
-
-
-_sentinel_lock = threading.Lock()
-_sentinel: TileDriftSentinel | None = None
-
-
-def ensure_sentinel() -> TileDriftSentinel | None:
-    """Idempotently start the process-wide drift sentinel when
-    WEEDTPU_TILE_SENTINEL_INTERVAL asks for one (codec-hosting servers
-    call this at start; co-hosted servers share it)."""
-    global _sentinel
-    with _sentinel_lock:
-        if _sentinel is None:
-            s = TileDriftSentinel()
-            if s.interval <= 0:
-                return None
-            _sentinel = s.start()
-        return _sentinel
-
-
-def sentinel_status() -> dict | None:
-    with _sentinel_lock:
-        s = _sentinel
-    return s.status() if s is not None else None
-
-
-def set_sentinel(s: TileDriftSentinel | None) -> None:
-    """Tests/servers: install (or clear) the process-wide sentinel whose
-    status /debug/pipeline reports."""
-    global _sentinel
-    with _sentinel_lock:
-        _sentinel = s
-
-
 # -- /debug/pipeline -------------------------------------------------------
 
 def local_snapshot(limit: int = 16) -> dict:
     """Everything this process knows about its own data-plane
-    performance: jobs + flows, the kernel roofline, and the tile
-    sentinel's verdict.  The payload /cluster/perf federates."""
+    performance: jobs + flows and the kernel roofline.  The payload
+    /cluster/perf federates."""
     from seaweedfs_tpu.stats import profile as _profile
-    out = {"id": TRACKER_ID, "enabled": perf_obs_enabled(),
-           "jobs": jobs_snapshot(limit),
-           "roofline": _profile.roofline_snapshot(),
-           # what each codec selection resolved to (backend, device,
-           # class, interpret, tile); empty until a codec was built
-           "codecs": _profile.codecs_snapshot(),
-           # backend compilations by the codec entry point open on the
-           # compiling thread: {entry: {count, seconds}}
-           "compiles": _profile.compiles_snapshot()}
-    tile = sentinel_status()
-    if tile is not None:
-        out["tile"] = tile
-    return out
+    return {"id": TRACKER_ID, "enabled": perf_obs_enabled(),
+            "jobs": jobs_snapshot(limit),
+            "roofline": _profile.roofline_snapshot(),
+            # what each codec selection resolved to (backend, device,
+            # class, interpret, tile); empty until a codec was built
+            "codecs": _profile.codecs_snapshot(),
+            # backend compilations by the codec entry point open on the
+            # compiling thread: {entry: {count, seconds}}
+            "compiles": _profile.compiles_snapshot()}
 
 
 async def handle_debug_pipeline(req):
     """``/debug/pipeline[?limit=N]``: per-job stage timelines (busy /
     blocked / queue depths / bottleneck verdicts), the continuous flow
-    accounts, the per-kernel roofline table, and the tile-drift
-    sentinel's last verdict.  Mounted loopback-gated on every server by
-    trace.debug_routes()."""
+    accounts, and the per-kernel roofline table.  Mounted loopback-gated
+    on every server by trace.debug_routes()."""
     from aiohttp import web
     try:
         limit = int(req.query.get("limit", "16"))

@@ -279,13 +279,9 @@ KERNELS = KernelProfile()
 # Achieved throughput per kernel row, divided by the measured ceiling of
 # the hardware resource it exercises, exported as
 # weedtpu_roofline_frac{resource,kernel} gauges: "encode is now
-# D2H-bound" becomes a queryable series instead of a bench-day
-# discovery.  Ceilings come from (highest precedence first)
-# set_ceiling() calls, the WEEDTPU_CEILINGS env
-# ("resource=GBps,resource=GBps"), and — for the device compute
-# ceiling — the bench tile sweep's persisted pin
-# (ops/pallas_gf.load_tile_pin), which records the winning tile's
-# measured GB/s alongside the backend/chip fingerprint.
+# D2H-bound" becomes a queryable series.  Ceilings come from (highest
+# precedence first) set_ceiling() calls and the WEEDTPU_CEILINGS env
+# ("resource=GBps,resource=GBps").
 
 _ceilings_lock = threading.Lock()
 _ceilings_set: dict[str, float] = {}
@@ -295,8 +291,8 @@ _ceilings_cache: tuple[float, dict] | None = None
 def set_ceiling(resource: str, gbps: float,
                 source: str = "measured") -> None:
     """Record a measured hardware ceiling (GB/s) for a resource
-    (device/h2d/d2h/disk/net).  Bench runs and servers that micro-measure
-    call this; WEEDTPU_CEILINGS overrides nothing set here."""
+    (device/h2d/d2h/disk/net).  Servers that micro-measure call this;
+    WEEDTPU_CEILINGS overrides nothing set here."""
     global _ceilings_cache
     with _ceilings_lock:
         _ceilings_set[resource] = float(gbps)
@@ -304,9 +300,8 @@ def set_ceiling(resource: str, gbps: float,
 
 
 def ceilings() -> dict[str, float]:
-    """resource -> GB/s ceiling, merged from set_ceiling() calls, the
-    WEEDTPU_CEILINGS env, and the tile pin's recorded kernel peak
-    (device).  Cached ~5s: the pin file read must not ride hot paths."""
+    """resource -> GB/s ceiling, merged from set_ceiling() calls and the
+    WEEDTPU_CEILINGS env.  Cached ~5s."""
     global _ceilings_cache
     now = time.monotonic()
     with _ceilings_lock:
@@ -323,21 +318,6 @@ def ceilings() -> dict[str, float]:
                     continue
                 if gbps > 0:
                     out[k.strip()] = gbps
-        # only consult the pin in a process whose codec selection already
-        # initialised a backend: chip_fingerprint() would, and a
-        # host-codec process sharing the machine with the volume server
-        # that owns the chip must never do that (`jax` being imported
-        # says nothing — ops.native_codec imports it)
-        if "device" not in out and jax_backend_noted():
-            try:
-                from seaweedfs_tpu.ops import pallas_gf
-                pin = pallas_gf.load_tile_pin()
-                if pin and pin.get("gbps") and \
-                        pin.get("fingerprint") == \
-                        pallas_gf.chip_fingerprint():
-                    out["device"] = float(pin["gbps"])
-            except Exception:
-                pass
         out.update(_ceilings_set)
         _ceilings_cache = (now, out)
         return dict(out)

@@ -129,7 +129,7 @@ def write_ec_files(base: str, dat_path: str | None = None,
     consumed and `cancel()` (returning True) aborts mid-stream — a 30GB
     encode must be observable and stoppable (the reference streams progress
     over its gRPC seam).  `stats`, when a dict, receives per-stage wall-time
-    attribution (read/encode/write seconds) for bench.py.
+    attribution (read/encode/write seconds): what /admin/ec/progress shows.
 
     Shards build under `.tmp` names and commit by rename only when the
     whole encode succeeds, so a cancelled/crashed encode leaves any
@@ -210,6 +210,40 @@ class EncodeCancelled(RuntimeError):
     pass
 
 
+def _pwrite_all(fd: int, view, off: int) -> None:
+    """pwrite may write short (RLIMIT_FSIZE edge, fs under pressure); a
+    silent short write would commit a shard with a zero gap."""
+    mv = memoryview(view)
+    while len(mv) > 0:
+        n = os.pwrite(fd, mv, off)
+        if n <= 0:
+            raise OSError("pwrite returned 0")
+        mv = mv[n:]
+        off += n
+
+
+def _pwritev_all(fd: int, bufs: list, off: int) -> None:
+    """Vectored pwrite of buffers destined for one contiguous file range:
+    a run of per-unit parity blocks lands in a single syscall instead of
+    one pwrite per unit.  Short writes (possibly mid-iovec) resume."""
+    if not hasattr(os, "pwritev"):
+        for b in bufs:
+            _pwrite_all(fd, b, off)
+            off += memoryview(b).nbytes
+        return
+    mvs = [memoryview(b) for b in bufs]
+    while mvs:
+        n = os.pwritev(fd, mvs, off)
+        if n <= 0:
+            raise OSError("pwritev returned 0")
+        off += n
+        while mvs and n >= len(mvs[0]):
+            n -= len(mvs[0])
+            mvs.pop(0)
+        if mvs and n:
+            mvs[0] = mvs[0][n:]
+
+
 _CFR_OK = True  # copy_file_range support, latched off on first failure
 
 
@@ -264,25 +298,20 @@ def _finalize_shards(out_fds, highwater, shard_size: int) -> None:
 def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
                    small_block: int, batch_size: int, out_fds,
                    progress=None, cancel=None, stats=None) -> None:
-    """Stream the .dat through the codec into the 14 shard fds.
-
-    Two strategies behind one surface, both writing through the
-    per-shard writer pool (_ShardWriterPool) so all 14 shard files land
-    concurrently:
-      - host codecs (native AVX2/GFNI): the GF matmul runs on the calling
+    """Stream the .dat through the codec into the shard fds: one strategy,
+    the overlapped reader -> dispatch -> drain -> writers pipeline
+    (_encode_pipelined), every shard file written through the per-shard
+    writer pool (_ShardWriterPool) so all of them land concurrently.  What
+    differs by codec is chosen from the codec ops/codecs.resolve built for
+    the platform:
+      - host codec (native AVX2/GFNI): the GF matmul runs on the dispatch
         thread straight off an mmap of the .dat via per-row pointers (no
         staging copy), data shards move by in-kernel copy_file_range on
-        their writers, and parity rides a small buffer ring — encode of
-        unit N overlaps the writes of units N-1.. .
-      - device codecs (Pallas/XLA/mesh/numpy): the overlapped reader ->
-        dispatch -> drain -> writers pipeline, since JAX dispatch is
-        async and the device round-trip genuinely overlaps host I/O.
-        Reads stage from the mmap into pooled buffers (no per-batch
-        allocation); only parity rides the device.
-
-    WEEDTPU_EC_PIPELINE=serial|pipelined|auto forces the strategy (the
-    pipelined machinery accepts host codecs too — bench.py uses that to
-    race the two modes on the same codec).
+        their writers, and parity rides a small buffer ring.
+      - device codecs (Pallas/XLA/mesh/numpy): reads stage from the mmap
+        into pooled buffers (no per-batch allocation), JAX dispatch is
+        async so the device round-trip overlaps host I/O, and only parity
+        rides the device.
 
     Rows wholly beyond the .dat are never read, encoded, or written: the
     parity of an all-zero row region is zero, so those regions become
@@ -290,7 +319,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     rows that carry data, against a column-sliced parity matrix."""
     # stage attribution always accumulates (even when the caller brought
     # no dict): the stats keys feed the pipeline job /debug/pipeline
-    # renders, so a production encode is observable, not just a bench one
+    # renders, so every encode is observable
     stats = stats if stats is not None else {}
     stats["bytes"] = dat_size
     shard_size = layout.shard_file_size(dat_size, large_block, small_block,
@@ -300,16 +329,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
         _finalize_shards(out_fds, highwater, shard_size)
         return
 
-    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
-    native_host = isinstance(codec, NativeRSCodec)
-    pipe = os.environ.get("WEEDTPU_EC_PIPELINE", "auto")
-    # the serial-host strategy needs the native ptr-matmul, so it is only
-    # reachable for host codecs; `auto` prefers the pipelined machinery
-    # even then — interleaved A/B pairs (bench._bench_pipeline_ratio) show
-    # the dedicated dispatch/drain threads edge out the serial loop even
-    # on a 2-core host, and wider hosts only widen the gap
-    use_serial = native_host and pipe == "serial"
-    stats["mode"] = "host-serial" if use_serial else "pipelined"
+    stats["mode"] = "pipelined"
     stats["backend"] = _backend_name(codec)
 
     t_wall = time.perf_counter()
@@ -322,16 +342,9 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
         mm = _map_readonly(dat_fd, dat_size)
         dat_view = np.frombuffer(mm, dtype=np.uint8)
         try:
-            if use_serial:
-                _encode_serial_host(codec, dat_fd, dat_view, dat_size,
-                                    large_block, small_block, batch_size,
-                                    out_fds, highwater, pjob, progress,
-                                    cancel)
-            else:
-                _encode_pipelined(codec, dat_fd, dat_view, dat_size,
-                                  large_block, small_block, batch_size,
-                                  out_fds, highwater, pjob, progress,
-                                  cancel)
+            _encode_pipelined(codec, dat_fd, dat_view, dat_size,
+                              large_block, small_block, batch_size,
+                              out_fds, highwater, pjob, progress, cancel)
         finally:
             del dat_view
             try:
@@ -355,7 +368,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
 def _book_stage_bytes(pjob, stats: dict, data_bytes: int,
                       parity_bytes: int) -> None:
     """Attribute the run's bytes to whichever stages actually ran (a
-    host-serial encode has no read/d2h stage; booking bytes against a
+    host-codec encode has no read/d2h stage; booking bytes against a
     zero-second stage would invent infinite-GB/s rows)."""
     for key, nbytes in (("read_s", data_bytes), ("encode_s", data_bytes),
                         ("d2h_s", parity_bytes),
@@ -407,13 +420,6 @@ def _unit_coverage(dat_size: int, row_start: int, block: int, col: int,
     return nz, tail
 
 
-# the raw write primitives live with the async engine now; re-exported
-# here because callers (and tests) reach them through this module
-from seaweedfs_tpu.storage.aio import (  # noqa: E402
-    _pwrite_all, _pwritev_all, aligned_empty as _aligned_empty)
-from seaweedfs_tpu.storage import aio as _aio  # noqa: E402
-
-
 def _countdown(n: int, cb):
     """Return a thunk that invokes cb after being called n times — the
     release hook for a pooled buffer fanned out to n shard writers."""
@@ -453,28 +459,21 @@ class _ShardWriterPool:
     dict as the write happens, summed over the threads, and close() adds
     the thread capacity behind each stage (`<stage>_workers`).
 
-    The actual byte-moving rides the host async-I/O engine
-    (storage/aio.py): each worker owns a WriteEngine (io_uring ring with
-    O_DIRECT on aligned runs, degrading to pwritev / buffered per
-    WEEDTPU_AIO).  Release hooks fire only after the engine drains a
-    batch — an async kernel may still be reading a parity buffer long
-    after submission returned.  `reg_bufs` (the parity/output rings) are
-    registered with every worker's ring so aligned writes go out as
-    WRITE_FIXED.  close() folds the engines' submit/complete seconds
-    into stats next to the write stages."""
+    One write primitive: a run of buffers bound for contiguous offsets
+    of one shard goes out as one synchronous `_pwritev_all` on the shard's
+    worker (`_copy_range` for the data shards' in-kernel copies).  A
+    release hook fires only after the batch it rode in has been written:
+    a recycled parity buffer must not be handed out before its pwritev
+    returned."""
 
     def __init__(self, fds, highwater=None, job=None, stage_of=None,
-                 depth: int | None = None, workers: int | None = None,
-                 reg_bufs=None):
+                 depth: int | None = None, workers: int | None = None):
         self._fds = list(fds)
         self._hw = highwater
-        # a bare pool (bench.py) times its batches for no run
+        # a bare pool (tests) times its batches for no run
         self._job = job if job is not None else _pipeline.UNTRACKED
         self._stats = job.stats if job is not None else None
         self._stage_of = stage_of or (lambda i: "write")
-        self._mode = _aio.engine_mode()
-        self._reg = list(reg_bufs) if reg_bufs else None
-        self._engines: list = []
         n = workers if workers else _writer_threads(len(self._fds))
         self._nworkers = max(1, min(len(self._fds), n))
         shards_per = -(-len(self._fds) // self._nworkers)
@@ -519,36 +518,24 @@ class _ShardWriterPool:
 
     def _run(self, w: int) -> None:
         q = self._queues[w]
-        eng = _aio.WriteEngine(mode=self._mode, reg=self._reg)
-        self._engines.append(eng)
-        try:
-            while True:
-                batch = q.get()
-                if batch is None:
-                    return
-                shard, item = batch
-                # releases fire only after the batch DRAINS: with an
-                # async ring the kernel may still be reading a buffer
-                # long after submission returned, and a recycled parity
-                # buffer mid-read is silent corruption
-                releases: list = []
-                with self._job.stage(self._stage_of(shard)) as st:
-                    self._write_batch(eng, shard, item, releases)
-                if not self.errors:  # a failed run's seconds mean nothing
-                    self._busy[shard] += st.seconds
-                for rel in releases:
-                    rel()
-        finally:
-            try:
-                eng.close()
-            except BaseException as e:
-                self.errors.append(e)
+        while True:
+            batch = q.get()
+            if batch is None:
+                return
+            shard, item = batch
+            # releases fire only after the whole batch is written: a
+            # recycled parity buffer mid-write is silent corruption
+            releases: list = []
+            with self._job.stage(self._stage_of(shard)) as st:
+                self._write_batch(shard, item, releases)
+            if not self.errors:  # a failed run's seconds mean nothing
+                self._busy[shard] += st.seconds
+            for rel in releases:
+                rel()
 
-    def _write_batch(self, eng, shard: int, item: list,
-                     releases: list) -> None:
-        """Submit one queue item's jobs to the engine and drain it; every
-        error is kept for close(), none raised."""
-        ends: list[tuple[int, int]] = []
+    def _write_batch(self, shard: int, item: list, releases: list) -> None:
+        """Write one queue item's jobs; every error is kept for close(),
+        none raised."""
         idx = 0
         while idx < len(item):
             data, cfr, off, release = item[idx]
@@ -564,19 +551,12 @@ class _ShardWriterPool:
                 fd = self._fds[shard]
                 if cfr is not None:
                     src_fd, src_off, count, src_view = cfr
-                    # in-kernel copies want plain buffered fd
-                    # semantics: barrier the ring, drop O_DIRECT
-                    eng.ensure_buffered(fd)
                     _copy_range(src_fd, fd, src_off, off, count,
                                 src_view=src_view)
                     end = off + count
-                    self._wbytes[shard] += count
-                    if self._hw is not None and \
-                            end > self._hw[shard]:
-                        self._hw[shard] = end
                 else:
                     # merge the run of pwrites targeting
-                    # contiguous offsets into one submission
+                    # contiguous offsets into one pwritev
                     bufs = [np.ascontiguousarray(data)]
                     end = off + bufs[0].nbytes
                     while (idx < len(item)
@@ -589,21 +569,12 @@ class _ShardWriterPool:
                         if item[idx][3] is not None:
                             releases.append(item[idx][3])
                         idx += 1
-                    eng.writev(fd, bufs, off)
-                    ends.append((end, end - off))
+                    _pwritev_all(fd, bufs, off)
+                self._wbytes[shard] += end - off
+                if self._hw is not None and end > self._hw[shard]:
+                    self._hw[shard] = end
             except BaseException as e:  # surfaced after close
                 self.errors.append(e)
-        try:
-            eng.drain()
-        except BaseException as e:
-            self.errors.append(e)
-        else:
-            if not self.errors:
-                for end, n in ends:
-                    self._wbytes[shard] += n
-                    if self._hw is not None and \
-                            end > self._hw[shard]:
-                        self._hw[shard] = end
 
     # a bare pool quacks like a _ShardFlusher so producers can submit
     # DIRECTLY when units are big enough that per-job queue hops are
@@ -615,10 +586,10 @@ class _ShardWriterPool:
         pass
 
     def close(self) -> None:
-        """Drain every queue, join the workers, fold the engines' seconds
-        and the thread capacity behind each stage into stats.  Idempotent,
-        and does not raise — callers inspect `.errors`, letting a
-        producer-side exception win over a writer one."""
+        """Drain every queue, join the workers, fold the thread capacity
+        behind each stage into stats.  Idempotent, and does not raise —
+        callers inspect `.errors`, letting a producer-side exception win
+        over a writer one."""
         if getattr(self, "_closed", False):
             return
         self._closed = True
@@ -626,44 +597,6 @@ class _ShardWriterPool:
             q.put(None)
         for t in self._threads:
             t.join()
-        if self._stats is not None:
-            # engine sub-stages: where the write stage's wall actually
-            # went — SQE stamping + submission syscalls vs CQE waits.
-            # These are SUBSETS of the write_* busy seconds (same clock,
-            # finer cut), so overlap_fraction excludes them; the
-            # pipeline snapshot shows them as disk stages with the full
-            # worker capacity behind them
-            sub = sum(e.submit_s for e in self._engines)
-            comp = sum(e.complete_s for e in self._engines)
-            if sub or comp:
-                self._stats["submit_s"] = \
-                    self._stats.get("submit_s", 0.0) + sub
-                self._stats["complete_s"] = \
-                    self._stats.get("complete_s", 0.0) + comp
-                for wkey in ("submit_workers", "complete_workers"):
-                    self._stats[wkey] = self._stats.get(wkey, 0.0) + \
-                        self._nworkers
-            direct = sum(e.direct_bytes for e in self._engines)
-            if direct:
-                self._stats["aio_direct_bytes"] = \
-                    self._stats.get("aio_direct_bytes", 0) + direct
-            # aio_mode is what the engines RESOLVED to, not what the
-            # pool asked for: a worker's ring setup can fail where the
-            # probe passed (RLIMIT_NOFILE, memlock) and degrade that
-            # engine alone — report the most-degraded mode seen so a
-            # partly-synchronous round never wears the 'uring' label in
-            # the trajectory gate's like-for-like comparison
-            rank = {"buffered": 0, "pwritev": 1, "uring": 2}
-            modes = [e.mode for e in self._engines]
-            resolved = min(modes, key=lambda m: rank.get(m, 0)) \
-                if modes else self._mode
-            cur = self._stats.get("aio_mode")
-            if cur is None or rank.get(resolved, 0) < rank.get(cur, 3):
-                self._stats["aio_mode"] = resolved
-            degraded = sum(1 for m in modes if m != self._mode)
-            if degraded:
-                self._stats["aio_degraded_engines"] = \
-                    self._stats.get("aio_degraded_engines", 0) + degraded
         if self._stats is not None:
             stage_busy: dict[str, float] = {}
             for i, busy in enumerate(self._busy):
@@ -713,19 +646,6 @@ def _make_sink(writers: "_ShardWriterPool", nshards: int, min_step: int):
     if min_step >= DIRECT_MIN:
         return writers
     return _ShardFlusher(writers, nshards)
-
-
-def _parity_ring_size(min_step: int, max_step: int) -> int:
-    """Buffers in the countdown-released parity ring.  Direct submission
-    needs only the pipeline headroom (writers release per job); the
-    batched path must cover a whole unflushed flush group of min_step
-    units or the encode stalls on its own batching.  Direct headroom is
-    kept at +1 (not more): each buffer is (m, max_step) — 64MB at the
-    production 16MB batch — so extra depth is a real RSS cost on a
-    storage host running concurrent encodes."""
-    if min_step >= DIRECT_MIN:
-        return PIPELINE_DEPTH + 1
-    return PIPELINE_DEPTH + max(1, FLUSH_BYTES // max_step)
 
 
 class _ShardFlusher:
@@ -778,13 +698,9 @@ def overlap_fraction(stats: dict) -> float | None:
     backpressured run reads as ~0, not as overlapped.  None when the
     stats carry no wall clock or no stage time (e.g. an empty volume)."""
     wall = stats.get("wall_s")
-    # submit_s/complete_s are the engine's finer cut of the same seconds
-    # the write stages already carry — counting them again would inflate
-    # the stage sum and fake overlap
     total = sum(v for key, v in stats.items()
                 if key.endswith("_s")
-                and key not in ("wall_s", "stall_s", "submit_s",
-                                "complete_s")
+                and key not in ("wall_s", "stall_s")
                 and key not in _PART_KEYS  # their lumps carry them
                 and isinstance(v, float))
     if not wall or total <= 0:
@@ -799,10 +715,7 @@ def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
     """Parity for one column unit of a stripe row — the job's `encode`
     stage: gf_matmul_ptrs straight off the .dat mmap into pbuf's m rows.
     A partial tail row is staged into the zeroed tailbuf first; a stripe
-    with nz < k populated rows uses a column-truncated generator.  This
-    is the ONE copy of the zero-copy host encode — both the serial and
-    pipelined strategies call it, so they stay byte-identical by
-    construction."""
+    with nz < k populated rows uses a column-truncated generator."""
     from seaweedfs_tpu import native
     with pjob.stage("encode", unit=unit) as st:
         rows = [dat_view[row_start + j * block + col:
@@ -823,79 +736,6 @@ def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
                             nbytes=nz * step)
 
 
-def _encode_serial_host(codec, dat_fd: int, dat_view: np.ndarray,
-                        dat_size: int, large_block: int, small_block: int,
-                        batch_size: int, out_fds, highwater, pjob,
-                        progress=None, cancel=None) -> None:
-    """Native-codec encode with overlapped shard I/O: the GF matmul runs
-    on the calling thread straight off the .dat mmap (zero staging copy),
-    while all 14 shard files are written by the per-shard writer pool —
-    the encode of unit N overlaps the data copies and parity writes of
-    units N-1.. still in flight.  Parity lands in a small ring of pooled
-    buffers so the matmul only waits (stall_s) when every buffer is still
-    queued behind the disks."""
-    k, m = codec.k, codec.m
-    min_step, max_step = _unit_steps(dat_size, large_block, small_block,
-                                     batch_size, data_shards=k)
-    # ALIGN-aligned parity ring: rows qualify for O_DIRECT + registered-
-    # buffer submission whenever the step is an ALIGN multiple
-    pbufs = [_aligned_empty((m, max_step))
-             for _ in range(_parity_ring_size(min_step, max_step))]
-    pbuf_pool: queue.Queue = queue.Queue()
-    for b in pbufs:
-        pbuf_pool.put(b)
-    tailbuf = np.zeros(max_step, dtype=np.uint8)
-    writers = _ShardWriterPool(
-        out_fds, highwater, pjob,
-        stage_of=lambda i: "write_data" if i < k else "write_parity",
-        reg_bufs=pbufs)
-    sink = _make_sink(writers, codec.k + codec.m, min_step)
-    done = 0
-    try:
-        for unit, (row_start, block, col, step, shard_off) in enumerate(
-                _iter_units(dat_size, large_block, small_block, batch_size,
-                            data_shards=k)):
-            if cancel is not None and cancel():
-                raise EncodeCancelled("ec encode cancelled")
-            if writers.failed:
-                break
-            nz, tail = _unit_coverage(dat_size, row_start, block, col, step,
-                                      data_shards=k)
-            if nz == 0:
-                continue
-            # data shards: in-kernel copy on the per-shard workers, no
-            # user-space transit (the mmap view outlives the pool)
-            for j in range(nz):
-                off = row_start + j * block + col
-                n = step if j < nz - 1 else tail
-                sink.copy(j, dat_fd, off, shard_off, n,
-                          src_view=dat_view)
-            try:
-                pbuf = pbuf_pool.get_nowait()
-            except queue.Empty:
-                # ship the pending batches first: their releases are what
-                # refill the ring (blocking before the flush would deadlock)
-                sink.flush()
-                with pjob.blocked("stall", unit=unit):
-                    pbuf = pbuf_pool.get()
-            _host_parity_unit(pjob, unit, codec, dat_view, tailbuf, pbuf,
-                              row_start, block, col, step, nz, tail)
-            release = _countdown(
-                m, lambda b=pbuf: pbuf_pool.put(b))
-            for i in range(m):
-                sink.put(k + i, pbuf[i, :step], shard_off,
-                         release=release)
-            done += (nz - 1) * step + tail
-            sink.account(step)
-            if progress is not None:
-                progress(done)
-        sink.flush()
-    finally:
-        writers.close()
-    if writers.errors:
-        raise writers.errors[0]
-
-
 def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                       dat_size: int, large_block: int, small_block: int,
                       batch_size: int, out_fds, highwater, pjob,
@@ -911,11 +751,9 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                way (they never round-trip the device).  For DEVICE
                codecs it also stages the stripe from the mmap into a
                pooled buffer (`read`; waiting for one is `stall`) — the
-               device needs a stable host buffer to transfer from.  HOST codecs skip the staging
-               copy entirely: the dispatch stage encodes straight off
-               the mmap, so forcing a host codec through this machinery
-               (WEEDTPU_EC_PIPELINE=pipelined) costs no extra memory
-               traffic vs the serial strategy.
+               device needs a stable host buffer to transfer from.  HOST
+               codecs skip the staging copy entirely: the dispatch stage
+               encodes straight off the mmap.
       dispatch (caller's thread) launches the parity matmul for stripe N
                — asynchronous on JAX backends (the seam's `h2d` and
                `dispatch`, which add up to `encode`), eager (ptr-matmul
@@ -940,20 +778,14 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     min_step, max_step = _unit_steps(dat_size, large_block, small_block,
                                      batch_size, data_shards=k)
     pool: queue.Queue = queue.Queue()
-    reg_bufs = None
     if native_host:
         tailbuf = np.zeros(max_step, dtype=np.uint8)
-        # sized like _parity_ring_size's BATCHED branch: the pipelined
-        # drain submits small units through a _ShardFlusher (its pwritev
-        # merging measures ~4% faster than direct submission even for
-        # DIRECT_MIN-sized units), so the ring must cover a full
-        # unflushed flush group.  ALIGN-aligned so O_DIRECT/WRITE_FIXED
-        # engage on production block sizes.
-        reg_bufs = [_aligned_empty((m, max_step))
-                    for _ in range(PIPELINE_DEPTH +
-                                   max(1, FLUSH_BYTES // max_step))]
-        for b in reg_bufs:
-            pool.put(b)
+        # the parity ring: the drain batches small units through a
+        # _ShardFlusher, whose writers release a buffer only once its
+        # flush group is written, so the ring must cover a whole
+        # unflushed flush group on top of the pipeline's own depth
+        for _ in range(PIPELINE_DEPTH + max(1, FLUSH_BYTES // max_step)):
+            pool.put(np.empty((m, max_step), dtype=np.uint8))
     else:
         for _ in range(PIPELINE_DEPTH):
             pool.put(np.empty((k, max_step), dtype=np.uint8))
@@ -968,8 +800,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     errors: list[BaseException] = []
     writers = _ShardWriterPool(
         out_fds, highwater, pjob,
-        stage_of=lambda i: "write_data" if i < k else "write_parity",
-        reg_bufs=reg_bufs)
+        stage_of=lambda i: "write_data" if i < k else "write_parity")
     done = 0
 
     def reader() -> None:
@@ -1027,8 +858,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
         # time overlaps the next unit's d2h instead of queueing behind a
         # flush-group boundary.  Tiny units keep the batcher — per-unit
         # queue hops would cost more than the writes.
-        flusher = writers if min_step >= DIRECT_MIN else \
-            _ShardFlusher(writers, codec.k + codec.m)
+        flusher = _make_sink(writers, k + m, min_step)
         while True:
             item = q_disp.get()
             if item is None:
@@ -1251,17 +1081,12 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
         # buffers (countdown-released once every shard writer is done with
         # its row) keep the decode from racing its own in-flight writes.
         wpos = {i: r for r, i in enumerate(missing)}
-        # aligned output ring, registered with the writer engines: the
-        # reconstruction writes ride the same aio path as encode parity
-        # (heal-side ceiling_frac must match the encode side's)
-        obufs = [_aligned_empty(
-            (len(missing), min(batch_size, max(shard_size, 1))))
-            for _ in range(PIPELINE_DEPTH)]
-        writers = _ShardWriterPool([out_fds[i] for i in missing], None,
-                                   pjob, reg_bufs=obufs)
+        writers = _ShardWriterPool([out_fds[i] for i in missing], None, pjob)
         opool: queue.Queue = queue.Queue()
-        for b in obufs:
-            opool.put(b)
+        for _ in range(PIPELINE_DEPTH):
+            opool.put(np.empty(
+                (len(missing), min(batch_size, max(shard_size, 1))),
+                dtype=np.uint8))
         for i, f in ins.items():
             if shard_size:
                 mm = _map_readonly(f.fileno(), shard_size)

@@ -21,7 +21,6 @@ range stack column-wise into a single GF(2^8) matmul — RS decodes
 byte-position by byte-position, so concatenated ranges rebuild exactly as
 they would one by one).  A small LRU keeps recently reconstructed ranges so
 repeated degraded GETs of a hot needle cost no shard I/O and no matmul.
-`WEEDTPU_EC_READ=serial` restores the per-interval loop (bench baseline).
 """
 
 from __future__ import annotations
@@ -277,26 +276,6 @@ class EcVolume:
         except OSError:
             return None
 
-    def read_interval(self, shard_id: int, offset: int, size: int,
-                      shard_reader: ShardReader | None = None) -> bytes:
-        data = self._read_local(shard_id, offset, size)
-        if data is not None and len(data) == size:
-            self._bump("local_shard_reads")
-            return data
-        if shard_reader is not None:
-            data = shard_reader(shard_id, offset, size)
-            if data is not None and len(data) == size:
-                self._bump("remote_shard_reads")
-                return data
-        return self._reconstruct_interval(shard_id, offset, size, shard_reader)
-
-    def _reconstruct_interval(self, shard_id: int, offset: int, size: int,
-                              shard_reader: ShardReader | None) -> bytes:
-        """Per-interval repair (the serial baseline and the read_interval
-        fallback): a reconstruction batch of one."""
-        return self._reconstruct_ranges([(shard_id, offset, size)],
-                                        shard_reader, use_cache=False)[0]
-
     def _read_segs_local(self, shard_id: int,
                          segs: list[tuple[int, int]]) -> bytes | None:
         """All (offset, size) segments of one shard, concatenated; None if
@@ -404,8 +383,8 @@ class EcVolume:
         return rows
 
     def _reconstruct_ranges(self, ranges: list[tuple[int, int, int]],
-                            shard_reader: ShardReader | None,
-                            use_cache: bool = True) -> list[bytes]:
+                            shard_reader: ShardReader | None
+                            ) -> list[bytes]:
         """Rebuild several (shard_id, offset, size) ranges in ONE batched
         codec dispatch: each survivor's slices concatenate into a single
         row, the decode matmul runs once over the whole concatenation, and
@@ -413,7 +392,7 @@ class EcVolume:
         out: list[bytes | None] = [None] * len(ranges)
         todo: list[int] = []
         for idx, key in enumerate(ranges):
-            data = self._cache_get(key) if use_cache else None
+            data = self._cache_get(key)
             if data is not None:
                 out[idx] = data
                 self._bump("reconstruct_cache_hits")
@@ -501,8 +480,7 @@ class EcVolume:
                 rebuilt[sid][pos + lead:pos + lead + size]).tobytes()
             pos += gsegs[i][1]
             out[idx] = data
-            if use_cache:
-                self._cache_put((sid, off, size), data)
+            self._cache_put((sid, off, size), data)
         return out  # type: ignore[return-value]
 
     def _read_ranges(self, plan: list[tuple[int, int, int]],
@@ -686,11 +664,9 @@ class EcVolume:
 
     def read_needle(self, needle_id: int,
                     shard_reader: ShardReader | None = None,
-                    mode: str | None = None,
                     skip_shards: frozenset | None = None) -> ndl.Needle:
         """Full needle read: locate -> plan all intervals -> batched shard
-        reads + one-shot reconstruction -> parse.  `mode` (or
-        WEEDTPU_EC_READ) = "serial" restores the per-interval loop.
+        reads + one-shot reconstruction -> parse.
 
         `skip_shards` withholds those shards from BOTH the local files
         and the remote reader, forcing the read through reconstruction —
@@ -721,7 +697,7 @@ class EcVolume:
             rank = getattr(inner, "locality_rank", None)
             if rank is not None:
                 skipping_reader.locality_rank = rank
-            return view.read_needle(needle_id, skipping_reader, mode)
+            return view.read_needle(needle_id, skipping_reader)
         with trace.span("ec.plan", needle=f"{needle_id:x}") as psp:
             dat_offset, size = self.find_needle(needle_id)
             length = t.actual_size(size, self.version)
@@ -734,13 +710,7 @@ class EcVolume:
                                                      self.small_block)
                 plan.append((sid, off, iv.size))
             psp.set(intervals=len(plan), bytes=length)
-        mode = mode or os.environ.get("WEEDTPU_EC_READ", "batched")
-        if mode == "serial":
-            parts = [self.read_interval(sid, off, size, shard_reader)
-                     for sid, off, size in plan]
-        else:
-            parts = self._read_ranges(plan, shard_reader)
-        record = b"".join(parts)
+        record = b"".join(self._read_ranges(plan, shard_reader))
         n = ndl.Needle.from_record(record, self.version)
         if n.id != needle_id:
             raise IOError(f"ec read returned needle {n.id:x}, wanted {needle_id:x}")

@@ -11,7 +11,6 @@ process in cProfile, hooks run on termination signals.
 from __future__ import annotations
 
 import atexit
-import contextlib
 import cProfile
 import faulthandler
 import logging
@@ -137,18 +136,6 @@ def stop_jax_profile() -> str | None:
             return None  # already stopped
     log.info("jax profiler trace written to %s", trace_dir)
     return trace_dir
-
-
-@contextlib.contextmanager
-def jax_profile(trace_dir: str | None):
-    """Context-manager variant (bench.py); a no-op when trace_dir is
-    falsy, so call sites can pass the flag straight through."""
-    started = bool(trace_dir) and start_jax_profile(trace_dir)
-    try:
-        yield
-    finally:
-        if started:
-            stop_jax_profile()
 
 
 def setup_jax_profile(trace_dir: str | None) -> None:

@@ -2,7 +2,8 @@
 
 Must set env before the first `import jax` anywhere in the test process so
 multi-chip sharding tests (parallel/) exercise real collectives without TPU
-hardware. Benchmarks (`bench.py`) do NOT import this and run on the real chip.
+hardware. The benchmark (`benchmark/run.py`) does NOT import this and runs on
+the real chip.
 """
 
 import os

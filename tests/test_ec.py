@@ -223,36 +223,30 @@ def test_ec_decode_back_to_volume(small_volume):
                                       SMALL * 4 + 1])
 def test_pipelined_and_serial_encode_byte_identical(tmp_path, monkeypatch,
                                                     batch, dat_size):
-    """The serial-host and pipelined strategies (and the numpy codec
-    through the pipelined machinery) must cut byte-identical .ec00-.ec13
-    shard files from the same .dat — the overlapped writer pool reorders
-    I/O, never contents."""
+    """The native host codec (zero-copy, off the mmap) and the numpy codec
+    (staged through the read pool) must cut byte-identical .ec00-.ec13
+    shard files from the same .dat through the one encode pipeline — the
+    overlapped writer pool reorders I/O, never contents."""
     from seaweedfs_tpu import native
     rng = np.random.default_rng(11)
     dat = rng.integers(0, 256, dat_size, dtype=np.uint8).tobytes()
-    runs = [("pipelined-native", "cpp", "pipelined"),
-            ("pipelined-numpy", "numpy", "pipelined")]
-    if native.available():
-        runs.append(("serial", "cpp", "serial"))
     shards: dict[str, list[bytes]] = {}
-    for name, codec, mode in runs:
+    for codec in ("cpp", "numpy"):
         if codec == "cpp" and not native.available():
             continue
-        d = tmp_path / name
+        d = tmp_path / codec
         d.mkdir()
         base = str(d / "v")
         with open(base + ".dat", "wb") as f:
             f.write(dat)
         monkeypatch.setenv("WEEDTPU_EC_CODEC", codec)
-        monkeypatch.setenv("WEEDTPU_EC_PIPELINE", mode)
         stats: dict = {}
         ec_files.write_ec_files(base, large_block=LARGE, small_block=SMALL,
                                 batch_size=batch, stats=stats)
-        want_mode = "host-serial" if name == "serial" else "pipelined"
-        assert stats["mode"] == want_mode, (name, stats)
-        shards[name] = [open(base + layout.to_ext(i), "rb").read()
-                        for i in range(layout.TOTAL_SHARDS)]
-    golden = shards["pipelined-numpy"]
+        assert stats["mode"] == "pipelined", (codec, stats)
+        shards[codec] = [open(base + layout.to_ext(i), "rb").read()
+                         for i in range(layout.TOTAL_SHARDS)]
+    golden = shards["numpy"]
     for name, got in shards.items():
         for i in range(layout.TOTAL_SHARDS):
             assert got[i] == golden[i], (name, i)

@@ -12,7 +12,6 @@ and the fleet scrub rate, and both recover once the load stops — with
 the retune queryable as a history series and a pinned trace."""
 
 import io
-import json
 import logging
 import threading
 import time
@@ -526,31 +525,6 @@ def test_weedlog_exc_info_carries_traceback(caplog):
     with caplog.at_level(logging.WARNING, logger="tlog"):
         weedlog.warning("plain", name="tlog")
     assert "Traceback" not in caplog.text
-
-
-# ---- bench trajectory: record-only over a wiped history ----------------
-
-def test_trajectory_empty_history_is_record_only(tmp_path, monkeypatch,
-                                                 capsys):
-    import bench
-    monkeypatch.setattr(bench, "__file__", str(tmp_path / "bench.py"))
-    hist = tmp_path / "bench_history.jsonl"
-    hist.write_text("")  # freshly wiped: exists, zero entries
-    extra: dict = {}
-    bench._record_trajectory(100.0, "tpu", extra)
-    assert extra.get("bench_trajectory_record_only") is True
-    assert "bench_regression" not in extra
-    err = capsys.readouterr().err
-    assert "trajectory gate skipped" in err
-    assert "ec_encode_rs10_4" in err  # says WHAT went ungated
-    entries = [json.loads(line) for line in
-               hist.read_text().splitlines()]
-    assert entries[-1]["metrics"]["ec_encode_rs10_4"] == 100.0
-    # the recorded round arms the gate for the next one
-    extra2: dict = {}
-    bench._record_trajectory(50.0, "tpu", extra2)
-    assert "bench_trajectory_record_only" not in extra2
-    assert "ec_encode_rs10_4" in extra2.get("bench_regression", {})
 
 
 # ---- 3-node integration ------------------------------------------------

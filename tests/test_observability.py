@@ -978,6 +978,25 @@ def test_every_env_knob_documented_in_readme():
         f"README.md: {missing}")
 
 
+def test_removed_ec_fork_knobs_are_read_nowhere():
+    """Repo lint: the variables that selected a second write engine, a
+    second encode strategy, a second read engine or another tile are
+    gone from the package, the README and chip_smoke.py — a path that
+    only a user-set variable selects has no cell on either side of it."""
+    import pathlib
+    root = pathlib.Path(__file__).resolve().parent.parent
+    gone = re.compile(
+        r"WEEDTPU_(AIO|AIO_DEPTH|AIO_DIRECT|EC_PIPELINE|EC_READ|EC_TILE|"
+        r"TILE_PIN|TILE_SENTINEL_INTERVAL)\b")
+    files = [root / "README.md", root / "chip_smoke.py",
+             *(root / "seaweedfs_tpu").rglob("*.py")]
+    hits = sorted({f"{p.relative_to(root)}: {m.group(0)}" for p in files
+                   for m in gone.finditer(p.read_text(encoding="utf-8"))})
+    assert not hits, hits
+    assert not (root / "bench.py").exists()
+    assert not (root / "seaweedfs_tpu" / "storage" / "aio.py").exists()
+
+
 def test_every_control_endpoint_documented_in_readme():
     """Repo lint: every /cluster/* and /admin/* HTTP endpoint the
     servers register must appear in README.md — an undocumented control

@@ -1,12 +1,9 @@
-"""Performance-observatory tests (stats/pipeline.py + the roofline and
-tile-drift planes): stage-accounting math invariants (busy/blocked
-separation, stats-dict merge, queue-depth bounds), bottleneck attribution
-with ceiling fractions, fleet aggregation with tracker dedupe, tile-pin
-provenance + drift-sentinel verdicts, bench-trajectory like-for-like
-config gating, and two cluster integrations — an e2e fleet conversion
-whose /cluster/perf bottleneck verdict must match the max-busy-fraction
-stage, and a forced-stale tile pin firing (then clearing) the
-tile_pin_stale alert on /cluster/alerts."""
+"""Performance-observatory tests (stats/pipeline.py + the roofline
+plane): stage-accounting math invariants (busy/blocked separation,
+stats-dict merge, queue-depth bounds), bottleneck attribution with
+ceiling fractions, fleet aggregation with tracker dedupe, and a cluster
+integration — an e2e fleet conversion whose /cluster/perf bottleneck
+verdict must match the max-busy-fraction stage."""
 
 import io
 import json
@@ -23,15 +20,13 @@ from tests.test_maintenance import _get, _post
 
 @pytest.fixture(autouse=True)
 def _fresh_observatory(monkeypatch):
-    """Every test starts with an empty job registry, no installed
-    sentinel, and the enabled() cache invalidated (its 0.5s TTL would
-    otherwise leak one test's WEEDTPU_PERF_OBS into the next)."""
+    """Every test starts with an empty job registry and the enabled()
+    cache invalidated (its 0.5s TTL would otherwise leak one test's
+    WEEDTPU_PERF_OBS into the next)."""
     monkeypatch.setattr(pipeline, "_enabled_cache", (0.0, True))
     pipeline.reset()
-    pipeline.set_sentinel(None)
     yield
     pipeline.reset()
-    pipeline.set_sentinel(None)
     pipeline._enabled_cache = (0.0, True)
 
 
@@ -60,9 +55,9 @@ def test_stage_accounting_busy_blocked_invariants():
 
 def test_stage_seconds_write_through_and_stall_maps_to_blocked():
     # a Stage books its seconds to the job AND to the `<stage>_s` key of
-    # the wrapped stats dict (what /admin/ec/progress and bench.py read);
-    # seconds folded into the dict alone (the write engines' submit and
-    # complete) still make a stage; stall_s is idle, never a stage
+    # the wrapped stats dict (what /admin/ec/progress reads); seconds
+    # folded into the dict alone still make a stage; stall_s is idle,
+    # never a stage
     stats = {"write_parity_s": 1.0}
     job = pipeline.PipelineJob("t", stats)
     with job.stage("encode", nbytes=10):
@@ -168,26 +163,6 @@ def test_writer_pool_worker_counts_accumulate_across_pools(tmp_path):
         os.close(fd)
     assert stats["write_workers"] == pytest.approx(expected)
     assert stats["write_workers"] > pools[0]._nworkers  # summed
-
-
-def test_aio_submit_complete_stages_flow_through_snapshot():
-    """The host I/O engine's submit/complete split (storage/aio.py)
-    rides the same stats-dict contract as every other stage: the
-    observatory snapshot carries both, worker-normalized, and maps them
-    to the disk resource for ceiling attribution."""
-    stats = {"write_parity_s": 2.0, "write_parity_workers": 2,
-             "submit_s": 0.5, "submit_workers": 2,
-             "complete_s": 0.25, "complete_workers": 2}
-    job = pipeline.track("aio", stats)
-    job.finish()
-    snap = job.snapshot()
-    assert snap["stages"]["submit"]["busy_s"] == 0.5
-    assert snap["stages"]["complete"]["busy_s"] == 0.25
-    assert pipeline.STAGE_RESOURCE["submit"] == "disk"
-    assert pipeline.STAGE_RESOURCE["complete"] == "disk"
-    # the sub-stages never outrank the write stage they are a cut of
-    best = max(snap["stages"], key=lambda s: snap["stages"][s]["busy_s"])
-    assert best == "write_parity"
 
 
 def test_perf_endpoint_is_cluster_internal_but_objects_stay_data():
@@ -302,25 +277,6 @@ def test_dispatch_parity_batch_books_h2d_exactly_once(unit_mesh):
     assert d2h == sum(b.nbytes for _, _, b in blocks) == 8 * 4 * 256
 
 
-def test_drift_gauge_clears_when_pin_goes_unmeasurable(tmp_path,
-                                                       monkeypatch):
-    """After a stale verdict, deleting the pin (the obvious
-    remediation) must zero weedtpu_tile_drift so tile_pin_stale can
-    clear — not latch the last stale value until process restart."""
-    from seaweedfs_tpu.ops import pallas_gf
-    pin = str(tmp_path / "pin.json")
-    monkeypatch.setenv("WEEDTPU_TILE_PIN", pin)
-    pallas_gf.save_tile_pin(65536, 100.0)
-    s = pipeline.TileDriftSentinel(
-        measure=lambda: {65536: 100.0, 131072: 200.0})
-    assert s.run_once()["state"] == "stale"
-    assert metrics.TILE_DRIFT.labels().value == pytest.approx(1.0)
-    os.remove(pin)
-    assert s.run_once()["state"] == "no_pin"
-    assert metrics.TILE_DRIFT.labels().value == 0.0
-    assert metrics.TILE_DRIFT_RATIO.labels().value == 1.0
-
-
 def test_roofline_snapshot_fractions_and_offenders():
     profile.KERNELS.reset()
     profile.KERNELS.record("encode_parity", "device", wall_s=1.0,
@@ -360,7 +316,7 @@ def test_aggregate_fleet_dedupes_trackers_and_picks_worst_verdict():
             "stages": {"write_parity": {"busy_s": 1.0, "bytes": 5e8,
                                         "busy_frac": 0.4}},
             "bottleneck": {"stage": "write_parity", "busy_frac": 0.4}}
-    shared = {"id": "AA", "jobs": [job], "tile": {"state": "ok"}}
+    shared = {"id": "AA", "jobs": [job]}
     out = pipeline.aggregate_fleet([
         ("vs1", shared), ("vs2", shared),  # co-hosted: same tracker id
         ("vs3", {"id": "BB", "jobs": [weak]})])
@@ -371,100 +327,78 @@ def test_aggregate_fleet_dedupes_trackers_and_picks_worst_verdict():
     # worst (max busy_frac) bottleneck wins the per-kind verdict
     assert out["bottlenecks"]["fleet_convert"]["stage"] == "encode"
     assert out["bottlenecks"]["fleet_convert"]["node"] == "vs1"
-    assert out["tiles"] == {"vs1": {"state": "ok"}}
 
 
-# ---- tile pin + drift sentinel -----------------------------------------
+# ---- one tile a platform ------------------------------------------------
 
-def test_tile_pin_roundtrip_and_foreign_fingerprint_never_applies(
-        tmp_path, monkeypatch):
-    from seaweedfs_tpu.ops import pallas_gf
-    pin_path = str(tmp_path / "pin.json")
-    monkeypatch.setenv("WEEDTPU_TILE_PIN", pin_path)
-    monkeypatch.delenv("WEEDTPU_EC_TILE", raising=False)
-    pallas_gf.save_tile_pin(65536, 222.2, {"65536": 222.2})
-    pin = pallas_gf.load_tile_pin()
-    assert pin["tile"] == 65536 and pin["gbps"] == 222.2
-    assert pin["fingerprint"] == pallas_gf.chip_fingerprint()
-    assert pallas_gf.resolved_tile() == 65536  # matching pin applies
-    # a pin recorded on different hardware is provenance-only
-    pin["fingerprint"] = "tpu:v9:8"
-    with open(pin_path, "w") as f:
-        json.dump(pin, f)
-    assert pallas_gf.resolved_tile() != 65536 or \
-        pallas_gf.DEFAULT_TILE == 65536
-    st = pipeline.TileDriftSentinel(
-        measure=lambda: {65536: 1.0}, pin_path=pin_path).run_once()
-    assert st["state"] == "fingerprint_mismatch"
-
-
-def test_sentinel_verdicts_stale_ok_and_failed(tmp_path, monkeypatch):
-    from seaweedfs_tpu.ops import pallas_gf
+@pytest.fixture
+def tile_noise(tmp_path, monkeypatch):
+    """Everything that used to move the tile: the override, a pin where
+    the variable says and one under $HOME, both naming another tile."""
+    pin = {"tile": 65536, "gbps": 300.0, "fingerprint": "cpu:cpu:8"}
+    for path in (tmp_path / "pin.json",
+                 tmp_path / ".weedtpu_tile_pin.json"):
+        path.write_text(json.dumps(pin))
+    monkeypatch.setenv("WEEDTPU_EC_TILE", "65536")
     monkeypatch.setenv("WEEDTPU_TILE_PIN", str(tmp_path / "pin.json"))
-    pallas_gf.save_tile_pin(65536, 100.0)
-    s = pipeline.TileDriftSentinel(
-        measure=lambda: {65536: 100.0, 131072: 150.0})
-    st = s.run_once()
-    assert st["state"] == "stale" and st["best_tile"] == 131072
-    assert st["drift"] == pytest.approx(0.5)
-    assert st["sweep"]  # the sweep table rides the verdict for the page
-    assert metrics.TILE_DRIFT.labels().value == pytest.approx(0.5)
-    st = pipeline.TileDriftSentinel(
-        measure=lambda: {65536: 150.0, 131072: 140.0}).run_once()
-    assert st["state"] == "ok" and st["drift"] == 0.0
-    st = pipeline.TileDriftSentinel(
-        measure=lambda: {131072: 1.0}).run_once()
-    assert st["state"] == "sweep_failed"  # pinned tile did not measure
-    st = pipeline.TileDriftSentinel(
-        measure=lambda: (_ for _ in ()).throw(RuntimeError("boom"))
-    ).run_once()
-    assert st["state"] == "sweep_failed" and "boom" in st["error"]
+    monkeypatch.setenv("HOME", str(tmp_path))
 
 
-def test_no_pin_is_quiet_and_default_alert_rule_exists(tmp_path,
-                                                       monkeypatch):
-    from seaweedfs_tpu.stats import history
-    monkeypatch.setenv("WEEDTPU_TILE_PIN", str(tmp_path / "absent.json"))
-    st = pipeline.TileDriftSentinel(measure=lambda: {}).run_once()
-    assert st["state"] == "no_pin"
-    monkeypatch.delenv("WEEDTPU_ALERT_RULES", raising=False)
-    rules = {r["name"]: r for r in history.parse_alert_rules()}
-    rule = rules["tile_pin_stale"]
-    assert rule["series"] == "weedtpu_tile_drift"
-    assert rule["op"] == "gt" and rule["value"] == pytest.approx(0.1)
+@pytest.mark.parametrize("backend,want", [("tpu", "TPU_TILE"),
+                                          ("cpu", "DEFAULT_TILE")])
+def test_resolved_tile_is_one_constant_a_platform(monkeypatch, tile_noise,
+                                                  backend, want):
+    import jax
 
-
-# ---- bench trajectory: like-for-like configs ---------------------------
-
-def test_trajectory_gate_compares_only_matching_fingerprints(
-        tmp_path, monkeypatch):
-    import bench
-    monkeypatch.setattr(bench, "__file__",
-                        str(tmp_path / "bench.py"))
-    hist = tmp_path / "bench_history.jsonl"
-    prior = {"n": 1, "backend": "tpu",
-             "config": {"backend": "tpu", "fingerprint": "tpu:v5e:1"},
-             "metrics": {"ec_encode_rs10_4": 300.0}}
-    hist.write_text(json.dumps(prior) + "\n")
-    # same backend string, DIFFERENT chip: must not gate against the
-    # 300 GB/s prior (the CPU-fallback-masquerade failure mode)
     from seaweedfs_tpu.ops import pallas_gf
-    monkeypatch.setattr(pallas_gf, "chip_fingerprint",
-                        lambda: "cpu:haswell:1")
-    extra: dict = {}
-    bench._record_trajectory(100.0, "tpu", extra)
-    assert "bench_regression" not in extra
-    entries = [json.loads(line) for line in
-               hist.read_text().splitlines()]
-    assert entries[-1]["config"]["fingerprint"] == "cpu:haswell:1"
-    assert entries[-1]["config"]["backend"] == "tpu"
-    # matching fingerprint: the same 3x drop now fails the gate
-    monkeypatch.setattr(pallas_gf, "chip_fingerprint",
-                        lambda: "tpu:v5e:1")
-    extra2: dict = {}
-    bench._record_trajectory(100.0, "tpu", extra2)
-    assert "bench_regression" in extra2
-    assert "ec_encode_rs10_4" in extra2["bench_regression"]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert pallas_gf.resolved_tile() == getattr(pallas_gf, want)
+    assert pallas_gf.resolved_tile(256) == 256  # an explicit tile is kept
+
+
+def test_codec_resolve_touches_no_file_and_perf_reports_the_tile(
+        monkeypatch, tile_noise):
+    """`codecs.resolve` rides every degraded-read batch: no stat, no
+    open, and the tile of the kernel's shell is the platform's constant.
+    /perf says so under `codecs`, and has no `tile` block of its own."""
+    import asyncio
+    import builtins
+
+    import jax
+
+    from seaweedfs_tpu.ops import codecs, pallas_gf
+    from tests.test_stage_tracing import _mock_req
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(profile, "_codecs_noted", {})
+    codecs._build.cache_clear()
+    try:
+        first = codecs.resolve("rs_10_4", "tpu")  # imports, builds, notes
+        touched: list = []
+
+        def refuse(name):
+            def hook(*a, **kw):
+                touched.append((name, a[:1]))
+                raise AssertionError(f"{name}{a[:1]} during resolve")
+            return hook
+
+        with monkeypatch.context() as m:
+            for name in ("stat", "lstat", "open", "listdir", "access"):
+                m.setattr(os, name, refuse(f"os.{name}"))
+            m.setattr(builtins, "open", refuse("open"))
+            again = codecs.resolve("rs_10_4", "tpu")
+        assert not touched and again is first
+        assert type(first).__name__ == "PallasRSCodec"
+        assert first.tile == pallas_gf.TPU_TILE == 131072
+        resp = asyncio.run(pipeline.handle_perf(
+            _mock_req("/perf", "10.0.0.9")))
+        body = json.loads(resp.text)
+        assert "tile" not in body and "tiles" not in \
+            pipeline.aggregate_fleet([("vs1", body)])
+        noted = [c for c in body["codecs"] if c["asked"] == "tpu"]
+        assert noted and noted[0]["codec"] == "PallasRSCodec"
+        assert noted[0]["tile"] == 131072
+    finally:
+        codecs._build.cache_clear()  # no CPU test inherits a Pallas shell
 
 
 def test_ec_read_flow_account_books_stage_occupancy(tmp_path, monkeypatch):
@@ -577,69 +511,3 @@ def test_fleet_convert_bottleneck_matches_max_busy_stage_on_cluster_perf(
         assert "fleet_convert" in text and "bottleneck" in text, text
     finally:
         c.stop()
-
-
-def test_forced_stale_tile_fires_then_clears_cluster_alert(
-        tmp_path, monkeypatch):
-    """A stale pin as a page: a pinned tile that no longer wins
-    its own micro-sweep by >10% fires tile_pin_stale on /cluster/alerts
-    (sweep table attached to the sentinel status), and clears after the
-    pin wins again."""
-    from seaweedfs_tpu.ops import pallas_gf
-    monkeypatch.setenv("WEEDTPU_TILE_PIN", str(tmp_path / "pin.json"))
-    monkeypatch.setenv("WEEDTPU_SCRUB_MBPS", "0")
-    monkeypatch.setenv("WEEDTPU_REPAIR_INTERVAL", "3600")
-    monkeypatch.setenv("WEEDTPU_AGG_INTERVAL", "0")
-    # the default tile_pin_stale rule with test-sized hysteresis (house
-    # pattern: hist_cluster tightens for= so the suite sees both edges)
-    monkeypatch.setenv(
-        "WEEDTPU_ALERT_RULES",
-        "tile_pin_stale=threshold,series=weedtpu_tile_drift,"
-        "agg=max,window=2,op=gt,value=0.1,for=0,clear_for=0.2")
-    pallas_gf.save_tile_pin(65536, 300.0)
-    sweeps = {"stale": {65536: 100.0, 131072: 330.0},
-              "ok": {65536: 330.0, 131072: 100.0}}
-    mode = {"m": "stale"}
-    sentinel = pipeline.TileDriftSentinel(
-        measure=lambda: sweeps[mode["m"]])
-    pipeline.set_sentinel(sentinel)
-    c = Cluster(tmp_path, n_volume_servers=1).start()
-    try:
-        c.wait_heartbeats()
-        st = sentinel.run_once()
-        assert st["state"] == "stale" and st["sweep"], st
-
-        def alerts():
-            return _get(c.master.url, "/cluster/alerts?refresh=1",
-                        timeout=60)
-
-        def rule_state(st_):
-            return next(r for r in st_["rules"]
-                        if r["name"] == "tile_pin_stale")["state"]
-
-        st_a = alerts()
-        if rule_state(st_a) != "firing":
-            st_a = alerts()
-        assert rule_state(st_a) == "firing", st_a
-        # the sentinel's verdict (sweep table included) is on the
-        # observatory surfaces the page links to
-        dbg = _get(c.volume_servers[0].url, "/debug/pipeline")
-        assert dbg["tile"]["state"] == "stale" and dbg["tile"]["sweep"]
-        perf = _get(c.master.url, "/cluster/perf")
-        assert any(t.get("state") == "stale"
-                   for t in perf["tiles"].values()), perf["tiles"]
-
-        # recovery: the pin wins the micro-sweep again
-        mode["m"] = "ok"
-        assert sentinel.run_once()["state"] == "ok"
-        deadline = time.time() + 20
-        state = "firing"
-        while time.time() < deadline:
-            time.sleep(0.3)
-            state = rule_state(alerts())
-            if state == "ok":
-                break
-        assert state == "ok", state
-    finally:
-        c.stop()
-        metrics.TILE_DRIFT.labels().set(0.0)

@@ -44,14 +44,14 @@ ENGINE = {"encode": {"ec.encode.read", "ec.encode.write_data",
 # every key /admin/ec/progress `stages` carried before the seam's cut
 # (the parent commit's stats dicts of the same tiny runs)
 OLD_KEYS = {
-    "encode": {"aio_mode", "backend", "bytes", "d2h_s", "encode_s", "mode",
+    "encode": {"backend", "bytes", "d2h_s", "encode_s", "mode",
                "overlap_frac", "read_s", "stall_s", "wall_s",
                "write_data_s", "write_data_workers", "write_parity_s",
                "write_parity_workers"},
-    "rebuild": {"aio_mode", "bytes", "codec", "mode", "overlap_frac",
+    "rebuild": {"bytes", "codec", "mode", "overlap_frac",
                 "reconstruct_s", "stall_s", "wall_s", "write_s",
                 "write_workers"},
-    "fleet": {"aio_mode", "backend", "bytes", "committed_bases", "d2h_s",
+    "fleet": {"backend", "bytes", "committed_bases", "d2h_s",
               "devices", "encode_s", "mode", "overlap_frac", "read_s",
               "stall_s", "unit_batch", "units", "volumes", "wall_s",
               "write_data_s", "write_data_workers", "write_parity_s",
