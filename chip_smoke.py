@@ -616,8 +616,9 @@ def run(args, srv: Server, report: dict, cache_dir: str) -> None:
     # -- device proof ----------------------------------------------------------
     with phase("device"):
         perf = srv.perf()
-        # one dispatch per small-block row, plus the preflight volume's
-        check_device_only(perf, "encode_parity", rows + 1)
+        # one dispatch per unit of sixteen small-block rows (16 MiB of
+        # every shard: ec_files._iter_spans), plus the preflight volume's
+        check_device_only(perf, "encode_parity", -(-rows // 16) + 1)
         check(len(perf["codecs"]) == len(blocks),
               f"a second codec was resolved: {perf['codecs']}")
 

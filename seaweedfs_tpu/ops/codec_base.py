@@ -78,28 +78,56 @@ def select_survivors(code, present: tuple, wanted: list[int]) -> tuple:
     return tuple(present[: code.k])
 
 
-def stacked(data, k: int) -> jax.Array:
-    """Inside a program: the [k, W] stack of an input in *linear* form,
-    which is the k rows as a tuple of [W] arrays or one after the other
-    in one [k * W] array.  The runtime moves a 1-D array between host and
-    device as it lies; a 2-D uint8 one goes through a relayout on the
-    host, in both directions, that costs more than the transfer (a
-    `[10, 16 MiB]` put 48 ms against 16 for its ten rows, a `[1, 16 MiB]`
-    copy back 88 ms against 6; PERF.md, PR 26).  So the reconstruct seam
-    moves only 1-D arrays, and the program that applies the matrix lays
-    them out: `linear=True` on a matrix apply is this on the way in and
-    the product's rows one after the other in one array on the way out."""
+def stacked(data, k: int, stripes: int = 0) -> jax.Array:
+    """Inside a program: the [k, W] stack of an input in *linear* form.
+    The runtime moves a 1-D array between host and device as it lies; a
+    2-D uint8 one goes through a relayout on the host, in both directions,
+    that costs more than the transfer (a `[10, 16 MiB]` put 48 ms against
+    16 for its ten rows, a `[1, 16 MiB]` copy back 88 ms against 6;
+    PERF.md, PR 26).  So the reconstruct seam and, since PR 31, the encode
+    seam move only 1-D arrays, and the program that applies the matrix
+    lays them out: `linear=True` on a matrix apply is this on the way in
+    and `unstacked` on the way out.  Three forms come in:
+
+      the k rows as a tuple of [W] arrays (a rebuild batch, row by row
+      from the shard files' maps);
+      the k rows one after the other in one [k * W] array (a degraded
+      read's stack);
+      `stripes` = R >= 1 stripe rows as they lie in a `.dat`, row-major:
+      1-D pieces (one array, or a tuple put piece by piece) that hold,
+      one after the other, R rows of k blocks of `small` bytes each.
+      Block j of every row is shard j's, so [R, k, small] becomes
+      [k, R * small] (an encode unit: W = R * small contiguous bytes of
+      each shard file)."""
     if isinstance(data, (tuple, list)):
-        return jnp.stack(data, axis=0)
-    return data.reshape(k, -1)
+        if not stripes:
+            return jnp.stack(data, axis=0)
+        data = jnp.concatenate(data)
+    if not stripes:
+        return data.reshape(k, -1)
+    return data.reshape(stripes, k, -1).transpose(1, 0, 2).reshape(k, -1)
+
+
+def unstacked(out: jax.Array, stripes: int = 0):
+    """Inside a program: the [m, W] product of a *linear* apply as it goes
+    back.  A decode's rows go one after the other in one [m * W] array (a
+    rebuild batch loses one or two shards: 16 or 32 MiB).  An encode
+    unit's (`stripes` >= 1, see `stacked`) go as m arrays of [W], one
+    contiguous run of each parity shard's file: the copy back lands in
+    memory the host's allocator hands out, which maps an array over 32 MiB
+    afresh every time and pays its first touch (TPU v5e's host: one
+    64 MiB array back in 71-79 ms, four of 16 MiB in 6.5; PERF.md,
+    PR 31)."""
+    return tuple(out) if stripes else out.reshape(-1)
 
 
 class RSCodecBase:
     """Encode / reconstruct for one fixed-matrix GF(2^8) code.
 
-    `matrix_apply_factory(C) -> callable([k, n] bytes, linear=False) ->
-    [m, n] bytes` (`linear`: 1-D in and out, see `stacked`) supplies the
-    device kernel for a fixed GF(2^8) matrix C.
+    `matrix_apply_factory(C) -> callable([k, n] bytes, linear=False,
+    stripes=0) -> [m, n] bytes` (`linear`: 1-D in and out, `stripes` rows
+    of a `.dat` in and m runs out, see `stacked` and `unstacked`) supplies
+    the device kernel for a fixed GF(2^8) matrix C.
     """
 
     def __init__(self, code, matrix_apply_factory):
@@ -130,6 +158,13 @@ class RSCodecBase:
     def encode_parity(self, data: jax.Array) -> jax.Array:
         """[k, n] data -> [m, n] parity (systematic: data shards unchanged)."""
         return self._parity(data)
+
+    def encode_parity_linear(self, spans, stripes: int) -> tuple:
+        """`stripes` stripe rows of a `.dat` as 1-D arrays (`stacked`'s
+        third form) -> their parity as m arrays of [W], W the rows' bytes
+        of one shard (`unstacked`): the layout, the parity apply and the
+        split are one program, and only 1-D arrays cross."""
+        return self._parity(spans, True, stripes)
 
     def encode_parity_batch(self, units: jax.Array) -> jax.Array:
         """[U, k, n] unit batch -> [U, m, n] parity in ONE device dispatch

@@ -293,6 +293,11 @@ def _build(tag: str, backend: str):
     programs are cached on it."""
     spec = parse_tag(tag)
     codec = _shell(_code_for(spec), backend)
+    if backend in ("pallas", "xla", "mesh", "fleet"):
+        import jax
+        if jax.default_backend() != "cpu":  # results are copied back
+            from seaweedfs_tpu.ops import dispatch
+            dispatch.keep_freed_pages()
     if spec.family == "msr":
         from seaweedfs_tpu.ops import msr
         codec = msr.MSRFileCodec(codec)

@@ -23,7 +23,9 @@ engine's job and named `codec.<stage>` on spans and profiler annotations:
                compilation); a host codec's whole computation
   device_wait  blocked in `block_until_ready`: the queued transfers and
                the kernel
-  d2h_copy     `np.asarray` once the array is ready
+  d2h_copy     `np.asarray` once the array is ready (what is left of the
+               copy where it was asked for at the enqueue: an encode
+               unit's parity runs)
 
 No synchronisation is added for the sake of measurement: a
 `block_until_ready` stands only directly before a copy of the same array,
@@ -92,6 +94,29 @@ def _to_host(arrays: list, job, unit, kernel: str) -> list[np.ndarray]:
     return host
 
 
+def keep_freed_pages() -> None:
+    """Have the host allocator (glibc) keep the pages of freed buffers of
+    up to 32 MiB instead of handing each one out fresh.  The runtime
+    allocates the destination of every copy back (no handle in the public
+    API), 16 MiB at a time in the bulk engines, and by default whether
+    that is memory touched before or a new mapping, whose 4,096 pages are
+    then faulted in one by one while sixteen other threads map, unmap and
+    write, is a state a process falls into for good: TPU v5e,
+    `ecvol.encode`, 3.69-4.03 GB/s in nine runs and 2.21 / 2.28 in two,
+    with every host stage two to five times as long; 1.82 with every
+    buffer forced fresh (`MALLOC_MMAP_THRESHOLD_=1048576`), 3.62-4.01 in
+    sixteen of sixteen runs with freed memory kept (PERF.md, PR 31).  Called once a
+    device shell is built on a platform that copies back (ops/codecs); the
+    price is that up to 1 GiB of freed heap stays resident."""
+    import ctypes
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):  # another libc: nothing to set
+        return
+    mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD: its maximum, and no longer dynamic
+    mallopt(-1, 1 << 30)   # M_TRIM_THRESHOLD
+
+
 def _host_classes():
     from seaweedfs_tpu.models.rs import RSCode
     from seaweedfs_tpu.ops.native_codec import NativeRSCodec
@@ -149,10 +174,62 @@ def describe(codec, jax_live: bool = False) -> dict:
     return info
 
 
+def _unstriped(spans, k: int, stripes: int) -> np.ndarray:
+    """The [k, W] host array of a unit that came as spans of a `.dat`
+    (`codec_base.stacked`'s third form, on the host): a copy, for a codec
+    that has no linear apply to lay the unit out on the device."""
+    flat = spans[0] if len(spans) == 1 else np.concatenate(spans)
+    return np.ascontiguousarray(
+        flat.reshape(stripes, k, -1).transpose(1, 0, 2)).reshape(k, -1)
+
+
 @codec_entry("encode_parity")
-def dispatch_parity(codec, batch: np.ndarray, job=None, unit=None):
-    """Dispatch [k, B] -> [m, B] parity. JAX backends return the device
-    array WITHOUT materialising it; host backends compute eagerly."""
+def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
+    """Dispatch the parity of one unit, [k, B] -> [m, B].  JAX backends
+    return device arrays WITHOUT materialising them (`materialize` is the
+    sync point); host backends compute eagerly.
+
+    `batch` is a `[k, B]` host array (the scrubber's window, regen, a
+    test), which goes up and comes back as it is, 2-D; or the unit as the
+    encode engine selects it in the `.dat`'s map: a sequence of 1-D spans
+    that hold, one after the other, `stripes` >= 1 stripe rows of k blocks
+    each (`codec_base.stacked`'s third form; B = `stripes` blocks).  For a
+    codec with a linear apply (`encode_parity_linear`: the Pallas and XLA
+    shells) the spans are put as 1-D arrays from where they lie, the
+    program lays them out, and the parity comes back as m arrays of `[B]`,
+    one contiguous run of each parity shard (`codec_base.unstacked`): no
+    byte is copied on the host and nothing 2-D crosses.  A span of several
+    rows goes up row by row where a row is at least `ROW_PUTS_FROM` (TPU
+    v5e, a 160 MiB unit: 27.7 ms in one array, 14.5 as its sixteen 10 MiB
+    rows; PERF.md, PR 31), else as one array.  The runtime reads a span
+    after its put returns, so the spans stay alive and unchanged until the
+    result is materialised (a sealed `.dat`'s map, or the engine's staged
+    last row, held by the unit's queue item).  Every other codec (a host
+    shell, the bare numpy reference, `MSRFileCodec`, the column-sharded
+    mesh encoder) gets its `[k, B]` array built from the spans on the host
+    (`_unstriped`), which the job counts as `rows_staged`."""
+    if not (isinstance(batch, np.ndarray) and batch.ndim == 2):
+        spans = list(batch)
+        if hasattr(codec, "encode_parity_linear"):  # a device shell
+            nbytes = sum(s.nbytes for s in spans)
+            row = nbytes // stripes
+            if row >= ROW_PUTS_FROM:
+                spans = [s[o:o + row] for s in spans
+                         for o in range(0, len(s), row)]
+            import jax.numpy as jnp
+
+            def run(placed):
+                runs = codec.encode_parity_linear(placed, stripes)
+                for a in runs:  # see materialize
+                    a.copy_to_host_async()
+                return runs
+            return _device_call(
+                job, unit, "encode_parity", nbytes,
+                lambda: tuple(jnp.asarray(s) for s in spans), run,
+                stripes=stripes)
+        batch = _unstriped(spans, codec.k, stripes)
+        if job is not None and (stripes > 1 or len(spans) > 1):
+            job.count("rows_staged", stripes)
     nbytes = batch.nbytes
     if _is_host(codec):
         return _host_call(job, unit, "encode_parity", nbytes,
@@ -167,12 +244,21 @@ def dispatch_parity(codec, batch: np.ndarray, job=None, unit=None):
 
 
 def materialize(parity, kernel: str = "encode_parity", job=None,
-                unit=None) -> np.ndarray:
+                unit=None):
     """Sync point of an async dispatch: host backends already returned
     numpy; device arrays are waited for and then copied back here, both
-    attributed to `kernel`."""
+    attributed to `kernel`.  What comes back has the dispatch's form, and
+    either way its i-th item is parity row i: the m `[B]` runs of a unit
+    that went up as spans, an `[m, B]` array otherwise.  The runs' copies
+    back were asked for when the unit was enqueued, all m at once, so they
+    follow the program on the device's queue, ahead of the puts of the
+    units behind it, and `d2h_copy` here is what is left of them (TPU v5e,
+    `ecvol.encode`: 2.94 GB/s asked for here, before the wait, 3.75-4.03
+    at the enqueue, 1.74 one by one after the wait; PERF.md, PR 31)."""
     if isinstance(parity, np.ndarray):
         return parity
+    if isinstance(parity, tuple):
+        return _to_host(list(parity), job, unit, kernel)
     return _to_host([parity], job, unit, kernel)[0]
 
 

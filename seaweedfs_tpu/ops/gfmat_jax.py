@@ -66,15 +66,15 @@ def bitsliced_apply_body(bitmat: jax.Array, data: jax.Array) -> jax.Array:
     return pack_bits(ybits)
 
 
-@functools.partial(jax.jit, static_argnames="linear")
-def _bitsliced_apply(bitmat: jax.Array, data, linear: bool = False
-                     ) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("linear", "stripes"))
+def _bitsliced_apply(bitmat: jax.Array, data, linear: bool = False,
+                     stripes: int = 0):
     """`linear`: 1-D in and out, laid out in this program
-    (codec_base.stacked)."""
+    (codec_base.stacked and unstacked; `stripes` rows of a `.dat`)."""
     if linear:
-        data = codec_base.stacked(data, bitmat.shape[1] // 8)
+        data = codec_base.stacked(data, bitmat.shape[1] // 8, stripes)
     out = bitsliced_apply_body(bitmat, data)
-    return out.reshape(-1) if linear else out
+    return codec_base.unstacked(out, stripes) if linear else out
 
 
 def bitsliced_apply_batch_body(bitmat: jax.Array, data: jax.Array
@@ -100,9 +100,10 @@ class JaxGFMatrix:
         self.bitmat = jnp.asarray(
             gf.gf_matrix_to_bitmatrix(self.C).astype(np.int8))
 
-    def __call__(self, data, linear: bool = False) -> jax.Array:
+    def __call__(self, data, linear: bool = False,
+                 stripes: int = 0) -> jax.Array:
         """data [k, n] uint8 -> [m, n] uint8 product over GF(2^8)."""
-        return _bitsliced_apply(self.bitmat, data, linear)
+        return _bitsliced_apply(self.bitmat, data, linear, stripes)
 
     def apply_batch(self, data: jax.Array) -> jax.Array:
         """data [U, k, n] -> [U, m, n] in one dispatch."""

@@ -106,13 +106,21 @@ GF_APPLY_BATCH = "_gf_apply_batch"
 
 
 @codec_base.named_jit(GF_APPLY, static_argnames=("k", "m", "kpad", "tile",
-                                                 "interpret", "linear"))
+                                                 "interpret", "linear",
+                                                 "stripes"))
 def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
-              tile: int, interpret: bool, linear: bool = False) -> jax.Array:
+              tile: int, interpret: bool, linear: bool = False,
+              stripes: int = 0):
     """`linear`: 1-D in and out, laid out in this program
-    (codec_base.stacked)."""
+    (codec_base.stacked and unstacked; `stripes` rows of a `.dat`, whose
+    width need be no tile multiple: the pad and the cut are in this
+    program too)."""
+    cut = None
     if linear:
-        data = codec_base.stacked(data, k)
+        data = codec_base.stacked(data, k, stripes)
+        if data.shape[1] % tile:
+            cut = data.shape[1]
+            data = jnp.pad(data, ((0, 0), (0, -cut % tile)))
     _, n = data.shape
     assert n % tile == 0, (n, tile)
     kernel = functools.partial(_gf_apply_kernel, k=k, m=m, kpad=kpad)
@@ -133,7 +141,9 @@ def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
         interpret=interpret,
         name=GF_APPLY,
     )(bitmat, data)
-    return out.reshape(-1) if linear else out
+    if cut is not None:
+        out = out[:, :cut]
+    return codec_base.unstacked(out, stripes) if linear else out
 
 
 def _gf_apply_batch_kernel(bitmat_ref, x_ref, o_ref, *, k: int, m: int,
@@ -207,10 +217,11 @@ class PallasGFMatrix:
         self.bitmat = jnp.asarray(gf_matrix_to_bitmatrix_planemajor(
             self.C, self.kpad).astype(np.int8))
 
-    def __call__(self, data, linear: bool = False) -> jax.Array:
-        if linear:  # the reconstruct seam's: a tile multiple wide already
+    def __call__(self, data, linear: bool = False,
+                 stripes: int = 0) -> jax.Array:
+        if linear:  # the seams': laid out, and padded where need be, inside
             return _gf_apply(self.bitmat, data, self.k, self.m, self.kpad,
-                             self.tile, self.interpret, True)
+                             self.tile, self.interpret, True, stripes)
         k, n = data.shape
         assert k == self.k, (k, self.k)
         pad = (-n) % self.tile

@@ -1,12 +1,13 @@
 """EC shard file generation / rebuild / decode — the TPU data plane.
 
 The reference streams 10x256KB buffers through a CPU SIMD encoder
-(weed/storage/erasure_coding/ec_encoder.go:120-235). Here each batch is a
-[10, B] uint8 matrix shipped to the device once and erasure-coded by the
-bit-sliced MXU codec; B defaults to 16MB per shard (160MB per batch) so the
-kernel runs deep in its throughput regime and host<->device transfers
-amortise. Data shards are written straight from the host buffer — only
-parity ([4, B]) comes back from the device.
+(weed/storage/erasure_coding/ec_encoder.go:120-235). Here a unit is a span
+of the .dat's map, B = 16MB per shard (160MB under RS(10,4): sixteen 1MB
+stripe rows), put to the device from where it lies and erasure-coded by the
+bit-sliced MXU codec, so the kernel runs deep in its throughput regime and
+host<->device transfers amortise. Data shards are copied in the kernel
+(copy_file_range) — only parity (four runs of [B], one a parity shard)
+comes back from the device.
 
 Functions mirror the reference's capability surface:
   write_ec_files      <- WriteEcFiles (ec_encoder.go:56)
@@ -206,6 +207,61 @@ def _iter_units(dat_size: int, large_block: int, small_block: int,
         shard_base += small_block
 
 
+def _iter_spans(dat_size: int, large_block: int, small_block: int,
+                batch_size: int, data_shards: int = layout.DATA_SHARDS):
+    """_iter_units' units as a device codec is handed them: yield
+    (row_start, block, col, step, shard_off, rows).  Consecutive whole
+    rows of one block size (step == block) are contiguous in the .dat
+    (striping is row-major) and in every shard file, so up to
+    batch_size // block of them are one unit: the span
+    dat[row_start : row_start + rows * k * block], rows * block bytes of
+    each shard at shard_off.  A block wider than batch_size stays cut in
+    columns, one unit each (rows == 1, k spans of step bytes)."""
+    run = None
+    for row_start, block, col, step, shard_off in _iter_units(
+            dat_size, large_block, small_block, batch_size, data_shards):
+        if (run is not None and step == block == run[1]
+                and run[5] < batch_size // block):
+            run[5] += 1
+            continue
+        if run is not None:
+            yield tuple(run)
+        run = [row_start, block, col, step, shard_off, 1]
+    if run is not None:
+        yield tuple(run)
+
+
+def _unit_spans(dat_view: np.ndarray, dat_size: int, k: int, row_start: int,
+                block: int, col: int, step: int, rows: int):
+    """-> (spans, staged): one of _iter_spans' units as 1-D views of the
+    .dat's map that hold, one after the other, `rows` stripe rows of k
+    blocks of `step` bytes.  No byte moves but for a row that runs past
+    the end of the .dat (the volume's last): that one is copied into a
+    zeroed buffer of its own (`staged` 1, else 0), since the parity of a
+    short row is the parity of the row with zeros after it.  A unit that
+    holds no data at all gives no spans."""
+    if step == block:  # whole rows: one run of the map
+        row = k * block
+        full = min(rows, (dat_size - row_start) // row)
+        spans = [dat_view[row_start:row_start + full * row]] if full else []
+        if full == rows:
+            return spans, 0
+        offs = [row_start + full * row]  # the one row past the end
+        width = row
+    else:  # a column cut of one row: k runs, `block` apart
+        offs = [row_start + j * block + col for j in range(k)]
+        if offs[-1] + step <= dat_size:
+            return [dat_view[o:o + step] for o in offs], 0
+        if offs[0] >= dat_size:
+            return [], 0
+        spans, width = [], step
+    tail = np.zeros(len(offs) * width, dtype=np.uint8)
+    for i, off in enumerate(offs):
+        src = dat_view[off:max(off, min(off + width, dat_size))]
+        tail[i * width:i * width + len(src)] = src
+    return spans + [tail], 1
+
+
 class EncodeCancelled(RuntimeError):
     pass
 
@@ -308,10 +364,13 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
         thread straight off an mmap of the .dat via per-row pointers (no
         staging copy), data shards move by in-kernel copy_file_range on
         their writers, and parity rides a small buffer ring.
-      - device codecs (Pallas/XLA/mesh/numpy): reads stage from the mmap
-        into pooled buffers (no per-batch allocation), JAX dispatch is
-        async so the device round-trip overlaps host I/O, and only parity
-        rides the device.
+      - device codecs (Pallas/XLA/mesh/numpy): a unit is a span of the
+        mmap, up to batch_size bytes of every shard (_iter_spans: sixteen
+        1 MiB stripe rows at the served sizes), selected as views and put
+        from where it lies (no staging copy either: only the volume's
+        last, short row is copied, `rows_staged`); JAX dispatch is async
+        so the device round-trip overlaps host I/O, and only parity
+        rides the device, one contiguous run a shard a unit.
 
     Rows wholly beyond the .dat are never read, encoded, or written: the
     parity of an all-zero row region is zero, so those regions become
@@ -322,6 +381,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     # renders, so every encode is observable
     stats = stats if stats is not None else {}
     stats["bytes"] = dat_size
+    stats["rows_staged"] = 0  # stripe rows copied on the host (job.count)
     shard_size = layout.shard_file_size(dat_size, large_block, small_block,
                                         data_shards=codec.k)
     highwater = [0] * (codec.k + codec.m)
@@ -725,6 +785,7 @@ def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
             tailbuf[:tail] = rows[nz - 1][:tail]
             tailbuf[tail:step] = 0
             rows[nz - 1] = tailbuf
+            pjob.count("rows_staged", 1)
         code = codec.code
         mat = code.parity_matrix if nz == code.k else \
             np.ascontiguousarray(code.parity_matrix[:, :nz])
@@ -746,38 +807,51 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     slow stage backpressures the ones before it instead of buffering the
     volume:
 
-      reader   walks the unit iterator for stripe N+1; data shards go to
+      reader   walks the unit iterator for unit N+1; data shards go to
                their shard writers by in-kernel copy_file_range on the
                way (they never round-trip the device).  For DEVICE
-               codecs it also stages the stripe from the mmap into a
-               pooled buffer (`read`; waiting for one is `stall`) — the
-               device needs a stable host buffer to transfer from.  HOST
-               codecs skip the staging copy entirely: the dispatch stage
-               encodes straight off the mmap.
-      dispatch (caller's thread) launches the parity matmul for stripe N
-               — asynchronous on JAX backends (the seam's `h2d` and
+               codecs a unit is a span of the .dat's map (_iter_spans:
+               up to batch_size // block consecutive stripe rows, which
+               lie one after the other in the .dat and in every shard
+               file), and `read` is its selection as views
+               (_unit_spans): no byte moves but the volume's last, short
+               row, copied into a zeroed buffer and counted
+               (`rows_staged`, 0 or 1 a call).  At most PIPELINE_DEPTH
+               units are between selection and materialised parity;
+               waiting for a slot is `stall`.  HOST codecs walk
+               _iter_units' units and the dispatch stage encodes them
+               straight off the mmap.
+      dispatch (caller's thread) launches the parity matmul for unit N
+               — asynchronous on JAX backends (the seam's `h2d`: the
+               spans put as 1-D arrays from where they lie, and
                `dispatch`, which add up to `encode`), eager (ptr-matmul
                off the mmap into a pooled parity ring: `encode`) for
                native host codecs
-      drain    materialises stripe N-1's parity (the seam's `device_wait`
+      drain    materialises unit N-1's parity (the seam's `device_wait`
                and `d2h_copy`, which add up to `d2h`: the device sync
-               point) and fans its m rows out to the shard writers
-      writers  striped pwrite workers over the 14 shard fds
+               point), m runs of [W], and hands each parity shard's
+               writer its own at shard_off: m writes a unit
+      writers  striped pwrite workers over the shard fds
                (_ShardWriterPool), so parity files land concurrently
                instead of serially
 
-    A batch buffer returns to the pool as soon as its parity is
-    materialised — until then the device may still be reading the
-    (possibly zero-copy-aliased on CPU backends) host memory.  Parity
-    rows are views into the materialised array, kept alive by the writer
-    queue items (host-codec parity rides a countdown-released ring
-    instead)."""
+    A unit's slot frees as soon as its parity is materialised — until
+    then the device may still be reading the (possibly
+    zero-copy-aliased on CPU backends) host memory, so the unit's spans
+    ride its queue item that long; the map itself outlives the call and
+    a sealed .dat does not change.  Parity runs are the materialised
+    arrays, kept alive by the writer queue items (host-codec
+    parity rides a countdown-released ring instead).  `progress` and
+    `cancel` are called once a unit."""
     from seaweedfs_tpu.ops.native_codec import NativeRSCodec
     native_host = isinstance(codec, NativeRSCodec)
     k, m = codec.k, codec.m
     min_step, max_step = _unit_steps(dat_size, large_block, small_block,
                                      batch_size, data_shards=k)
+    # native host codec: the parity ring; device codecs hold no buffer,
+    # `slots` bounds their units in flight
     pool: queue.Queue = queue.Queue()
+    slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
     if native_host:
         tailbuf = np.zeros(max_step, dtype=np.uint8)
         # the parity ring: the drain batches small units through a
@@ -786,12 +860,10 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
         # unflushed flush group on top of the pipeline's own depth
         for _ in range(PIPELINE_DEPTH + max(1, FLUSH_BYTES // max_step)):
             pool.put(np.empty((m, max_step), dtype=np.uint8))
-    else:
-        for _ in range(PIPELINE_DEPTH):
-            pool.put(np.empty((k, max_step), dtype=np.uint8))
     q_read: queue.Queue = queue.Queue(maxsize=PIPELINE_DEPTH)
     # q_disp is unbounded: it carries at most one entry per in-flight
-    # pooled buffer (the pool is the real backpressure) plus FLUSH nudges
+    # unit (the slots / the ring are the real backpressure) plus FLUSH
+    # nudges
     q_disp: queue.Queue = queue.Queue()
     # dispatch sends this when it runs dry on parity buffers: the drain's
     # flusher may be sitting on the very jobs whose releases would refill
@@ -806,43 +878,46 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     def reader() -> None:
         nonlocal done
         flusher = _ShardFlusher(writers, k)  # data shards only
+        geometry = (dat_size, large_block, small_block, batch_size, k)
+        units = _iter_spans(*geometry) if not native_host else (
+            u + (1,) for u in _iter_units(*geometry))
         try:
-            for unit, (row_start, block, col, step, shard_off) in enumerate(
-                    _iter_units(dat_size, large_block, small_block,
-                                batch_size, data_shards=k)):
+            for unit, (row_start, block, col, step, shard_off,
+                       rows) in enumerate(units):
                 if errors or writers.failed:  # downstream died: stop
                     break
                 if cancel is not None and cancel():
                     raise EncodeCancelled("ec encode cancelled")
-                nz, tail = _unit_coverage(dat_size, row_start, block, col,
-                                          step, data_shards=k)
-                if nz == 0:
+                covered = 0
+                for r in range(rows):
+                    nz, tail = _unit_coverage(
+                        dat_size, row_start + r * k * block, block, col,
+                        step, data_shards=k)
+                    for j in range(nz):
+                        off = row_start + (r * k + j) * block + col
+                        flusher.copy(j, dat_fd, off, shard_off + r * step,
+                                     step if j < nz - 1 else tail,
+                                     src_view=dat_view)
+                    if nz:
+                        covered += (nz - 1) * step + tail
+                        flusher.account(step)
+                if not covered:
                     continue
-                for j in range(nz):
-                    off = row_start + j * block + col
-                    n = step if j < nz - 1 else tail
-                    flusher.copy(j, dat_fd, off, shard_off, n,
-                                 src_view=dat_view)
                 if native_host:
                     # zero-copy: dispatch encodes off the mmap directly
                     q_read.put((unit, None, step, shard_off,
                                 (row_start, block, col, nz, tail)))
                 else:
                     with pjob.blocked("stall", unit=unit):
-                        buf = pool.get()
+                        slots.acquire()
                     with pjob.stage("read", unit=unit):
-                        batch = buf[:, :step]
-                        for j in range(k):
-                            off = row_start + j * block + col
-                            n = max(0, min(step, dat_size - off))
-                            if n > 0:
-                                np.copyto(batch[j, :n],
-                                          dat_view[off:off + n])
-                            if n < step:
-                                batch[j, max(n, 0):] = 0
-                    q_read.put((unit, buf, step, shard_off, None))
-                done += (nz - 1) * step + tail
-                flusher.account(step)
+                        spans, staged = _unit_spans(
+                            dat_view, dat_size, k, row_start, block, col,
+                            step, rows)
+                        if staged:
+                            pjob.count("rows_staged", staged)
+                    q_read.put((unit, spans, rows * step, shard_off, rows))
+                done += covered
                 if progress is not None:
                     progress(done)
             flusher.flush()
@@ -867,13 +942,13 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
             if item is FLUSH:
                 flusher.flush()
                 continue
-            unit, buf, step, shard_off, parity, release = item
+            unit, spans, step, shard_off, parity, release = item
             if failed or errors or writers.failed:
                 if release is not None:
                     for _ in range(m):
                         release()
-                elif buf is not None:
-                    pool.put(buf)
+                else:
+                    slots.release()
                 continue
             if release is not None:  # host parity: already materialised
                 for i in range(m):
@@ -886,11 +961,14 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
             except BaseException as e:
                 errors.append(e)
                 failed = True  # keep draining so nothing deadlocks
-                pool.put(buf)
                 continue
-            pool.put(buf)  # device is done with the host memory now
-            for i in range(pnp.shape[0]):
-                flusher.put(k + i, pnp[i, :step], shard_off)
+            finally:
+                del spans, item  # the device is done with the host memory
+                slots.release()
+            # m runs from a linear apply, the rows of [m, step] from any
+            # other
+            for i, run in enumerate(pnp):
+                flusher.put(k + i, run[:step], shard_off)
             flusher.account(step)
 
     t_r = threading.Thread(target=reader, name="ec-reader", daemon=True)
@@ -904,13 +982,14 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 break
             # stage-queue depth at the consume site
             pjob.queue("q_read", q_read.qsize(), PIPELINE_DEPTH)
-            unit, buf, step, shard_off, coverage = item
+            # geom: a host unit's coverage, a device unit's stripe rows
+            unit, spans, step, shard_off, geom = item
             if errors or writers.failed:  # stop dispatching, surface below
-                if buf is not None:
-                    pool.put(buf)
+                if spans is not None:
+                    slots.release()
                 continue
             if native_host:
-                row_start, block, col, nz, tail = coverage
+                row_start, block, col, nz, tail = geom
                 try:
                     pbuf = pool.get_nowait()
                 except queue.Empty:
@@ -922,19 +1001,25 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 release = _countdown(m, lambda b=pbuf: pool.put(b))
                 q_disp.put((unit, None, step, shard_off, pbuf, release))
             else:
-                parity = _dispatch_parity(codec, buf[:, :step], job=pjob,
-                                          unit=unit)
-                q_disp.put((unit, buf, step, shard_off, parity, None))
+                try:
+                    parity = _dispatch_parity(codec, spans, job=pjob,
+                                              unit=unit, stripes=geom)
+                except BaseException as e:
+                    errors.append(e)  # the reader stops at its next unit
+                    slots.release()
+                    raise
+                q_disp.put((unit, spans, step, shard_off, parity, None))
+            del item, spans  # the queue item alone holds a unit's views
     finally:
         q_disp.put(None)
         t_d.join()
-        while t_r.is_alive():  # unblock a reader stuck on a full q_read
+        while t_r.is_alive():  # unblock a reader stuck on q_read or a slot
             try:
                 item = q_read.get(timeout=0.05)
             except queue.Empty:
                 continue
             if item is not None and item[1] is not None:
-                pool.put(item[1])  # keep the pool whole or the reader starves
+                slots.release()
         t_r.join()
         writers.close()  # after the producers: no submission can block now
     if errors:

@@ -61,6 +61,34 @@ def test_tag_grammar_and_degradation(monkeypatch):
     assert codecs.default_tag() == "msr_9_16"
 
 
+@pytest.mark.parametrize("platform, backend, wanted", [
+    ("tpu", "xla", [(-3, 32 << 20), (-1, 1 << 30)]),
+    ("cpu", "xla", []),       # nothing is copied back on the CPU backend
+    ("tpu", "numpy", []),     # a host shell copies nothing back either
+])
+def test_a_device_shell_keeps_freed_result_pages(monkeypatch, platform,
+                                                 backend, wanted):
+    """Building a device shell on a platform that copies results back
+    sets glibc's M_MMAP_THRESHOLD and M_TRIM_THRESHOLD once
+    (ops/dispatch.keep_freed_pages), so the runtime's 16 MiB result
+    buffers come back as touched memory; no other build does."""
+    import ctypes
+
+    import jax
+    calls: list[tuple[int, int]] = []
+    libc = types.SimpleNamespace(
+        mallopt=lambda param, value: calls.append((param, value)) or 1)
+    monkeypatch.setattr(ctypes, "CDLL", lambda name: libc)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    codecs._build.cache_clear()
+    try:
+        codecs._build("rs_10_4", backend)
+        codecs._build("rs_10_4", backend)  # cached: not set again
+    finally:
+        codecs._build.cache_clear()
+    assert calls == wanted
+
+
 def test_registry_lists_every_family():
     tags = {s.family for s in codecs.registered()}
     assert tags == {"rs", "lrc", "msr"}

@@ -269,6 +269,157 @@ def test_rebuild_stats_report_overlap(small_volume):
     assert "wall_s" in stats
 
 
+# ---- the encode unit: a span of the .dat's map --------------------------
+
+def _lrc_reference(data):
+    from seaweedfs_tpu.models import lrc
+    return lrc.encode(data)
+
+
+def _rs_reference(data):
+    from seaweedfs_tpu.models import rs
+    return rs.get_code(10, 4).encode_numpy(data)
+
+
+UNIT_TAGS = {"rs_10_4": (10, 14, _rs_reference),
+             "lrc_12_2_2": (12, 16, _lrc_reference)}
+UNIT_TILE = 256
+# (large, small, batch, .dat bytes as a function of k, units, rows_staged):
+# a unit is batch // block consecutive whole rows (R = 4 small rows but
+# where stated), one dispatch each
+UNIT_CASES = {
+    "dat_shorter_than_one_row":
+        (4096, 256, 1024, lambda k: 300, 1, 1),
+    "exactly_R_rows":
+        (4096, 256, 1024, lambda k: 4 * k * 256, 1, 0),
+    "R_rows_and_a_partial_last_row":
+        (4096, 256, 1024, lambda k: 4 * k * 256 + 77, 2, 1),
+    "last_unit_of_fewer_than_R_rows":
+        (4096, 256, 1024, lambda k: 6 * k * 256, 2, 0),
+    # three large rows, two to a unit, then one small row and a partial
+    "large_rows_then_small_rows":
+        (512, 256, 1024, lambda k: 3 * k * 512 + k * 256 + 9, 3, 1),
+    # R * small = 400 bytes: the Pallas shell pads to 512 in its program
+    "small_block_no_tile_multiple":
+        (10000, 100, 400, lambda k: 5 * k * 100 + 1, 2, 1),
+    # a block wider than the batch stays cut in columns: 2 rows x 2 cuts
+    "block_wider_than_the_batch":
+        (4096, 256, 128, lambda k: 2 * k * 256, 4, 0),
+}
+
+
+def _unit_reference(raw: bytes, k: int, large: int, small: int, encode):
+    """The shard files `raw` must encode to: upstream's row-major
+    striping k wide (large rows while more than one large row's bytes
+    remain, then small rows, the last zero-padded), by hand, under the
+    plain reference's generator."""
+    files = [bytearray() for _ in range(k)]
+    at = 0
+    while len(raw) - at > k * large:
+        for j in range(k):
+            files[j] += raw[at:at + large]
+            at += large
+    while at < len(raw):
+        for j in range(k):
+            files[j] += raw[at:at + small].ljust(small, b"\0")
+            at += small
+    return encode(np.array([np.frombuffer(bytes(f), dtype=np.uint8)
+                            for f in files]))
+
+
+def _use_codec(monkeypatch, kind: str, tag: str) -> None:
+    """`jax`: the XLA shell, as resolved; `pallas_interpret`: the fused
+    kernel under the Pallas interpreter at a 256-byte tile."""
+    if kind == "jax":
+        monkeypatch.setenv("WEEDTPU_EC_CODEC", "jax")
+        return
+    from seaweedfs_tpu.ops import codecs, pallas_gf
+    codec = pallas_gf.PallasRSCodec(
+        codecs._code_for(codecs.parse_tag(tag)), tile=UNIT_TILE,
+        interpret=True)
+    monkeypatch.setattr(ec_files, "_get_codec", lambda *a, **kw: codec)
+
+
+@pytest.mark.parametrize("case", list(UNIT_CASES))
+@pytest.mark.parametrize("kind", ["jax", "pallas_interpret"])
+@pytest.mark.parametrize("tag", list(UNIT_TAGS))
+def test_encode_unit_is_a_span_of_the_dat_map(tmp_path, monkeypatch, tag,
+                                              kind, case):
+    """A device codec is handed the .dat by spans of its map, up to
+    batch // block stripe rows at once: the shard set is the plain
+    reference's byte for byte, one dispatch a unit, and no row is copied
+    on the host but a last one that runs past the end of the .dat."""
+    k, n, encode = UNIT_TAGS[tag]
+    large, small, batch, size, units, staged = UNIT_CASES[case]
+    raw = np.random.default_rng(31).bytes(size(k))
+    base = str(tmp_path / "9")
+    with open(base + ".dat", "wb") as f:
+        f.write(raw)
+    _use_codec(monkeypatch, kind, tag)
+    seen: list[int] = []
+    real = ec_files._dispatch_parity
+
+    def spy(codec, spans, job=None, unit=None, stripes=0):
+        # 1-D spans that hold `stripes` rows of k blocks, nothing 2-D
+        assert all(s.ndim == 1 and s.dtype == np.uint8 for s in spans)
+        block, rest = divmod(sum(s.nbytes for s in spans), stripes * k)
+        assert rest == 0 and block in (small, large, batch), (block, rest)
+        seen.append(stripes)
+        return real(codec, spans, job=job, unit=unit, stripes=stripes)
+    monkeypatch.setattr(ec_files, "_dispatch_parity", spy)
+    stats: dict = {}
+    ec_files.write_ec_files(base, large_block=large, small_block=small,
+                            batch_size=batch, stats=stats, codec_tag=tag)
+    want = _unit_reference(raw, k, large, small, encode)
+    assert want.shape[0] == n
+    for i in range(n):
+        with open(base + layout.to_ext(i), "rb") as f:
+            assert f.read() == want[i].tobytes(), f"shard file {i}"
+    assert not os.path.exists(base + layout.to_ext(n))
+    assert len(seen) == units, seen
+    assert max(seen) <= max(1, batch // small, batch // large)
+    assert stats["rows_staged"] == staged
+    assert {"read_s", "stall_s", "h2d_s", "device_wait_s",
+            "d2h_copy_s"} <= set(stats), sorted(stats)
+    assert stats["mode"] == "pipelined"
+
+
+@pytest.mark.parametrize("kind", ["jax", "numpy"])
+def test_cancel_between_units_leaves_the_previous_shard_set(tmp_path,
+                                                            monkeypatch,
+                                                            kind):
+    """`cancel()` is asked once a unit: a generate cancelled after its
+    first unit raises, leaves no `.tmp` behind and every file of the set
+    that was there as it was."""
+    monkeypatch.setenv("WEEDTPU_EC_CODEC", kind)
+    base = str(tmp_path / "4")
+    rng = np.random.default_rng(4)
+    with open(base + ".dat", "wb") as f:
+        f.write(rng.bytes(9 * 10 * 256 + 5))  # ten rows: three units of 4
+    ec_files.write_ec_files(base, large_block=8192, small_block=256,
+                            batch_size=1024)
+    before = {i: open(base + layout.to_ext(i), "rb").read()
+              for i in range(layout.TOTAL_SHARDS)}
+    with open(base + ".dat", "wb") as f:  # another volume's bytes
+        f.write(rng.bytes(9 * 10 * 256 + 5))
+    asked: list[int] = []
+    progressed: list[int] = []
+
+    def cancel() -> bool:
+        asked.append(len(progressed))
+        return len(progressed) >= 1
+
+    with pytest.raises(ec_files.EncodeCancelled):
+        ec_files.write_ec_files(base, large_block=8192, small_block=256,
+                                batch_size=1024, cancel=cancel,
+                                progress=progressed.append)
+    assert asked == [0, 1]  # before each unit, not each row
+    assert progressed == [4 * 10 * 256]
+    assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
+    for i, was in before.items():
+        assert open(base + layout.to_ext(i), "rb").read() == was, i
+
+
 # ---- golden fixture ---------------------------------------------------
 
 @pytest.mark.skipif(reference_fixture("weed/storage/erasure_coding/1.dat") is None,

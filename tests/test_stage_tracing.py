@@ -267,6 +267,32 @@ def test_sum_identities_and_every_old_key(kind, tmp_path):
             max(0.0, 1.0 - stats["wall_s"] / stage_sum), 3)
 
 
+def test_encode_stages_are_entered_once_a_unit(tmp_path):
+    """A device codec's encode unit is a span of the .dat's map
+    (ec_files._iter_spans): `read` (the selection, and the copy of a last
+    short row), `stall` (the wait for one of PIPELINE_DEPTH slots) and the
+    seam's four stages are entered once a unit, not once a row, and
+    `rows_staged` is on the job's stats in every call."""
+    stats, _ = prepare("encode", tmp_path)()
+    # 230,000 bytes: two large rows of 10 x 10,000, each cut in ten
+    # columns of BATCH bytes, then thirty whole small rows, ten to a unit
+    units = 2 * (LARGE // BATCH) + 30 // (BATCH // SMALL)
+    stages = _last_job("ec_encode")["stages"]
+    for name in ("read", "h2d", "dispatch", "device_wait", "d2h_copy"):
+        assert stages[name]["items"] == units, (name, stages[name])
+    assert stats["rows_staged"] == 0
+    assert {"read_s", "stall_s"} <= set(stats)
+    # one short row after them: one more unit, and the one staged row
+    base = str(tmp_path / "1")
+    with open(base + ".dat", "ab") as f:
+        f.write(b"x" * 37)
+    stats = {}
+    ec_files.write_ec_files(base, large_block=LARGE, small_block=SMALL,
+                            batch_size=BATCH, stats=stats)
+    assert stats["rows_staged"] == 1
+    assert _last_job("ec_encode")["stages"]["read"]["items"] == units + 1
+
+
 def test_reconstruct_books_device_seconds():
     """PERF.md's verdict table (PR 23): `reconstruct` had no device row."""
     codec = ec_files._get_codec("jax")

@@ -80,6 +80,11 @@ def _lifted(C, sharding):
     return _spec(bm.shape, bm.dtype, sharding), seam._kpad(C.shape[1])
 
 
+# rows of a wide encode unit: sixteen 1 MiB stripe rows, DEFAULT_BATCH
+# bytes of every shard
+WIDE = 16
+
+
 @pytest.mark.parametrize("tag, wanted, width, shape", [
     ("rs_10_4", None, MIB, (4, 10)),
     ("rs_10_4", [3], 16 * MIB, (1, 10)),
@@ -89,13 +94,20 @@ def _lifted(C, sharding):
     ("lrc_12_2_2", [0], TILE, (1, 6)),
     ("lrc_12_2_2", [0, 1], 16 * MIB, (2, 12)),
     ("lrc_12_2_2", [0, 6], TILE, (2, 12)),
+    ("rs_10_4", "unit", WIDE * MIB, (4, 10)),
+    ("lrc_12_2_2", "unit", WIDE * MIB, (4, 12)),
 ], ids=["encode_10_4", "rebuild_batch_1_row", "read_2_rows_smallest_bucket",
         "lrc_encode_4_12", "lrc_local_rebuild_batch_1_6",
         "lrc_local_read_smallest_bucket_1_6", "lrc_global_rebuild_2_12",
-        "lrc_read_one_lost_in_each_group_2_12"])
+        "lrc_read_one_lost_in_each_group_2_12",
+        "encode_unit_16_rows_10_4", "lrc_encode_unit_16_rows_4_12"])
 def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     """The served single-chip programs at TPU_TILE: [k, 1 MiB] under the
-    parity matrix, and the two ends of what the reconstruct seam runs
+    parity matrix (the scrubber's 2-D window), the wide encode unit as the
+    encode seam puts it (ops/dispatch.dispatch_parity: sixteen 1 MiB
+    stripe rows of a `.dat`, each row one 1-D array of k MiB, laid out
+    [k, 16 MiB] inside the program, parity back as m runs of [16 MiB]), and
+    the two ends of what the reconstruct seam runs
     (ops/dispatch.reconstruct_batch): a rebuild batch, the widest bucket,
     and a degraded read at the narrowest.  RS(10,4), and Azure LRC(12,2,2)
     (`lrc_12_2_2`): its [4, 12] parity, the [1, 6] of ones that rebuilds
@@ -103,7 +115,8 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     and the [2, 12] of the global fallback, each as its basis stages it."""
     from seaweedfs_tpu.ops import codecs
     code = codecs._code_for(codecs.parse_tag(tag))
-    if wanted is None:
+    unit = wanted == "unit"
+    if wanted is None or unit:
         C = code.parity_matrix
     else:
         have = [i for i in range(code.n) if i not in wanted]
@@ -113,8 +126,14 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     bm, kpad = _lifted(C, one)
     m, k = C.shape
     # a decode as the seam runs it, 1-D in and out (codec_base.stacked):
-    # a wide stack as its ten rows, a narrow one as one array
-    if wanted is None:
+    # a wide stack as its ten rows, a narrow one as one array; an encode
+    # unit as its stripe rows
+    stripes = 0
+    if unit:
+        assert k * MIB >= dispatch.ROW_PUTS_FROM  # so: row by row
+        data = tuple(_spec((k * MIB,), jnp.uint8, one) for _ in range(WIDE))
+        stripes = WIDE
+    elif wanted is None:
         data = _spec((k, width), jnp.uint8, one)
     elif width >= dispatch.ROW_PUTS_FROM:
         data = tuple(_spec((width,), jnp.uint8, one) for _ in range(k))
@@ -122,11 +141,15 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
         data = _spec((k * width,), jnp.uint8, one)
     compiled = pallas_gf._gf_apply.lower(
         bm, data, k=k, m=m, kpad=kpad, tile=TILE, interpret=False,
-        linear=wanted is not None).compile()
-    assert compiled.out_info.shape == (
-        (m, width) if wanted is None else (m * width,))
+        linear=wanted is not None, stripes=stripes).compile()
+    if unit:
+        assert [o.shape for o in compiled.out_info] == [(width,)] * m
+    else:
+        assert compiled.out_info.shape == (
+            (m, width) if wanted is None else (m * width,))
     _assert_trace_names(compiled,
-                        "gf_apply" if wanted is None else "gf_reconstruct",
+                        "gf_apply" if wanted is None or unit
+                        else "gf_reconstruct",
                         "jit__gf_apply")
 
 
