@@ -78,7 +78,7 @@ def select_survivors(code, present: tuple, wanted: list[int]) -> tuple:
     return tuple(present[: code.k])
 
 
-def stacked(data, k: int, stripes: int = 0) -> jax.Array:
+def stacked(data, k: int, stripes: int = 0, alpha: int = 1) -> jax.Array:
     """Inside a program: the [k, W] stack of an input in *linear* form.
     The runtime moves a 1-D array between host and device as it lies; a
     2-D uint8 one goes through a relayout on the host, in both directions,
@@ -98,17 +98,25 @@ def stacked(data, k: int, stripes: int = 0) -> jax.Array:
       one after the other, R rows of k blocks of `small` bytes each.
       Block j of every row is shard j's, so [R, k, small] becomes
       [k, R * small] (an encode unit: W = R * small contiguous bytes of
-      each shard file)."""
+      each shard file).
+
+    `alpha` > 1 (a sub-packetised code: PM-MSR, ops/msr.py): the matrix
+    works on k *virtual* rows, alpha a shard file, and what comes in, in
+    any of the three forms, is the k / alpha files' rows.  Sub-row a of a
+    file is its byte set {t * alpha + a}, so each [W] file row is split
+    into its alpha sub-rows of [W / alpha] here (`_subrows`), a
+    byte-granular transpose inside the program where the host would copy
+    every byte."""
+    files = k // alpha
     if isinstance(data, (tuple, list)):
-        if not stripes:
-            return jnp.stack(data, axis=0)
-        data = jnp.concatenate(data)
-    if not stripes:
-        return data.reshape(k, -1)
-    return data.reshape(stripes, k, -1).transpose(1, 0, 2).reshape(k, -1)
+        data = jnp.concatenate(data) if stripes else jnp.stack(data, axis=0)
+    if stripes:
+        data = data.reshape(stripes, files, -1).transpose(1, 0, 2)
+    data = data.reshape(files, -1)
+    return data if alpha == 1 else _subrows(data, alpha)
 
 
-def unstacked(out: jax.Array, stripes: int = 0):
+def unstacked(out: jax.Array, stripes: int = 0, alpha: int = 1):
     """Inside a program: the [m, W] product of a *linear* apply as it goes
     back.  A decode's rows go one after the other in one [m * W] array (a
     rebuild batch loses one or two shards: 16 or 32 MiB).  An encode
@@ -117,17 +125,62 @@ def unstacked(out: jax.Array, stripes: int = 0):
     memory the host's allocator hands out, which maps an array over 32 MiB
     afresh every time and pays its first touch (TPU v5e's host: one
     64 MiB array back in 71-79 ms, four of 16 MiB in 6.5; PERF.md,
-    PR 31)."""
+    PR 31).  `alpha` > 1: the m virtual rows are merged back into the
+    bytes of m / alpha files first (`stacked`'s split, undone)."""
+    if alpha > 1:
+        out = _files(out, alpha)
     return tuple(out) if stripes else out.reshape(-1)
+
+
+# Sub-row a of a file is its bytes {t * alpha + a}: [W / alpha, alpha]
+# turned round, whose minor dimension of alpha = 8 bytes the TPU's compiler
+# lays out 16 times as wide as it is and takes minutes over (v5e, compiled
+# with no chip: an encode unit's split alone 227 s and 2.4 GB of
+# temporaries).  A matrix apply is column-local, so the sub-rows' columns
+# may stand in any order that `_files` undoes: the file's bytes as
+# [LANES, W / LANES] turned round whole, a plain 2-D transpose that leaves
+# the alpha sub-rows of a stretch of the file under one another in runs of
+# LANES columns, then those runs gathered by sub-row with the minor
+# dimension left alone (column q * LANES + p of a sub-row is the file's
+# column p * (W / alpha / LANES) + q).  The barrier keeps the compiler from
+# folding the two steps back into the one it cannot do.
+LANES = 128
+
+
+def _subrows(data: jax.Array, alpha: int) -> jax.Array:
+    """[files, W] -> [files * alpha, W / alpha]: each file's alpha
+    byte-interleaved sub-rows under one another, their columns in the
+    order above where W allows it and in the file's own order else."""
+    files, width = data.shape
+    if width % (alpha * LANES):
+        return data.reshape(files, -1, alpha).swapaxes(1, 2).reshape(
+            files * alpha, -1)
+    turned = jax.lax.optimization_barrier(
+        data.reshape(files, LANES, -1).swapaxes(1, 2))
+    return turned.reshape(files, -1, alpha, LANES).swapaxes(1, 2).reshape(
+        files * alpha, -1)
+
+
+def _files(rows: jax.Array, alpha: int) -> jax.Array:
+    """`_subrows`, undone: [m, W / alpha] sub-rows -> [m / alpha, W]."""
+    files, sub = rows.shape[0] // alpha, rows.shape[1]
+    if sub % LANES:
+        return rows.reshape(files, alpha, sub).swapaxes(1, 2).reshape(
+            files, -1)
+    turned = jax.lax.optimization_barrier(
+        rows.reshape(files, alpha, -1, LANES).swapaxes(1, 2).reshape(
+            files, -1, LANES))
+    return turned.swapaxes(1, 2).reshape(files, -1)
 
 
 class RSCodecBase:
     """Encode / reconstruct for one fixed-matrix GF(2^8) code.
 
     `matrix_apply_factory(C) -> callable([k, n] bytes, linear=False,
-    stripes=0) -> [m, n] bytes` (`linear`: 1-D in and out, `stripes` rows
-    of a `.dat` in and m runs out, see `stacked` and `unstacked`) supplies
-    the device kernel for a fixed GF(2^8) matrix C.
+    stripes=0, alpha=1) -> [m, n] bytes` (`linear`: 1-D in and out,
+    `stripes` rows of a `.dat` in and m runs out, `alpha` sub-rows a file
+    split and merged, see `stacked` and `unstacked`) supplies the device
+    kernel for a fixed GF(2^8) matrix C.
     """
 
     def __init__(self, code, matrix_apply_factory):
@@ -159,12 +212,13 @@ class RSCodecBase:
         """[k, n] data -> [m, n] parity (systematic: data shards unchanged)."""
         return self._parity(data)
 
-    def encode_parity_linear(self, spans, stripes: int) -> tuple:
+    def encode_parity_linear(self, spans, stripes: int,
+                             alpha: int = 1) -> tuple:
         """`stripes` stripe rows of a `.dat` as 1-D arrays (`stacked`'s
-        third form) -> their parity as m arrays of [W], W the rows' bytes
-        of one shard (`unstacked`): the layout, the parity apply and the
-        split are one program, and only 1-D arrays cross."""
-        return self._parity(spans, True, stripes)
+        third form) -> their parity as m / alpha arrays of [W], W the
+        rows' bytes of one shard (`unstacked`): the layout, the parity
+        apply and the split are one program, and only 1-D arrays cross."""
+        return self._parity(spans, True, stripes, alpha)
 
     def encode_parity_batch(self, units: jax.Array) -> jax.Array:
         """[U, k, n] unit batch -> [U, m, n] parity in ONE device dispatch
@@ -189,7 +243,7 @@ class RSCodecBase:
                                 list(wanted))
 
     def reconstruct_stack(self, stack, present, wanted: list[int],
-                          linear: bool = False) -> jax.Array:
+                          linear: bool = False, alpha: int = 1) -> jax.Array:
         """[len(basis), W] survivor rows in `decode_basis(present, wanted)`
         order -> the [len(wanted), W] rebuilt rows: the cached decode
         matrix applied to the stack as it is, one program and nothing
@@ -198,9 +252,11 @@ class RSCodecBase:
         caller's to keep to a few values (`bucket`).
 
         `linear`: the stack comes, and the rows go back, as 1-D arrays
-        (`stacked`)."""
+        (`stacked`; `alpha` > 1: as the rows of len(basis) / alpha and
+        len(wanted) / alpha files, `present` and `wanted` naming their
+        sub-rows)."""
         _, mat = self._cached_decode(tuple(sorted(present)), tuple(wanted))
-        return mat(stack, linear)
+        return mat(stack, linear, 0, alpha)
 
     def reconstruct(self, shards: dict[int, jax.Array],
                     wanted: list[int] | None = None) -> dict[int, jax.Array]:

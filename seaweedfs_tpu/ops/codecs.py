@@ -22,11 +22,13 @@ is 6 + 3 on every backend.  `rs` and `lrc` are fixed matrices with a
 `decode_matrix`, which is all the fused Pallas kernel
 (`pallas_gf.PallasGFMatrix`, generic in (m, k)) and the XLA and native
 shells ask for: on a TPU under `auto` / `tpu` both get `PallasRSCodec`.
-MSR wraps a shell in its interleaving file codec and stays on the XLA
-body there.  A tag whose family or geometry the chosen backend does
-not carry raises `CodecUnsupported` before any file is touched (the
-volume server answers 400 with the reason): the mesh encoder and the
-fleet conversion are Reed-Solomon only, the latter RS(10,4) only.
+So does MSR, whose inner code is a fixed matrix over alpha sub-rows a
+file ([72, 72] under msr_9_16; the kernel's tile follows from the matrix,
+`pallas_gf.matrix_tile`), wrapped in its file codec.  A tag whose family
+or geometry the chosen backend does not carry raises `CodecUnsupported`
+before any file is touched (the volume server answers 400 with the
+reason): the mesh encoder and the fleet conversion are Reed-Solomon
+only, the latter RS(10,4) only.
 
 Knobs: WEEDTPU_CODEC_DEFAULT (tag or family for untagged volumes),
 WEEDTPU_CODEC_LRC ("k,l,g" params behind the bare "lrc" family name),
@@ -241,9 +243,9 @@ def backend_for(spec: CodecSpec, kind: str, platform: str | None = None,
                 f"code allows")
         return "mesh"
     if kind == "tpu" or (kind == "auto" and platform == "tpu"):
-        # the fused kernel takes any fixed matrix; the MSR file codec's
-        # interleave stays on the XLA body (ROADMAP R8)
-        return "xla" if spec.family == "msr" else "pallas"
+        # the fused kernel takes any fixed matrix, the MSR inner code's
+        # among them (its byte interleave is inside the same program)
+        return "pallas"
     if kind == "auto" and platform == "native":
         return "native"
     return "xla"

@@ -84,8 +84,7 @@ def _to_host(arrays: list, job, unit, kernel: str) -> list[np.ndarray]:
     them back."""
     with _stage(job, "device_wait", unit, kernel=kernel) as wait:
         for a in arrays:
-            if not isinstance(a, np.ndarray):  # MSRFileCodec re-interleaves
-                a.block_until_ready()          # on the host already
+            a.block_until_ready()
     with _stage(job, "d2h_copy", unit, kernel=kernel) as copy:
         host = [np.asarray(a) for a in arrays]
     KERNELS.record(kernel, "device", calls=0, device_s=wait.seconds,
@@ -107,7 +106,21 @@ def keep_freed_pages() -> None:
     buffer forced fresh (`MALLOC_MMAP_THRESHOLD_=1048576`), 3.62-4.01 in
     sixteen of sixteen runs with freed memory kept (PERF.md, PR 31).  Called once a
     device shell is built on a platform that copies back (ops/codecs); the
-    price is that up to 1 GiB of freed heap stays resident."""
+    price is that up to 1 GiB of freed heap stays resident in the main
+    arena, and in a thread's arena what its busiest call held.
+
+    The two thresholds keep the main arena's pages only.  The buffers are
+    asked for on the thread that enqueues, a request's worker, whose arena
+    is a chain of 64 MiB heaps, and glibc unmaps every heap of it that
+    falls wholly free unless the room left in the heap before it is under
+    M_TOP_PAD, whatever the trim threshold says.  Three 16 MiB runs fill a
+    heap, so a call's buffers (nine a unit under PM-MSR(9,16), 432 MiB in
+    flight) were unmapped and faulted in again as they went, more or fewer
+    by what small and lasting allocation happened to pin which heap in
+    that process.  A pad of one whole heap keeps them all (TPU v5e,
+    `pmmsr.encode`, one seed, runs in turn: 3.53-3.74 GB/s in six of six
+    with it, 3.40-3.54 in five and 2.86 in one without; PERF.md, PR 32).
+    """
     import ctypes
     try:
         mallopt = ctypes.CDLL(None).mallopt
@@ -115,6 +128,7 @@ def keep_freed_pages() -> None:
         return
     mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD: its maximum, and no longer dynamic
     mallopt(-1, 1 << 30)   # M_TRIM_THRESHOLD
+    mallopt(-2, 64 << 20)  # M_TOP_PAD: a thread arena's heap (HEAP_MAX_SIZE)
 
 
 def _host_classes():
@@ -171,7 +185,19 @@ def describe(codec, jax_live: bool = False) -> dict:
     else:  # the Pallas shell carries both; the XLA shell its bucket tile
         info.update({f: getattr(shell, f) for f in ("interpret", "tile")
                      if hasattr(shell, f)})
+    info.update(geometry(codec))
     return info
+
+
+def geometry(codec) -> dict:
+    """The rows the codec's parity matrix takes and gives, and how many of
+    them are one shard file's: 10, 4 and 1 under rs_10_4; 72, 72 and 8
+    under msr_9_16, whose matrix works on the files' byte-interleaved
+    sub-rows.  /perf says it on the codec's block and on the
+    `encode_parity` row."""
+    shell = getattr(codec, "inner", codec)
+    return {"rows_in": shell.k, "rows_out": shell.m,
+            "alpha": getattr(codec, "alpha", 1)}
 
 
 def _unstriped(spans, k: int, stripes: int) -> np.ndarray:
@@ -195,22 +221,24 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
     that hold, one after the other, `stripes` >= 1 stripe rows of k blocks
     each (`codec_base.stacked`'s third form; B = `stripes` blocks).  For a
     codec with a linear apply (`encode_parity_linear`: the Pallas and XLA
-    shells) the spans are put as 1-D arrays from where they lie, the
-    program lays them out, and the parity comes back as m arrays of `[B]`,
-    one contiguous run of each parity shard (`codec_base.unstacked`): no
-    byte is copied on the host and nothing 2-D crosses.  A span of several
+    shells, and `MSRFileCodec` over one) the spans are put as 1-D arrays
+    from where they lie, the program lays them out (and splits them into
+    a sub-packetised code's sub-rows), and the parity comes back as m
+    arrays of `[B]`, one contiguous run of each parity shard
+    (`codec_base.unstacked`): no byte is copied on the host and nothing
+    2-D crosses.  A span of several
     rows goes up row by row where a row is at least `ROW_PUTS_FROM` (TPU
     v5e, a 160 MiB unit: 27.7 ms in one array, 14.5 as its sixteen 10 MiB
     rows; PERF.md, PR 31), else as one array.  The runtime reads a span
     after its put returns, so the spans stay alive and unchanged until the
     result is materialised (a sealed `.dat`'s map, or the engine's staged
     last row, held by the unit's queue item).  Every other codec (a host
-    shell, the bare numpy reference, `MSRFileCodec`, the column-sharded
-    mesh encoder) gets its `[k, B]` array built from the spans on the host
-    (`_unstriped`), which the job counts as `rows_staged`."""
+    shell, the bare numpy reference, the column-sharded mesh encoder) gets
+    its `[k, B]` array built from the spans on the host (`_unstriped`),
+    which the job counts as `rows_staged`."""
     if not (isinstance(batch, np.ndarray) and batch.ndim == 2):
         spans = list(batch)
-        if hasattr(codec, "encode_parity_linear"):  # a device shell
+        if hasattr(codec, "encode_parity_linear") and not _is_host(codec):
             nbytes = sum(s.nbytes for s in spans)
             row = nbytes // stripes
             if row >= ROW_PUTS_FROM:
@@ -223,6 +251,8 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
                 for a in runs:  # see materialize
                     a.copy_to_host_async()
                 return runs
+            KERNELS.note("encode_parity", "device", **geometry(codec),
+                         tile=getattr(codec, "inner", codec).tile)
             return _device_call(
                 job, unit, "encode_parity", nbytes,
                 lambda: tuple(jnp.asarray(s) for s in spans), run,
@@ -427,9 +457,8 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
                           dict(zip(ids, rows)), wanted=wanted)
     import jax.numpy as jnp
     if not hasattr(codec, "reconstruct_stack"):
-        # a dict of rows at their own length is all MSRFileCodec (which
-        # interleaves whole files round its shell, and brings its rows
-        # back itself) and the column-sharded mesh encoder take
+        # a dict of rows at their own length is all the column-sharded
+        # mesh encoder takes
         out = _device_call(
             job, unit, "reconstruct", nbytes,
             lambda: {i: jnp.asarray(r) for i, r in zip(ids, rows)},

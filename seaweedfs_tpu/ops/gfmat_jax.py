@@ -66,15 +66,16 @@ def bitsliced_apply_body(bitmat: jax.Array, data: jax.Array) -> jax.Array:
     return pack_bits(ybits)
 
 
-@functools.partial(jax.jit, static_argnames=("linear", "stripes"))
+@functools.partial(jax.jit, static_argnames=("linear", "stripes", "alpha"))
 def _bitsliced_apply(bitmat: jax.Array, data, linear: bool = False,
-                     stripes: int = 0):
+                     stripes: int = 0, alpha: int = 1):
     """`linear`: 1-D in and out, laid out in this program
-    (codec_base.stacked and unstacked; `stripes` rows of a `.dat`)."""
+    (codec_base.stacked and unstacked; `stripes` rows of a `.dat`,
+    `alpha` sub-rows a file)."""
     if linear:
-        data = codec_base.stacked(data, bitmat.shape[1] // 8, stripes)
+        data = codec_base.stacked(data, bitmat.shape[1] // 8, stripes, alpha)
     out = bitsliced_apply_body(bitmat, data)
-    return codec_base.unstacked(out, stripes) if linear else out
+    return codec_base.unstacked(out, stripes, alpha) if linear else out
 
 
 def bitsliced_apply_batch_body(bitmat: jax.Array, data: jax.Array
@@ -101,9 +102,9 @@ class JaxGFMatrix:
             gf.gf_matrix_to_bitmatrix(self.C).astype(np.int8))
 
     def __call__(self, data, linear: bool = False,
-                 stripes: int = 0) -> jax.Array:
+                 stripes: int = 0, alpha: int = 1) -> jax.Array:
         """data [k, n] uint8 -> [m, n] uint8 product over GF(2^8)."""
-        return _bitsliced_apply(self.bitmat, data, linear, stripes)
+        return _bitsliced_apply(self.bitmat, data, linear, stripes, alpha)
 
     def apply_batch(self, data: jax.Array) -> jax.Array:
         """data [U, k, n] -> [U, m, n] in one dispatch."""

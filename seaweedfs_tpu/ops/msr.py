@@ -11,8 +11,8 @@ against the naive k-shard copy, under the 0.334 reduced-read RS floor.
 
 Construction (product-matrix, MSR point, beta = 1)
 --------------------------------------------------
-alpha = k - 1, d = 2*alpha = 2k - 2, n <= d + 1 nodes.  Node i has an
-encoding row psi_i = [phi_i, lambda_i * phi_i] of length d, where
+alpha = k - 1, d = 2*alpha = 2k - 2, n >= d + 1 nodes (d + 2 here).
+Node i has an encoding row psi_i = [phi_i, lambda_i * phi_i] of length d, where
 phi_i = [1, x_i, .., x_i^(alpha-1)] is Vandermonde over distinct
 x_i = g^i and lambda_i = x_i^alpha (distinct while alpha*i < 255 for
 all i).  The message is M = [[S1],[S2]] with S1, S2 symmetric
@@ -39,8 +39,13 @@ Two classes:
   so its parity_matrix [k*alpha, k*alpha] drops straight into the
   RSCodecBase / NativeRSCodec / matrix_apply_factory seam (the XLA
   bit-sliced, fused Pallas and AVX2 backends run it unchanged).
-- MSRFileCodec: the file-level wrapper (k files in, n files out) that
-  owns the interleave reshapes; what the storage layer sees.
+- MSRFileCodec: the file-level wrapper (k files in, n files out); what
+  the storage layer sees.  Over a device shell it has the shells' linear
+  surface (`encode_parity_linear`, `decode_basis`, `reconstruct_stack`):
+  whole files' rows cross as 1-D arrays and the program that applies the
+  matrix splits them into sub-rows and merges the product back
+  (codec_base.stacked / unstacked, `alpha`), so the host copies no byte.
+  A `[k, L]` array and a host shell go through the reshapes below.
 """
 
 from __future__ import annotations
@@ -224,6 +229,40 @@ class MSRFileCodec:
         if factory is not None:
             self._factory = factory
         self.host_backend = getattr(inner, "host_backend", False)
+
+    @property
+    def tile(self) -> int:
+        """The reconstruct seam's bucket unit, in file bytes: alpha times
+        the shell's (its parity matrix's), so that the narrowest bucket is
+        one tile of sub-row bytes."""
+        return self.inner.tile * self.alpha
+
+    def encode_parity_linear(self, spans, stripes: int) -> tuple:
+        """`stripes` stripe rows of a `.dat`, k blocks wide, as 1-D arrays
+        -> the m parity files' runs, m arrays of [W]: one program of the
+        device shell (RSCodecBase.encode_parity_linear, `alpha`)."""
+        return self.inner.encode_parity_linear(spans, stripes, self.alpha)
+
+    def decode_basis(self, present, wanted: list[int]) -> tuple:
+        """The k surviving files whose rows `reconstruct_stack` takes, in
+        the order it takes them."""
+        return tuple(self.decode_select(list(present), list(wanted)))
+
+    def reconstruct_stack(self, stack, present, wanted: list[int],
+                          linear: bool = False):
+        """The `decode_basis(present, wanted)` files' rows -> the `wanted`
+        files' rows, whole files in and out: the shell's decode over their
+        sub-rows, [len(wanted) * alpha, k * alpha] (`linear`: 1-D in and
+        out, split and merged in the program; else [k, W] -> [w, W])."""
+        a = self.alpha
+        rows = [f * a + j for f in self.decode_basis(present, wanted)
+                for j in range(a)]
+        want = [w * a + j for w in wanted for j in range(a)]
+        if linear:
+            return self.inner.reconstruct_stack(stack, rows, want, True, a)
+        return interleave_merge(
+            self.inner.reconstruct_stack(interleave_split(stack, self.k, a),
+                                         rows, want), len(wanted), a)
 
     def encode_parity(self, data):
         """[k, L] data files -> [m, L] parity files (L % alpha == 0)."""

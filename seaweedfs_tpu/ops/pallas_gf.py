@@ -42,14 +42,37 @@ DEFAULT_TILE = 32768  # interpreter/CPU default: small pads for small inputs
 TPU_TILE = 131072  # the served tile on a chip; which candidate is fastest
 #                    is not measured on current code
 PLANE_PAD = 16  # sublane alignment for each bit-plane block
+# What one grid step of the body may hold in VMEM: its bit planes,
+# [8 * kpad, tile] int8, and its accumulator, [8 * m, tile] int32.  32 MiB
+# is what the widest matrix the kernel served before PM-MSR, [4, 10] (and
+# [4, 12]: kpad 16 either way), holds at TPU_TILE, 16 MiB of each, so
+# every RS and LRC matrix keeps the platform's tile; [72, 72] (kpad 80)
+# would hold 80 + 288 MiB there, more than the chip has, and takes 8192,
+# which costs it nothing: the MXU bounds that kernel, and on a v5e it runs
+# the same 4.7-4.9 ms a 144 MiB unit at 4096 and at 8192 (PERF.md, PR 32).
+VMEM_BUDGET = 32 << 20
 
 
 def resolved_tile(tile: int | None = None) -> int:
-    """The tile a codec will actually use: the explicit argument, else
-    one constant a platform."""
+    """The widest tile a codec will use: the explicit argument, else one
+    constant a platform (`matrix_tile` narrows it for a matrix whose body
+    would not fit at that width)."""
     if tile is not None:
         return tile
     return TPU_TILE if jax.default_backend() == "tpu" else DEFAULT_TILE
+
+
+def matrix_tile(m: int, kpad: int, widest: int) -> int:
+    """The tile of an [m, k] matrix's kernel: `widest` halved until the
+    body's planes and accumulator (8 * kpad + 32 * m bytes a column) fit
+    VMEM_BUDGET.  A rule of the matrix alone, so that one code's matrices
+    can differ: under PM-MSR(9,16) on a TPU the [72, 72] parity takes
+    8192, a one-lost [8, 72] decode 32768, the repair's [8, 16] 65536 and
+    its [1, 8] the platform's 131072."""
+    tile = widest
+    while tile > 128 and tile * (8 * kpad + 32 * m) > VMEM_BUDGET:
+        tile //= 2
+    return tile
 
 
 def gf_matrix_to_bitmatrix_planemajor(C: np.ndarray, kpad: int | None = None) -> np.ndarray:
@@ -107,17 +130,18 @@ GF_APPLY_BATCH = "_gf_apply_batch"
 
 @codec_base.named_jit(GF_APPLY, static_argnames=("k", "m", "kpad", "tile",
                                                  "interpret", "linear",
-                                                 "stripes"))
+                                                 "stripes", "alpha"))
 def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
               tile: int, interpret: bool, linear: bool = False,
-              stripes: int = 0):
+              stripes: int = 0, alpha: int = 1):
     """`linear`: 1-D in and out, laid out in this program
     (codec_base.stacked and unstacked; `stripes` rows of a `.dat`, whose
     width need be no tile multiple: the pad and the cut are in this
-    program too)."""
+    program too; `alpha` > 1: the k and m rows are the byte-interleaved
+    sub-rows of k / alpha and m / alpha files, split and merged here)."""
     cut = None
     if linear:
-        data = codec_base.stacked(data, k, stripes)
+        data = codec_base.stacked(data, k, stripes, alpha)
         if data.shape[1] % tile:
             cut = data.shape[1]
             data = jnp.pad(data, ((0, 0), (0, -cut % tile)))
@@ -143,7 +167,7 @@ def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
     )(bitmat, data)
     if cut is not None:
         out = out[:, :cut]
-    return codec_base.unstacked(out, stripes) if linear else out
+    return codec_base.unstacked(out, stripes, alpha) if linear else out
 
 
 def _gf_apply_batch_kernel(bitmat_ref, x_ref, o_ref, *, k: int, m: int,
@@ -210,7 +234,7 @@ class PallasGFMatrix:
         self.C = np.asarray(C, dtype=np.uint8)
         self.m, self.k = self.C.shape
         self.kpad = max(PLANE_PAD, -(-self.k // PLANE_PAD) * PLANE_PAD)
-        self.tile = resolved_tile(tile)
+        self.tile = matrix_tile(self.m, self.kpad, resolved_tile(tile))
         self.interpret = bool(interpret)
         # cast on the host: `jnp.asarray(..., dtype=)` builds a program
         # per matrix shape, on the first degraded read of each pattern
@@ -218,10 +242,10 @@ class PallasGFMatrix:
             self.C, self.kpad).astype(np.int8))
 
     def __call__(self, data, linear: bool = False,
-                 stripes: int = 0) -> jax.Array:
+                 stripes: int = 0, alpha: int = 1) -> jax.Array:
         if linear:  # the seams': laid out, and padded where need be, inside
             return _gf_apply(self.bitmat, data, self.k, self.m, self.kpad,
-                             self.tile, self.interpret, True, stripes)
+                             self.tile, self.interpret, True, stripes, alpha)
         k, n = data.shape
         assert k == self.k, (k, self.k)
         pad = (-n) % self.tile

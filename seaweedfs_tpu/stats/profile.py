@@ -230,6 +230,7 @@ class KernelProfile:
 
     def __init__(self):
         self._rows: dict[str, dict[str, float]] = {}
+        self._notes: dict[str, dict] = {}
         self._lock = threading.Lock()
 
     def record(self, kernel: str, backend: str = "host", *,
@@ -248,6 +249,15 @@ class KernelProfile:
                 if v:
                     row[f] += v
 
+    def note(self, kernel: str, backend: str = "host", **what) -> None:
+        """What the entry point last ran, said and not summed (the rows
+        and tile of the encode matrix): /perf carries it on the kernel's
+        rows."""
+        self._notes[f"{kernel}[{backend}]"] = what
+
+    def notes(self, key: str) -> dict:
+        return dict(self._notes.get(key, ()))
+
     def snapshot(self) -> dict[str, dict[str, float]]:
         with self._lock:
             return {k: dict(v) for k, v in self._rows.items()}
@@ -255,6 +265,7 @@ class KernelProfile:
     def reset(self) -> None:
         with self._lock:
             self._rows.clear()
+        self._notes.clear()
 
     def table(self) -> str:
         snap = sorted(self.snapshot().items(),
@@ -460,6 +471,7 @@ def roofline_snapshot() -> dict:
             if c:
                 row["ceiling_gbps"] = round(c, 3)
                 row["ceiling_frac"] = round(min(gbps / c, 9.99), 4)
+            row.update(KERNELS.notes(key))
             rows.append(row)
     rows.sort(key=lambda r: -r["busy_s"])
     return {"ceilings": {k: round(v, 3) for k, v in ceil.items()},

@@ -62,14 +62,14 @@ def test_tag_grammar_and_degradation(monkeypatch):
 
 
 @pytest.mark.parametrize("platform, backend, wanted", [
-    ("tpu", "xla", [(-3, 32 << 20), (-1, 1 << 30)]),
+    ("tpu", "xla", [(-3, 32 << 20), (-1, 1 << 30), (-2, 64 << 20)]),
     ("cpu", "xla", []),       # nothing is copied back on the CPU backend
     ("tpu", "numpy", []),     # a host shell copies nothing back either
 ])
 def test_a_device_shell_keeps_freed_result_pages(monkeypatch, platform,
                                                  backend, wanted):
     """Building a device shell on a platform that copies results back
-    sets glibc's M_MMAP_THRESHOLD and M_TRIM_THRESHOLD once
+    sets glibc's M_MMAP_THRESHOLD, M_TRIM_THRESHOLD and M_TOP_PAD once
     (ops/dispatch.keep_freed_pages), so the runtime's 16 MiB result
     buffers come back as touched memory; no other build does."""
     import ctypes
@@ -87,6 +87,45 @@ def test_a_device_shell_keeps_freed_result_pages(monkeypatch, platform,
     finally:
         codecs._build.cache_clear()
     assert calls == wanted
+
+
+def test_freed_result_pages_stay_in_a_thread_arena():
+    """What the three settings are for, on the allocator itself and in a
+    process of its own (they last): 16 MiB buffers allocated and freed on
+    a thread that is not the main one, as a request's worker allocates
+    the copies back, are faulted in once and handed out touched after
+    that.  Without M_TOP_PAD glibc unmaps a thread arena's heaps as they
+    fall free and every round faults them in again."""
+    import subprocess
+    import sys
+    script = """
+import ctypes, resource, threading
+try:
+    ctypes.CDLL(None).mallopt
+except (OSError, AttributeError):
+    print("no mallopt"); raise SystemExit
+import numpy as np
+from seaweedfs_tpu.ops import dispatch
+dispatch.keep_freed_pages()
+faults = []
+def rounds():
+    for _ in range(3):
+        f0 = resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+        bufs = [np.empty(16 << 20, np.uint8) for _ in range(6)]
+        for b in bufs:
+            b[::4096] = 1
+        del bufs, b
+        faults.append(resource.getrusage(resource.RUSAGE_SELF).ru_minflt - f0)
+t = threading.Thread(target=rounds); t.start(); t.join()
+print(*faults)
+"""
+    out = subprocess.run([sys.executable, "-c", script], check=True,
+                         capture_output=True, text=True,
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"}).stdout
+    if out.strip() == "no mallopt":
+        pytest.skip("another libc")
+    first, *later = map(int, out.split())
+    assert first > 1000 and max(later) < first // 10, out
 
 
 def test_registry_lists_every_family():

@@ -329,7 +329,7 @@ def test_degraded_read_gathers_one_local_group(tmp_path, monkeypatch, kind,
     ("rs_6_3", "cpp", None, 1, False, "native"),
     ("rs_10_4", "numpy", None, 1, False, "numpy"),
     ("rs_10_4", "mesh", None, 1, False, "mesh"),
-    ("msr_9_16", "auto", "tpu", 1, False, "xla"),
+    ("msr_9_16", "auto", "tpu", 1, False, "pallas"),
     ("rs_10_4", "auto", "tpu", 4, True, "fleet"),
     ("rs_10_4", "auto", "tpu", 1, True, "pallas"),
     ("rs_10_4", "cpp", None, 1, True, "native"),

@@ -18,6 +18,7 @@ import pathlib
 import re
 import subprocess
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -139,8 +140,10 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
         data = tuple(_spec((width,), jnp.uint8, one) for _ in range(k))
     else:
         data = _spec((k * width,), jnp.uint8, one)
+    tile = pallas_gf.matrix_tile(m, kpad, TILE)
+    assert tile == TILE  # the rule leaves these programs as they were
     compiled = pallas_gf._gf_apply.lower(
-        bm, data, k=k, m=m, kpad=kpad, tile=TILE, interpret=False,
+        bm, data, k=k, m=m, kpad=kpad, tile=tile, interpret=False,
         linear=wanted is not None, stripes=stripes).compile()
     if unit:
         assert [o.shape for o in compiled.out_info] == [(width,)] * m
@@ -150,6 +153,85 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     _assert_trace_names(compiled,
                         "gf_apply" if wanted is None or unit
                         else "gf_reconstruct",
+                        "jit__gf_apply")
+
+
+@pytest.mark.parametrize("m, k", [(4, 10), (1, 10), (2, 10), (4, 12),
+                                  (1, 6), (2, 12)])
+def test_tile_rule_keeps_rs_and_lrc_at_the_platform_tile(m, k):
+    """`PallasGFMatrix`'s tile follows from the matrix since PM-MSR's
+    [72, 72] (pallas_gf.matrix_tile); every matrix the RS(10,4) and
+    LRC(12,2,2) cells lift keeps TPU_TILE, so none of their programs
+    changed."""
+    kpad = pmesh._ApplyKernel("pallas", TILE)._kpad(k)
+    assert kpad == pallas_gf.PLANE_PAD
+    assert pallas_gf.matrix_tile(m, kpad, TILE) == TILE == 131072
+
+
+# PM-MSR(9,16), `msr_9_16`: alpha = 8 sub-rows a file, so the matrices
+# work on 72 virtual rows and the byte interleave is inside the program
+ALPHA = 8
+
+
+@pytest.mark.parametrize("what, shape, tile", [
+    ("unit", (72, 72), 8192),
+    ("rebuild_batch", (8, 72), 32768),
+    ("read_two_lost_narrowest_bucket", (16, 72), 16384),
+    ("repair_at_the_rebuilder", (8, 16), 65536),
+    ("repair_at_a_helper", (1, 8), TILE),
+])
+def test_msr_programs_compile_for_v5e(v5e, what, shape, tile):
+    """The `msr_9_16` programs as the seams put them, with the tile the
+    rule gives each matrix: the sixteen-row encode unit (sixteen 1-D rows
+    of 9 MiB in, split into 72 sub-rows, [72, 72], merged, nine runs of
+    [16 MiB] out), a one-lost rebuild batch (nine survivor rows of 16 MiB,
+    [8, 72]), a two-lost degraded read at the narrowest bucket (one array
+    of nine rows of `MSRFileCodec.tile` bytes, [16, 72]), and the
+    regenerating repair's two applies, 2-D as `dispatch.apply_matrix`
+    puts them: [1, 8] on a helper's sub-rows, [8, 16] on what sixteen
+    helpers sent."""
+    from seaweedfs_tpu.ops import msr
+    code = msr.get_code(9, 16)
+    files = msr.MSRFileCodec(types.SimpleNamespace(code=code, tile=8192))
+    one = SingleDeviceSharding(v5e[0])
+    linear, stripes, alpha = True, 0, ALPHA
+    if what == "unit":
+        C = code.parity_matrix
+        data = tuple(_spec((9 * MIB,), jnp.uint8, one) for _ in range(WIDE))
+        stripes, out = WIDE, [(WIDE * MIB,)] * 9
+    elif what == "rebuild_batch":
+        C = code.decode_matrix(
+            [r for f in range(18) if f != 3 for r in code.node_rows(f)],
+            code.node_rows(3))
+        data = tuple(_spec((16 * MIB,), jnp.uint8, one) for _ in range(9))
+        out = (16 * MIB,)
+    elif what == "read_two_lost_narrowest_bucket":
+        C = code.decode_matrix(
+            [r for f in range(2, 18) for r in code.node_rows(f)],
+            code.node_rows(0) + code.node_rows(1))
+        assert files.tile == 8 * 8192 < dispatch.ROW_PUTS_FROM
+        data = _spec((9 * files.tile,), jnp.uint8, one)
+        out = (2 * files.tile,)
+    else:
+        linear, alpha = False, 1
+        C = code.repair_coeff(3) if shape == (1, 8) else \
+            code.repair_matrix(3, [f for f in range(18) if f != 3][:16])
+        data = _spec((shape[1], tile), jnp.uint8, one)
+        out = (shape[0], tile)
+    assert C.shape == shape
+    bm, kpad = _lifted(C, one)
+    m, k = C.shape
+    assert pallas_gf.matrix_tile(m, kpad, TILE) == tile
+    compiled = pallas_gf._gf_apply.lower(
+        bm, data, k=k, m=m, kpad=kpad, tile=tile, interpret=False,
+        linear=linear, stripes=stripes, alpha=alpha).compile()
+    if what == "unit":
+        assert [o.shape for o in compiled.out_info] == out
+    else:
+        assert compiled.out_info.shape == out
+    _assert_trace_names(compiled,
+                        "gf_reconstruct" if "lost" in what or
+                        what == "rebuild_batch" else "gf_apply",
                         "jit__gf_apply")
 
 
