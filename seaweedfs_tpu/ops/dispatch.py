@@ -240,10 +240,7 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
         spans = list(batch)
         if hasattr(codec, "encode_parity_linear") and not _is_host(codec):
             nbytes = sum(s.nbytes for s in spans)
-            row = nbytes // stripes
-            if row >= ROW_PUTS_FROM:
-                spans = [s[o:o + row] for s in spans
-                         for o in range(0, len(s), row)]
+            spans = unit_pieces(spans, stripes)
             import jax.numpy as jnp
 
             def run(placed):
@@ -292,17 +289,56 @@ def materialize(parity, kernel: str = "encode_parity", job=None,
     return _to_host([parity], job, unit, kernel)[0]
 
 
-@codec_entry("fleet_encode")
-def dispatch_parity_batch(codec, units, job=None, unit=None):
-    """Dispatch a [U, k, B] unit batch -> [U, m, B] parity in ONE kernel
-    launch — the fleet-conversion hot path (ops/fleet_convert.py).
+def unit_pieces(spans, stripes: int) -> list:
+    """The 1-D arrays a unit of `stripes` stripe rows goes up as: its
+    spans cut into their rows where a row is at least `ROW_PUTS_FROM`,
+    else the spans as they are.  Views: no byte moves."""
+    row = sum(s.nbytes for s in spans) // stripes
+    if row < ROW_PUTS_FROM:
+        return list(spans)
+    return [s[o:o + row] for s in spans for o in range(0, len(s), row)]
 
-    A mesh encoder H2Ds through its matched in_sharding (`place`: each
-    chip pulls exactly its U/D units) so the dispatch never reshards.
-    Host backends loop eagerly per unit (they have no batch geometry to
-    win; the pipeline's value there is the interleaved I/O).  Device
-    dispatches return un-materialised; `unit_parity_shards` is the
-    streaming sync point."""
+
+@codec_entry("fleet_encode")
+def dispatch_parity_batch(codec, units, job=None, unit=None,
+                          stripes: int = 0):
+    """Dispatch one batch of the fleet-conversion stream
+    (ops/fleet_convert.py) in ONE launch.  Device dispatches return
+    un-materialised; `unit_parity_shards` is the streaming sync point.
+
+    `units` is a list of the codec's `unit_slots` slots, for a codec that
+    lays a unit out on the device (`encode_units_linear`: the mesh's
+    FleetUnitEncoder): each slot holds a unit as the stream selects it in
+    a `.dat`'s map, the 1-D pieces (`unit_pieces`) of `stripes` stripe
+    rows, alike in length from slot to slot, or None.  Every piece is put
+    1-D to its slot's device from where it lies (`place_units`; an empty
+    slot costs no PCIe byte), the mesh program lays the units out and
+    gives each one's parity as m runs of `[stripes * block]`, and the
+    copies back of the occupied slots' runs are asked for here, at the
+    enqueue (see `materialize`).  What comes back is a list, slot by
+    slot, of m device arrays or None.  The runtime reads a piece after
+    its put returns, so the pieces stay alive and unchanged until the
+    parity is materialised.
+
+    Or `units` is a `[U, k, B]` host array (a host codec's staged batch,
+    a one-device codec's, a test's) -> `[U, m, B]`: a mesh encoder H2Ds
+    it through its matched in_sharding (`place`: each chip pulls exactly
+    its U/D units), host backends loop eagerly per unit (they have no
+    batch geometry to win; the pipeline's value there is the interleaved
+    I/O)."""
+    if not isinstance(units, np.ndarray):
+        def run(placed):
+            parity = [runs if u is not None else None for runs, u in
+                      zip(codec.encode_units_linear(placed, stripes),
+                          units)]
+            for runs in filter(None, parity):
+                for a in runs:
+                    a.copy_to_host_async()
+            return parity
+        return _device_call(
+            job, unit, "fleet_encode",
+            sum(p.nbytes for u in filter(None, units) for p in u),
+            lambda: codec.place_units(units), run, stripes=stripes)
     nbytes = units.nbytes
     if _is_numpy_ref(codec):
         def batched(us):
@@ -319,16 +355,46 @@ def dispatch_parity_batch(codec, units, job=None, unit=None):
     return _host_call(job, unit, "fleet_encode", nbytes, batched, units)
 
 
+def parity_devices(parity) -> int:
+    """How many devices a dispatched batch's parity lives on (0: a host
+    codec returned numpy)."""
+    if isinstance(parity, list):
+        return len({d for runs in filter(None, parity)
+                    for d in runs[0].devices()})
+    sharding = getattr(parity, "sharding", None)
+    return len(sharding.device_set) if sharding is not None else 0
+
+
 def unit_parity_shards(parity, kernel: str = "fleet_encode", job=None,
                        unit=None):
     """Streaming sync point of a batched dispatch: yield
-    (unit_start, unit_stop, np.ndarray) per device-local block as each
-    block's D2H completes — on a mesh the drain hands shards to their
-    writers as they come off each chip instead of waiting for a full
-    gather.  Host arrays yield one block immediately.  No stage stays
-    open across a yield: what the consumer does with a block is its own."""
+    (unit_start, unit_stop, block) per device-local block as each
+    block's D2H completes, `block[u - unit_start][i]` parity row i of
+    unit u: on a mesh the drain hands shards to their writers as they
+    come off each chip instead of waiting for a full gather.  A batch
+    that went up as spans yields one unit a block, its m contiguous runs
+    (their copies were asked for at the enqueue: `d2h_copy` is what is
+    left of them), and nothing for an empty slot; a `[U, m, B]` device
+    array yields each device's `[U/D, m, B]`; host arrays yield one block
+    immediately.  No stage stays open across a yield: what the consumer
+    does with a block is its own."""
     if isinstance(parity, np.ndarray):
         yield 0, parity.shape[0], parity
+        return
+    if isinstance(parity, list):
+        with _stage(job, "device_wait", unit, kernel=kernel) as wait:
+            for runs in filter(None, parity):
+                for a in runs:
+                    a.block_until_ready()
+        KERNELS.record(kernel, "device", calls=0, device_s=wait.seconds)
+        for slot, runs in enumerate(parity):
+            if runs is None:
+                continue
+            with _stage(job, "d2h_copy", unit, kernel=kernel) as copy:
+                host = [np.asarray(a) for a in runs]
+            KERNELS.record(kernel, "device", calls=0, d2h_s=copy.seconds,
+                           d2h_bytes=sum(h.nbytes for h in host))
+            yield slot, slot + 1, [host]
         return
     shards = getattr(parity, "addressable_shards", None)
     if not shards:

@@ -6,32 +6,51 @@ idle — fleet-wide cold-volume conversion (the consumer the autopilot
 demote path feeds) runs as N serial encodes.  This module interleaves N
 volumes' column units into ONE stream of unit batches:
 
-    readers     stage units round-robin across volumes into pooled
-                [U, k, B] host batches (data shards go straight to each
-                volume's writer pool by in-kernel copy_file_range — they
-                never touch the device)
-    dispatch    H2D through the encoder's matched in_sharding (on a mesh
-                each chip pulls exactly its U/D units) and launches ONE
-                batched parity kernel per batch (pallas grid over units;
-                ops/dispatch.dispatch_parity_batch)
-    drain       streams parity off the device PER DEVICE SHARD as each
-                block's D2H lands (dispatch.unit_parity_shards) and fans
-                rows to the owning volume's writers — no full gather
+    reader      walks the volumes round-robin and fills batches of one
+                unit a slot (data shards go straight to each volume's
+                writer pool by in-kernel copy_file_range on the way — they
+                never touch the device).  For a codec that lays a unit out
+                on the device (`encode_units_linear`: the mesh's
+                FleetUnitEncoder) a unit is a span of the volume's `.dat`
+                map, as a single volume's encode has it
+                (ec_files._iter_spans: up to batch_size // block
+                consecutive stripe rows, sixteen 1 MiB rows at the served
+                sizes), selected as views (ec_files._unit_spans): the
+                only bytes the reader moves are a volume's last, short
+                row, into a zeroed buffer of its own, counted as
+                `rows_staged`.  A batch holds units of one shape (one
+                program a shape); a slot left without one stays empty.
+                For every other codec (a host shell, a one-device codec)
+                a unit is one stripe row, copied into a pooled [U, k, W]
+                host batch and counted
+    dispatch    puts every unit's pieces 1-D to its own device from where
+                they lie and launches ONE mesh program a batch, which
+                lays the units out and runs the batched parity kernel
+                (ops/dispatch.dispatch_parity_batch; a staged batch goes
+                up 2-D through the encoder's matched in_sharding)
+    drain       waits, then takes each unit's parity as it comes off its
+                device (dispatch.unit_parity_shards: m contiguous runs a
+                unit, their copies asked for at the enqueue) and hands
+                parity shard k + i of the owning volume one run at
+                shard_off — no full gather
     writers     per-volume _ShardWriterPool; a volume whose last unit
                 drains is finalized (truncate to shard size, .vif,
                 tmp -> rename commit) while the stream keeps feeding the
                 other volumes
 
-Double buffering falls out of the pooled batches: H2D + kernel for batch
-N+1 runs while batch N is still draining D2H + writes.  Failure/cancel
-anywhere aborts the WHOLE run cleanly: uncommitted volumes keep their
-previous valid shard set (same .tmp recycle + rename-on-success contract
-as write_ec_files), committed volumes stay committed.
+Double buffering falls out of the bounded batches in flight: H2D + kernel
+for batch N+1 runs while batch N is still draining D2H + writes; a unit's
+spans stay alive and unchanged until its batch's parity is materialised
+(the maps outlive the run, a staged row rides the batch's queue item).
+Failure/cancel anywhere aborts the WHOLE run cleanly: uncommitted volumes
+keep their previous valid shard set (same .tmp recycle + rename-on-success
+contract as write_ec_files), committed volumes stay committed.
 
-Knobs: WEEDTPU_CONVERT_UNITS (units per device batch, default 4; rounded
-up to an even mesh split), WEEDTPU_CONVERT_DEPTH (in-flight batches,
-default 2 = double buffered).  The master-side pacing of fleet runs
-lives in maintenance/convert.py; this module is the data plane.
+Knobs: WEEDTPU_CONVERT_UNITS (unit slots a batch, default 4; rounded up
+to an even mesh split, so one unit a chip on four), WEEDTPU_CONVERT_DEPTH
+(batches in flight, default 2 = double buffered).  The master-side pacing
+of fleet runs lives in maintenance/convert.py; this module is the data
+plane.
 """
 
 from __future__ import annotations
@@ -45,14 +64,15 @@ import numpy as np
 
 from seaweedfs_tpu.ops.dispatch import (backend_name,
                                         dispatch_parity_batch,
-                                        unit_parity_shards)
+                                        parity_devices, unit_parity_shards,
+                                        unit_pieces)
 from seaweedfs_tpu.stats import netflow as _netflow
 from seaweedfs_tpu.stats import pipeline as _pipeline
 from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
     DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
-    _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
-    _unit_coverage, _unit_steps, overlap_fraction, write_vif)
+    _iter_spans, _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
+    _unit_coverage, _unit_spans, _unit_steps, overlap_fraction, write_vif)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -85,7 +105,7 @@ class _VolumeJob:
     its writer pool, and completion accounting."""
 
     def __init__(self, base: str, dat_path: str | None, large_block: int,
-                 small_block: int, batch_size: int, pjob):
+                 small_block: int, batch_size: int, pjob, spans: bool):
         self.base = base
         self.dat_path = dat_path or base + ".dat"
         self.dat_size = os.path.getsize(self.dat_path)
@@ -115,8 +135,13 @@ class _VolumeJob:
         self.data_flusher = _ShardFlusher(self.writers, layout.TOTAL_SHARDS)
         self.parity_flusher = _ShardFlusher(self.writers,
                                             layout.TOTAL_SHARDS)
-        self.units = _iter_units(self.dat_size, large_block, small_block,
-                                 batch_size)
+        # (row_start, block, col, step, shard_off, rows): spans of the map
+        # for a codec that lays a unit out on the device, else one stripe
+        # row (or a column cut of one) a unit
+        geometry = (self.dat_size, large_block, small_block, batch_size)
+        self.units = _iter_spans(*geometry) if spans else (
+            u + (1,) for u in _iter_units(*geometry))
+        self.held = None  # the next unit, selected and not yet in a batch
         self.units_read = 0
         self.units_total: int | None = None  # set when the iterator ends
         self.units_drained = 0   # written by the drain thread only
@@ -185,6 +210,7 @@ class _VolumeJob:
                     pass
 
     def release(self) -> None:
+        self.held = None
         if self.view is not None:
             self.view = None
         if self.mm is not None:
@@ -228,11 +254,14 @@ def convert_volumes(bases: list[str], *,
     slots = getattr(codec, "unit_slots", None)
     if slots is not None:  # round to an even mesh split
         U = slots(U)
+    # a codec that lays a unit out on the device gets spans of the map
+    spans = hasattr(codec, "encode_units_linear")
 
     stats = stats if stats is not None else {}
     stats["mode"] = "fleet"
     stats["backend"] = backend_name(codec)
     stats["unit_batch"] = U
+    stats["rows_staged"] = 0  # stripe rows copied on the host (pjob.count)
     # class=convert on THIS thread and (contextvars are per-thread) re-
     # stamped inside each pipeline thread, so any hop made on the
     # conversion's behalf — wherever it runs — books as convert
@@ -248,28 +277,92 @@ def convert_volumes(bases: list[str], *,
                            span="ec.fleet", sums=ENCODE_SUMS)
     try:
         jobs = [_VolumeJob(b, None, large_block, small_block, batch_size,
-                           pjob) for b in bases]
+                           pjob, spans) for b in bases]
     except BaseException as e:  # a volume that cannot be opened: no run
         pjob.finish(e)
         raise
     stats["bytes"] = sum(j.dat_size for j in jobs)
 
-    # one staging width covers every job (ragged tails zero-fill): pooled
-    # [U, k, W] batches, depth+1 so H2D/kernel of batch N+1 overlaps the
-    # D2H/writes of batch N
-    W = max(_unit_steps(j.dat_size, large_block, small_block,
-                        batch_size)[1] for j in jobs)
+    # depth+1 batches between selection and materialised parity, so the
+    # H2D and kernel of batch N+1 overlap the D2H and writes of batch N.
+    # Spans hold no buffer (the pool's items are tokens); a staged batch
+    # is a pooled [U, k, W] array, one width for every job (ragged tails
+    # zero-fill)
     pool: queue.Queue = queue.Queue()
+    W = 0 if spans else max(_unit_steps(
+        j.dat_size, large_block, small_block, batch_size)[1] for j in jobs)
     for _ in range(depth + 1):
-        pool.put(np.empty((U, k, W), dtype=np.uint8))
+        pool.put(None if spans else np.empty((U, k, W), dtype=np.uint8))
     q_read: queue.Queue = queue.Queue(maxsize=depth)
     q_disp: queue.Queue = queue.Queue()
     errors: list[BaseException] = []
     done_total = 0
 
-    def reader() -> None:
-        """Round-robin units across volumes into staged unit batches."""
+    def peek(job):
+        """The job's next unit that holds data, selected once: (unit,
+        pieces, rows staged, shape).  Spans: the unit as 1-D views of the
+        map (no byte moves but a volume's last, short row, into a zeroed
+        buffer of its own) cut into the pieces it goes up as, whose
+        lengths are the shape a batch shares.  None at the volume's end."""
+        while job.held is None:
+            unit = job.next_unit()
+            if unit is None:
+                return None
+            row_start, block, col, step, _, rows = unit
+            if spans:
+                views, staged = _unit_spans(job.view, job.dat_size, k,
+                                            row_start, block, col, step,
+                                            rows)
+                if views:
+                    pieces = unit_pieces(views, rows)
+                    job.held = (unit, pieces, staged,
+                                (rows, *map(len, pieces)))
+            elif _unit_coverage(job.dat_size, row_start, block, col,
+                                step)[0]:
+                job.held = unit, None, 1, None
+            if job.held is None:
+                # a trailing column unit wholly beyond the .dat: nothing
+                # to encode or write
+                job.units_skipped += 1
+        return job.held
+
+    def take(job, slot):
+        """Move the job's selected unit into a batch; a staged unit's k
+        blocks are copied into `slot`."""
+        unit, pieces, staged, _ = job.held
+        job.held = None
+        row_start, block, col, step, shard_off, rows = unit
+        if slot is not None:
+            for j in range(k):
+                off = row_start + j * block + col
+                n = max(0, min(step, job.dat_size - off))
+                if n > 0:
+                    np.copyto(slot[j, :n], job.view[off:off + n])
+                slot[j, n:] = 0
+        pjob.count("rows_staged", staged)
+        return pieces, (job, shard_off, rows * step), unit
+
+    def ship_data(job, unit):
+        """A unit's data shards go to the volume's own writers by
+        in-kernel copies, block by block: they never ride the device."""
         nonlocal done_total
+        row_start, block, col, step, shard_off, rows = unit
+        for r in range(rows):
+            nz, tail = _unit_coverage(
+                job.dat_size, row_start + r * k * block, block, col, step)
+            for j in range(nz):
+                job.data_flusher.copy(
+                    j, job.dat_f.fileno(),
+                    row_start + (r * k + j) * block + col,
+                    shard_off + r * step, step if j < nz - 1 else tail,
+                    src_view=job.view)
+            if nz:
+                done_total += (nz - 1) * step + tail
+                job.done_bytes += (nz - 1) * step + tail
+                job.data_flusher.account(step)
+
+    def reader() -> None:
+        """Round-robin units across volumes into batches of one shape."""
         active = list(jobs)
         _netflow.set_class(flow_cls)
         batch = 0
@@ -279,50 +372,43 @@ def convert_volumes(bases: list[str], *,
                     raise EncodeCancelled("fleet conversion cancelled")
                 with pjob.blocked("stall", unit=batch):
                     buf = pool.get()
-                metas = []
+                units, metas, taken, shape = [], [], [], None
+                # `read` is what ec.encode.read is: the selection and the
+                # bytes the reader itself moves
                 with pjob.stage("read", unit=batch):
-                    while len(metas) < U and active:
-                        job = active[len(metas) % len(active)]
-                        unit = job.next_unit()
-                        if unit is None:
-                            active.remove(job)
-                            continue
-                        row_start, block, col, step, shard_off = unit
-                        nz, tail = _unit_coverage(
-                            job.dat_size, row_start, block, col, step)
-                        if nz == 0:
-                            # a trailing column unit wholly beyond the
-                            # .dat: nothing to encode or write
-                            job.units_skipped += 1
-                            continue
-                        # data shards: in-kernel copies on the volume's
-                        # own writers — they never ride the device
-                        for j in range(nz):
-                            off = row_start + j * block + col
-                            n = step if j < nz - 1 else tail
-                            job.data_flusher.copy(j, job.dat_f.fileno(), off,
-                                             shard_off, n,
-                                             src_view=job.view)
-                        slot = buf[len(metas)]
-                        for j in range(k):
-                            off = row_start + j * block + col
-                            n = max(0, min(step, job.dat_size - off))
-                            if n > 0:
-                                np.copyto(slot[j, :n],
-                                          job.view[off:off + n])
-                            if n < W:
-                                slot[j, max(n, 0):] = 0
-                        metas.append((job, shard_off, step))
-                        done_total += (nz - 1) * step + tail
-                        job.done_bytes += (nz - 1) * step + tail
-                        job.data_flusher.account(step)
-                    if progress is not None:
-                        progress(done_total)
+                    took = True
+                    while len(metas) < U and took:
+                        took = False
+                        for job in list(active):
+                            held = peek(job)
+                            if held is None:
+                                active.remove(job)
+                            elif not metas or held[3] == shape:
+                                shape = held[3]
+                                pieces, meta, unit = take(
+                                    job, None if spans else buf[len(metas)])
+                                units.append(pieces)
+                                metas.append(meta)
+                                taken.append((job, unit))
+                                took = True
+                                if len(metas) == U:
+                                    break
+                for job, unit in taken:
+                    ship_data(job, unit)
+                if progress is not None:
+                    progress(done_total)
                 if metas:
-                    q_read.put((batch, buf, metas))
+                    # a slot with no unit is None: a short last batch, or
+                    # one closed because the volumes' next units differ
+                    # in shape
+                    units += [None] * (U - len(units))
+                    q_read.put((batch, buf, metas,
+                                units if spans else buf,
+                                shape[0] if spans else 0))
                     batch += 1
                 else:
                     pool.put(buf)
+                del units  # the queue item alone holds a unit's views
         except BaseException as e:
             errors.append(e)
         finally:
@@ -337,7 +423,7 @@ def convert_volumes(bases: list[str], *,
             item = q_disp.get()
             if item is None:
                 return
-            batch, buf, metas, parity = item
+            batch, buf, metas, parity = item[:4]
             if failed or errors:
                 pool.put(buf)
                 continue
@@ -351,19 +437,23 @@ def convert_volumes(bases: list[str], *,
                                                       unit=batch):
                     if not released:
                         # the first yield implies block_until_ready has
-                        # returned: the device is done with the staging
-                        # memory even though later shards are still
-                        # transferring
+                        # returned: the device is done with the host
+                        # memory (the staging buffer, or the spans, which
+                        # the queue item held until here) even though
+                        # later shards are still transferring
                         pool.put(buf)
                         released = True
+                        del item
                     touched = []
                     for u in range(a, min(b, len(metas))):
-                        job, shard_off, step = metas[u]
-                        rows = block[u - a]
-                        for i in range(m):
-                            job.parity_flusher.put(k + i, rows[i, :step],
+                        job, shard_off, width = metas[u]
+                        # m runs of a unit that went up as spans, the
+                        # rows of [m, W] of a staged one: one contiguous
+                        # run of each parity shard's file either way
+                        for i, run in enumerate(block[u - a]):
+                            job.parity_flusher.put(k + i, run[:width],
                                                    shard_off)
-                        job.parity_flusher.account(step)
+                        job.parity_flusher.account(width)
                         job.units_drained += 1
                         if job.drained_all():
                             job.finalize()
@@ -392,24 +482,25 @@ def convert_volumes(bases: list[str], *,
             # deep q_disp means the drain/writers are
             pjob.queue("q_read", q_read.qsize(), depth)
             pjob.queue("q_disp", q_disp.qsize())
-            batch, buf, metas = item
+            batch, buf, metas, units, stripes = item
             if errors:
                 pool.put(buf)
                 continue
             try:
-                parity = dispatch_parity_batch(codec, buf, job=pjob,
-                                               unit=batch)
+                parity = dispatch_parity_batch(codec, units, job=pjob,
+                                               unit=batch, stripes=stripes)
                 # how many devices the unit batch's parity lives on (0:
                 # a host codec returned numpy) — a mesh that silently ran
                 # on its first chip must show
-                sharding = getattr(parity, "sharding", None)
-                if sharding is not None:
-                    stats["devices"] = max(stats.get("devices", 0),
-                                           len(sharding.device_set))
-                q_disp.put((batch, buf, metas, parity))
+                stats["devices"] = max(stats.get("devices", 0),
+                                       parity_devices(parity))
+                # the item carries the units: their views and staged rows
+                # live until the drain has the parity
+                q_disp.put((batch, buf, metas, parity, units))
             except BaseException as e:
                 errors.append(e)
                 pool.put(buf)
+            del item, units
     finally:
         q_disp.put(None)
         t_d.join()

@@ -273,21 +273,30 @@ class FleetUnitEncoder:
     """Unit-parallel fleet encode: the mesh shape of the multi-volume
     conversion pipeline (ops/fleet_convert.py).
 
-    A batch of U independent [k, B] column units — interleaved from N
-    volumes — shards over the mesh on the unit axis.  Each chip encodes
-    its U/D units wholly (parity is unit-local): NO collectives, no
-    cross-chip bytes, so 8 chips process 8x the units of 1 at equal unit
-    size.  The jitted shard_map is built once; its in/out shardings are
-    both P(unit_axis), so a device-resident output (or a staging buffer
-    placed by `place`) feeds the next call without any reshard.  The
-    input batch is not donated: [U, k, B] in and [U, m, B] out differ in
-    shape, and the executable compiled for a v5e carries no input/output
-    alias when it is.
+    A batch of U independent units, interleaved from N volumes, shards
+    over the mesh on the unit axis.  Each chip encodes its U/D units
+    wholly (parity is unit-local): NO collectives, no cross-chip bytes.
+    Both forms run `_ApplyKernel.batch_body` inside one jitted shard_map
+    (`jit_batch_body` on a device trace) whose in and out shardings are
+    P(unit_axis):
 
-    D2H is per-device: `unit_shards(parity)` yields each device's local
-    [U/D, m, B] block the moment it is fetched, so the conversion drain
-    streams shards to their writers as they come off the device rather
-    than after a full gather.
+      a unit as the conversion stream selects it in a `.dat`'s map
+      (`place_units`, `encode_units_linear`): 1-D pieces that hold, one
+      after the other, R stripe rows of k blocks (`codec_base.stacked`'s
+      third form).  Each piece is put to the unit's own device from where
+      it lies, the per-device arrays become unit-sharded global arrays
+      without a copy, the program lays the unit out as [1, k, R * block]
+      on its chip and gives the parity back as m 1-D runs of [R * block],
+      one contiguous run of each parity shard's file: nothing 2-D
+      crosses (TPU v5e: a 2-D uint8 array goes through a host relayout in
+      both directions that costs more than the transfer; PERF.md, PR 26
+      and PR 31).  One program per distinct unit shape;
+      a [U, k, B] batch (`place`, `encode_parity_batch`): a host array
+      from a test or a caller that holds one; parity [U, m, B], its
+      device-local blocks streamed by `unit_shards`.
+
+    Nothing is donated: input and output differ in shape, and the
+    executable compiled for a v5e carries no input/output alias.
     """
 
     def __init__(self, code, mesh: Mesh | None = None,
@@ -308,12 +317,82 @@ class FleetUnitEncoder:
             self.kernel.batch_body,
             mesh=mesh, in_specs=(P(), P(unit_axis)),
             out_specs=P(unit_axis)))
+        self._encode_linear = codec_base.named_jit(
+            "batch_body", static_argnames=("stripes",))(self._linear)
+        # (device, bytes) -> a zero piece resident on that device: what a
+        # slot with no unit is made of
+        self._zeros: dict = {}
 
     def unit_slots(self, min_units: int) -> int:
         """Round a desired in-flight unit count up to an even per-device
         split (shard_map needs one)."""
         D = self.n_devices
         return max(D, -(-min_units // D) * D)
+
+    def _linear(self, bm, units, stripes: int):
+        """[slot-in-device][piece] unit-sharded 1-D arrays -> [slot][row]
+        unit-sharded parity runs: inside the shard_map each chip sees its
+        own units' pieces, stacks them (`codec_base.stacked`), runs the
+        batch kernel on [U/D, k, W] and splits the parity into m runs a
+        unit (`codec_base.unstacked`)."""
+        def body(bm, units):
+            # the barrier keeps the compiler from fusing a unit's layout
+            # into the step to three dimensions: fused, a two-row unit
+            # compiled for a v5e in 44 s into 17 MB of code, apart in 6 s
+            # (compiled with no chip, PR 33)
+            out = self.kernel.batch_body(bm, jnp.stack(
+                [jax.lax.optimization_barrier(
+                    codec_base.stacked(pieces, self.k, stripes))
+                 for pieces in units]))
+            return tuple(codec_base.unstacked(o, stripes) for o in out)
+        return shard_map(body, mesh=self.mesh,
+                         in_specs=(P(), P(self.unit_axis)),
+                         out_specs=P(self.unit_axis))(bm, units)
+
+    def _zero_piece(self, device, n: int) -> jax.Array:
+        z = self._zeros.get((device, n))
+        if z is None:  # a put, not a program: nothing compiles for it
+            z = self._zeros[device, n] = jax.device_put(
+                np.zeros(n, dtype=np.uint8), device)
+        return z
+
+    def place_units(self, units: list) -> tuple:
+        """H2D a batch of `unit_slots` slots, each a unit as a sequence of
+        1-D host pieces (all units of one batch alike in their pieces'
+        lengths) or None: every piece goes to its slot's device as it
+        lies, 1-D, and piece i of the d-th devices' j-th slots becomes one
+        global array sharded over the mesh, assembled from the per-device
+        arrays without a copy.  An empty slot takes zeros that already
+        live on its device and costs no PCIe byte.  The runtime may read
+        a piece after this returns: the caller keeps the pieces alive and
+        unchanged until the parity is materialised."""
+        devices = list(self.mesh.devices.flat)
+        per = len(units) // len(devices)
+        assert per * len(devices) == len(units), (len(units), len(devices))
+        lengths = [len(p) for p in next(u for u in units if u is not None)]
+        placed = [jax.device_put(list(u), dev) if u is not None
+                  else [self._zero_piece(dev, n) for n in lengths]
+                  for u, dev in zip(units, (d for d in devices
+                                           for _ in range(per)))]
+        return tuple(
+            tuple(jax.make_array_from_single_device_arrays(
+                (len(devices) * n,), self.in_sharding,
+                [placed[d * per + j][i] for d in range(len(devices))])
+                for i, n in enumerate(lengths))
+            for j in range(per))
+
+    def encode_units_linear(self, placed: tuple, stripes: int) -> list:
+        """`place_units`' arrays -> per slot, in `place_units`' order, the
+        m parity runs of its unit as 1-D arrays on the slot's device
+        (un-materialised: the caller's sync point waits and copies)."""
+        out = self._encode_linear(self.parity_bits, placed, stripes=stripes)
+        D = self.n_devices
+        # global run -> its D device-local runs, in mesh order
+        local = [[sorted(run.addressable_shards,
+                         key=lambda s: s.index[0].start or 0)
+                  for run in runs] for runs in out]
+        return [tuple(sh[d].data for sh in local[j])
+                for d in range(D) for j in range(len(out))]
 
     def place(self, host_units: np.ndarray) -> jax.Array:
         """H2D a [U, k, B] host batch with units sharded over the mesh:

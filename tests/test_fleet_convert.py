@@ -71,6 +71,117 @@ def test_convert_volumes_byte_identity(tmp_path, unit_mesh):
         assert ec_files.read_vif(base)["dat_file_size"] == len(data)
 
 
+def _reference_shards(tmp_path, base, data):
+    """The volume's shard files under the plain numpy RS code
+    (models/rs), written by the single-volume engine."""
+    ref = str(tmp_path / ("ref_" + os.path.basename(base)))
+    with open(ref + ".dat", "wb") as f:
+        f.write(data)
+    os.environ["WEEDTPU_EC_CODEC"] = "numpy"
+    try:
+        ec_files.write_ec_files(ref, large_block=10_000, small_block=100)
+    finally:
+        del os.environ["WEEDTPU_EC_CODEC"]
+    return _shard_bytes(ref)
+
+
+# sizes of the volumes of one run over the 8-device mesh.  Rows are 10
+# blocks of 100 bytes, a unit up to ten of them (batch_size 1000), and a
+# volume over 100,000 bytes starts with a large-block row cut in columns
+# of ten 1000-byte pieces: three unit shapes and the short last units.
+SPAN_CASES = {
+    "unequal_sizes_mixed_shapes": [200_000, 137_777, 95_001, 4_000, 23_456],
+    "ends_on_a_row_boundary": [50_000],
+    "ends_inside_a_row": [50_001],
+    "shorter_than_one_stripe_row": [37],
+    "an_empty_volume_among_others": [0, 12_345],
+    "more_volumes_than_devices": [10_000 + 1000 * i for i in range(11)],
+    "fewer_volumes_than_devices": [30_000, 30_500, 29_999],
+}
+
+
+@pytest.mark.parametrize("sizes", list(SPAN_CASES.values()),
+                         ids=list(SPAN_CASES))
+def test_convert_spans_byte_identity(tmp_path, unit_mesh, monkeypatch,
+                                     sizes):
+    """A codec that lays a unit out on the device (FleetUnitEncoder) gets
+    every unit as spans of the `.dat` map, put 1-D a device: shard files
+    byte for byte the plain RS code's, `rows_staged` one for each volume
+    that ends inside a stripe row and nothing else, no [U, k, W] staging
+    buffer, batches of one shape with empty slots where they close short,
+    and a zero unit's parity never copied back."""
+    from seaweedfs_tpu.parallel import mesh as pmesh
+    bases, payloads = _make_volumes(tmp_path, sizes)
+    codec = pmesh.FleetUnitEncoder(rs.get_code(10, 4), unit_mesh)
+    batches, staging = [], []
+    orig_dispatch, orig_empty = fleet_convert.dispatch_parity_batch, np.empty
+
+    def dispatch(codec, units, **kw):
+        batches.append((units, kw["stripes"]))
+        return orig_dispatch(codec, units, **kw)
+
+    def empty(shape, *a, **kw):
+        if np.ndim(shape) and len(shape) == 3:
+            staging.append(shape)
+        return orig_empty(shape, *a, **kw)
+
+    monkeypatch.setattr(fleet_convert, "dispatch_parity_batch", dispatch)
+    monkeypatch.setattr(np, "empty", empty)
+    stats: dict = {}
+    rep = fleet_convert.convert_volumes(
+        bases, large_block=10_000, small_block=100, batch_size=1000,
+        codec=codec, stats=stats)
+    monkeypatch.undo()
+    assert rep["bytes"] == sum(sizes)
+    for base, data in zip(bases, payloads):
+        got, want = _shard_bytes(base), _reference_shards(tmp_path, base,
+                                                           data)
+        assert sorted(got) == list(range(layout.TOTAL_SHARDS))
+        for i in range(layout.TOTAL_SHARDS):
+            assert got[i] == want[i], (base, i)
+        assert ec_files.read_vif(base)["dat_file_size"] == len(data)
+    assert stats["rows_staged"] == sum(1 for n in sizes if n % 1000)
+    assert not staging
+    assert stats["unit_batch"] == 8
+    occupied = 0
+    for units, stripes in batches:
+        assert isinstance(units, list) and len(units) == 8
+        shapes = {tuple(map(len, u)) for u in units if u is not None}
+        assert len(shapes) == 1  # one program a batch
+        assert all(p.ndim == 1 for u in units if u is not None for p in u)
+        # whole 100-byte rows, or one large-block row's 1000-byte columns
+        assert sum(shapes.pop()) in (stripes * 1000, stripes * 10_000)
+        occupied += sum(u is not None for u in units)
+    assert occupied == rep["units"] == stats["units"]
+    if any(sizes):
+        assert rep["devices"] == min(8, max(
+            sum(u is not None for u in units) for units, _ in batches))
+
+
+def test_span_batch_parity_runs_and_empty_slots(unit_mesh):
+    """A batch of spans through the seam: each occupied slot's parity is
+    m contiguous 1-D runs on the slot's own device, an empty slot gives
+    None and is never yielded."""
+    from seaweedfs_tpu.ops import dispatch
+    from seaweedfs_tpu.parallel import mesh as pmesh
+    code = rs.get_code(10, 4)
+    enc = pmesh.FleetUnitEncoder(code, unit_mesh)
+    rng = np.random.default_rng(3)
+    flat = [rng.integers(0, 256, 3 * 10 * 64, dtype=np.uint8)
+            for _ in range(5)]
+    parity = dispatch.dispatch_parity_batch(
+        enc, [[f] for f in flat] + [None] * 3, stripes=3)
+    assert [runs is None for runs in parity] == [False] * 5 + [True] * 3
+    assert dispatch.parity_devices(parity) == 5
+    blocks = list(dispatch.unit_parity_shards(parity))
+    assert [(a, b) for a, b, _ in blocks] == [(s, s + 1) for s in range(5)]
+    for (_, _, (runs,)), f in zip(blocks, flat):
+        want = code.encode_numpy(
+            f.reshape(3, 10, 64).transpose(1, 0, 2).reshape(10, -1))[10:]
+        assert len(runs) == 4 and all(r.ndim == 1 for r in runs)
+        assert np.array_equal(np.stack(runs), want)
+
+
 def test_convert_books_class_convert(tmp_path):
     """The whole conversion runs under netflow class=convert, so any
     network hop made on its behalf books repair-adjacent bytes."""
@@ -82,9 +193,12 @@ def test_convert_books_class_convert(tmp_path):
     assert seen and set(seen) == {"convert"}
 
 
-def test_convert_cancel_clean_abort(tmp_path):
+@pytest.mark.parametrize("kind", ["fleet", "numpy"])
+def test_convert_cancel_clean_abort(tmp_path, kind):
     """Cancel mid-stream: EncodeCancelled, NO partial .ecXX visible, no
-    .tmp litter, and a previous valid shard set survives untouched."""
+    .tmp litter, and a previous valid shard set survives untouched —
+    whether units go up as spans of the maps (the mesh encoder) or are
+    staged into [U, k, W] batches (a host codec)."""
     bases, _ = _make_volumes(tmp_path, [300_000, 280_000], seed=9)
     # volume 0 already has a valid shard set from an earlier encode
     os.environ["WEEDTPU_EC_CODEC"] = "numpy"
@@ -103,7 +217,7 @@ def test_convert_cancel_clean_abort(tmp_path):
     with pytest.raises(ec_files.EncodeCancelled):
         fleet_convert.convert_volumes(
             bases, large_block=10_000, small_block=100, batch_size=1000,
-            cancel=cancel)
+            cancel=cancel, codec=fleet_convert.fleet_codec(kind))
     # the old set is byte-identical, the fresh volume has nothing visible
     assert _shard_bytes(bases[0]) == before
     assert _shard_bytes(bases[1]) == {}

@@ -256,11 +256,14 @@ def test_multiworker_stage_occupancy_does_not_outrank_saturated_stage():
     assert bn2["achieved_gbps"] == pytest.approx(4 / 0.3, rel=0.01)
 
 
-def test_dispatch_parity_batch_books_h2d_exactly_once(unit_mesh):
+@pytest.mark.parametrize("form", ["array", "spans"])
+def test_dispatch_parity_batch_books_h2d_exactly_once(unit_mesh, form):
     """The mesh place() seam books its own H2D; dispatch_parity_batch
     must not book it again when IT calls place() (the default
     fleet-convert path) — double-booking inflated the fleet_encode h2d
-    roofline row 2x."""
+    roofline row 2x.  A batch of spans (a unit a device, each piece put
+    1-D to its own device) books the occupied slots' bytes, once, and
+    brings back no parity of an empty slot."""
     from seaweedfs_tpu.models import rs
     from seaweedfs_tpu.ops import dispatch
     from seaweedfs_tpu.parallel import mesh as pmesh
@@ -268,13 +271,21 @@ def test_dispatch_parity_batch_books_h2d_exactly_once(unit_mesh):
     units = np.random.default_rng(3).integers(
         0, 256, (8, 10, 256), dtype=np.uint8)
     before = profile.KERNELS.snapshot().get("fleet_encode[device]", {})
-    parity = dispatch.dispatch_parity_batch(enc, units)
+    if form == "array":
+        parity = dispatch.dispatch_parity_batch(enc, units)
+        up, back = units.nbytes, 8 * 4 * 256
+    else:  # five units of one stripe row, three empty slots
+        parity = dispatch.dispatch_parity_batch(
+            enc, [[u.reshape(-1)] for u in units[:5]] + [None] * 3,
+            stripes=1)
+        up, back = units[:5].nbytes, 5 * 4 * 256
     blocks = list(dispatch.unit_parity_shards(parity))
     after = profile.KERNELS.snapshot()["fleet_encode[device]"]
     h2d = after["h2d_bytes"] - before.get("h2d_bytes", 0.0)
     d2h = after["d2h_bytes"] - before.get("d2h_bytes", 0.0)
-    assert h2d == units.nbytes  # once, not twice
-    assert d2h == sum(b.nbytes for _, _, b in blocks) == 8 * 4 * 256
+    assert after["calls"] - before.get("calls", 0) == 1
+    assert h2d == up  # once, not twice
+    assert d2h == sum(np.asarray(b).nbytes for _, _, b in blocks) == back
 
 
 def test_roofline_snapshot_fractions_and_offenders():

@@ -28,6 +28,9 @@ from tests.test_observability import _mock_req
 
 LARGE, SMALL, BATCH = 10000, 100, 1000
 KINDS = ["encode", "rebuild", "fleet", "degraded_read"]
+# and the fleet stream over the unit-sharded mesh, whose units go up as
+# spans of the `.dat` maps (in-process only: it needs the virtual devices)
+MESH_KINDS = KINDS + ["fleet_spans"]
 SEAM = {"codec.h2d", "codec.dispatch", "codec.device_wait",
         "codec.d2h_copy"}
 # the engine's own stages that a tiny run on a device codec must show
@@ -38,6 +41,8 @@ ENGINE = {"encode": {"ec.encode.read", "ec.encode.write_data",
                       "ec.rebuild.write"},
           "fleet": {"ec.fleet.read", "ec.fleet.write_data",
                     "ec.fleet.write_parity"},
+          "fleet_spans": {"ec.fleet.read", "ec.fleet.write_data",
+                          "ec.fleet.write_parity"},
           "degraded_read": {"ec.read.local_pread",
                             "ec.read.gather_survivors",
                             "ec.read.reconstruct"}}
@@ -58,6 +63,9 @@ OLD_KEYS = {
               "write_parity_workers"},
     "degraded_read": {"gather_survivors", "local_pread", "reconstruct"},
 }
+# PR 33: the fleet job says how many stripe rows it copied on the host
+OLD_KEYS["fleet"].add("rows_staged")
+OLD_KEYS["fleet_spans"] = OLD_KEYS["fleet"]
 
 
 @pytest.fixture(autouse=True)
@@ -110,19 +118,31 @@ def prepare(kind: str, tmp_path):
             assert ec_files.rebuild_ec_files(base, batch_size=BATCH,
                                              stats=stats) == [3]
             return stats, ("job", _last_job("ec_rebuild")["id"])
-    elif kind == "fleet":
+    elif kind in ("fleet", "fleet_spans"):
         bases = []
         for i, size in enumerate((150_000, 99_777)):
             bases.append(str(tmp_path / f"f{i}"))
             rng.integers(0, 256, size, dtype=np.uint8).tofile(
                 bases[-1] + ".dat")
+        codec = None  # the one-device XLA shell: a staged [U, k, W] batch
+        if kind == "fleet_spans":
+            from seaweedfs_tpu.models import rs
+            from seaweedfs_tpu.parallel import mesh as pmesh
+            codec = pmesh.FleetUnitEncoder(
+                rs.get_code(10, 4), pmesh.make_mesh(8, ("unit",)))
 
         def op():
             stats: dict = {}
             fleet_convert.convert_volumes(
                 bases, large_block=LARGE, small_block=SMALL,
-                batch_size=BATCH, stats=stats)
-            assert stats["backend"] == "JaxRSCodec"
+                batch_size=BATCH, stats=stats, codec=codec)
+            assert stats["backend"] == (
+                "JaxRSCodec" if codec is None else "FleetUnitEncoder")
+            # every row of the staged stream (ten columns of a large row
+            # and fifty small rows, then a hundred small rows); the one
+            # volume's last, short row where units go up from the maps
+            assert stats["rows_staged"] == (60 + 100 if codec is None
+                                            else 1)
             return stats, ("job", _last_job("fleet_convert")["id"])
     else:
         base, blobs = _make_ec(tmp_path)
@@ -168,7 +188,7 @@ def _annotations(trace_dir: str) -> dict[str, list[dict]]:
 
 # -- (a) the profiler session is the switch ---------------------------------
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", MESH_KINDS)
 def test_stages_annotate_the_profilers_trace(kind, tmp_path):
     op = prepare(kind, tmp_path / "data")
     assert pipeline._profiler_annotation() is None  # no session: nothing
@@ -238,11 +258,11 @@ def _sum(stats, *keys):
     return sum(stats[k] for k in keys)
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", MESH_KINDS)
 def test_sum_identities_and_every_old_key(kind, tmp_path):
     stats, _ = prepare(kind, tmp_path)()
     assert OLD_KEYS[kind] <= set(stats), OLD_KEYS[kind] - set(stats)
-    if kind in ("encode", "fleet"):
+    if kind in ("encode", "fleet", "fleet_spans"):
         assert stats["encode_s"] == pytest.approx(
             _sum(stats, "h2d_s", "dispatch_s"), rel=1e-9)
         assert stats["d2h_s"] == pytest.approx(

@@ -273,6 +273,36 @@ def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
               NamedSharding(col_mesh, P(None, "data")))).compile()
 
 
+@pytest.mark.parametrize("rows", [WIDE, 10])
+def test_fleet_unit_program_compiles_for_v5e(v5e, rows):
+    """The fleet stream's unit program over all four devices, as the seam
+    launches it (ops/dispatch.dispatch_parity_batch on spans): one unit a
+    chip, each of its `rows` stripe rows a 1-D array of 10 MiB on that
+    chip (a global [4 * 10 MiB] array sharded over the unit axis), laid
+    out [1, 10, rows MiB] inside the shard_map, through the batch kernel,
+    parity back as m 1-D runs of [rows MiB] a chip.  The benchmark's
+    256 MiB volumes cut a 16-row and a 10-row unit each."""
+    code = rs.get_code(10, 4)
+    fleet_mesh = Mesh(np.array(v5e), ("unit",))
+    enc = pmesh.FleetUnitEncoder(code, fleet_mesh, kernel="pallas",
+                                 tile=TILE)
+    assert 10 * MIB >= dispatch.ROW_PUTS_FROM  # so: row by row
+    bm = _spec(enc.parity_bits.shape, jnp.int8,
+               NamedSharding(fleet_mesh, P()))
+    units = (tuple(_spec((4 * 10 * MIB,), jnp.uint8, enc.in_sharding)
+                   for _ in range(rows)),)
+    compiled = enc._encode_linear.lower(bm, units, stripes=rows).compile()
+    (runs,) = compiled.out_info
+    assert [o.shape for o in runs] == [(4 * rows * MIB,)] * 4
+    assert all(o.sharding.is_equivalent_to(enc.in_sharding, 1)
+               for o in runs)  # (rows MiB,) a device
+    text = compiled.as_text()
+    assert not re.search(r"all-(reduce|gather|to-all)|collective-permute",
+                         text)  # parity is unit-local
+    assert "input_output_alias" not in text
+    _assert_trace_names(compiled, "gf_apply_batch", "jit_batch_body")
+
+
 def test_tpu_codec_off_tpu_raises_instead_of_interpreting(monkeypatch):
     from seaweedfs_tpu.storage.ec import ec_files
     assert jax.default_backend() == "cpu"
