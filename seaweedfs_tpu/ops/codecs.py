@@ -24,11 +24,14 @@ is 6 + 3 on every backend.  `rs` and `lrc` are fixed matrices with a
 shells ask for: on a TPU under `auto` / `tpu` both get `PallasRSCodec`.
 So does MSR, whose inner code is a fixed matrix over alpha sub-rows a
 file ([72, 72] under msr_9_16; the kernel's tile follows from the matrix,
-`pallas_gf.matrix_tile`), wrapped in its file codec.  A tag whose family
-or geometry the chosen backend does not carry raises `CodecUnsupported`
-before any file is touched (the volume server answers 400 with the
-reason): the mesh encoder and the fleet conversion are Reed-Solomon
-only, the latter RS(10,4) only.
+`pallas_gf.matrix_tile`), wrapped in its file codec.  The multi-volume
+conversion (`fleet=True`) carries every tag a single volume's encode
+carries, on every backend: its stream takes k, m and the sub-rows a file
+from the codec (ops/fleet_convert).  A tag whose family or geometry the
+chosen backend does not carry raises `CodecUnsupported` before any file
+is touched (the volume server answers 400 with the reason): the
+column-sharded mesh encoder is Reed-Solomon only, no set has more than
+`layout.MAX_TOTAL_SHARDS` files.
 
 Knobs: WEEDTPU_CODEC_DEFAULT (tag or family for untagged volumes),
 WEEDTPU_CODEC_LRC ("k,l,g" params behind the bare "lrc" family name),
@@ -216,18 +219,15 @@ def backend_for(spec: CodecSpec, kind: str, platform: str | None = None,
     alone, which is the only kind that asks JAX anything (`_platform`);
     `fleet` is the multi-volume conversion's resolution, which under
     `auto` takes the unit-sharded mesh encoder when there is more than
-    one device.  Raises CodecUnsupported where the backend does not
+    one device (and under `mesh` too: units shard where columns would)
+    and carries every tag, family and geometry the single-volume
+    resolution does.  Raises CodecUnsupported where the backend does not
     carry the tag."""
     from seaweedfs_tpu.storage.ec import layout
     if spec.k < 1 or spec.m < 1 or spec.n > layout.MAX_TOTAL_SHARDS:
         raise CodecUnsupported(
             f"{spec.tag}: {spec.k} + {spec.m} shard files; a shard set "
             f"has at least 1 + 1 and at most {layout.MAX_TOTAL_SHARDS}")
-    if fleet and spec.tag != DEFAULT_TAG:
-        raise CodecUnsupported(
-            f"{spec.tag}: fleet conversion stripes and writes the "
-            f"{DEFAULT_TAG} layout only (ops/fleet_convert._VolumeJob); "
-            f"convert the volume with /admin/ec/generate")
     if fleet and (kind in ("mesh", "fleet") or
                   (kind == "auto" and devices > 1)):
         return "fleet"

@@ -181,7 +181,7 @@ def describe(codec, jax_live: bool = False) -> dict:
     if kernel is not None:
         info.update(body=kernel.kind, mesh_devices=int(shell.mesh.size))
         if kernel.kind == "pallas":  # always compiled inside shard_map
-            info.update(interpret=False, tile=kernel.tile)
+            info.update(interpret=False, tile=shell.tile)
     else:  # the Pallas shell carries both; the XLA shell its bucket tile
         info.update({f: getattr(shell, f) for f in ("interpret", "tile")
                      if hasattr(shell, f)})
@@ -193,11 +193,17 @@ def geometry(codec) -> dict:
     """The rows the codec's parity matrix takes and gives, and how many of
     them are one shard file's: 10, 4 and 1 under rs_10_4; 72, 72 and 8
     under msr_9_16, whose matrix works on the files' byte-interleaved
-    sub-rows.  /perf says it on the codec's block and on the
-    `encode_parity` row."""
+    sub-rows.  /perf says it on the codec's block and, with the matrix's
+    tile, on the `encode_parity` and `fleet_encode` rows."""
     shell = getattr(codec, "inner", codec)
     return {"rows_in": shell.k, "rows_out": shell.m,
             "alpha": getattr(codec, "alpha", 1)}
+
+
+def _note_matrix(kernel: str, codec) -> None:
+    """What a device entry point's parity matrix is, on its /perf row."""
+    KERNELS.note(kernel, "device", **geometry(codec),
+                 tile=getattr(getattr(codec, "inner", codec), "tile", None))
 
 
 def _unstriped(spans, k: int, stripes: int) -> np.ndarray:
@@ -248,8 +254,7 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
                 for a in runs:  # see materialize
                     a.copy_to_host_async()
                 return runs
-            KERNELS.note("encode_parity", "device", **geometry(codec),
-                         tile=getattr(codec, "inner", codec).tile)
+            _note_matrix("encode_parity", codec)
             return _device_call(
                 job, unit, "encode_parity", nbytes,
                 lambda: tuple(jnp.asarray(s) for s in spans), run,
@@ -313,9 +318,11 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
     rows, alike in length from slot to slot, or None.  Every piece is put
     1-D to its slot's device from where it lies (`place_units`; an empty
     slot costs no PCIe byte), the mesh program lays the units out and
-    gives each one's parity as m runs of `[stripes * block]`, and the
-    copies back of the occupied slots' runs are asked for here, at the
-    enqueue (see `materialize`).  What comes back is a list, slot by
+    gives each one's parity as one run of `[stripes * block]` for each of
+    the m parity files (k and m are the codec's files; under a
+    sub-packetised code the program splits and merges their sub-rows),
+    and the copies back of the occupied slots' runs are asked for here, at
+    the enqueue (see `materialize`).  What comes back is a list, slot by
     slot, of m device arrays or None.  The runtime reads a piece after
     its put returns, so the pieces stay alive and unchanged until the
     parity is materialised.
@@ -335,6 +342,7 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
                 for a in runs:
                     a.copy_to_host_async()
             return parity
+        _note_matrix("fleet_encode", codec)
         return _device_call(
             job, unit, "fleet_encode",
             sum(p.nbytes for u in filter(None, units) for p in u),
@@ -348,6 +356,7 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
             lambda us: np.stack([codec.encode_parity(u) for u in us]))
     else:
         import jax.numpy as jnp
+        _note_matrix("fleet_encode", codec)
         return _device_call(job, unit, "fleet_encode", nbytes,
                             lambda: getattr(codec, "place",
                                             jnp.asarray)(units),

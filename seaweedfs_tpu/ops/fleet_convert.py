@@ -4,7 +4,10 @@
 encode / write, but between volumes the device drains and the writers
 idle — fleet-wide cold-volume conversion (the consumer the autopilot
 demote path feeds) runs as N serial encodes.  This module interleaves N
-volumes' column units into ONE stream of unit batches:
+volumes' column units into ONE stream of unit batches, under whatever
+code the codec handed in is (its k data and m parity files a volume, its
+stripe width k, its sub-rows a file: RS(10,4)'s 10 + 4, LRC(12,2,2)'s
+12 + 4, PM-MSR(9,16)'s 9 + 9 with 8 sub-rows; `.vif` under its tag):
 
     reader      walks the volumes round-robin and fills batches of one
                 unit a slot (data shards go straight to each volume's
@@ -15,7 +18,8 @@ volumes' column units into ONE stream of unit batches:
                 map, as a single volume's encode has it
                 (ec_files._iter_spans: up to batch_size // block
                 consecutive stripe rows, sixteen 1 MiB rows at the served
-                sizes), selected as views (ec_files._unit_spans): the
+                sizes, k blocks wide), selected as views
+                (ec_files._unit_spans): the
                 only bytes the reader moves are a volume's last, short
                 row, into a zeroed buffer of its own, counted as
                 `rows_staged`.  A batch holds units of one shape (one
@@ -25,14 +29,15 @@ volumes' column units into ONE stream of unit batches:
                 host batch and counted
     dispatch    puts every unit's pieces 1-D to its own device from where
                 they lie and launches ONE mesh program a batch, which
-                lays the units out and runs the batched parity kernel
+                lays the units out (and splits a sub-packetised code's
+                file rows into sub-rows) and runs the batched parity kernel
                 (ops/dispatch.dispatch_parity_batch; a staged batch goes
                 up 2-D through the encoder's matched in_sharding)
     drain       waits, then takes each unit's parity as it comes off its
-                device (dispatch.unit_parity_shards: m contiguous runs a
-                unit, their copies asked for at the enqueue) and hands
-                parity shard k + i of the owning volume one run at
-                shard_off — no full gather
+                device (dispatch.unit_parity_shards: one contiguous run
+                of each of the m parity files a unit, their copies asked
+                for at the enqueue) and hands parity shard k + i of the
+                owning volume one run at shard_off — no full gather
     writers     per-volume _ShardWriterPool; a volume whose last unit
                 drains is finalized (truncate to shard size, .vif,
                 tmp -> rename commit) while the stream keeps feeding the
@@ -91,9 +96,11 @@ def fleet_codec(kind: str | None = None, tag: str | None = None):
     attached device (a real slice, or the virtual CPU mesh in tests)
     selects the unit-sharded FleetUnitEncoder, one device whatever a
     single volume's encode resolves to — every backend takes
-    `dispatch_parity_batch`.  The stream stripes and writes RS(10,4)
-    (`_VolumeJob`): any other `tag` raises codecs.CodecUnsupported.  A
-    backend that fails to initialise raises."""
+    `dispatch_parity_batch`.  `tag` is the code the volumes go under
+    (None: the default, rs_10_4), any that `resolve` carries for a single
+    volume: the stream takes its striping, its files and its sub-rows
+    from the codec (`convert_volumes`).  A tag no backend carries raises
+    codecs.CodecUnsupported; a backend that fails to initialise raises."""
     from seaweedfs_tpu.ops import codecs
     kind = kind or os.environ.get("WEEDTPU_CONVERT_CODEC") or \
         os.environ.get("WEEDTPU_EC_CODEC", "auto")
@@ -105,26 +112,30 @@ class _VolumeJob:
     its writer pool, and completion accounting."""
 
     def __init__(self, base: str, dat_path: str | None, large_block: int,
-                 small_block: int, batch_size: int, pjob, spans: bool):
+                 small_block: int, batch_size: int, pjob, spans: bool,
+                 spec):
+        """`spec` (codecs.CodecSpec) is the code the volume goes under:
+        its k-wide striping, its n shard files, its tag in the `.vif`."""
         self.base = base
+        self.tag = spec.tag
         self.dat_path = dat_path or base + ".dat"
         self.dat_size = os.path.getsize(self.dat_path)
         self.large_block = large_block
         self.small_block = small_block
+        k, n = spec.k, spec.n
         self.shard_size = layout.shard_file_size(
-            self.dat_size, large_block, small_block)
+            self.dat_size, large_block, small_block, data_shards=k)
         self.tmp_paths = [base + layout.to_ext(i) + ".tmp"
-                          for i in range(layout.TOTAL_SHARDS)]
+                          for i in range(n)]
         self.out_fds = [os.open(p, os.O_RDWR | os.O_CREAT, 0o644)
                         for p in self.tmp_paths]
-        self.highwater = [0] * layout.TOTAL_SHARDS
+        self.highwater = [0] * n
         self.dat_f = open(self.dat_path, "rb")
         self.mm = None
         self.view: np.ndarray | None = None
         if self.dat_size:
             self.mm = _map_readonly(self.dat_f.fileno(), self.dat_size)
             self.view = np.frombuffer(self.mm, dtype=np.uint8)
-        k = layout.DATA_SHARDS
         self.writers = _ShardWriterPool(
             self.out_fds, self.highwater, pjob,
             stage_of=lambda i: "write_data" if i < k else "write_parity")
@@ -132,13 +143,12 @@ class _VolumeJob:
         # ships data-shard copies, the drain ships parity rows — a
         # _ShardFlusher is single-producer (its per-shard job lists and
         # accumulator are unlocked)
-        self.data_flusher = _ShardFlusher(self.writers, layout.TOTAL_SHARDS)
-        self.parity_flusher = _ShardFlusher(self.writers,
-                                            layout.TOTAL_SHARDS)
+        self.data_flusher = _ShardFlusher(self.writers, n)
+        self.parity_flusher = _ShardFlusher(self.writers, n)
         # (row_start, block, col, step, shard_off, rows): spans of the map
         # for a codec that lays a unit out on the device, else one stripe
         # row (or a column cut of one) a unit
-        geometry = (self.dat_size, large_block, small_block, batch_size)
+        geometry = (self.dat_size, large_block, small_block, batch_size, k)
         self.units = _iter_spans(*geometry) if spans else (
             u + (1,) for u in _iter_units(*geometry))
         self.held = None  # the next unit, selected and not yet in a batch
@@ -181,7 +191,7 @@ class _VolumeJob:
         for fd in self.out_fds:
             os.close(fd)
         self.out_fds = []
-        write_vif(self.base, self.dat_size)
+        write_vif(self.base, self.dat_size, codec=self.tag)
         for i, p in enumerate(self.tmp_paths):
             os.replace(p, self.base + layout.to_ext(i))
         self.committed = True
@@ -236,11 +246,15 @@ def convert_volumes(bases: list[str], *,
     `progress(bytes_done)` sees TOTAL volume bytes consumed across the
     fleet; `cancel()` aborts the whole run (uncommitted volumes roll
     back).  `stats` receives the usual per-stage wall-second attribution
-    plus units/volumes counters.  `codec_tag` is refused unless it names
-    the layout this stream writes (`fleet_codec`)."""
+    plus units/volumes counters, the code's tag (`codec`), its files a
+    volume (`shard_files`) and its sub-rows a file (`alpha`).  The code is
+    the codec's: `codec_tag` names it where no `codec` is handed in
+    (`fleet_codec`), and k, m and the stripe width follow from it."""
     if not bases:
         return {"volumes": {}, "bytes": 0}
     codec = codec if codec is not None else fleet_codec(tag=codec_tag)
+    from seaweedfs_tpu.ops import codecs
+    spec = codecs.spec_of(codec)
 
     # chaos hook: an armed shard_write_error fault fails the conversion
     # like a dying disk — before any tmp shard file exists
@@ -248,7 +262,7 @@ def convert_volumes(bases: list[str], *,
     for base in bases:
         _faults.check_shard_write(base)
 
-    k, m = layout.DATA_SHARDS, layout.PARITY_SHARDS
+    k, m = spec.k, spec.m
     depth = max(1, _env_int("WEEDTPU_CONVERT_DEPTH", 2))
     U = max(1, _env_int("WEEDTPU_CONVERT_UNITS", 4))
     slots = getattr(codec, "unit_slots", None)
@@ -261,6 +275,7 @@ def convert_volumes(bases: list[str], *,
     stats["mode"] = "fleet"
     stats["backend"] = backend_name(codec)
     stats["unit_batch"] = U
+    stats.update(codec=spec.tag, shard_files=spec.n, alpha=spec.alpha)
     stats["rows_staged"] = 0  # stripe rows copied on the host (pjob.count)
     # class=convert on THIS thread and (contextvars are per-thread) re-
     # stamped inside each pipeline thread, so any hop made on the
@@ -277,7 +292,7 @@ def convert_volumes(bases: list[str], *,
                            span="ec.fleet", sums=ENCODE_SUMS)
     try:
         jobs = [_VolumeJob(b, None, large_block, small_block, batch_size,
-                           pjob, spans) for b in bases]
+                           pjob, spans, spec) for b in bases]
     except BaseException as e:  # a volume that cannot be opened: no run
         pjob.finish(e)
         raise
@@ -290,7 +305,8 @@ def convert_volumes(bases: list[str], *,
     # zero-fill)
     pool: queue.Queue = queue.Queue()
     W = 0 if spans else max(_unit_steps(
-        j.dat_size, large_block, small_block, batch_size)[1] for j in jobs)
+        j.dat_size, large_block, small_block, batch_size, k)[1]
+        for j in jobs)
     for _ in range(depth + 1):
         pool.put(None if spans else np.empty((U, k, W), dtype=np.uint8))
     q_read: queue.Queue = queue.Queue(maxsize=depth)
@@ -318,7 +334,7 @@ def convert_volumes(bases: list[str], *,
                     job.held = (unit, pieces, staged,
                                 (rows, *map(len, pieces)))
             elif _unit_coverage(job.dat_size, row_start, block, col,
-                                step)[0]:
+                                step, k)[0]:
                 job.held = unit, None, 1, None
             if job.held is None:
                 # a trailing column unit wholly beyond the .dat: nothing
@@ -349,7 +365,8 @@ def convert_volumes(bases: list[str], *,
         row_start, block, col, step, shard_off, rows = unit
         for r in range(rows):
             nz, tail = _unit_coverage(
-                job.dat_size, row_start + r * k * block, block, col, step)
+                job.dat_size, row_start + r * k * block, block, col, step,
+                k)
             for j in range(nz):
                 job.data_flusher.copy(
                     j, job.dat_f.fileno(),
@@ -447,9 +464,10 @@ def convert_volumes(bases: list[str], *,
                     touched = []
                     for u in range(a, min(b, len(metas))):
                         job, shard_off, width = metas[u]
-                        # m runs of a unit that went up as spans, the
-                        # rows of [m, W] of a staged one: one contiguous
-                        # run of each parity shard's file either way
+                        # the m parity files' runs of a unit that went
+                        # up as spans, the rows of [m, W] of a staged
+                        # one: one contiguous run of each parity shard's
+                        # file either way
                         for i, run in enumerate(block[u - a]):
                             job.parity_flusher.put(k + i, run[:width],
                                                    shard_off)
@@ -534,8 +552,7 @@ def convert_volumes(bases: list[str], *,
         done_jobs = [j for j in jobs if j.committed]
         _book_stage_bytes(pjob, stats,
                           sum(j.dat_size for j in done_jobs),
-                          layout.PARITY_SHARDS *
-                          sum(j.shard_size for j in done_jobs))
+                          m * sum(j.shard_size for j in done_jobs))
         pjob.finish(errors[0] if errors else None)
     if errors:
         raise errors[0]

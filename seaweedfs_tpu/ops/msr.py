@@ -229,6 +229,14 @@ class MSRFileCodec:
         if factory is not None:
             self._factory = factory
         self.host_backend = getattr(inner, "host_backend", False)
+        if hasattr(inner, "encode_units_linear"):
+            # the fleet stream's surface (parallel/mesh.FleetUnitEncoder):
+            # a unit's pieces hold rows of k file blocks and come back as
+            # m file runs, split and merged in the mesh program
+            self.unit_slots = inner.unit_slots
+            self.place_units = inner.place_units
+            self.encode_units_linear = functools.partial(
+                inner.encode_units_linear, alpha=self.alpha)
 
     @property
     def tile(self) -> int:
@@ -271,10 +279,21 @@ class MSRFileCodec:
                                 self.m, self.alpha)
 
     def encode_parity_batch(self, units):
-        """[U, k, L] -> [U, m, L] through the inner batch kernel."""
+        """[U, k, L] -> [U, m, L]: a staged batch of the fleet stream, one
+        stripe row of k file blocks a unit.  On a device shell with a
+        linear apply each unit is one program that splits its rows into
+        sub-rows and merges the product (a stripe row is
+        `codec_base.stacked`'s third form; the eager [L / alpha, alpha]
+        turn is the one a TPU's compiler takes minutes over); else the
+        reshapes below round the inner batch kernel."""
         U, kk, L = units.shape
         a = self.alpha
         assert kk == self.k and L % a == 0, units.shape
+        if not isinstance(units, np.ndarray) and \
+                hasattr(self.inner, "encode_parity_linear"):
+            import jax.numpy as jnp
+            return jnp.stack([jnp.stack(self.inner.encode_parity_linear(
+                units[u].reshape(-1), 1, a)) for u in range(U)])
         virt = units.reshape(U, self.k, L // a, a).swapaxes(2, 3).reshape(
             U, self.k * a, L // a)
         enc = getattr(self.inner, "encode_parity_batch", None)

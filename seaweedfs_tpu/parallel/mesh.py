@@ -85,7 +85,11 @@ class _ApplyKernel:
     `body(bm, x2)` / `batch_body(bm, x3)` apply it to a local [k, n] /
     [U, k, n] block inside shard_map.  Both bodies are un-jitted — they
     inline into the enclosing jit(shard_map) — and both tolerate
-    non-tile-aligned column counts (the Pallas body pads internally)."""
+    non-tile-aligned column counts (the Pallas body pads internally).
+    `tile` is the widest the Pallas body will use; the tile of a matrix
+    follows from the matrix (`matrix_tile`, as `PallasGFMatrix` has it:
+    131072 on a TPU for every RS and LRC matrix, 8192 for PM-MSR's
+    [72, 72])."""
 
     def __init__(self, kernel: str = "auto", tile: int | None = None):
         self.kind = resolve_kernel(kernel)
@@ -109,16 +113,23 @@ class _ApplyKernel:
         pp = self._pg.PLANE_PAD
         return max(pp, -(-k // pp) * pp)
 
+    def matrix_tile(self, m: int, k: int) -> int:
+        """The tile of an [m, k] matrix's Pallas body (0: the XLA body
+        has none)."""
+        if self._pg is None:
+            return 0
+        return self._pg.matrix_tile(m, self._kpad(k), self.tile)
+
     def body(self, bm: jax.Array, x: jax.Array) -> jax.Array:
         if self._pg is None:
             return gfmat_jax.bitsliced_apply_body(bm, x)
         k, n = x.shape
         m = bm.shape[0] // 8
-        pad = (-n) % self.tile
+        tile = self.matrix_tile(m, k)
+        pad = (-n) % tile
         if pad:
             x = jnp.pad(x, ((0, 0), (0, pad)))
-        out = self._pg._gf_apply(bm, x, k, m, self._kpad(k), self.tile,
-                                 False)
+        out = self._pg._gf_apply(bm, x, k, m, self._kpad(k), tile, False)
         return out[:, :n] if pad else out
 
     def batch_body(self, bm: jax.Array, x: jax.Array) -> jax.Array:
@@ -126,11 +137,12 @@ class _ApplyKernel:
             return gfmat_jax.bitsliced_apply_batch_body(bm, x)
         U, k, n = x.shape
         m = bm.shape[0] // 8
-        pad = (-n) % self.tile
+        tile = self.matrix_tile(m, k)
+        pad = (-n) % tile
         if pad:
             x = jnp.pad(x, ((0, 0), (0, 0), (0, pad)))
-        out = self._pg._gf_apply_batch(bm, x, k, m, self._kpad(k),
-                                       self.tile, False)
+        out = self._pg._gf_apply_batch(bm, x, k, m, self._kpad(k), tile,
+                                       False)
         return out[:, :, :n] if pad else out
 
 
@@ -152,6 +164,7 @@ class ShardedRSEncoder:
         self.col_axis = col_axis
         self.vol_axis = vol_axis
         self.kernel = _ApplyKernel(kernel, tile)
+        self.tile = self.kernel.matrix_tile(self.m, self.k)  # for /perf
         self.parity_bits = self.kernel.lift(code.parity_matrix)
 
         apply_body = self.kernel.body
@@ -290,7 +303,13 @@ class FleetUnitEncoder:
       one contiguous run of each parity shard's file: nothing 2-D
       crosses (TPU v5e: a 2-D uint8 array goes through a host relayout in
       both directions that costs more than the transfer; PERF.md, PR 26
-      and PR 31).  One program per distinct unit shape;
+      and PR 31).  Under a sub-packetised code (`alpha` > 1: PM-MSR,
+      whose matrix works on alpha sub-rows a file, `MSRFileCodec` over
+      this encoder) the same pieces hold rows of k / alpha blocks, the
+      program splits each file's R * block bytes into its alpha sub-rows
+      ([1, k, R * block / alpha]: sixteen 9 MiB rows become
+      [1, 72, 2 MiB] under msr_9_16) and merges the product back into
+      m / alpha file runs.  One program per distinct unit shape;
       a [U, k, B] batch (`place`, `encode_parity_batch`): a host array
       from a test or a caller that holds one; parity [U, m, B], its
       device-local blocks streamed by `unit_shards`.
@@ -310,6 +329,7 @@ class FleetUnitEncoder:
         self.unit_axis = unit_axis
         self.n_devices = mesh.shape[unit_axis]
         self.kernel = _ApplyKernel(kernel, tile)
+        self.tile = self.kernel.matrix_tile(self.m, self.k)  # for /perf
         self.parity_bits = self.kernel.lift(code.parity_matrix)
         self.in_sharding = NamedSharding(mesh, P(unit_axis))
         # the fleet program's name on a device trace: `jit_batch_body`
@@ -318,7 +338,7 @@ class FleetUnitEncoder:
             mesh=mesh, in_specs=(P(), P(unit_axis)),
             out_specs=P(unit_axis)))
         self._encode_linear = codec_base.named_jit(
-            "batch_body", static_argnames=("stripes",))(self._linear)
+            "batch_body", static_argnames=("stripes", "alpha"))(self._linear)
         # (device, bytes) -> a zero piece resident on that device: what a
         # slot with no unit is made of
         self._zeros: dict = {}
@@ -329,12 +349,13 @@ class FleetUnitEncoder:
         D = self.n_devices
         return max(D, -(-min_units // D) * D)
 
-    def _linear(self, bm, units, stripes: int):
-        """[slot-in-device][piece] unit-sharded 1-D arrays -> [slot][row]
+    def _linear(self, bm, units, stripes: int, alpha: int = 1):
+        """[slot-in-device][piece] unit-sharded 1-D arrays -> [slot][run]
         unit-sharded parity runs: inside the shard_map each chip sees its
-        own units' pieces, stacks them (`codec_base.stacked`), runs the
-        batch kernel on [U/D, k, W] and splits the parity into m runs a
-        unit (`codec_base.unstacked`)."""
+        own units' pieces, stacks them (`codec_base.stacked`; `alpha`
+        sub-rows a file), runs the batch kernel on [U/D, k, W / alpha] and
+        splits the parity into m / alpha file runs a unit
+        (`codec_base.unstacked`)."""
         def body(bm, units):
             # the barrier keeps the compiler from fusing a unit's layout
             # into the step to three dimensions: fused, a two-row unit
@@ -342,9 +363,10 @@ class FleetUnitEncoder:
             # (compiled with no chip, PR 33)
             out = self.kernel.batch_body(bm, jnp.stack(
                 [jax.lax.optimization_barrier(
-                    codec_base.stacked(pieces, self.k, stripes))
+                    codec_base.stacked(pieces, self.k, stripes, alpha))
                  for pieces in units]))
-            return tuple(codec_base.unstacked(o, stripes) for o in out)
+            return tuple(codec_base.unstacked(o, stripes, alpha)
+                         for o in out)
         return shard_map(body, mesh=self.mesh,
                          in_specs=(P(), P(self.unit_axis)),
                          out_specs=P(self.unit_axis))(bm, units)
@@ -381,11 +403,14 @@ class FleetUnitEncoder:
                 for i, n in enumerate(lengths))
             for j in range(per))
 
-    def encode_units_linear(self, placed: tuple, stripes: int) -> list:
+    def encode_units_linear(self, placed: tuple, stripes: int,
+                            alpha: int = 1) -> list:
         """`place_units`' arrays -> per slot, in `place_units`' order, the
-        m parity runs of its unit as 1-D arrays on the slot's device
-        (un-materialised: the caller's sync point waits and copies)."""
-        out = self._encode_linear(self.parity_bits, placed, stripes=stripes)
+        m / alpha parity files' runs of its unit as 1-D arrays on the
+        slot's device (un-materialised: the caller's sync point waits and
+        copies)."""
+        out = self._encode_linear(self.parity_bits, placed, stripes=stripes,
+                                  alpha=alpha)
         D = self.n_devices
         # global run -> its D device-local runs, in mesh order
         local = [[sorted(run.addressable_shards,

@@ -182,6 +182,208 @@ def test_span_batch_parity_runs_and_empty_slots(unit_mesh):
         assert np.array_equal(np.stack(runs), want)
 
 
+# ---- the stream under the volumes' code ----------------------------------
+# k, the shard files a volume and the sub-rows a file come from the codec:
+# every tag a single volume's encode carries, on the mesh encoder (units
+# as spans of the maps), on a host codec and on a one-device device codec
+# (one stripe row a unit, staged [U, k, W]).
+
+# block sizes that msr_9_16's eight sub-rows a file divide; a unit is up
+# to eight small rows, a large block is cut in sixteen columns
+LARGE, SMALL, BATCH = 16_384, 128, 1024
+TAGS = ["rs_10_4", "rs_6_3", "lrc_12_2_2", "msr_9_16"]
+
+
+def _model_encode(tag: str):
+    """The plain reference's encode of the tag's code: [k, L] -> [n, L]."""
+    from seaweedfs_tpu.models import lrc as lrc_model, msr as msr_model
+    from seaweedfs_tpu.ops import codecs
+    spec = codecs.parse_tag(tag)
+    if spec.family == "msr":
+        assert spec.params == (9, 16)
+        return msr_model.encode
+    if spec.family == "lrc":
+        assert spec.params == (12, 2, 2)
+        return lrc_model.encode
+    return rs.get_code(spec.k, spec.m).encode_numpy
+
+
+def _model_files(tag: str, raw: bytes) -> list[bytes]:
+    """The shard files `raw` must convert to: upstream's row-major
+    striping k wide (large rows while more than one large row's bytes
+    remain, then small rows, the last zero-padded), by hand, under the
+    model's reference encode."""
+    from seaweedfs_tpu.ops import codecs
+    k = codecs.parse_tag(tag).k
+    files = [bytearray() for _ in range(k)]
+    at = 0
+    while len(raw) - at > k * LARGE:
+        for j in range(k):
+            files[j] += raw[at:at + LARGE]
+            at += LARGE
+    while at < len(raw):
+        for j in range(k):
+            files[j] += raw[at:at + SMALL].ljust(SMALL, b"\0")
+            at += SMALL
+    if not files[0]:
+        return [b""] * codecs.parse_tag(tag).n
+    return [f.tobytes() for f in _model_encode(tag)(np.array(
+        [np.frombuffer(bytes(f), dtype=np.uint8) for f in files]))]
+
+
+def _files_of(base: str, n: int) -> list[bytes]:
+    out = []
+    for i in range(n):
+        with open(base + layout.to_ext(i), "rb") as f:
+            out.append(f.read())
+    assert not os.path.exists(base + layout.to_ext(n))
+    return out
+
+
+def _single_volume_files(tmp_path, base: str, raw: bytes, tag: str,
+                         monkeypatch) -> list[bytes]:
+    """What `write_ec_files(..., codec_tag=tag)` leaves of the same
+    `.dat`, under the numpy codec."""
+    from seaweedfs_tpu.ops import codecs
+    ref = str(tmp_path / ("single_" + os.path.basename(base)))
+    with open(ref + ".dat", "wb") as f:
+        f.write(raw)
+    with monkeypatch.context() as m:
+        m.setenv("WEEDTPU_EC_CODEC", "numpy")
+        ec_files.write_ec_files(ref, large_block=LARGE, small_block=SMALL,
+                                batch_size=BATCH, codec_tag=tag)
+    assert ec_files.read_vif(ref)["codec"] == tag
+    return _files_of(ref, codecs.parse_tag(tag).n)
+
+
+def _tag_sizes(tag: str) -> list[int]:
+    """Unequal volumes: one with a large row, one that ends inside a
+    stripe row, one that ends on a row boundary, one inside its first
+    row."""
+    from seaweedfs_tpu.ops import codecs
+    k = codecs.parse_tag(tag).k
+    return [k * LARGE + 5 * k * SMALL + 777, 137_777, 11 * k * SMALL, 37]
+
+
+@pytest.mark.parametrize("kind", ["fleet", "numpy", "jax"])
+@pytest.mark.parametrize("tag", TAGS)
+def test_convert_under_the_tag_byte_identity(tmp_path, monkeypatch, tag,
+                                             kind):
+    """`convert_volumes(..., codec_tag=tag)`: every shard file of every
+    volume equals, byte for byte, the file `write_ec_files(...,
+    codec_tag=tag)` leaves and the model's reference encode; n files a
+    volume, the tag in the `.vif`, the code on the job's stats."""
+    from seaweedfs_tpu.ops import codecs
+    spec = codecs.parse_tag(tag)
+    sizes = _tag_sizes(tag)
+    bases, payloads = _make_volumes(tmp_path, sizes, seed=34)
+    monkeypatch.setenv("WEEDTPU_CONVERT_CODEC", kind)
+    stats: dict = {}
+    rep = fleet_convert.convert_volumes(
+        bases, large_block=LARGE, small_block=SMALL, batch_size=BATCH,
+        codec_tag=tag, stats=stats)
+    assert rep["bytes"] == sum(sizes)
+    assert (stats["codec"], stats["shard_files"], stats["alpha"]) == \
+        (tag, spec.n, spec.alpha)
+    spans = kind == "fleet"
+    if kind != "numpy":  # numpy: the bare RS code, or a shell round another
+        assert stats["backend"] == {"fleet": "FleetUnitEncoder",
+                                    "jax": "JaxRSCodec"}[kind]
+    for base, raw in zip(bases, payloads):
+        got = _files_of(base, spec.n)
+        assert got == _single_volume_files(tmp_path, base, raw, tag,
+                                           monkeypatch), base
+        assert got == _model_files(tag, raw), base
+        assert ec_files.read_vif(base) == {
+            "version": ec_files.read_vif(base)["version"],
+            "dat_file_size": len(raw), "codec": tag}
+    if spans:  # only a volume's last, short row is copied on the host
+        assert stats["rows_staged"] == sum(
+            1 for n in sizes if n % (spec.k * SMALL))
+        assert rep["devices"] > 1
+    else:
+        assert stats["rows_staged"] == stats["units"]
+    assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
+
+
+def test_convert_msr_on_a_four_device_mesh(tmp_path, monkeypatch):
+    """PM-MSR(9,16) over four devices, as a four-chip host has it: a unit
+    is eight 9-block stripe rows put 1-D to its device, the mesh program
+    splits each file's bytes into eight sub-rows ([1, 72, W / 8]) and
+    gives back nine contiguous file runs; eighteen files a volume."""
+    from seaweedfs_tpu.ops import codecs, msr
+    from seaweedfs_tpu.parallel import mesh as pmesh
+    tag = "msr_9_16"
+    enc = pmesh.FleetUnitEncoder(msr.get_code(9, 16),
+                                 pmesh.make_mesh(4, ("unit",)))
+    codec = msr.MSRFileCodec(enc)
+    assert (codec.k, codec.m, codec.alpha, enc.k, enc.m) == (9, 9, 8, 72, 72)
+    assert codecs.spec_of(codec).tag == tag
+    sizes = [9 * SMALL * 8 * 3, 9 * SMALL * 8 * 3 - 5, 9 * SMALL * 13 + 1,
+             9 * SMALL * 8, 50_000, 50_001]
+    bases, payloads = _make_volumes(tmp_path, sizes, seed=35)
+    batches = []
+    orig = fleet_convert.dispatch_parity_batch
+
+    def dispatch(codec, units, **kw):
+        parity = orig(codec, units, **kw)
+        batches.append((units, kw["stripes"], parity))
+        return parity
+
+    monkeypatch.setattr(fleet_convert, "dispatch_parity_batch", dispatch)
+    stats: dict = {}
+    rep = fleet_convert.convert_volumes(
+        bases, large_block=LARGE, small_block=SMALL, batch_size=BATCH,
+        codec=codec, stats=stats)
+    monkeypatch.undo()
+    assert (stats["unit_batch"], rep["devices"]) == (4, 4)
+    assert (stats["codec"], stats["shard_files"], stats["alpha"]) == \
+        (tag, 18, 8)
+    for base, raw in zip(bases, payloads):
+        assert _files_of(base, 18) == _model_files(tag, raw), base
+    assert stats["rows_staged"] == sum(1 for n in sizes if n % (9 * SMALL))
+    for units, stripes, parity in batches:
+        assert isinstance(units, list) and len(units) == 4
+        shapes = {tuple(map(len, u)) for u in units if u is not None}
+        assert len(shapes) == 1  # one program a batch
+        assert sum(shapes.pop()) == stripes * 9 * SMALL
+        for u, runs in zip(units, parity):
+            assert (u is None) == (runs is None)
+            if runs is not None:  # nine file runs, not 72 sub-rows
+                assert [r.shape for r in runs] == [(stripes * SMALL,)] * 9
+
+
+@pytest.mark.parametrize("kind", ["fleet", "numpy"])
+def test_convert_cancel_under_msr_keeps_the_previous_set(tmp_path,
+                                                         monkeypatch, kind):
+    """A cancelled run under `msr_9_16` leaves the previous 18-file set
+    and its `.vif` untouched, nothing of the fresh volume visible and no
+    `.tmp` behind."""
+    tag = "msr_9_16"
+    bases, payloads = _make_volumes(tmp_path, [300_000, 280_000], seed=36)
+    with monkeypatch.context() as m:
+        m.setenv("WEEDTPU_EC_CODEC", "numpy")
+        ec_files.write_ec_files(bases[0], large_block=LARGE,
+                                small_block=SMALL, batch_size=BATCH,
+                                codec_tag=tag)
+    before, vif = _files_of(bases[0], 18), ec_files.read_vif(bases[0])
+    assert before == _model_files(tag, payloads[0])
+    calls = []
+
+    def cancel():
+        calls.append(1)
+        return len(calls) > 2  # abort a couple of units in
+
+    with pytest.raises(ec_files.EncodeCancelled):
+        fleet_convert.convert_volumes(
+            bases, large_block=LARGE, small_block=SMALL, batch_size=BATCH,
+            cancel=cancel, codec=fleet_convert.fleet_codec(kind, tag))
+    assert _files_of(bases[0], 18) == before
+    assert ec_files.read_vif(bases[0]) == vif
+    assert _shard_bytes(bases[1]) == {}
+    assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
+
+
 def test_convert_books_class_convert(tmp_path):
     """The whole conversion runs under netflow class=convert, so any
     network hop made on its behalf books repair-adjacent bytes."""

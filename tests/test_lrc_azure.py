@@ -334,9 +334,16 @@ def test_degraded_read_gathers_one_local_group(tmp_path, monkeypatch, kind,
     ("rs_10_4", "auto", "tpu", 1, True, "pallas"),
     ("rs_10_4", "cpp", None, 1, True, "native"),
     ("lrc_12_2_2", "mesh", None, 1, False, None),
-    ("rs_6_3", "auto", "tpu", 4, True, None),
-    ("lrc_12_2_2", "cpp", None, 1, True, None),
+    # the conversion stream is under the volumes' code (PR 34): what was
+    # refused for the fleet resolves as it does for a single volume
+    ("rs_6_3", "auto", "tpu", 4, True, "fleet"),
+    ("lrc_12_2_2", "cpp", None, 1, True, "native"),
     ("rs_40_8", "jax", None, 1, False, None),
+    ("msr_9_16", "auto", "tpu", 4, True, "fleet"),
+    ("msr_9_16", "auto", "tpu", 1, True, "pallas"),
+    ("lrc_12_2_2", "mesh", None, 1, True, "fleet"),
+    ("lrc_12_2_2", "auto", "native", 1, True, "native"),
+    ("rs_40_8", "auto", "tpu", 4, True, None),
 ])
 def test_backend_is_a_pure_function_of_tag_kind_and_platform(
         tag, kind, platform, devices, fleet, backend):
@@ -367,8 +374,11 @@ def test_one_object_from_all_three_former_entry_points(monkeypatch):
     assert (six.k, six.m) == (6, 3) and six is codecs.make_codec("rs_6_3",
                                                                  "jax")
     assert np.array_equal(six.code.matrix, rs.get_code(6, 3).matrix)
-    with pytest.raises(codecs.CodecUnsupported, match="rs_6_3"):
-        fleet_convert.fleet_codec("jax", "rs_6_3")
+    # the fleet's resolution carries the tag too, over the same object
+    assert fleet_convert.fleet_codec("jax", "rs_6_3") is six
+    assert fleet_convert.fleet_codec("jax", TAG) is codecs.resolve(TAG, "jax")
+    with pytest.raises(codecs.CodecUnsupported, match="rs_40_8"):
+        fleet_convert.fleet_codec("jax", "rs_40_8")
     with pytest.raises(codecs.CodecUnsupported, match="no such code"):
         codecs.resolve("lrc_12_5_2", "jax")  # 12 data in 5 groups
     # the selection rides /perf `codecs`
@@ -485,9 +495,48 @@ def test_rs_6_3_builds_six_plus_three(server):
     _no_leftovers(base)
 
 
+@pytest.mark.parametrize("tag, n", [("rs_6_3", 9), (TAG, 16),
+                                    ("msr_9_16", 18), (None, 14)])
+def test_fleet_convert_under_the_tag_equals_generate(server, tag, n):
+    """What `/admin/ec/fleet_convert` answered 400 to until PR 34: with a
+    `codec` the volume goes under that code, n files and the tag in the
+    `.vif`, byte for byte what `/admin/ec/generate` under the tag leaves;
+    `/admin/ec/progress` says the code, `/perf` the matrix.  Without a
+    tag: rs_10_4, as before."""
+    from seaweedfs_tpu.stats.profile import KERNELS
+    vs, base = server
+    KERNELS.reset()
+    body = {"volumes": [3]} if tag is None else {"volumes": [3],
+                                                 "codec": tag}
+    status, out = _call(vs.handle_ec_fleet_convert, body)
+    assert status == 200 and out["converted"] == [3], out
+    spec = codecs.parse_tag(tag)
+    got = _shard_files(base, n)
+    assert not os.path.exists(base + layout.to_ext(n))
+    assert ec_files.read_vif(base)["codec"] == spec.tag
+    assert vs.store.get_volume(3).read_only  # the set is the copy of record
+    status, job = _call(vs.handle_ec_progress, {"volumeId": "3"})
+    assert status == 200 and job["kind"] == "fleet_convert"
+    assert (job["stages"]["codec"], job["stages"]["shard_files"],
+            job["stages"]["alpha"]) == (spec.tag, n, spec.alpha)
+    row = next(r for r in pipeline.local_snapshot()["roofline"]["rows"]
+               if r["kernel"] == "fleet_encode")
+    assert (row["backend"], row["rows_in"], row["rows_out"], row["alpha"],
+            row["tile"]) == ("device", spec.k * spec.alpha,
+                             spec.m * spec.alpha, spec.alpha, 32768)
+    for i in range(n):
+        os.remove(base + layout.to_ext(i))
+    gen = {"volume": 3} if tag is None else {"volume": 3, "codec": tag}
+    status, out = _call(vs.handle_ec_generate, gen)
+    assert (status, out["shards"]) == (200, list(range(n)))
+    assert _shard_files(base, n) == got
+    _no_leftovers(base)
+
+
 @pytest.mark.parametrize("path, body, why", [
-    ("fleet_convert", {"volumes": [3], "codec": "rs_6_3"}, "fleet conversion"),
-    ("fleet_convert", {"volumes": [3], "codec": TAG}, "fleet conversion"),
+    ("fleet_convert", {"volumes": [3], "codec": "rs_40_8"}, "at most 32"),
+    ("fleet_convert", {"volumes": [3], "codec": "lrc_12_5_2"},
+     "no such code"),
     ("generate", {"volume": 3, "codec": "rs_40_8"}, "at most 32"),
     ("generate", {"volume": 3, "codec": "lrc_12_5_2"}, "no such code"),
 ])

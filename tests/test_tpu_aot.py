@@ -62,16 +62,23 @@ def _assert_trace_names(compiled, kernel: str, module: str) -> None:
     table = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
                         / "kernels.json").read_text())
     patterns = [re.compile(p) for p in table["kernels"][kernel]["patterns"]]
+    call = _kernel_call(compiled, module)
+    assert any(p.search(call) for p in patterns), call[:120]
+    others = [k for k, spec in table["kernels"].items()
+              if spec["patterns"] != table["kernels"][kernel]["patterns"]
+              and any(re.search(p, call) for p in spec["patterns"])]
+    assert not others, (call[:120], others)
+
+
+def _kernel_call(compiled, module: str) -> str:
+    """The HLO line of the program's one Mosaic custom call, as a device
+    trace names its event; the program is module `module`."""
     text = compiled.as_text()
     assert text.startswith(f"HloModule {module},"), text[:80]
     calls = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1, calls
-    assert any(p.search(calls[0]) for p in patterns), calls[0][:120]
-    others = [k for k, spec in table["kernels"].items()
-              if spec["patterns"] != table["kernels"][kernel]["patterns"]
-              and any(re.search(p, calls[0]) for p in spec["patterns"])]
-    assert not others, (calls[0][:120], others)
+    return calls[0]
 
 
 def _lifted(C, sharding):
@@ -246,6 +253,42 @@ def test_gf_apply_batch_compiles_for_v5e(v5e):
     _assert_trace_names(compiled, "gf_apply_batch", "jit__gf_apply_batch")
 
 
+def _assert_kernel_file_names(compiled, kernel: str, module: str) -> None:
+    """`_assert_trace_names` for a kernel that is a file of its own,
+    `benchmark/kernels/<kernel>.json` (a narrower pattern of a kernel
+    kernels.json has: the events of one matrix)."""
+    spec = json.loads((pathlib.Path(__file__).parent.parent / "benchmark"
+                       / "kernels" / f"{kernel}.json").read_text())
+    assert spec["name"] == kernel
+    call = _kernel_call(compiled, module)
+    assert any(re.search(p, call) for p in spec["patterns"]), call[:120]
+
+
+def test_gf_apply_batch_int8_compiles_for_v5e(v5e):
+    """The batch kernel under PM-MSR(9,16)'s [72, 72] as the fleet unit
+    program runs it on a chip: one unit of sixteen 9 MiB stripe rows split
+    into 72 sub-rows, [1, 72, 2 MiB], kpad 80, at the tile the rule gives
+    the matrix (8192: at the platform's 131072 the body would hold 368 MiB
+    of planes and accumulator).  Its event on a device trace is what
+    `benchmark/kernels/gf_apply_batch_int8.json` sums, and
+    `gf_apply_batch`'s pattern takes it too (`encode_kernel_s_per_gb`)."""
+    from seaweedfs_tpu.ops import msr
+    code = msr.get_code(9, 16)
+    one = SingleDeviceSharding(v5e[0])
+    bm, kpad = _lifted(code.parity_matrix, one)
+    seam = pmesh._ApplyKernel("pallas", TILE)
+    tile = seam.matrix_tile(72, 72)
+    assert (kpad, tile) == (80, 8192) == (
+        seam._kpad(72), pallas_gf.matrix_tile(72, 80, TILE))
+    compiled = pallas_gf._gf_apply_batch.lower(
+        bm, _spec((1, 72, WIDE * MIB // ALPHA), jnp.uint8, one),
+        k=72, m=72, kpad=kpad, tile=tile, interpret=False).compile()
+    assert compiled.out_info.shape == (1, 72, WIDE * MIB // ALPHA)
+    _assert_kernel_file_names(compiled, "gf_apply_batch_int8",
+                              "jit__gf_apply_batch")
+    _assert_trace_names(compiled, "gf_apply_batch", "jit__gf_apply_batch")
+
+
 def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
     """The mesh wrappers around the Pallas body, over all four devices:
     shard_map checks varying-manual-axes, and a pallas_call whose
@@ -273,27 +316,37 @@ def test_mesh_encoders_trace_and_compile_with_the_pallas_body(v5e):
               NamedSharding(col_mesh, P(None, "data")))).compile()
 
 
-@pytest.mark.parametrize("rows", [WIDE, 10])
-def test_fleet_unit_program_compiles_for_v5e(v5e, rows):
+@pytest.mark.parametrize("tag, rows", [("rs_10_4", WIDE), ("rs_10_4", 10),
+                                       ("msr_9_16", WIDE)])
+def test_fleet_unit_program_compiles_for_v5e(v5e, tag, rows):
     """The fleet stream's unit program over all four devices, as the seam
     launches it (ops/dispatch.dispatch_parity_batch on spans): one unit a
-    chip, each of its `rows` stripe rows a 1-D array of 10 MiB on that
-    chip (a global [4 * 10 MiB] array sharded over the unit axis), laid
-    out [1, 10, rows MiB] inside the shard_map, through the batch kernel,
-    parity back as m 1-D runs of [rows MiB] a chip.  The benchmark's
-    256 MiB volumes cut a 16-row and a 10-row unit each."""
-    code = rs.get_code(10, 4)
+    chip, each of its `rows` stripe rows a 1-D array of k MiB on that
+    chip (a global [4 * k MiB] array sharded over the unit axis), laid
+    out [1, k, rows MiB] inside the shard_map, through the batch kernel,
+    parity back as one 1-D run of [rows MiB] a parity file a chip.  The
+    benchmark's 256 MiB volumes cut a 16-row and a 10-row unit each under
+    rs_10_4.  Under msr_9_16 (16 rows and 13; the 13-row program takes
+    44 s to compile here and is left to the chip) each file's bytes are
+    split into eight sub-rows inside the same program: [1, 72, 2 MiB]
+    through the [72, 72] kernel at tile 8192, nine file runs back."""
+    from seaweedfs_tpu.ops import codecs
+    spec = codecs.parse_tag(tag)
+    code = codecs._code_for(spec)
     fleet_mesh = Mesh(np.array(v5e), ("unit",))
     enc = pmesh.FleetUnitEncoder(code, fleet_mesh, kernel="pallas",
                                  tile=TILE)
-    assert 10 * MIB >= dispatch.ROW_PUTS_FROM  # so: row by row
+    assert enc.tile == (TILE if spec.alpha == 1 else 8192)
+    k, m = spec.k, spec.m
+    assert k * MIB >= dispatch.ROW_PUTS_FROM  # so: row by row
     bm = _spec(enc.parity_bits.shape, jnp.int8,
                NamedSharding(fleet_mesh, P()))
-    units = (tuple(_spec((4 * 10 * MIB,), jnp.uint8, enc.in_sharding)
+    units = (tuple(_spec((4 * k * MIB,), jnp.uint8, enc.in_sharding)
                    for _ in range(rows)),)
-    compiled = enc._encode_linear.lower(bm, units, stripes=rows).compile()
+    compiled = enc._encode_linear.lower(bm, units, stripes=rows,
+                                        alpha=spec.alpha).compile()
     (runs,) = compiled.out_info
-    assert [o.shape for o in runs] == [(4 * rows * MIB,)] * 4
+    assert [o.shape for o in runs] == [(4 * rows * MIB,)] * m
     assert all(o.sharding.is_equivalent_to(enc.in_sharding, 1)
                for o in runs)  # (rows MiB,) a device
     text = compiled.as_text()
@@ -301,6 +354,9 @@ def test_fleet_unit_program_compiles_for_v5e(v5e, rows):
                          text)  # parity is unit-local
     assert "input_output_alias" not in text
     _assert_trace_names(compiled, "gf_apply_batch", "jit_batch_body")
+    if spec.alpha > 1:
+        _assert_kernel_file_names(compiled, "gf_apply_batch_int8",
+                                  "jit_batch_body")
 
 
 def test_tpu_codec_off_tpu_raises_instead_of_interpreting(monkeypatch):
