@@ -1,9 +1,12 @@
 """Backend dispatch seam between the EC data path and the codec registry.
 
-The storage layer (storage/ec/ec_files.py) needs exactly three
-capabilities from whatever codec `_get_codec` hands it: dispatch a parity
-encode, materialise the result on the host, and reconstruct missing rows.
-The backends differ in a way that matters to the I/O engine — host codecs
+The storage layer (storage/ec/ec_files.py) needs exactly two
+capabilities from whatever codec `_get_codec` hands it, each cut at its
+sync point: dispatch a parity encode and materialise the result on the
+host (`dispatch_parity`, `materialize`), dispatch the reconstruction of
+missing rows and materialise those (`dispatch_reconstruct`,
+`materialize_rows`; `reconstruct_batch` is the two in a row, for a caller
+with one batch).  The backends differ in a way that matters to the I/O engine — host codecs
 (native C++ / numpy) compute eagerly and return numpy, while JAX device
 codecs dispatch asynchronously and return an un-materialised device array
 whose d2h transfer is the sync point.  Centralising the isinstance
@@ -25,7 +28,7 @@ engine's job and named `codec.<stage>` on spans and profiler annotations:
                the kernel
   d2h_copy     `np.asarray` once the array is ready (what is left of the
                copy where it was asked for at the enqueue: an encode
-               unit's parity runs)
+               unit's parity runs, a reconstruct's rows)
 
 No synchronisation is added for the sake of measurement: a
 `block_until_ready` stands only directly before a copy of the same array,
@@ -37,6 +40,8 @@ it (stats/profile.codec_entry).
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import numpy as np
 
@@ -504,12 +509,31 @@ def _staged(rows, order: list[int], width: int) -> np.ndarray:
     return buf
 
 
+class _Enqueued(NamedTuple):
+    """A reconstruct a device codec has enqueued and not yet given back:
+    the program's un-materialised result, how to cut it, and the host
+    memory its puts read (views of a rebuild's maps, or the stacked copy),
+    alive as long as this is."""
+    out: object
+    wanted: list[int]
+    width: int
+    n: int
+    held: list
+
+
 @codec_entry("reconstruct")
-def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
-                      job=None, unit=None) -> dict[int, np.ndarray]:
-    """Rebuild `wanted` shard rows (host bytes in and out) from the
-    survivors `ids`, whose rows of n bytes each `rows` holds in that
-    order: a `[len(ids), n]` array or a sequence of rows.
+def dispatch_reconstruct(codec, rows, ids: list[int], wanted: list[int],
+                         job=None, unit=None):
+    """Enqueue the rebuild of `wanted` shard rows from the survivors
+    `ids`, whose rows of n bytes each `rows` holds in that order: a
+    `[len(ids), n]` array or a sequence of rows.  What comes back goes to
+    `materialize_rows`, the sync point, as `dispatch_parity`'s goes to
+    `materialize`: a device codec's is un-materialised and no wait stands
+    here, so the caller may enqueue the next batch before it asks for this
+    one (`ec_files.rebuild_ec_files`); a host codec and the numpy reference
+    compute here, and so does the column-sharded mesh encoder, which waits
+    inside (it takes a dict of rows at their own length), and theirs is
+    the `{shard: row}` dict already.
 
     A device codec is handed the rows its decode matrix wants, in its
     order and W wide, W the bucket of n (`codec_base.bucket`): one program
@@ -517,11 +541,15 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
     crossing (`codec_base.stacked`), and the cut back to n is a view on
     the host.  How they go up rests on n and W, not on what `rows` is:
     rows as wide as their bucket and at least `ROW_PUTS_FROM` (a rebuild
-    batch) are put one by one from where they lie, and the runtime reads
-    them after the put returns, so they stay alive and unchanged until
-    this does (it waits for the result); any others are stacked on the
-    host first (`_staged`: the one host copy of a degraded read, or of a
-    rebuild's short last batch), which the job counts as `rows_staged`."""
+    batch) are put one by one from where they lie; any others are stacked
+    on the host first (`_staged`: the one host copy of a degraded read, or
+    of a rebuild's short last batch), which the job counts as
+    `rows_staged`.  The runtime reads a row after its put returns: what
+    comes back holds `rows` and the stacked copy, and whoever holds it
+    keeps them alive and unchanged until `materialize_rows` has returned.
+    The result's copy back is asked for here, at the enqueue, so that it
+    follows the program on the device's queue ahead of the next batch's
+    puts (see `materialize`)."""
     ids = list(ids)
     n = len(rows[0])
     nbytes = len(ids) * n
@@ -532,8 +560,6 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
                           dict(zip(ids, rows)), wanted=wanted)
     import jax.numpy as jnp
     if not hasattr(codec, "reconstruct_stack"):
-        # a dict of rows at their own length is all the column-sharded
-        # mesh encoder takes
         out = _device_call(
             job, unit, "reconstruct", nbytes,
             lambda: {i: jnp.asarray(r) for i, r in zip(ids, rows)},
@@ -544,21 +570,51 @@ def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
     from seaweedfs_tpu.ops.codec_base import bucket
     order = [ids.index(i) for i in codec.decode_basis(ids, wanted)]
     width = bucket(n, codec.tile)
+    held = [rows]
 
     def put():
         if width == n and width >= ROW_PUTS_FROM:
             return tuple(jnp.asarray(rows[src]) for src in order)
         stack = _staged(rows, order, width)
-        if job is not None and stack is not rows:
-            job.count("rows_staged", len(order))
+        if stack is not rows:
+            held.append(stack)
+            if job is not None:
+                job.count("rows_staged", len(order))
         if width >= ROW_PUTS_FROM:
             return tuple(jnp.asarray(row) for row in stack)
         return jnp.asarray(stack.reshape(-1))
 
-    out = _device_call(
-        job, unit, "reconstruct", nbytes, put,
-        lambda dev: codec.reconstruct_stack(dev, ids, wanted, linear=True),
-        h2d_bytes=len(order) * width, wanted=len(wanted))
-    host, = _to_host([out], job, unit, "reconstruct")
-    host = host.reshape(len(wanted), width)
-    return {w: host[r, :n] for r, w in enumerate(wanted)}
+    def run(placed):
+        out = codec.reconstruct_stack(placed, ids, wanted, linear=True)
+        out.copy_to_host_async()
+        return out
+
+    out = _device_call(job, unit, "reconstruct", nbytes, put, run,
+                       h2d_bytes=len(order) * width, wanted=len(wanted))
+    return _Enqueued(out, list(wanted), width, n, held)
+
+
+def materialize_rows(pending, job=None, unit=None) -> dict[int, np.ndarray]:
+    """Sync point of `dispatch_reconstruct`: the `{shard: row}` dict of
+    host rows, n bytes each.  A dict is handed through; a device codec's
+    result is waited for (`device_wait`: the queued puts and the program)
+    and copied back (`d2h_copy`: what is left of the copy asked for at
+    the enqueue), `[rows wanted x W]` flat, and cut to n as views."""
+    if isinstance(pending, dict):
+        return pending
+    host, = _to_host([pending.out], job, unit, "reconstruct")
+    host = host.reshape(len(pending.wanted), pending.width)
+    return {w: host[r, :pending.n] for r, w in enumerate(pending.wanted)}
+
+
+def reconstruct_batch(codec, rows, ids: list[int], wanted: list[int],
+                      job=None, unit=None) -> dict[int, np.ndarray]:
+    """Rebuild `wanted` shard rows (host bytes in and out) from the
+    survivors `ids`: `dispatch_reconstruct` and `materialize_rows` one
+    after the other on the calling thread, which books the seam's four
+    stages: what a degraded read, regen and the scrubber call.  It waits
+    for the result, so `rows` need stay alive and unchanged only until it
+    returns."""
+    return materialize_rows(
+        dispatch_reconstruct(codec, rows, ids, wanted, job=job, unit=unit),
+        job=job, unit=unit)

@@ -55,11 +55,14 @@ from seaweedfs_tpu.stats import trace as _trace  # noqa: E402
 from seaweedfs_tpu.ops.dispatch import (  # noqa: E402
     backend_name as _backend_name,
     dispatch_parity as _dispatch_parity,
+    dispatch_reconstruct as _dispatch_reconstruct,
     materialize as _materialize,
+    materialize_rows as _materialize_rows,
     reconstruct_batch as _reconstruct_batch,
 )
 
-# batch buffers in flight: read N+1 / encode N / drain N-1
+# units (encode) or batches (rebuild) between selection and materialised
+# result: read N+1 / encode N / drain N-1
 PIPELINE_DEPTH = int(os.environ.get("WEEDTPU_EC_PIPELINE_DEPTH", "3"))
 # queued writes per shard fd before submission backpressures
 WRITER_DEPTH = int(os.environ.get("WEEDTPU_EC_WRITER_DEPTH", "4"))
@@ -329,8 +332,8 @@ def _copy_range(src_fd: int, dst_fd: int, src_off: int, dst_off: int,
 
 
 # the lumps /admin/ec/progress has always shown, and the stages of the
-# dispatch seam (ops/dispatch.py) and of the rebuild loop that add up to
-# each (stats/pipeline.PipelineJob `sums`)
+# dispatch seam (ops/dispatch.py) and of the rebuild engine's two threads
+# that add up to each (stats/pipeline.PipelineJob `sums`)
 ENCODE_SUMS = {"encode": ("h2d", "dispatch"),
                "d2h": ("device_wait", "d2h_copy")}
 REBUILD_SUMS = {"reconstruct": ("stage", "h2d", "dispatch", "device_wait",
@@ -1055,6 +1058,117 @@ def basis_kind(codec, use: list[int]) -> str:
     return "local" if len(groups) == 1 and None not in groups else "global"
 
 
+def _write_rows(writers: "_ShardWriterPool", opool: queue.Queue,
+                obuf: np.ndarray, n: int, off: int) -> None:
+    """Hand a rebuild batch's rows, `obuf[r, :n]` for lost shard r, to
+    their writers at `off`; the buffer goes back to the output ring once
+    every writer is done with its row."""
+    release = _countdown(len(obuf), lambda: opool.put(obuf))
+    for r in range(len(obuf)):
+        writers.put(r, obuf[r, :n], off, release=release)
+
+
+def _rebuild_pipelined(codec, views: dict, use: list[int],
+                       missing: list[int], shard_size: int, batch_size: int,
+                       writers: "_ShardWriterPool", opool: queue.Queue, pjob,
+                       progress=None, cancel=None) -> None:
+    """Rebuild's batches through the dispatch seam, encode's shape
+    (`_encode_pipelined`) with the reader and the dispatcher one thread: no
+    byte moves on the host before a put, so there is nothing to read ahead.
+
+      caller   walks the batches: waits for one of PIPELINE_DEPTH slots
+               (`stall`), selects the batch's rows in the maps (`stage`: no
+               byte moves) and enqueues them (the seam's `h2d` and
+               `dispatch`).  A device codec's result comes back
+               un-materialised, so batch N+1's rows go up while batch N is
+               out; a host codec behind the seam computes here
+      drain    materialises batch N (the seam's `device_wait` and
+               `d2h_copy`), frees its slot, waits for a buffer of the output
+               ring (`stall`), copies the rebuilt rows into it (`unstage`)
+               and hands each to its shard's writer
+      writers  `_ShardWriterPool`; a buffer returns to the ring once every
+               writer is done with its row
+
+    The six stages book to the one job from two threads and add up to
+    `reconstruct` (REBUILD_SUMS), which may so pass the wall.  A batch's
+    views, its stacked copy if it had one and its device arrays ride its
+    queue item and die when its result is materialised.  However the walk
+    ends (the last batch, `cancel`, a failed writer, an exception from
+    either half of the seam), every batch that went up is waited for and
+    the drain thread joined before this returns: the caller closes the maps
+    next.  The first error is raised here; a failed writer's is the
+    caller's to raise, after the pool's close.  `stats["inflight_max"]`:
+    the most batches between enqueue and materialised result at once."""
+    slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
+    q_out: queue.Queue = queue.Queue()  # unbounded: `slots` is the bound
+    errors: list[BaseException] = []
+    # one writer each, so no lock: in flight is their difference
+    enqueued = materialised = 0
+
+    def drain() -> None:
+        nonlocal materialised
+        while True:
+            item = q_out.get()
+            if item is None:
+                return
+            unit, off, n, pending = item
+            try:
+                # a batch of a run that failed is waited for like any other:
+                # the device may be reading its rows in the maps
+                rebuilt = _materialize_rows(pending, job=pjob, unit=unit)
+            except BaseException as e:  # raised by the caller's thread
+                errors.append(e)
+                continue
+            finally:
+                del item, pending  # the device is done with the host memory
+                materialised += 1
+                slots.release()
+            if errors or writers.failed:
+                continue
+            with pjob.blocked("stall", unit=unit):
+                obuf = opool.get()
+            with pjob.stage("unstage", unit=unit):
+                for r, i in enumerate(missing):
+                    np.copyto(obuf[r, :n], rebuilt[i])
+            del rebuilt
+            _write_rows(writers, opool, obuf, n, off)
+
+    t_d = threading.Thread(target=drain, name="ec-rebuild-drain",
+                           daemon=True)
+    t_d.start()
+    done = 0
+    try:
+        for unit, off in enumerate(range(0, shard_size, batch_size)):
+            if cancel is not None and cancel():
+                raise EncodeCancelled("ec rebuild cancelled")
+            if errors or writers.failed:
+                break
+            n = min(batch_size, shard_size - off)
+            with pjob.blocked("stall", unit=unit):
+                slots.acquire()
+            try:
+                with pjob.stage("stage", unit=unit):
+                    rows = [views[i][off:off + n] for i in use]
+                pending = _dispatch_reconstruct(codec, rows, use, missing,
+                                                job=pjob, unit=unit)
+            except BaseException:
+                slots.release()
+                raise
+            enqueued += 1
+            pjob.stats["inflight_max"] = max(pjob.stats["inflight_max"],
+                                             enqueued - materialised)
+            q_out.put((unit, off, n, pending))
+            del rows, pending  # the queue item alone holds a batch's views
+            done += n * len(use)
+            if progress is not None:
+                progress(done)
+    finally:
+        q_out.put(None)
+        t_d.join()
+    if errors:
+        raise errors[0]
+
+
 def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                      progress=None, cancel=None, stats=None,
                      codec_tag: str | None = None) -> list[int]:
@@ -1072,19 +1186,28 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     decode matmul by row pointer or to the dispatch seam as views, which a
     device codec puts up uncopied where they are a whole bucket wide
     (`ops/dispatch.ROW_PUTS_FROM`; `stats["rows_staged"]` counts the rows
-    copied first: a short last batch).  Of its overlap, the writes alone:
-    rebuilt shards land in a countdown-released buffer ring and stream to
-    per-shard writer workers (the decode of batch N overlaps the writes of
-    batch N-1) into recycled `.tmp` inodes, committed by rename only on
-    success (reference: RebuildEcFiles, ec_encoder.go:237-291); a device
-    batch's put, kernel, copy back and `unstage` run one after the other.
+    copied first: a short last batch).  Rebuilt shards land in a
+    countdown-released buffer ring and stream to per-shard writer workers
+    into recycled `.tmp` inodes, committed by rename only on success
+    (reference: RebuildEcFiles, ec_encoder.go:237-291).
+
+    What overlaps follows from the codec.  The native host codec decodes
+    batch N on the calling thread while the writers have batch N-1
+    (`stats["mode"]` `host-serial`).  Every other codec goes through
+    encode's reader -> dispatch -> drain shape (`_rebuild_pipelined`,
+    `pipelined`): up to PIPELINE_DEPTH batches are between their put and
+    their materialised result, batch N+1's rows going up while batch N's
+    program, copy back, `unstage` and writes run; `stats["inflight_max"]`
+    says how many there were at most.
 
     The runtime reads a row after its put returns, so a batch's views and
-    device arrays must be dead before the maps close: the seam waits for
-    each batch's result and frees its device arrays before the next is
-    taken, and where a reference outlives the loop (a traceback, the CPU
-    backend aliasing an aligned view of the read-only maps) `mm.close()`
-    raises `BufferError`, let pass: the mapping goes with its last view."""
+    device arrays must be dead before the maps close: they ride the
+    batch's queue item, which dies when its result is materialised, and
+    the drain thread is joined, whatever ended the loop, before this
+    function's `finally` closes anything.  Where a reference outlives the
+    loop all the same (a traceback, the CPU backend aliasing an aligned
+    view of the read-only maps) `mm.close()` raises `BufferError`, let
+    pass: the mapping goes with its last view."""
     from seaweedfs_tpu.ops import codecs as _codecs
     spec = _codecs.parse_tag(codec_tag or
                              (read_vif(base) or {}).get("codec"))
@@ -1110,6 +1233,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     stats["survivors"] = len(use)
     stats["basis"] = basis_kind(codec, use)
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
+    stats["inflight_max"] = 0  # batches out at once (_rebuild_pipelined)
     # MSR sub-packetization: every chunk a codec's interleave must see is
     # an alpha multiple (shard files themselves are block-multiples)
     if spec.alpha > 1:
@@ -1121,7 +1245,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
 
     from seaweedfs_tpu.ops.native_codec import NativeRSCodec
     native_host = isinstance(codec, NativeRSCodec)
-    stats["mode"] = "host-serial" if native_host else "staged"
+    stats["mode"] = "host-serial" if native_host else "pipelined"
     if native_host:
         from seaweedfs_tpu import native
         dec_mat = codec.code.decode_matrix(list(present), list(missing))
@@ -1165,7 +1289,6 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
         # workers while the next batch's decode matmul runs.  Pooled output
         # buffers (countdown-released once every shard writer is done with
         # its row) keep the decode from racing its own in-flight writes.
-        wpos = {i: r for r, i in enumerate(missing)}
         writers = _ShardWriterPool([out_fds[i] for i in missing], None, pjob)
         opool: queue.Queue = queue.Queue()
         for _ in range(PIPELINE_DEPTH):
@@ -1177,44 +1300,31 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                 mm = _map_readonly(f.fileno(), shard_size)
                 maps[i] = mm
                 views[i] = np.frombuffer(mm, dtype=np.uint8)
-        done = 0
-        # a batch's time is `reconstruct`: the native matmul booked whole,
-        # or for a device codec the sum of this loop's `stage` (the rows
-        # selected in the maps: no byte moves) and `unstage` (copy into the
-        # output ring) and the four stages the dispatch seam books between
-        # them (REBUILD_SUMS)
-        for unit, off in enumerate(range(0, shard_size, batch_size)):
-            if cancel is not None and cancel():
-                raise EncodeCancelled("ec rebuild cancelled")
-            if writers.failed:
-                break
-            n = min(batch_size, shard_size - off)
-            with pjob.blocked("stall", unit=unit):
-                obuf = opool.get()
-            if native_host:
+        if native_host:
+            # the matmul straight off the maps into the output ring, a
+            # batch booked whole as `reconstruct`
+            done = 0
+            for unit, off in enumerate(range(0, shard_size, batch_size)):
+                if cancel is not None and cancel():
+                    raise EncodeCancelled("ec rebuild cancelled")
+                if writers.failed:
+                    break
+                n = min(batch_size, shard_size - off)
+                with pjob.blocked("stall", unit=unit):
+                    obuf = opool.get()
                 with pjob.stage("reconstruct", unit=unit) as st:
                     rows = [views[i][off:off + n] for i in use]
-                    outs = [obuf[r, :n] for r in range(len(missing))]
-                    native.gf_matmul_ptrs(dec_mat, rows, outs, n)
+                    native.gf_matmul_ptrs(dec_mat, rows, list(obuf), n)
                 _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
                                         nbytes=len(use) * n)
-            else:
-                with pjob.stage("stage", unit=unit):
-                    rows = [views[i][off:off + n] for i in use]
-                rebuilt = _reconstruct_batch(codec, rows, use, missing,
-                                             job=pjob, unit=unit)
-                del rows  # no view of a map outlives its batch
-                with pjob.stage("unstage", unit=unit):
-                    for r, i in enumerate(missing):
-                        np.copyto(obuf[r, :n], rebuilt[i])
-            release = _countdown(len(missing),
-                                 lambda b=obuf: opool.put(b))
-            for i in missing:
-                writers.put(wpos[i], obuf[wpos[i], :n], off,
-                            release=release)
-            done += n * len(use)
-            if progress is not None:
-                progress(done)
+                _write_rows(writers, opool, obuf, n, off)
+                done += n * len(use)
+                if progress is not None:
+                    progress(done)
+        else:
+            _rebuild_pipelined(codec, views, use, missing, shard_size,
+                               batch_size, writers, opool, pjob, progress,
+                               cancel)
         writers.close()
         if writers.errors:
             raise writers.errors[0]

@@ -275,7 +275,7 @@ def _spied_rebuild(monkeypatch, base, tag, lost):
     monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", BATCH)
     maps, calls, staged = [], [], []
     real_map, real_seam, real_staged = (
-        ec_files._map_readonly, ec_files._reconstruct_batch,
+        ec_files._map_readonly, ec_files._dispatch_reconstruct,
         dispatch._staged)
 
     def map_spy(fd, size):
@@ -295,7 +295,7 @@ def _spied_rebuild(monkeypatch, base, tag, lost):
         return real_staged(rows, order, width)
 
     monkeypatch.setattr(ec_files, "_map_readonly", map_spy)
-    monkeypatch.setattr(ec_files, "_reconstruct_batch", seam_spy)
+    monkeypatch.setattr(ec_files, "_dispatch_reconstruct", seam_spy)
     monkeypatch.setattr(dispatch, "_staged", staged_spy)
     stats: dict = {}
     assert ec_files.rebuild_ec_files(base, batch_size=BATCH, stats=stats,

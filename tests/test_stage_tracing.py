@@ -12,6 +12,7 @@ import os
 import signal
 import subprocess
 import sys
+import threading
 import time
 
 import jax
@@ -66,6 +67,8 @@ OLD_KEYS = {
 # PR 33: the fleet job says how many stripe rows it copied on the host
 OLD_KEYS["fleet"].add("rows_staged")
 OLD_KEYS["fleet_spans"] = OLD_KEYS["fleet"]
+# PR 35: the rebuild job says how many batches were out at once
+OLD_KEYS["rebuild"].add("inflight_max")
 
 
 @pytest.fixture(autouse=True)
@@ -271,6 +274,7 @@ def test_sum_identities_and_every_old_key(kind, tmp_path):
         assert stats["reconstruct_s"] == pytest.approx(
             _sum(stats, "stage_s", "h2d_s", "dispatch_s", "device_wait_s",
                  "d2h_copy_s", "unstage_s"), rel=1e-9)
+        assert stats["overlap_frac"] == ec_files.overlap_fraction(stats)
     else:
         # the read engine nests: `reconstruct` holds the seam's four
         parts = sum(stats[s]["busy_s"] for s in
@@ -285,6 +289,39 @@ def test_sum_identities_and_every_old_key(kind, tmp_path):
                         and k not in ec_files._PART_KEYS)
         assert stats["overlap_frac"] == round(
             max(0.0, 1.0 - stats["wall_s"] / stage_sum), 3)
+
+
+def test_rebuild_books_its_six_stages_to_one_job_from_two_threads(
+        tmp_path, monkeypatch):
+    """Batches are in flight (ec_files._rebuild_pipelined): the calling
+    thread selects and enqueues, the drain thread waits, copies back and
+    unstages, every stage once a batch to the one `ec_rebuild` job."""
+    booked = []
+    real = pipeline.PipelineJob._book
+
+    def book(self, name, secs, nbytes, items, blocked):
+        if self.kind == "ec_rebuild" and items:
+            booked.append((name, self.job_id,
+                           threading.current_thread().name))
+        return real(self, name, secs, nbytes, items, blocked)
+
+    monkeypatch.setattr(pipeline.PipelineJob, "_book", book)
+    op = prepare("rebuild", tmp_path)
+    booked.clear()
+    stats, (_, job_id) = op()
+    me = threading.current_thread().name
+    by_stage = {name: {(job, thread) for n, job, thread in booked
+                       if n == name} for name, _, _ in booked}
+    for name in ("stage", "h2d", "dispatch"):
+        assert by_stage[name] == {(job_id, me)}, name
+    for name in ("device_wait", "d2h_copy", "unstage"):
+        assert by_stage[name] == {(job_id, "ec-rebuild-drain")}, name
+    stages = _last_job("ec_rebuild")["stages"]
+    batches = stages["stage"]["items"]
+    assert batches > ec_files.PIPELINE_DEPTH
+    assert {stages[s]["items"] for s in by_stage if s != "write"} == \
+        {batches}
+    assert 1 <= stats["inflight_max"] <= ec_files.PIPELINE_DEPTH
 
 
 def test_encode_stages_are_entered_once_a_unit(tmp_path):
