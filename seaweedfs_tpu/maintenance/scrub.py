@@ -142,7 +142,10 @@ def syndrome_scan(ev, codec=None, window: int | None = None,
     -1 when the corruption could not be localized to one shard."""
     from seaweedfs_tpu.ops import codecs as _codecs
     from seaweedfs_tpu.ops import dispatch
+    from seaweedfs_tpu.stats import pipeline
     from seaweedfs_tpu.storage.ec import ec_files
+    # where the seam's stages of every window book: `ec_scrub` on /perf
+    flow = pipeline.flow("ec_scrub", span="ec.scrub")
     if codec is None:
         codec = ec_files._get_codec(tag=getattr(ev, "codec_tag", None))
     spec = getattr(ev, "spec", None) or _codecs.spec_of(codec)
@@ -177,7 +180,8 @@ def syndrome_scan(ev, codec=None, window: int | None = None,
             continue
         batch = np.stack([rows[i] for i in range(k)])
         with trace.span("scrub.syndrome", offset=off, bytes=batch.nbytes):
-            masks = dispatch.parity_mismatch(codec, batch, parity_have)
+            masks = dispatch.parity_mismatch(codec, batch, parity_have,
+                                             job=flow)
         if stats is not None:
             stats["windows"] = stats.get("windows", 0) + 1
         if limiter is not None:

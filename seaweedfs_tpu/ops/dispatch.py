@@ -428,7 +428,7 @@ def unit_parity_shards(parity, kernel: str = "fleet_encode", job=None,
 
 
 def parity_mismatch(codec, data: np.ndarray,
-                    parity_rows: dict[int, np.ndarray]
+                    parity_rows: dict[int, np.ndarray], job=None
                     ) -> dict[int, np.ndarray]:
     """Scrub seam: recompute the parity of a [k, B] data-stripe window
     through the SAME backend dispatch the encoder uses and compare
@@ -436,8 +436,9 @@ def parity_mismatch(codec, data: np.ndarray,
     per supplied parity row (row index is parity-relative: 0..m-1).
     One dispatch verifies the whole window — RS(10,4) syndrome checking
     IS a batched GF(2^8) matmul, the workload this seam accelerates.
-    (Profiled under `encode_parity` — it runs the encode kernel.)"""
-    expect = materialize(dispatch_parity(codec, data))
+    (Profiled under `encode_parity` — it runs the encode kernel; the
+    seam's four stages book to `job`, the scrubber's flow account.)"""
+    expect = materialize(dispatch_parity(codec, data, job=job), job=job)
     return {r: np.not_equal(expect[r],
                             np.frombuffer(stored, dtype=np.uint8)
                             if isinstance(stored, (bytes, bytearray))

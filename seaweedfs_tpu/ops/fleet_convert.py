@@ -77,7 +77,7 @@ from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
     DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
     _iter_spans, _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
-    _unit_coverage, _unit_spans, _unit_steps, overlap_fraction, write_vif)
+    _state_overlap, _unit_coverage, _unit_spans, _unit_steps, write_vif)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -111,11 +111,14 @@ class _VolumeJob:
     """One volume mid-conversion: source map, recycled .tmp shard fds,
     its writer pool, and completion accounting."""
 
-    def __init__(self, base: str, dat_path: str | None, large_block: int,
-                 small_block: int, batch_size: int, pjob, spans: bool,
-                 spec):
+    def __init__(self, index: int, base: str, dat_path: str | None,
+                 large_block: int, small_block: int, batch_size: int, pjob,
+                 spans: bool, spec):
         """`spec` (codecs.CodecSpec) is the code the volume goes under:
-        its k-wide striping, its n shard files, its tag in the `.vif`."""
+        its k-wide striping, its n shard files, its tag in the `.vif`.
+        `index` is the volume's place in the run: the `unit` of its
+        `join_writers` and `commit` stages."""
+        self.index = index
         self.base = base
         self.tag = spec.tag
         self.dat_path = dat_path or base + ".dat"
@@ -158,7 +161,7 @@ class _VolumeJob:
         self.units_skipped = 0   # written by the reader thread only
         self.done_bytes = 0
         self.committed = False
-        self._stats = pjob.stats
+        self._pjob = pjob
 
     def next_unit(self):
         try:
@@ -176,48 +179,50 @@ class _VolumeJob:
             self.units_drained + self.units_skipped >= self.units_total
 
     def finalize(self) -> None:
-        """All units drained: barrier on the writers, cut shards to size,
-        commit by rename.  Runs on the drain thread while the stream
-        keeps feeding other volumes."""
+        """All units drained: barrier on the writers (`join_writers`),
+        cut shards to size, commit by rename (`commit`).  Runs on the
+        drain thread while the stream keeps feeding other volumes."""
         self.data_flusher.flush()
         self.parity_flusher.flush()
-        self.writers.close()
+        self.writers.close(self.index)
         if self.writers.errors:
             raise self.writers.errors[0]
-        for fd, hw in zip(self.out_fds, self.highwater):
-            os.ftruncate(fd, min(hw, self.shard_size))
-            if hw < self.shard_size:
-                os.ftruncate(fd, self.shard_size)
-        for fd in self.out_fds:
-            os.close(fd)
-        self.out_fds = []
-        write_vif(self.base, self.dat_size, codec=self.tag)
-        for i, p in enumerate(self.tmp_paths):
-            os.replace(p, self.base + layout.to_ext(i))
+        with self._pjob.stage("commit", unit=self.index):
+            for fd, hw in zip(self.out_fds, self.highwater):
+                os.ftruncate(fd, min(hw, self.shard_size))
+                if hw < self.shard_size:
+                    os.ftruncate(fd, self.shard_size)
+            for fd in self.out_fds:
+                os.close(fd)
+            self.out_fds = []
+            write_vif(self.base, self.dat_size, codec=self.tag)
+            for i, p in enumerate(self.tmp_paths):
+                os.replace(p, self.base + layout.to_ext(i))
         self.committed = True
         # callers that must react per-volume (the volume server's freeze
         # bookkeeping) see commits even when a LATER volume fails the run
-        self._stats.setdefault("committed_bases", []).append(self.base)
+        self._pjob.stats.setdefault("committed_bases", []).append(self.base)
 
     def abort(self) -> None:
         """Failure path: drop fds and every .tmp so no partial shard set
         is ever visible; a previous valid shard set stays untouched."""
         try:
-            self.writers.close()
+            self.writers.close(self.index)
         except Exception:
             pass
-        for fd in self.out_fds:
-            try:
-                os.close(fd)
-            except OSError:
-                pass
-        self.out_fds = []
-        if not self.committed:
-            for p in self.tmp_paths:
+        with self._pjob.stage("commit", unit=self.index):
+            for fd in self.out_fds:
                 try:
-                    os.remove(p)
+                    os.close(fd)
                 except OSError:
                     pass
+            self.out_fds = []
+            if not self.committed:
+                for p in self.tmp_paths:
+                    try:
+                        os.remove(p)
+                    except OSError:
+                        pass
 
     def release(self) -> None:
         self.held = None
@@ -283,20 +288,26 @@ def convert_volumes(bases: list[str], *,
     flow_cls = _netflow.current_class() or "convert"
     _flow_token = _netflow.set_class(flow_cls)
     t_wall = time.perf_counter()
-    # stages as the single-volume encode names them: the reader's `read`
-    # and `stall`, the writers' `write_data` and `write_parity`, and the
-    # dispatch seam's four, which add up to `encode` and `d2h`; a unit
-    # batch's index rides each as `unit`
+    # stages as the single-volume encode names them: the caller's `open`,
+    # `await_unit`, `join_drain` and `commit`, the reader's `read`,
+    # `ship_data` and `stall`, the drain's `await_parity`, the writers'
+    # `write_data` and `write_parity` and each volume's `join_writers` and
+    # `commit` (its index as `unit`), and the dispatch seam's four, which
+    # add up to `encode` and `d2h`; a unit batch's index rides the others
+    # as `unit`
     pjob = _pipeline.track("fleet_convert", stats,
                            meta={"volumes": len(bases), "unit_batch": U},
                            span="ec.fleet", sums=ENCODE_SUMS)
     try:
-        jobs = [_VolumeJob(b, None, large_block, small_block, batch_size,
-                           pjob, spans, spec) for b in bases]
+        with pjob.stage("open", files=len(bases) * (spec.n + 1)) as st:
+            jobs = [_VolumeJob(i, b, None, large_block, small_block,
+                               batch_size, pjob, spans, spec)
+                    for i, b in enumerate(bases)]
+            stats["bytes"] = sum(j.dat_size for j in jobs)
+            st.set(bytes=stats["bytes"])
     except BaseException as e:  # a volume that cannot be opened: no run
         pjob.finish(e)
         raise
-    stats["bytes"] = sum(j.dat_size for j in jobs)
 
     # depth+1 batches between selection and materialised parity, so the
     # H2D and kernel of batch N+1 overlap the D2H and writes of batch N.
@@ -307,8 +318,10 @@ def convert_volumes(bases: list[str], *,
     W = 0 if spans else max(_unit_steps(
         j.dat_size, large_block, small_block, batch_size, k)[1]
         for j in jobs)
-    for _ in range(depth + 1):
-        pool.put(None if spans else np.empty((U, k, W), dtype=np.uint8))
+    with pjob.stage("open"):
+        for _ in range(depth + 1):
+            pool.put(None if spans else
+                     np.empty((U, k, W), dtype=np.uint8))
     q_read: queue.Queue = queue.Queue(maxsize=depth)
     q_disp: queue.Queue = queue.Queue()
     errors: list[BaseException] = []
@@ -410,8 +423,9 @@ def convert_volumes(bases: list[str], *,
                                 took = True
                                 if len(metas) == U:
                                     break
-                for job, unit in taken:
-                    ship_data(job, unit)
+                with pjob.stage("ship_data", unit=batch):
+                    for job, unit in taken:
+                        ship_data(job, unit)
                 if progress is not None:
                     progress(done_total)
                 if metas:
@@ -437,14 +451,15 @@ def convert_volumes(bases: list[str], *,
         failed = False
         _netflow.set_class(flow_cls)
         while True:
-            item = q_disp.get()
+            with pjob.blocked("await_parity"):
+                item = q_disp.get()
             if item is None:
                 return
             batch, buf, metas, parity = item[:4]
-            if failed or errors:
-                pool.put(buf)
-                continue
             try:
+                if failed or errors:
+                    pool.put(buf)
+                    continue
                 # stream: each block fans out (and its parity writes
                 # submit) the moment its d2h lands, instead of waiting
                 # for a full gather — write_parity overlaps the d2h of
@@ -484,15 +499,19 @@ def convert_volumes(bases: list[str], *,
             except BaseException as e:
                 errors.append(e)
                 failed = True
-                continue
+            finally:
+                # every unit's parity is on the host (or the batch failed)
+                pjob.occupancy("inflight", -1)
 
     t_r = threading.Thread(target=reader, name="fleet-reader", daemon=True)
     t_d = threading.Thread(target=drain, name="fleet-drain", daemon=True)
-    t_r.start()
-    t_d.start()
+    with pjob.stage("open"):
+        t_r.start()
+        t_d.start()
     try:
         while True:
-            item = q_read.get()
+            with pjob.blocked("await_unit"):
+                item = q_read.get()
             if item is None:
                 break
             # stage-queue depths at the consume site: a persistently full
@@ -514,22 +533,25 @@ def convert_volumes(bases: list[str], *,
                                        parity_devices(parity))
                 # the item carries the units: their views and staged rows
                 # live until the drain has the parity
+                pjob.occupancy("inflight", +1)
                 q_disp.put((batch, buf, metas, parity, units))
             except BaseException as e:
                 errors.append(e)
                 pool.put(buf)
             del item, units
     finally:
-        q_disp.put(None)
-        t_d.join()
-        while t_r.is_alive():  # unblock a reader stuck on a full q_read
-            try:
-                item = q_read.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            if item is not None:
-                pool.put(item[1])
-        t_r.join()
+        with pjob.blocked("join_drain"):
+            q_disp.put(None)
+            t_d.join()
+            # unblock a reader stuck on a full q_read
+            while t_r.is_alive():
+                try:
+                    item = q_read.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+                if item is not None:
+                    pool.put(item[1])
+            t_r.join()
         # empty volumes never enter the stream; commit them here, and on
         # any error roll every uncommitted volume back
         for job in jobs:
@@ -541,14 +563,16 @@ def convert_volumes(bases: list[str], *,
         for job in jobs:
             if errors and not job.committed:
                 job.abort()
-            job.release()
+        with pjob.stage("commit"):
+            for job in jobs:
+                job.release()
         _netflow.reset(_flow_token)
         stats["wall_s"] = time.perf_counter() - t_wall
         # analytic stage bytes (the layout fixes them; zero hot-path
         # cost): the occupancy timeline gets achieved GB/s per stage.
         # Only COMMITTED volumes' bytes count — an aborted half-run must
         # not credit the full planned bytes and report achieved GB/s
-        # (even ceiling_frac > 1) the hardware never moved
+        # the hardware never moved
         done_jobs = [j for j in jobs if j.committed]
         _book_stage_bytes(pjob, stats,
                           sum(j.dat_size for j in done_jobs),
@@ -561,9 +585,7 @@ def convert_volumes(bases: list[str], *,
             raise job.writers.errors[0]
     stats["volumes"] = len(jobs)
     stats["units"] = sum(j.units_read for j in jobs)
-    frac = overlap_fraction(stats)
-    if frac is not None:
-        stats["overlap_frac"] = frac
+    _state_overlap(stats)
     return {"volumes": {j.base: {"bytes": j.dat_size,
                                  "shard_size": j.shard_size}
                         for j in jobs},

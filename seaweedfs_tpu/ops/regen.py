@@ -312,8 +312,11 @@ def execute_plan(codec, plan: RepairPlan, read_local, fetch_partial,
     `fetch_partial(group, shards, coeff, off, n) -> bytes` raises
     HelperDied on transport failure.  Raises propagate mid-range — the
     caller owns tmp-file discipline, so a dead helper can never leave a
-    partial shard visible."""
+    partial shard visible.  The seam's stages of every apply book to the
+    flow account `ec_regen` on /perf."""
     from seaweedfs_tpu.ops import dispatch
+    from seaweedfs_tpu.stats import pipeline as _pipeline
+    flow = _pipeline.flow("ec_regen", span="ec.regen")
     own_pool = pool is None
     remote_groups = {p.group.node for seg in plan.segments
                      for p in seg.parts if p.group.locality != 0}
@@ -346,9 +349,10 @@ def execute_plan(codec, plan: RepairPlan, read_local, fetch_partial,
                             raise HelperDied("", (sid,))
                         rows.append(np.frombuffer(data, dtype=np.uint8))
                     out = dispatch.apply_matrix(codec, part.coeff,
-                                                np.stack(rows))
+                                                np.stack(rows), job=flow)
                     if part.post is not None:
-                        out = dispatch.apply_matrix(codec, part.post, out)
+                        out = dispatch.apply_matrix(codec, part.post, out,
+                                                    job=flow)
                     acc = _xor_into(acc, out)
                 for fut in as_completed(futs):
                     part = futs[fut]
@@ -372,7 +376,8 @@ def execute_plan(codec, plan: RepairPlan, read_local, fetch_partial,
                     arr = np.frombuffer(payload, dtype=np.uint8) \
                         .reshape(part.coeff.shape[0], n)
                     if part.post is not None:
-                        arr = dispatch.apply_matrix(codec, part.post, arr)
+                        arr = dispatch.apply_matrix(codec, part.post, arr,
+                                                    job=flow)
                     acc = _xor_into(acc, arr)
                 assert acc is not None, "plan segment with no parts"
                 if plan.out_rows == 1:

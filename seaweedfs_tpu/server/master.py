@@ -968,7 +968,7 @@ class MasterServer:
         """Fleet performance observatory: every node's /debug/pipeline
         payload (per-job stage timelines, roofline rows) merged into
         fleet occupancy per (kind, stage), the worst bottleneck verdict
-        per pipeline kind, and the fleet's worst roofline offenders.
+        per pipeline kind, and the fleet's roofline rows.
         Thread-safe sync function: the handler calls it via to_thread."""
         import json as _json
 
@@ -983,9 +983,9 @@ class MasterServer:
             else:
                 per_node.append((name, payload))
         out = _pipeline.aggregate_fleet(per_node)
-        # roofline rows across the deduped nodes, worst offenders first
-        # (same tracker-id dedupe as the jobs: co-hosted servers share
-        # one kernel profile)
+        # roofline rows across the deduped nodes, busiest first (same
+        # tracker-id dedupe as the jobs: co-hosted servers share one
+        # kernel profile)
         rows: list[dict] = []
         seen: set[str] = set()
         for node, payload in per_node:
@@ -998,7 +998,6 @@ class MasterServer:
                 rows.append({"node": node, **row})
         rows.sort(key=lambda r: -r.get("busy_s", 0.0))
         out["roofline"] = rows
-        out["offenders"] = _pipeline.roofline_offenders({"rows": rows})
         hot = self.collect_hot_tier()
         if hot:
             out["hot_tier"] = hot
@@ -1070,7 +1069,7 @@ class MasterServer:
 
     async def handle_cluster_perf(self, req: web.Request) -> web.Response:
         """/cluster/perf: fleet pipeline occupancy + bottleneck verdicts
-        + roofline offenders (loopback-gated like the rest of the
+        + roofline rows (loopback-gated like the rest of the
         debug-derived surface — it carries file paths and kernel
         internals)."""
         err = trace.loopback_error(req)

@@ -908,22 +908,16 @@ def cmd_cluster_slo(env: CommandEnv, args, out):
 @command("cluster.perf")
 def cmd_cluster_perf(env: CommandEnv, args, out):
     """Fleet performance observatory (/cluster/perf): per-pipeline stage
-    occupancy, the bottleneck verdict per pipeline kind (the stage whose
-    busy fraction bounds throughput, with its achieved-vs-ceiling
-    fraction when the resource's roofline is measured), and the worst
-    roofline offenders fleet-wide.
-    -top N offender rows (default 5); -json dumps the raw merge.
+    occupancy and the bottleneck verdict per pipeline kind (the stage
+    whose busy fraction bounds throughput).
+    -json dumps the raw merge.
     Runbook: a benchmark regression names WHAT got slower — this names
-    WHERE (stage + node + distance from the hardware)."""
+    WHERE (stage + node)."""
     flags = parse_flags(args)
     st = env.master_get("/cluster/perf")
     if "json" in flags:
         print(json.dumps(st, separators=(",", ":")), file=out)
         return
-    try:
-        top_n = max(1, int(flags.get("top", "5")))
-    except ValueError:
-        top_n = 5
     print(f"perf: nodes={len(st.get('nodes', []))} "
           f"running={len(st.get('running', []))}"
           + (f" node_errors={len(st['node_errors'])}"
@@ -936,9 +930,6 @@ def cmd_cluster_perf(env: CommandEnv, args, out):
         if bn:
             verdict = (f"  << bottleneck: {bn.get('stage')} "
                        f"busy={bn.get('busy_frac', 0):.0%}")
-            if bn.get("ceiling_frac") is not None:
-                verdict += (f" @ {bn['ceiling_frac']:.0%} of "
-                            f"{bn.get('resource')} ceiling")
         print(f"{kind}:{verdict}", file=out)
         stages = occ[kind]
         for stage in sorted(stages,
@@ -949,15 +940,6 @@ def cmd_cluster_perf(env: CommandEnv, args, out):
                   f"[{bar:20s}] max={row['max_busy_frac']:.0%} "
                   f"{row['bytes'] / 1e9:8.3f} GB over "
                   f"{row['jobs']} jobs", file=out)
-    offenders = (st.get("offenders") or [])[:top_n]
-    if offenders:
-        print("roofline offenders (furthest from their ceiling, "
-              "busiest first):", file=out)
-        for r in offenders:
-            print(f"  {r.get('node', '?'):22s} {r['kernel']:14s} "
-                  f"{r['resource']:6s} {r['achieved_gbps']:9.3f} GB/s "
-                  f"= {r['ceiling_frac']:.0%} of "
-                  f"{r.get('ceiling_gbps', 0):.3f}", file=out)
     cx = st.get("codecs") or {}
     if cx.get("mix"):
         print("codecs: " + " ".join(

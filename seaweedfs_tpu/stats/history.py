@@ -1143,10 +1143,6 @@ alerts: <span class="badge {badge.get(alerts.get('state', ''), '')}">{_h(alerts.
       "<table>" + _spark_row(
           store, "pipeline", "weedtpu_pipeline_stage_seconds_total",
           None, "rate", rng, step, combine="stage") + "</table>")}
-{sect("Roofline fraction (achieved / measured ceiling by resource)",
-      "<table>" + _spark_row(
-          store, "roofline", "weedtpu_roofline_frac", None, "last",
-          rng, step) + "</table>")}
 {sect("Interference (foreground p99 inflation by class / governed rates)",
       "<table>" + _spark_row(
           store, "interference", "weedtpu_interference_index", None,

@@ -389,15 +389,8 @@ def start_pushing(gateway_url: str, job: str, interval: float = 15.0,
 def scrape_response(req):
     """Shared aiohttp /metrics response with content negotiation: the
     OpenMetrics rendering (exemplars linking latency buckets to trace
-    ids) when the scraper asks for it, Prometheus text 0.0.4 otherwise.
-    Roofline fractions are re-derived from the live kernel profile here,
-    so every scrape carries current achieved-vs-ceiling numbers."""
+    ids) when the scraper asks for it, Prometheus text 0.0.4 otherwise."""
     from aiohttp import web
-    try:
-        from seaweedfs_tpu.stats import profile as _profile
-        _profile.export_roofline()
-    except Exception:  # the observatory must never break a scrape
-        weedlog.V(1, "metrics").infof("roofline export failed")
     if "application/openmetrics-text" in req.headers.get("Accept", ""):
         return web.Response(text=REGISTRY.render(openmetrics=True),
                             content_type="application/openmetrics-text")
@@ -640,10 +633,9 @@ CANARY_LATENCY = REGISTRY.gauge(
     "weedtpu_canary_latency_seconds",
     "canary probe latency quantiles over the rolling window",
     ("path", "quantile"))
-# performance observatory (stats/pipeline.py, stats/profile.py
-# rooflines): per-stage busy seconds whose RATE is stage occupancy
-# (1 busy-second/second == a saturated stage), bytes moved per stage, and
-# per-kernel achieved-vs-ceiling fractions.
+# performance observatory (stats/pipeline.py): per-stage busy seconds
+# whose RATE is stage occupancy (1 busy-second/second == a saturated
+# stage) and bytes moved per stage.
 PIPELINE_STAGE_SECONDS = REGISTRY.counter(
     "weedtpu_pipeline_stage_seconds_total",
     "busy seconds per data-plane pipeline stage (rate == occupancy)",
@@ -651,11 +643,6 @@ PIPELINE_STAGE_SECONDS = REGISTRY.counter(
 PIPELINE_STAGE_BYTES = REGISTRY.counter(
     "weedtpu_pipeline_stage_bytes_total",
     "bytes processed per data-plane pipeline stage", ("kind", "stage"))
-ROOFLINE_FRAC = REGISTRY.gauge(
-    "weedtpu_roofline_frac",
-    "achieved throughput of a kernel as a fraction of the measured "
-    "hardware ceiling of the resource it exercises",
-    ("resource", "kernel"))
 # interference observatory + governor (stats/interference.py): the
 # foreground-impact index per node and background traffic class, the
 # governed rate per background-work target, and the retune event

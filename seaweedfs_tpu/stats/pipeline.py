@@ -5,8 +5,7 @@ Every overlapped pipeline in the data path (the EC encode/rebuild
 engines in storage/ec/ec_files.py, the multi-volume fleet conversion in
 ops/fleet_convert.py, the EC degraded-read engine) accumulates
 per-stage wall seconds; this module is the always-on answer to "which
-stage bounds throughput and how far from the hardware roofline are we?"
-(none of its device numbers is measured on current code — PERF.md):
+stage bounds throughput, and what was the call doing meanwhile?":
 
 - **Stage** (``job.stage(name)`` / ``job.blocked(name)``) — the EC
   plane's ONE timing primitive.  One enter/exit books the stage's
@@ -18,22 +17,53 @@ stage bounds throughput and how far from the hardware roofline are we?"
   the host plane of the profiler's trace, on the device planes' clock.
   Span names are dotted and stable: ``<job's span prefix>.<stage>``
   (``ec.encode.read``), or ``codec.<stage>`` for the four stages the
-  dispatch seam (ops/dispatch.py) books to the calling job.
+  dispatch seam (ops/dispatch.py) books to the calling job.  The bulk
+  engines' stages, work unless marked (b) for blocked, which never
+  counts as busy:
+
+    caller   ``open`` (tmp outputs created, sources opened and mapped,
+             writer pools and pipeline threads started, rings
+             allocated), ``await_unit`` (b: the dispatcher waiting for
+             the reader's next unit), the seam's ``h2d`` and
+             ``dispatch``, rebuild's ``stall`` (b) and ``stage``,
+             ``join_drain`` (b: the last unit enqueued, waiting for the
+             drain and the reader to end), ``join_writers`` (b: the
+             writer pool's close: queued writes, then the join),
+             ``commit`` (truncate, close, unmap, ``.vif``, renames)
+    reader   ``stall`` (b), ``read``, ``ship_data`` (a unit's data-shard
+             copy jobs handed to the writers)
+    drain    ``await_parity`` / ``await_batch`` (b: waiting for the next
+             enqueued unit), the seam's ``device_wait`` and
+             ``d2h_copy``, rebuild's ``stall`` (b) and ``unstage``
+    writers  ``write_data``, ``write_parity``, ``write``
+
+  On the calling thread they follow one another from the job's first
+  line to its last, so they add up to the job's ``call_s``.
 
 - **PipelineJob** — stage accounting for one run: per-stage busy
   seconds (doing work), blocked seconds (backpressured on a downstream
   ring/queue), bytes, items, and queue-depth high-water marks.
   Finished jobs land in a bounded ring; running jobs are observable
-  live.  ``bottleneck()`` attributes the run to the stage whose busy
-  fraction bounds throughput and — when a hardware ceiling for that
-  stage's resource is known (stats/profile.py ceilings) — how close to
-  it the stage ran.
+  live.  A tracked job opens a ``jax.profiler.TraceAnnotation`` named
+  ``job.<span>`` (``job.ec.encode``) carrying its id, on the thread that
+  tracks it, and closes it in ``finish()``, under the stages' gate (an
+  open profiler session): the call itself is on the profiler's trace,
+  from the first file opened to the last rename, and a reader of the
+  trace can cut it to the calls.  ``finish()`` states ``call_s``
+  (tracked to finished) beside the engine's own ``wall_s``.
+  ``occupancy(name, delta)`` is the ONE gauge: a time-weighted count
+  (units between enqueue and materialised result: ``inflight``) which
+  ``finish()`` states as ``<name>_max``, ``<name>_avg`` and
+  ``<name>_ge2_frac`` over ``wall_s``.  ``bottleneck()`` names the stage
+  whose busy fraction bounds throughput.
 
 - **FlowAccount** — the continuous twin for long-lived engines (the EC
-  read path): cumulative per-stage busy seconds/bytes whose counter
-  rates ARE stage occupancy (``weedtpu_pipeline_stage_seconds_total``:
-  1 busy-second per second == a saturated stage), so "degraded reads
-  went remote-fetch-bound at 14:05" is a /cluster/history query.
+  read path ``ec_read``, the reduced-read repair ``ec_regen``, the
+  scrubber ``ec_scrub``): cumulative per-stage busy seconds/bytes whose
+  counter rates ARE stage occupancy
+  (``weedtpu_pipeline_stage_seconds_total``: 1 busy-second per second
+  == a saturated stage), so "degraded reads went remote-fetch-bound at
+  14:05" is a /cluster/history query.
 
 Surfaces: ``/debug/pipeline`` on every server (loopback-gated, mounted
 by trace.debug_routes) renders per-job timelines; master
@@ -95,16 +125,6 @@ _flows: dict[str, "FlowAccount"] = {}
 # bottleneck attribution (a fully backpressured producer reads as
 # blocked, not as the bottleneck)
 IDLE_STAGES = ("stall", "blocked", "idle")
-
-# stage -> hardware-resource mapping for ceiling attribution
-# (stats/profile.py holds the measured ceilings themselves)
-STAGE_RESOURCE = {
-    "encode": "device", "reconstruct": "device", "d2h": "d2h",
-    "read": "disk", "local_pread": "disk",
-    "write": "disk", "write_data": "disk", "write_parity": "disk",
-    "remote_fetch": "net",
-}
-
 
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
@@ -185,7 +205,10 @@ class PipelineJob:
     names (``ec.encode``; the kind when not given).  ``sums`` names the
     lumps the engine publishes beside their parts, ``{"d2h":
     ("device_wait", "d2h_copy")}``: a part's seconds book to its lump
-    too, so the lump IS the sum at every moment of the run."""
+    too, so the lump IS the sum at every moment of the run.  A job made
+    to be registered puts itself on the profiler's trace too, as
+    ``job.<span>`` from here to ``finish()``: make it and finish it on
+    one thread, the engine's caller."""
 
     def __init__(self, kind: str, stats: dict | None = None,
                  total_bytes: int = 0, meta: dict | None = None,
@@ -209,6 +232,15 @@ class PipelineJob:
         self._stages: dict[str, list[float]] = {}
         # queue -> [last, max, sum, samples, bound]
         self._queues: dict[str, list[float]] = {}
+        # gauge -> [level, since, level-seconds, seconds at >= 2, max]
+        self._gauges: dict[str, list[float]] = {}
+        # the call on the profiler's trace, outside the stages' `ec.` /
+        # `codec.` names: a reader of stages must not take it for one
+        ann = _profiler_annotation() if register else None
+        if ann is not None:
+            ann = ann("job." + self.span, **self.annotation_ids())
+            ann.__enter__()
+        self._ann = ann
         self._registered = register and perf_obs_enabled()
         if self._registered:
             with _reg_lock:
@@ -266,6 +298,30 @@ class PipelineJob:
         with self._lock:
             self.stats[name] = self.stats.get(name, 0) + n
 
+    def occupancy(self, name: str, delta: int) -> None:
+        """Move the gauge `name` by `delta` (+1 where a unit is enqueued,
+        -1 where its result is materialised, from whichever threads):
+        the count is weighted by the time it stood, and `finish()` states
+        its maximum, its mean and the share of the wall at two or more."""
+        now = time.perf_counter()
+        with self._lock:
+            g = self._gauges.get(name)
+            if g is None:
+                g = self._gauges[name] = [0, now, 0.0, 0.0, 0]
+            self._settle(g, now)
+            g[0] += delta
+            if g[0] > g[4]:
+                g[4] = g[0]
+
+    @staticmethod
+    def _settle(g: list, now: float) -> None:
+        """Book the time gauge `g` stood at its level since its last move."""
+        held = now - g[1]
+        g[2] += g[0] * held
+        if g[0] >= 2:
+            g[3] += held
+        g[1] = now
+
     def queue(self, name: str, depth: int, bound: int = 0) -> None:
         """Sample a queue's depth (producers call at put/get sites)."""
         with self._lock:
@@ -280,16 +336,38 @@ class PipelineJob:
             if bound:
                 q[4] = float(bound)
 
+    def _wall(self) -> float:
+        """The run's clock (under the lock): the stats dict's wall_s when
+        the pipeline stamped one — the job's own bracket includes
+        setup/teardown outside it — else that bracket, so far."""
+        wall = self.stats.get("wall_s")
+        if isinstance(wall, (int, float)) and wall > 0:
+            return wall
+        return self.wall_s if self.wall_s is not None \
+            else time.perf_counter() - self._t0
+
     def finish(self, error: BaseException | str | None = None) -> None:
-        """Seal the job: stamp the wall clock, book the cumulative stage
+        """Seal the job: stamp the wall clock (`call_s`), state the
+        gauges, close the job's annotation, book the cumulative stage
         seconds/bytes counters, move registry entry active -> recent."""
         with self._lock:
             if self.state != "running":
                 return
-            self.wall_s = time.perf_counter() - self._t0
+            now = time.perf_counter()
+            self.wall_s = self.stats["call_s"] = now - self._t0
             self.state = "failed" if error else "done"
             if error:
                 self.error = str(error) or type(error).__name__
+            wall = max(self._wall(), 1e-9)  # a gauge moves only inside it
+            for name, g in self._gauges.items():
+                self._settle(g, now)
+                self.stats[name + "_max"] = int(g[4])
+                self.stats[name + "_avg"] = round(g[2] / wall, 4)
+                self.stats[name + "_ge2_frac"] = round(
+                    min(1.0, g[3] / wall), 4)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._registered:
             with _reg_lock:
                 _active.pop(self.job_id, None)
@@ -322,10 +400,11 @@ class PipelineJob:
 
     def _stats_stage_seconds(self) -> dict[str, float]:
         """Stage wall-seconds from the wrapped stats dict (`encode_s`,
-        `write_parity_s`, ...).  `wall_s` is the clock, not a stage."""
+        `write_parity_s`, ...).  `wall_s` and `call_s` are clocks, not
+        stages."""
         out: dict[str, float] = {}
         for key, v in list(self.stats.items()):
-            if key.endswith("_s") and key != "wall_s" and \
+            if key.endswith("_s") and key not in ("wall_s", "call_s") and \
                     isinstance(v, (int, float)):
                 out[key[:-2]] = float(v)
         return out
@@ -334,13 +413,7 @@ class PipelineJob:
         with self._lock:
             stages_own = {k: list(v) for k, v in self._stages.items()}
             queues = {k: list(v) for k, v in self._queues.items()}
-            # the stats dict's wall_s is the canonical clock when the
-            # pipeline stamped one — the job's own bracket includes
-            # setup/teardown outside it
-            wall = self.stats.get("wall_s")
-            if not isinstance(wall, (int, float)) or wall <= 0:
-                wall = self.wall_s if self.wall_s is not None \
-                    else time.perf_counter() - self._t0
+            wall = self._wall()
             state, error = self.state, self.error
         merged: dict[str, dict] = {
             name: {"busy_s": busy, "blocked_s": blocked, "bytes": nbytes,
@@ -521,9 +594,8 @@ def reset() -> None:
 # -- bottleneck attribution -----------------------------------------------
 
 def bottleneck(snap: dict) -> dict | None:
-    """The stage whose busy fraction bounds this job's throughput, plus
-    its achieved-vs-ceiling fraction when the stage maps to a resource
-    with a measured ceiling (stats/profile.py).  Stages are concurrent
+    """The stage whose busy fraction bounds this job's throughput, with
+    its achieved GB/s where it booked bytes.  Stages are concurrent
     (that is the point of the pipelines), so the max busy-FRACTION
     stage — occupancy of the stage's worker capacity, see snapshot() —
     IS the throughput bound: the wall clock can never beat the time its
@@ -548,14 +620,6 @@ def bottleneck(snap: dict) -> dict | None:
         active = row["busy_s"] / row.get("workers", 1)
         gbps = row["bytes"] / 1e9 / max(active, 1e-9)
         out["achieved_gbps"] = round(gbps, 3)
-        resource = STAGE_RESOURCE.get(best_name)
-        if resource is not None:
-            from seaweedfs_tpu.stats import profile as _profile
-            ceil = _profile.ceilings().get(resource)
-            if ceil:
-                out["resource"] = resource
-                out["ceiling_gbps"] = round(ceil, 3)
-                out["ceiling_frac"] = round(min(gbps / ceil, 9.99), 3)
     return out
 
 
@@ -603,15 +667,6 @@ def aggregate_fleet(per_node: list[tuple[str, dict]]) -> dict:
                     verdicts[kind] = {"node": node, **bn}
     return {"nodes": nodes, "occupancy": occupancy,
             "bottlenecks": verdicts, "running": running}
-
-
-def roofline_offenders(roofline: dict, limit: int = 5) -> list[dict]:
-    """The busiest kernel/resource rows ranked by how far they run from
-    their ceiling — the "what should the next perf round attack" list."""
-    rows = [r for r in roofline.get("rows", [])
-            if r.get("ceiling_frac") is not None and r.get("busy_s", 0.0)]
-    rows.sort(key=lambda r: (r["ceiling_frac"], -r["busy_s"]))
-    return rows[:limit]
 
 
 # -- /debug/pipeline -------------------------------------------------------

@@ -285,55 +285,6 @@ class KernelProfile:
 KERNELS = KernelProfile()
 
 
-# -- roofline accounting --------------------------------------------------
-#
-# Achieved throughput per kernel row, divided by the measured ceiling of
-# the hardware resource it exercises, exported as
-# weedtpu_roofline_frac{resource,kernel} gauges: "encode is now
-# D2H-bound" becomes a queryable series.  Ceilings come from (highest
-# precedence first) set_ceiling() calls and the WEEDTPU_CEILINGS env
-# ("resource=GBps,resource=GBps").
-
-_ceilings_lock = threading.Lock()
-_ceilings_set: dict[str, float] = {}
-_ceilings_cache: tuple[float, dict] | None = None
-
-
-def set_ceiling(resource: str, gbps: float,
-                source: str = "measured") -> None:
-    """Record a measured hardware ceiling (GB/s) for a resource
-    (device/h2d/d2h/disk/net).  Servers that micro-measure call this;
-    WEEDTPU_CEILINGS overrides nothing set here."""
-    global _ceilings_cache
-    with _ceilings_lock:
-        _ceilings_set[resource] = float(gbps)
-        _ceilings_cache = None
-
-
-def ceilings() -> dict[str, float]:
-    """resource -> GB/s ceiling, merged from set_ceiling() calls and the
-    WEEDTPU_CEILINGS env.  Cached ~5s."""
-    global _ceilings_cache
-    now = time.monotonic()
-    with _ceilings_lock:
-        cached = _ceilings_cache
-        if cached is not None and now - cached[0] < 5.0:
-            return dict(cached[1])
-        out: dict[str, float] = {}
-        for part in os.environ.get("WEEDTPU_CEILINGS", "").split(","):
-            k, sep, v = part.partition("=")
-            if sep:
-                try:
-                    gbps = float(v)
-                except ValueError:
-                    continue
-                if gbps > 0:
-                    out[k.strip()] = gbps
-        out.update(_ceilings_set)
-        _ceilings_cache = (now, out)
-        return dict(out)
-
-
 # -- what the codec selection resolved to ---------------------------------
 
 _codecs_noted: dict[tuple, dict] = {}
@@ -441,11 +392,9 @@ _ROOFLINE_TRANSFERS = (("h2d", "h2d_s", "h2d_bytes"),
 
 
 def roofline_snapshot() -> dict:
-    """Per-kernel achieved GB/s per resource + fraction of the measured
-    ceiling where one is known.  Rows without meaningful time (<1ms
-    accumulated) are skipped — a fraction computed over noise would
-    jitter the gauges."""
-    ceil = ceilings()
+    """Per-kernel calls, bytes, busy seconds and achieved GB/s per
+    resource (`rows`).  Rows without meaningful time (<1ms accumulated)
+    are skipped: a rate computed over noise says nothing."""
     rows: list[dict] = []
     for key, r in KERNELS.snapshot().items():
         kernel, _, backend = key.partition("[")
@@ -467,30 +416,10 @@ def roofline_snapshot() -> dict:
                    "busy_s": round(secs, 4),
                    "gbytes": round(nbytes / 1e9, 4),
                    "achieved_gbps": round(gbps, 3)}
-            c = ceil.get(resource)
-            if c:
-                row["ceiling_gbps"] = round(c, 3)
-                row["ceiling_frac"] = round(min(gbps / c, 9.99), 4)
             row.update(KERNELS.notes(key))
             rows.append(row)
     rows.sort(key=lambda r: -r["busy_s"])
-    return {"ceilings": {k: round(v, 3) for k, v in ceil.items()},
-            "rows": rows}
-
-
-def export_roofline() -> None:
-    """Stamp weedtpu_roofline_frac{resource,kernel} from the live kernel
-    profile — called on every /metrics render (stats/metrics.py), so the
-    TSDB/dashboard see the fractions at scrape cadence."""
-    from seaweedfs_tpu.stats import pipeline as _pipeline
-    if not _pipeline.perf_obs_enabled():
-        return
-    from seaweedfs_tpu.stats import metrics as _metrics
-    for row in roofline_snapshot()["rows"]:
-        frac = row.get("ceiling_frac")
-        if frac is not None:
-            _metrics.ROOFLINE_FRAC.labels(
-                row["resource"], row["kernel"]).set(frac)
+    return {"rows": rows}
 
 
 # -- /debug/pprof --------------------------------------------------------

@@ -157,27 +157,44 @@ def write_ec_files(base: str, dat_path: str | None = None,
 
     tmp_paths = [base + layout.to_ext(i) + ".tmp"
                  for i in range(spec.n)]
-    # O_RDWR without O_TRUNC: recycle pages of stale tmp files (see above);
-    # _encode_stream ftruncates each fd to its exact final size.
-    out_fds = [os.open(p_, os.O_RDWR | os.O_CREAT, 0o644) for p_ in tmp_paths]
+    # stage attribution always accumulates (even when the caller brought
+    # no dict): the stats keys feed the pipeline job /debug/pipeline
+    # renders, so every encode is observable.  The job is the call, from
+    # the first tmp file opened to the last rename
+    stats = stats if stats is not None else {}
+    pjob = _pipeline.track("ec_encode", stats, dat_size,
+                           meta={"mode": "pipelined"}, span="ec.encode",
+                           sums=ENCODE_SUMS)
+    out_fds: list[int] = []
     ok = False
     try:
+        with pjob.stage("open", files=spec.n):
+            # O_RDWR without O_TRUNC: recycle pages of stale tmp files (see
+            # above); _encode_stream ftruncates each fd to its exact size
+            for p_ in tmp_paths:
+                out_fds.append(os.open(p_, os.O_RDWR | os.O_CREAT, 0o644))
         _encode_stream(codec, dat_path, dat_size, large_block, small_block,
-                       batch_size, out_fds, progress, cancel, stats)
+                       batch_size, out_fds, progress, cancel, pjob)
         ok = True
     finally:
-        for fd in out_fds:
-            os.close(fd)
-        if ok:
-            write_vif(base, dat_size, codec=spec.tag)
-            for i, p_ in enumerate(tmp_paths):
-                os.replace(p_, base + layout.to_ext(i))
-        else:
-            for p_ in tmp_paths:
-                try:
-                    os.remove(p_)
-                except OSError:
-                    pass
+        try:
+            with pjob.stage("commit"):
+                for fd in out_fds:
+                    os.close(fd)
+                if ok:
+                    write_vif(base, dat_size, codec=spec.tag)
+                    for i, p_ in enumerate(tmp_paths):
+                        os.replace(p_, base + layout.to_ext(i))
+                else:
+                    for p_ in tmp_paths:
+                        try:
+                            os.remove(p_)
+                        except OSError:
+                            pass
+        finally:
+            _state_overlap(stats)
+            pjob.finish(None if ok else
+                        (sys.exc_info()[1] or "encode failed"))
 
 
 def _iter_units(dat_size: int, large_block: int, small_block: int,
@@ -356,7 +373,7 @@ def _finalize_shards(out_fds, highwater, shard_size: int) -> None:
 
 def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
                    small_block: int, batch_size: int, out_fds,
-                   progress=None, cancel=None, stats=None) -> None:
+                   progress, cancel, pjob) -> None:
     """Stream the .dat through the codec into the shard fds: one strategy,
     the overlapped reader -> dispatch -> drain -> writers pipeline
     (_encode_pipelined), every shard file written through the per-shard
@@ -378,54 +395,47 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     Rows wholly beyond the .dat are never read, encoded, or written: the
     parity of an all-zero row region is zero, so those regions become
     holes (_finalize_shards).  Partially-covered units encode only the
-    rows that carry data, against a column-sliced parity matrix."""
-    # stage attribution always accumulates (even when the caller brought
-    # no dict): the stats keys feed the pipeline job /debug/pipeline
-    # renders, so every encode is observable
-    stats = stats if stats is not None else {}
+    rows that carry data, against a column-sliced parity matrix.
+
+    The stages book to `pjob` (write_ec_files' job, whose `stats` this
+    fills): `open` for the .dat's map here, `commit` for the cut to size;
+    `wall_s` runs from before the map to after the pipeline's last join."""
+    stats = pjob.stats
     stats["bytes"] = dat_size
     stats["rows_staged"] = 0  # stripe rows copied on the host (job.count)
     shard_size = layout.shard_file_size(dat_size, large_block, small_block,
                                         data_shards=codec.k)
     highwater = [0] * (codec.k + codec.m)
-    if dat_size == 0:
-        _finalize_shards(out_fds, highwater, shard_size)
-        return
-
-    stats["mode"] = "pipelined"
-    stats["backend"] = _backend_name(codec)
-
-    t_wall = time.perf_counter()
-    import mmap as mmap_mod
-    with _pipeline.track("ec_encode", stats, dat_size,
-                         meta={"mode": stats["mode"]}, span="ec.encode",
-                         sums=ENCODE_SUMS) as pjob, \
-            open(dat_path, "rb") as datf:
-        dat_fd = datf.fileno()
-        mm = _map_readonly(dat_fd, dat_size)
-        dat_view = np.frombuffer(mm, dtype=np.uint8)
-        try:
-            _encode_pipelined(codec, dat_fd, dat_view, dat_size,
-                              large_block, small_block, batch_size,
-                              out_fds, highwater, pjob, progress, cancel)
-        finally:
-            del dat_view
+    if dat_size:
+        stats["mode"] = "pipelined"
+        stats["backend"] = _backend_name(codec)
+        t_wall = time.perf_counter()
+        with open(dat_path, "rb") as datf:
+            dat_fd = datf.fileno()
+            with pjob.stage("open", files=1, bytes=dat_size):
+                mm = _map_readonly(dat_fd, dat_size)
+            dat_view = np.frombuffer(mm, dtype=np.uint8)
             try:
-                mm.close()
-            except BufferError:
-                # an in-flight exception's traceback frames still hold
-                # views into the map; GC reaps the mapping with them
-                pass
-        stats["wall_s"] = time.perf_counter() - t_wall
-        frac = overlap_fraction(stats)
-        if frac is not None:
-            stats["overlap_frac"] = frac
-        # stage BYTES are analytic (the layout fixes them), booked once:
-        # zero hot-path cost, and the bottleneck verdict gets achieved
-        # GB/s per stage for its ceiling-fraction attribution
-        _book_stage_bytes(pjob, stats, dat_size,
-                          codec.m * shard_size)
-    _finalize_shards(out_fds, highwater, shard_size)
+                _encode_pipelined(codec, dat_fd, dat_view, dat_size,
+                                  large_block, small_block, batch_size,
+                                  out_fds, highwater, pjob, progress, cancel)
+            finally:
+                del dat_view
+                with pjob.stage("commit"):
+                    try:
+                        mm.close()
+                    except BufferError:
+                        # an in-flight exception's traceback frames still
+                        # hold views into the map; GC reaps the mapping
+                        # with them
+                        pass
+            stats["wall_s"] = time.perf_counter() - t_wall
+            # stage BYTES are analytic (the layout fixes them), booked
+            # once: zero hot-path cost, and the bottleneck verdict gets
+            # achieved GB/s per stage
+            _book_stage_bytes(pjob, stats, dat_size, codec.m * shard_size)
+    with pjob.stage("commit"):
+        _finalize_shards(out_fds, highwater, shard_size)
 
 
 def _book_stage_bytes(pjob, stats: dict, data_bytes: int,
@@ -648,18 +658,21 @@ class _ShardWriterPool:
     def flush(self) -> None:
         pass
 
-    def close(self) -> None:
+    def close(self, unit: int | None = None) -> None:
         """Drain every queue, join the workers, fold the thread capacity
         behind each stage into stats.  Idempotent, and does not raise —
         callers inspect `.errors`, letting a producer-side exception win
-        over a writer one."""
+        over a writer one.  The calling thread's wait for the queued
+        writes and the join is the job's `join_writers`, blocked (the
+        writers' own `write_*` stages run inside it)."""
         if getattr(self, "_closed", False):
             return
         self._closed = True
-        for q in self._queues:
-            q.put(None)
-        for t in self._threads:
-            t.join()
+        with self._job.blocked("join_writers", unit=unit):
+            for q in self._queues:
+                q.put(None)
+            for t in self._threads:
+                t.join()
         if self._stats is not None:
             stage_busy: dict[str, float] = {}
             for i, busy in enumerate(self._busy):
@@ -752,23 +765,40 @@ class _ShardFlusher:
                 self._jobs[shard] = []
 
 
+# keys ending in `_s` that are no work of a stage: the two clocks, and the
+# blocked stages (a thread waiting for a slot, a buffer, the next unit,
+# the drain or the writers)
+_NOT_WORK_KEYS = frozenset((
+    "wall_s", "call_s", "stall_s", "await_unit_s", "await_parity_s",
+    "await_batch_s", "join_drain_s", "join_writers_s"))
+
+
 def overlap_fraction(stats: dict) -> float | None:
     """Achieved stage overlap of an encode/rebuild run: 1 - wall / (sum of
     per-stage seconds).  0.0 means fully serial (the wall clock IS the sum
     of its stages); the upper bound for a given stage mix is
-    1 - max_stage/sum.  stall_s is producer IDLE time (waiting on a ring
-    buffer), not a productive stage, so it is excluded — a fully
-    backpressured run reads as ~0, not as overlapped.  None when the
-    stats carry no wall clock or no stage time (e.g. an empty volume)."""
+    1 - max_stage/sum.  stall_s and the other blocked stages' keys
+    (_NOT_WORK_KEYS) are a thread's IDLE time, not productive stages, so
+    they are excluded — a fully backpressured run reads as ~0, not as
+    overlapped.  None when the stats carry no wall clock or no stage time
+    (e.g. an empty volume)."""
     wall = stats.get("wall_s")
     total = sum(v for key, v in stats.items()
                 if key.endswith("_s")
-                and key not in ("wall_s", "stall_s")
+                and key not in _NOT_WORK_KEYS
                 and key not in _PART_KEYS  # their lumps carry them
                 and isinstance(v, float))
     if not wall or total <= 0:
         return None
     return round(max(0.0, 1.0 - wall / total), 3)
+
+
+def _state_overlap(stats: dict) -> None:
+    """`overlap_frac` of a call, over every stage it booked: stated last,
+    with the commit's seconds in."""
+    frac = overlap_fraction(stats)
+    if frac is not None:
+        stats["overlap_frac"] = frac
 
 
 def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
@@ -855,14 +885,6 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     # `slots` bounds their units in flight
     pool: queue.Queue = queue.Queue()
     slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
-    if native_host:
-        tailbuf = np.zeros(max_step, dtype=np.uint8)
-        # the parity ring: the drain batches small units through a
-        # _ShardFlusher, whose writers release a buffer only once its
-        # flush group is written, so the ring must cover a whole
-        # unflushed flush group on top of the pipeline's own depth
-        for _ in range(PIPELINE_DEPTH + max(1, FLUSH_BYTES // max_step)):
-            pool.put(np.empty((m, max_step), dtype=np.uint8))
     q_read: queue.Queue = queue.Queue(maxsize=PIPELINE_DEPTH)
     # q_disp is unbounded: it carries at most one entry per in-flight
     # unit (the slots / the ring are the real backpressure) plus FLUSH
@@ -873,9 +895,6 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     # the ring (blocking on pool.get() without the nudge deadlocks)
     FLUSH = object()
     errors: list[BaseException] = []
-    writers = _ShardWriterPool(
-        out_fds, highwater, pjob,
-        stage_of=lambda i: "write_data" if i < k else "write_parity")
     done = 0
 
     def reader() -> None:
@@ -892,18 +911,20 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 if cancel is not None and cancel():
                     raise EncodeCancelled("ec encode cancelled")
                 covered = 0
-                for r in range(rows):
-                    nz, tail = _unit_coverage(
-                        dat_size, row_start + r * k * block, block, col,
-                        step, data_shards=k)
-                    for j in range(nz):
-                        off = row_start + (r * k + j) * block + col
-                        flusher.copy(j, dat_fd, off, shard_off + r * step,
-                                     step if j < nz - 1 else tail,
-                                     src_view=dat_view)
-                    if nz:
-                        covered += (nz - 1) * step + tail
-                        flusher.account(step)
+                with pjob.stage("ship_data", unit=unit):
+                    for r in range(rows):
+                        nz, tail = _unit_coverage(
+                            dat_size, row_start + r * k * block, block, col,
+                            step, data_shards=k)
+                        for j in range(nz):
+                            off = row_start + (r * k + j) * block + col
+                            flusher.copy(j, dat_fd, off,
+                                         shard_off + r * step,
+                                         step if j < nz - 1 else tail,
+                                         src_view=dat_view)
+                        if nz:
+                            covered += (nz - 1) * step + tail
+                            flusher.account(step)
                 if not covered:
                     continue
                 if native_host:
@@ -938,7 +959,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
         # queue hops would cost more than the writes.
         flusher = _make_sink(writers, k + m, min_step)
         while True:
-            item = q_disp.get()
+            with pjob.blocked("await_parity"):
+                item = q_disp.get()
             if item is None:
                 flusher.flush()
                 return
@@ -951,6 +973,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                     for _ in range(m):
                         release()
                 else:
+                    pjob.occupancy("inflight", -1)
                     slots.release()
                 continue
             if release is not None:  # host parity: already materialised
@@ -967,6 +990,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 continue
             finally:
                 del spans, item  # the device is done with the host memory
+                pjob.occupancy("inflight", -1)
                 slots.release()
             # m runs from a linear apply, the rows of [m, step] from any
             # other
@@ -976,11 +1000,25 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
 
     t_r = threading.Thread(target=reader, name="ec-reader", daemon=True)
     t_d = threading.Thread(target=drain, name="ec-drain", daemon=True)
-    t_r.start()
-    t_d.start()
+    with pjob.stage("open"):
+        if native_host:
+            tailbuf = np.zeros(max_step, dtype=np.uint8)
+            # the parity ring: the drain batches small units through a
+            # _ShardFlusher, whose writers release a buffer only once its
+            # flush group is written, so the ring must cover a whole
+            # unflushed flush group on top of the pipeline's own depth
+            for _ in range(PIPELINE_DEPTH
+                           + max(1, FLUSH_BYTES // max_step)):
+                pool.put(np.empty((m, max_step), dtype=np.uint8))
+        writers = _ShardWriterPool(
+            out_fds, highwater, pjob,
+            stage_of=lambda i: "write_data" if i < k else "write_parity")
+        t_r.start()
+        t_d.start()
     try:
         while True:
-            item = q_read.get()
+            with pjob.blocked("await_unit"):
+                item = q_read.get()
             if item is None:
                 break
             # stage-queue depth at the consume site
@@ -1011,20 +1049,25 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                     errors.append(e)  # the reader stops at its next unit
                     slots.release()
                     raise
+                # out on the device until the drain has its parity
+                pjob.occupancy("inflight", +1)
                 q_disp.put((unit, spans, step, shard_off, parity, None))
             del item, spans  # the queue item alone holds a unit's views
     finally:
-        q_disp.put(None)
-        t_d.join()
-        while t_r.is_alive():  # unblock a reader stuck on q_read or a slot
-            try:
-                item = q_read.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            if item is not None and item[1] is not None:
-                slots.release()
-        t_r.join()
-        writers.close()  # after the producers: no submission can block now
+        with pjob.blocked("join_drain"):
+            q_disp.put(None)
+            t_d.join()
+            # unblock a reader stuck on q_read or a slot
+            while t_r.is_alive():
+                try:
+                    item = q_read.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+                if item is not None and item[1] is not None:
+                    slots.release()
+            t_r.join()
+        # after the producers: no submission can block now (`join_writers`)
+        writers.close()
     if errors:
         raise errors[0]
     if writers.errors:
@@ -1097,18 +1140,19 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
     either half of the seam), every batch that went up is waited for and
     the drain thread joined before this returns: the caller closes the maps
     next.  The first error is raised here; a failed writer's is the
-    caller's to raise, after the pool's close.  `stats["inflight_max"]`:
-    the most batches between enqueue and materialised result at once."""
+    caller's to raise, after the pool's close.  The job's gauge `inflight`
+    counts the batches between enqueue and materialised result
+    (`stats["inflight_max"]`, `inflight_avg`, `inflight_ge2_frac`); the
+    drain's wait for the next one is `await_batch`, the caller's for the
+    drain to end `join_drain`."""
     slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
     q_out: queue.Queue = queue.Queue()  # unbounded: `slots` is the bound
     errors: list[BaseException] = []
-    # one writer each, so no lock: in flight is their difference
-    enqueued = materialised = 0
 
     def drain() -> None:
-        nonlocal materialised
         while True:
-            item = q_out.get()
+            with pjob.blocked("await_batch"):
+                item = q_out.get()
             if item is None:
                 return
             unit, off, n, pending = item
@@ -1121,7 +1165,7 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
                 continue
             finally:
                 del item, pending  # the device is done with the host memory
-                materialised += 1
+                pjob.occupancy("inflight", -1)
                 slots.release()
             if errors or writers.failed:
                 continue
@@ -1135,7 +1179,8 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
 
     t_d = threading.Thread(target=drain, name="ec-rebuild-drain",
                            daemon=True)
-    t_d.start()
+    with pjob.stage("open"):
+        t_d.start()
     done = 0
     try:
         for unit, off in enumerate(range(0, shard_size, batch_size)):
@@ -1154,17 +1199,16 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
             except BaseException:
                 slots.release()
                 raise
-            enqueued += 1
-            pjob.stats["inflight_max"] = max(pjob.stats["inflight_max"],
-                                             enqueued - materialised)
+            pjob.occupancy("inflight", +1)
             q_out.put((unit, off, n, pending))
             del rows, pending  # the queue item alone holds a batch's views
             done += n * len(use)
             if progress is not None:
                 progress(done)
     finally:
-        q_out.put(None)
-        t_d.join()
+        with pjob.blocked("join_drain"):
+            q_out.put(None)
+            t_d.join()
     if errors:
         raise errors[0]
 
@@ -1233,7 +1277,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     stats["survivors"] = len(use)
     stats["basis"] = basis_kind(codec, use)
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
-    stats["inflight_max"] = 0  # batches out at once (_rebuild_pipelined)
+    stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
     # MSR sub-packetization: every chunk a codec's interleave must see is
     # an alpha multiple (shard files themselves are block-multiples)
     if spec.alpha > 1:
@@ -1280,26 +1324,30 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     # ENOSPC on the tmp outputs, must not leak a forever-"running"
     # ec_rebuild entry on /debug/pipeline
     try:
-        for i in use:
-            ins[i] = open(base + layout.to_ext(i), "rb")
-        for i, p_ in tmp_paths.items():
-            out_fds[i] = os.open(p_, os.O_RDWR | os.O_CREAT, 0o644)
-        # reconstruction writes ride the same per-shard writer pool as the
-        # encode path: rebuilding 4 lost shards streams them to 4 concurrent
-        # workers while the next batch's decode matmul runs.  Pooled output
-        # buffers (countdown-released once every shard writer is done with
-        # its row) keep the decode from racing its own in-flight writes.
-        writers = _ShardWriterPool([out_fds[i] for i in missing], None, pjob)
-        opool: queue.Queue = queue.Queue()
-        for _ in range(PIPELINE_DEPTH):
-            opool.put(np.empty(
-                (len(missing), min(batch_size, max(shard_size, 1))),
-                dtype=np.uint8))
-        for i, f in ins.items():
-            if shard_size:
-                mm = _map_readonly(f.fileno(), shard_size)
-                maps[i] = mm
-                views[i] = np.frombuffer(mm, dtype=np.uint8)
+        with pjob.stage("open", files=len(use) + len(missing),
+                        bytes=shard_size * len(use)):
+            for i in use:
+                ins[i] = open(base + layout.to_ext(i), "rb")
+            for i, p_ in tmp_paths.items():
+                out_fds[i] = os.open(p_, os.O_RDWR | os.O_CREAT, 0o644)
+            # reconstruction writes ride the same per-shard writer pool as
+            # the encode path: rebuilding 4 lost shards streams them to 4
+            # concurrent workers while the next batch's decode matmul runs.
+            # Pooled output buffers (countdown-released once every shard
+            # writer is done with its row) keep the decode from racing its
+            # own in-flight writes.
+            writers = _ShardWriterPool([out_fds[i] for i in missing], None,
+                                       pjob)
+            opool: queue.Queue = queue.Queue()
+            for _ in range(PIPELINE_DEPTH):
+                opool.put(np.empty(
+                    (len(missing), min(batch_size, max(shard_size, 1))),
+                    dtype=np.uint8))
+            for i, f in ins.items():
+                if shard_size:
+                    mm = _map_readonly(f.fileno(), shard_size)
+                    maps[i] = mm
+                    views[i] = np.frombuffer(mm, dtype=np.uint8)
         if native_host:
             # the matmul straight off the maps into the output ring, a
             # batch booked whole as `reconstruct`
@@ -1325,51 +1373,56 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
             _rebuild_pipelined(codec, views, use, missing, shard_size,
                                batch_size, writers, opool, pjob, progress,
                                cancel)
-        writers.close()
+        writers.close()  # `join_writers`
         if writers.errors:
             raise writers.errors[0]
-        for fd in out_fds.values():
-            os.ftruncate(fd, shard_size)
+        with pjob.stage("commit"):
+            for fd in out_fds.values():
+                os.ftruncate(fd, shard_size)
         stats["wall_s"] = time.perf_counter() - t_wall
-        frac = overlap_fraction(stats)
-        if frac is not None:
-            stats["overlap_frac"] = frac
         _book_stage_bytes(pjob, stats,
                           shard_size * len(use),
                           shard_size * len(missing))
         ok = True
     finally:
         _netflow.reset(_flow_token)
-        if writers is not None:
-            writers.close()  # idempotent; the fds must outlive the workers
-        # seal the job only after close() folded the writer-pool busy
-        # seconds into stats — finish() exports the stage counters, and
-        # a failed rebuild must not export zero write-stage occupancy.
-        # The in-flight exception (ENOSPC, vanished survivor) is the
-        # error operators triage from /debug/pipeline, not a generic tag
-        pjob.finish(None if ok else
-                    (sys.exc_info()[1] or "rebuild failed"))
-        job_span.__exit__(*sys.exc_info())
-        for f in ins.values():
-            f.close()
-        for i in list(views):
-            del views[i]
-        for mm in maps.values():
-            try:
-                mm.close()
-            except BufferError:
-                pass
-        for fd in out_fds.values():
-            os.close(fd)
-        if ok:
-            for i, p_ in tmp_paths.items():
-                os.replace(p_, base + layout.to_ext(i))
-        else:
-            for p_ in tmp_paths.values():
-                try:
-                    os.remove(p_)
-                except OSError:
-                    pass
+        error = None if ok else (sys.exc_info()[1] or "rebuild failed")
+        try:
+            if writers is not None:
+                # idempotent; the fds must outlive the workers
+                writers.close()
+            with pjob.stage("commit"):
+                for f in ins.values():
+                    f.close()
+                for i in list(views):
+                    del views[i]
+                for mm in maps.values():
+                    try:
+                        mm.close()
+                    except BufferError:
+                        pass
+                for fd in out_fds.values():
+                    os.close(fd)
+                if ok:
+                    for i, p_ in tmp_paths.items():
+                        os.replace(p_, base + layout.to_ext(i))
+                else:
+                    for p_ in tmp_paths.values():
+                        try:
+                            os.remove(p_)
+                        except OSError:
+                            pass
+        finally:
+            # the job is the call: it is sealed after the commit, and
+            # after close() folded the writer-pool busy seconds into
+            # stats — finish() exports the stage counters, and a failed
+            # rebuild must not export zero write-stage occupancy.  The
+            # in-flight exception (ENOSPC, vanished survivor) is the
+            # error operators triage from /debug/pipeline, not a generic
+            # tag
+            _state_overlap(stats)
+            pjob.finish(error)
+            job_span.__exit__(*sys.exc_info())
     return missing
 
 

@@ -15,7 +15,6 @@ import numpy as np
 import pytest
 
 from seaweedfs_tpu.ops import fleet_convert
-from seaweedfs_tpu.stats import pipeline
 from seaweedfs_tpu.storage.ec import ec_files, layout
 
 
@@ -347,19 +346,24 @@ def test_finalize_shards_cuts_a_longer_tmp_and_extends_with_a_hole(
 
 _GONE = ("submit_s", "complete_s", "submit_workers", "complete_workers",
          "aio_mode", "aio_direct_bytes", "aio_degraded_engines")
+# the call's own stages, in every engine and under every codec (PR 36)
+_CALL_KEYS = {"open_s", "join_writers_s", "commit_s", "call_s", "wall_s"}
 _ENCODE_KEYS = {
     "jax": {"read_s", "encode_s", "h2d_s", "dispatch_s", "d2h_s",
             "device_wait_s", "d2h_copy_s", "write_data_s",
-            "write_parity_s", "stall_s", "wall_s",
-            "write_data_workers", "write_parity_workers"},
-    "cpp": {"encode_s", "write_data_s", "write_parity_s", "wall_s",
-            "write_data_workers", "write_parity_workers"}}
+            "write_parity_s", "stall_s", "ship_data_s", "await_unit_s",
+            "await_parity_s", "join_drain_s",
+            "write_data_workers", "write_parity_workers"} | _CALL_KEYS,
+    "cpp": {"encode_s", "write_data_s", "write_parity_s", "ship_data_s",
+            "await_unit_s", "await_parity_s", "join_drain_s",
+            "write_data_workers", "write_parity_workers"} | _CALL_KEYS}
 _REBUILD_KEYS = {
     "jax": {"reconstruct_s", "stage_s", "h2d_s", "dispatch_s",
             "device_wait_s", "d2h_copy_s", "unstage_s", "write_s",
-            "stall_s", "wall_s", "write_workers"},
-    "cpp": {"reconstruct_s", "write_s", "stall_s", "wall_s",
-            "write_workers"}}
+            "stall_s", "await_batch_s", "join_drain_s",
+            "write_workers"} | _CALL_KEYS,
+    "cpp": {"reconstruct_s", "write_s", "stall_s",
+            "write_workers"} | _CALL_KEYS}
 
 
 @pytest.mark.parametrize("codec", ["jax", "cpp"])
@@ -389,7 +393,6 @@ def test_job_stats_carry_exactly_the_documented_stage_keys(
     # a ring that never ran dry books no stall
     assert got - {"stall_s"} == want - {"stall_s"}, sorted(got ^ want)
     assert not [k for k in _GONE if k in stats]
-    assert pipeline.STAGE_RESOURCE.keys().isdisjoint(("submit", "complete"))
 
 
 # ---- the three consumers on the one path -------------------------------

@@ -14,6 +14,7 @@ import subprocess
 import sys
 import threading
 import time
+import types
 
 import jax
 import numpy as np
@@ -35,18 +36,34 @@ MESH_KINDS = KINDS + ["fleet_spans"]
 SEAM = {"codec.h2d", "codec.dispatch", "codec.device_wait",
         "codec.d2h_copy"}
 # the engine's own stages that a tiny run on a device codec must show
-# (`stall` is there too whenever a pooled buffer was waited for)
+# (`stall` is there too whenever a pooled buffer was waited for); since
+# PR 36 the call's head, tail and waits are stages too
 ENGINE = {"encode": {"ec.encode.read", "ec.encode.write_data",
-                     "ec.encode.write_parity"},
+                     "ec.encode.write_parity", "ec.encode.open",
+                     "ec.encode.ship_data", "ec.encode.await_unit",
+                     "ec.encode.await_parity", "ec.encode.join_drain",
+                     "ec.encode.join_writers", "ec.encode.commit"},
           "rebuild": {"ec.rebuild.stage", "ec.rebuild.unstage",
-                      "ec.rebuild.write"},
+                      "ec.rebuild.write", "ec.rebuild.open",
+                      "ec.rebuild.await_batch", "ec.rebuild.join_drain",
+                      "ec.rebuild.join_writers", "ec.rebuild.commit"},
           "fleet": {"ec.fleet.read", "ec.fleet.write_data",
-                    "ec.fleet.write_parity"},
-          "fleet_spans": {"ec.fleet.read", "ec.fleet.write_data",
-                          "ec.fleet.write_parity"},
+                    "ec.fleet.write_parity", "ec.fleet.open",
+                    "ec.fleet.ship_data", "ec.fleet.await_unit",
+                    "ec.fleet.await_parity", "ec.fleet.join_drain",
+                    "ec.fleet.join_writers", "ec.fleet.commit"},
           "degraded_read": {"ec.read.local_pread",
                             "ec.read.gather_survivors",
                             "ec.read.reconstruct"}}
+ENGINE["fleet_spans"] = ENGINE["fleet"]
+# the bulk engines: kind -> (job kind, the job's span)
+BULK = {"encode": ("ec_encode", "ec.encode"),
+        "rebuild": ("ec_rebuild", "ec.rebuild"),
+        "fleet": ("fleet_convert", "ec.fleet"),
+        "fleet_spans": ("fleet_convert", "ec.fleet")}
+# stages whose annotation carries no `unit`: a writer's batch spans
+# several, and the call's own stages belong to no unit
+NO_UNIT = (".write", ".open", ".await_", ".join_", ".commit")
 # every key /admin/ec/progress `stages` carried before the seam's cut
 # (the parent commit's stats dicts of the same tiny runs)
 OLD_KEYS = {
@@ -69,6 +86,15 @@ OLD_KEYS["fleet"].add("rows_staged")
 OLD_KEYS["fleet_spans"] = OLD_KEYS["fleet"]
 # PR 35: the rebuild job says how many batches were out at once
 OLD_KEYS["rebuild"].add("inflight_max")
+# PR 36: the call's clock, its head, tail and waits, and the gauge
+for _kind, _more in (("encode", {"ship_data_s", "await_unit_s",
+                                 "await_parity_s"}),
+                     ("rebuild", {"await_batch_s"}),
+                     ("fleet", {"ship_data_s", "await_unit_s",
+                                "await_parity_s"})):
+    OLD_KEYS[_kind] |= _more | {
+        "call_s", "open_s", "join_drain_s", "join_writers_s", "commit_s",
+        "inflight_max", "inflight_avg", "inflight_ge2_frac"}
 
 
 @pytest.fixture(autouse=True)
@@ -179,13 +205,14 @@ def _annotations(trace_dir: str) -> dict[str, list[dict]]:
     found: dict[str, list[dict]] = {}
     for plane in ProfileData.from_file(path).planes:
         assert not plane.name.startswith("/device:") or not any(
-            e.name.startswith(("ec.", "codec."))
+            e.name.startswith(("ec.", "codec.", "job."))
             for ln in plane.lines for e in ln.events)
         for ln in plane.lines:
             for e in ln.events:
-                if e.name.startswith(("ec.", "codec.")):
-                    found.setdefault(e.name, []).append(
-                        {k: v for k, v in e.stats})
+                if e.name.startswith(("ec.", "codec.", "job.")):
+                    found.setdefault(e.name, []).append(dict(
+                        {k: v for k, v in e.stats}, t0=e.start_ns,
+                        t1=e.start_ns + e.duration_ns, thread=ln.name))
     return found
 
 
@@ -208,9 +235,68 @@ def test_stages_annotate_the_profilers_trace(kind, tmp_path):
         mine = [s for s in found[name] if str(s.get(id_key)) ==
                 str(id_value)]
         assert mine, (name, id_key, id_value, found[name][:3])
-        if kind != "degraded_read" and ".write" not in name:
-            # bulk stages say which unit (a writer's batch spans several)
+        if kind != "degraded_read" and not any(x in name for x in NO_UNIT):
+            # bulk stages say which unit
             assert {int(s["unit"]) for s in mine} >= {0}
+
+
+@pytest.mark.parametrize("kind", sorted(BULK))
+def test_the_job_is_on_the_trace_round_every_stage_of_the_call(
+        kind, tmp_path):
+    """`job.<span>` carries the job id and holds every annotation the job
+    left, on whichever thread, from `open` to the renames in `commit`:
+    `finish()` comes after the commit in all three engines."""
+    op = prepare(kind, tmp_path / "data")
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        _, (_, job_id) = op()
+    finally:
+        jax.profiler.stop_trace()
+    found = _annotations(str(tmp_path / "trace"))
+    span = BULK[kind][1]
+    mine = [s for s in found["job." + span] if int(s["job"]) == job_id]
+    assert len(mine) == 1, found["job." + span]
+    job, = mine
+    stages = [(name, s) for name, evs in found.items()
+              if not name.startswith("job.") for s in evs
+              if str(s.get("job")) == str(job_id)]
+    assert {name for name, _ in stages} >= SEAM | ENGINE[kind]
+    for name, s in stages:
+        assert job["t0"] <= s["t0"] and s["t1"] <= job["t1"], (name, s, job)
+    # the commit is the call's last stage, on the thread the job is on
+    last = max((s for name, s in stages
+                if name == span + ".commit" and s["thread"] == job["thread"]),
+               key=lambda s: s["t1"])
+    assert last["t1"] == max(s["t1"] for _, s in stages
+                             if s["thread"] == job["thread"])
+    # no stage reader may take the job for a stage
+    assert not [n for n in found if n.startswith("job.")
+                and n.startswith(("ec.", "codec."))]
+
+
+def test_no_session_builds_no_annotation_object(tmp_path, monkeypatch):
+    """The off path: with no profiler session open neither a stage nor a
+    job constructs an annotation."""
+    built = []
+
+    class Closed:
+        def __init__(self, *a, **kw):
+            built.append(a)
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+    ec_files._get_codec("jax")  # jax is loaded: the gate has a class to ask
+    monkeypatch.setattr(pipeline, "_trace_annotation", Closed)
+    for kind in sorted(BULK):
+        prepare(kind, tmp_path / kind)()
+    assert built == []
+    job = pipeline.track("t", span="ec.t")
+    with job.stage("s"), job.blocked("w"):
+        pass
+    job.finish()
+    assert built == [] and job._ann is None
 
 
 def test_a_session_opened_after_the_run_holds_no_annotation(tmp_path):
@@ -282,13 +368,184 @@ def test_sum_identities_and_every_old_key(kind, tmp_path):
         assert 0 < parts <= stats["reconstruct"]["busy_s"] + 1e-4
         assert stats["reconstruct"]["items"] == stats["dispatch"]["items"]
     if kind != "degraded_read":
-        # a part is never counted beside its lump
+        # a part is never counted beside its lump, and neither a clock
+        # nor a blocked stage counts as work
         stage_sum = sum(v for k, v in stats.items() if k.endswith("_s")
-                        and k not in ("wall_s", "stall_s", "submit_s",
-                                      "complete_s")
+                        and k not in ec_files._NOT_WORK_KEYS
                         and k not in ec_files._PART_KEYS)
         assert stats["overlap_frac"] == round(
             max(0.0, 1.0 - stats["wall_s"] / stage_sum), 3)
+        assert {"open_s", "commit_s"} <= set(stats)
+        assert stats["call_s"] >= stats["wall_s"] > 0
+        # blocked stages book as blocked, never busy
+        stages = _last_job(BULK[kind][0])["stages"]
+        for name in ("await_unit", "await_parity", "await_batch",
+                     "join_drain", "join_writers"):
+            if name in stages:
+                assert stages[name]["busy_s"] == 0, (name, stages[name])
+                assert stages[name]["blocked_s"] > 0, (name, stages[name])
+        assert _last_job(BULK[kind][0])["bottleneck"]["stage"] not in (
+            "await_unit", "await_parity", "await_batch", "join_drain",
+            "join_writers", "stall")
+
+
+@pytest.mark.parametrize("kind", sorted(BULK))
+def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
+    """On the thread that makes the call its stages follow one another
+    from the job's first line to its last.  A slowed map and a slowed
+    rename (the head and the tail) make the call long beside what lies
+    between two stages; the program itself sleeps nowhere."""
+    real_map, real_replace = ec_files._map_readonly, os.replace
+
+    def slow_map(fd, size):
+        time.sleep(0.05)
+        return real_map(fd, size)
+
+    def slow_replace(src, dst):
+        time.sleep(0.005)
+        return real_replace(src, dst)
+
+    op = prepare(kind, tmp_path)
+    monkeypatch.setattr(ec_files, "_map_readonly", slow_map)
+    monkeypatch.setattr(fleet_convert, "_map_readonly", slow_map)
+    monkeypatch.setattr(os, "replace", slow_replace)
+    booked = []
+    real = pipeline.PipelineJob._book
+
+    def book(self, name, secs, nbytes, items, blocked):
+        if self.kind == BULK[kind][0]:
+            booked.append((name, secs, threading.current_thread().name))
+        return real(self, name, secs, nbytes, items, blocked)
+
+    monkeypatch.setattr(pipeline.PipelineJob, "_book", book)
+    stats, _ = op()
+    me = threading.current_thread().name
+    mine = [(name, secs) for name, secs, thread in booked if thread == me]
+    assert {"open", "h2d", "dispatch", "join_drain", "commit"} <= \
+        {name for name, _ in mine}
+    total = sum(secs for _, secs in mine)
+    assert total == pytest.approx(stats["call_s"], rel=0.05), (
+        stats["call_s"], sorted(mine))
+    assert stats["open_s"] >= 0.05 and stats["commit_s"] >= 0.005
+
+
+def test_occupancy_states_max_mean_and_share_at_two_or_more(monkeypatch):
+    """The one gauge on a scripted clock: a count weighted by the time it
+    stood, over the engine's `wall_s`."""
+    ticks = iter([0.0,             # the job is made
+                  1.0, 2.0, 4.0,   # +1, +1, +1
+                  5.0, 7.0, 8.0,   # -1, -1, -1
+                  10.0, 10.0])     # finish; the second job is made
+    monkeypatch.setattr(pipeline, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(ticks), time=time.time,
+        monotonic=time.monotonic))
+    stats = {"wall_s": 10.0}
+    job = pipeline.PipelineJob("t", stats, register=False)
+    for delta in (+1, +1, +1, -1, -1, -1):
+        job.occupancy("inflight", delta)
+    job.finish()
+    # levels 0, 1, 2, 3, 2, 1, 0 stood 1, 1, 2, 1, 2, 1, 2 seconds
+    assert stats["inflight_max"] == 3
+    assert stats["inflight_avg"] == pytest.approx(
+        (1 * 1 + 2 * 2 + 3 * 1 + 2 * 2 + 1 * 1) / 10.0)
+    assert stats["inflight_ge2_frac"] == pytest.approx((2 + 1 + 2) / 10.0)
+    assert stats["call_s"] == 10.0
+    # a job whose gauge never moved states none of the three
+    assert "inflight_max" not in pipeline.PipelineJob(
+        "u", register=False).stats
+
+
+def test_occupancy_loses_no_move_under_many_threads():
+    """The gauge is moved from the dispatcher and the drain at once: more
+    threads than cores, a short switch interval, and every +1 has its -1."""
+    job = pipeline.PipelineJob("t", register=False)
+    workers, moves = 32, 400
+
+    def churn():
+        for _ in range(moves):
+            job.occupancy("inflight", +1)
+            job.occupancy("inflight", -1)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=churn) for _ in range(workers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    level, _, seconds, _, peak = job._gauges["inflight"]
+    assert level == 0 and 1 <= peak <= workers and seconds >= 0
+    job.finish()
+    assert job.stats["inflight_max"] == peak
+    assert 0 <= job.stats["inflight_ge2_frac"] <= 1
+
+
+def test_a_failed_rebuild_still_finishes_its_job(tmp_path, monkeypatch):
+    """A survivor that vanishes between the present-list and its open
+    fails the call inside `open`: the job is sealed `failed` with the
+    error, the tmp output is rolled back, and `call_s` is stated."""
+    base, _ = _make_ec(tmp_path)
+    os.remove(base + layout.to_ext(3))
+    real = ec_files._survivor_basis
+
+    def vanish(codec, present, wanted):
+        use = real(codec, present, wanted)
+        os.remove(base + layout.to_ext(use[-1]))
+        return use
+
+    monkeypatch.setattr(ec_files, "_survivor_basis", vanish)
+    stats: dict = {}
+    with pytest.raises(FileNotFoundError):
+        ec_files.rebuild_ec_files(base, batch_size=BATCH, stats=stats)
+    job = _last_job("ec_rebuild")
+    assert job["state"] == "failed" and "No such file" in job["error"]
+    assert not [j for j in pipeline.jobs_snapshot()
+                if j["state"] == "running"]
+    assert stats["call_s"] > 0 and "open_s" in stats and \
+        "commit_s" in stats
+    assert not os.path.exists(base + layout.to_ext(3) + ".tmp")
+    assert not os.path.exists(base + layout.to_ext(3))
+
+
+@pytest.mark.parametrize("kind", ["ec_regen", "ec_scrub"])
+def test_regen_and_the_scrubber_book_the_seam_to_a_flow(kind, tmp_path):
+    """Both call the seam outside any job: their four `codec.*` stages
+    land on a flow account of their own on /perf, as a read's do."""
+    codec = ec_files._get_codec("jax")
+    if kind == "ec_scrub":
+        from seaweedfs_tpu.maintenance import scrub
+        base, _ = _make_ec(tmp_path)
+        ev = ec_volume.EcVolume(base, LARGE, SMALL)
+        try:
+            assert scrub.syndrome_scan(ev, window=SMALL * 2) == []
+        finally:
+            ev.close()
+    else:
+        from seaweedfs_tpu.models import rs
+        from seaweedfs_tpu.ops import regen
+        from tests import test_regen as tr
+        shards = rs.get_code(10, 4).encode_numpy(
+            np.random.default_rng(1).integers(0, 256, (10, tr.L),
+                                              dtype=np.uint8))
+        out = np.zeros(tr.L, dtype=np.uint8)
+
+        def sink(off, row):
+            out[off:off + len(row)] = row
+
+        regen.repair_shard(
+            tr.CODE, codec, 2, tr._groups({2}), tr.L,
+            lambda sid, off, n: shards[sid][off:off + n].tobytes(),
+            tr._fetcher(shards, {}), sink, batch_size=4096, align=1024)
+        assert np.array_equal(out, shards[2])
+    flow = _last_job(kind)
+    assert flow["state"] == "flow"
+    for name in ("h2d", "dispatch", "device_wait", "d2h_copy"):
+        assert flow["stages"][name]["busy_s"] > 0, (name, flow["stages"])
+        assert flow["stages"][name]["items"] >= 1
 
 
 def test_rebuild_books_its_six_stages_to_one_job_from_two_threads(
@@ -319,8 +576,11 @@ def test_rebuild_books_its_six_stages_to_one_job_from_two_threads(
     stages = _last_job("ec_rebuild")["stages"]
     batches = stages["stage"]["items"]
     assert batches > ec_files.PIPELINE_DEPTH
-    assert {stages[s]["items"] for s in by_stage if s != "write"} == \
-        {batches}
+    assert {stages[s]["items"] for s in ec_files.REBUILD_SUMS[
+        "reconstruct"]} == {batches}
+    # the call's own stages are the caller's, once or twice a call
+    for name in ("open", "commit"):
+        assert by_stage[name] == {(job_id, me)}, name
     assert 1 <= stats["inflight_max"] <= ec_files.PIPELINE_DEPTH
 
 
