@@ -21,9 +21,11 @@ stage bounds throughput, and what was the call doing meanwhile?":
   engines' stages, work unless marked (b) for blocked, which never
   counts as busy:
 
-    caller   ``open`` (tmp outputs created, sources opened and mapped,
-             writer pools and pipeline threads started, rings
-             allocated), ``await_unit`` (b: the dispatcher waiting for
+    caller   ``open`` (tmp outputs created, sources opened, writer
+             pools and pipeline threads started, rings allocated; the
+             fleet's volumes mapped and populated), ``map`` (the
+             single-volume engines' sources mapped, no page made
+             ready), ``await_unit`` (b: the dispatcher waiting for
              the reader's next unit), the seam's ``h2d`` and
              ``dispatch``, rebuild's ``stall`` (b) and ``stage``,
              ``join_drain`` (b: the last unit enqueued, waiting for the

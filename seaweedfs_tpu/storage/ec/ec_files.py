@@ -20,6 +20,7 @@ Functions mirror the reference's capability surface:
 
 from __future__ import annotations
 
+import mmap
 import os
 import queue
 import sys
@@ -83,7 +84,9 @@ def _writer_threads(nshards: int) -> int:
 
 
 def _map_readonly(fd: int, size: int):
-    """Read-only map of a source file for the encode/rebuild producers.
+    """Read-only map of a volume's .dat for fleet conversion
+    (ops/fleet_convert._VolumeJob), the caller that is left: the
+    single-volume encode and rebuild engines map through _map_lazy.
 
     When the file plausibly fits in RAM (or WEEDTPU_EC_PREFAULT=always)
     the map is created MAP_POPULATE: one batched kernel pass sets up
@@ -112,6 +115,27 @@ def _map_readonly(fd: int, size: int):
     try:
         mm.madvise(mmap_mod.MADV_SEQUENTIAL)
     except (AttributeError, OSError):
+        pass
+    return mm
+
+
+def _map_lazy(fd: int):
+    """Read-only map of a source file for the single-volume encode and
+    rebuild engines, with no page made ready: a batch or a unit reads only
+    its own span of the map, and whoever reads a page first takes its
+    fault, which for a device codec is the runtime's copy threads, beside
+    the stream.  On the chip machine those faults cost a tenth of a
+    batch's puts, where populating the files whole held the first put back
+    by a quarter of a call, and a populated map a span, a page touched
+    ahead and MADV_POPULATE_READ (refused there) each cost more than the
+    faults they save (PERF.md section 6, PR 37).  So nothing is scheduled
+    and no size rule or knob applies: what _map_readonly's `never` gives
+    (MADV_SEQUENTIAL readahead for a file the page cache does not hold),
+    at any file size."""
+    mm = mmap.mmap(fd, 0, flags=mmap.MAP_SHARED, prot=mmap.PROT_READ)
+    try:
+        mm.madvise(mmap.MADV_SEQUENTIAL)
+    except (AttributeError, OSError):  # advice only
         pass
     return mm
 
@@ -398,11 +422,14 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     rows that carry data, against a column-sliced parity matrix.
 
     The stages book to `pjob` (write_ec_files' job, whose `stats` this
-    fills): `open` for the .dat's map here, `commit` for the cut to size;
-    `wall_s` runs from before the map to after the pipeline's last join."""
+    fills): `map` for the .dat's map here (_map_lazy: no page is made
+    ready before the first put; `spans_mapped` counts the units selected
+    from it), `commit` for the cut to size; `wall_s` runs from before the
+    map to after the pipeline's last join."""
     stats = pjob.stats
     stats["bytes"] = dat_size
     stats["rows_staged"] = 0  # stripe rows copied on the host (job.count)
+    stats["spans_mapped"] = 0  # units selected in the .dat's map (job.count)
     shard_size = layout.shard_file_size(dat_size, large_block, small_block,
                                         data_shards=codec.k)
     highwater = [0] * (codec.k + codec.m)
@@ -412,8 +439,8 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
         t_wall = time.perf_counter()
         with open(dat_path, "rb") as datf:
             dat_fd = datf.fileno()
-            with pjob.stage("open", files=1, bytes=dat_size):
-                mm = _map_readonly(dat_fd, dat_size)
+            with pjob.stage("map", files=1, bytes=dat_size):
+                mm = _map_lazy(dat_fd)
             dat_view = np.frombuffer(mm, dtype=np.uint8)
             try:
                 _encode_pipelined(codec, dat_fd, dat_view, dat_size,
@@ -927,6 +954,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                             flusher.account(step)
                 if not covered:
                     continue
+                pjob.count("spans_mapped", 1)
                 if native_host:
                     # zero-copy: dispatch encodes off the mmap directly
                     q_read.put((unit, None, step, shard_off,
@@ -1194,6 +1222,7 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
             try:
                 with pjob.stage("stage", unit=unit):
                     rows = [views[i][off:off + n] for i in use]
+                pjob.count("spans_mapped", len(rows))
                 pending = _dispatch_reconstruct(codec, rows, use, missing,
                                                 job=pjob, unit=unit)
             except BaseException:
@@ -1225,8 +1254,11 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
 
     The encode path's observability (`progress(bytes_done)` per batch over
     survivor bytes, `cancel()` aborts, `stats` gets per-stage seconds +
-    overlap_frac) and its zero-copy reads: survivor shards are mmap'd and a
-    batch is its rows where they lie in the maps, handed to the native
+    overlap_frac) and its zero-copy reads: survivor shards are mmap'd
+    (`_map_lazy`, stage `map`: no page is made ready before the first put,
+    a batch's faults are taken by whoever reads its rows) and a
+    batch is its rows where they lie in the maps (`stats["spans_mapped"]`
+    counts them: batches x survivors), handed to the native
     decode matmul by row pointer or to the dispatch seam as views, which a
     device codec puts up uncopied where they are a whole bucket wide
     (`ops/dispatch.ROW_PUTS_FROM`; `stats["rows_staged"]` counts the rows
@@ -1277,6 +1309,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     stats["survivors"] = len(use)
     stats["basis"] = basis_kind(codec, use)
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
+    stats["spans_mapped"] = 0  # rows selected in the maps: batches x survivors
     stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
     # MSR sub-packetization: every chunk a codec's interleave must see is
     # an alpha multiple (shard files themselves are block-multiples)
@@ -1311,7 +1344,6 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                            basis=stats["basis"])
     job_span.__enter__()
     t_wall = time.perf_counter()
-    import mmap as mmap_mod
     ins: dict[int, object] = {}
     maps = {}
     views = {}
@@ -1324,8 +1356,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     # ENOSPC on the tmp outputs, must not leak a forever-"running"
     # ec_rebuild entry on /debug/pipeline
     try:
-        with pjob.stage("open", files=len(use) + len(missing),
-                        bytes=shard_size * len(use)):
+        with pjob.stage("open", files=len(use) + len(missing)):
             for i in use:
                 ins[i] = open(base + layout.to_ext(i), "rb")
             for i, p_ in tmp_paths.items():
@@ -1343,9 +1374,12 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                 opool.put(np.empty(
                     (len(missing), min(batch_size, max(shard_size, 1))),
                     dtype=np.uint8))
+        # no page of a survivor is made ready here (_map_lazy): the first
+        # put waits for the maps alone, a batch reads its own span of each
+        with pjob.stage("map", files=len(use), bytes=shard_size * len(use)):
             for i, f in ins.items():
                 if shard_size:
-                    mm = _map_readonly(f.fileno(), shard_size)
+                    mm = _map_lazy(f.fileno())
                     maps[i] = mm
                     views[i] = np.frombuffer(mm, dtype=np.uint8)
         if native_host:
@@ -1363,6 +1397,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
                 with pjob.stage("reconstruct", unit=unit) as st:
                     rows = [views[i][off:off + n] for i in use]
                     native.gf_matmul_ptrs(dec_mat, rows, list(obuf), n)
+                pjob.count("spans_mapped", len(rows))
                 _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
                                         nbytes=len(use) * n)
                 _write_rows(writers, opool, obuf, n, off)

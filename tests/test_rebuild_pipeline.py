@@ -207,13 +207,13 @@ def test_a_call_that_ends_early_leaves_nothing(how, tmp_path, monkeypatch):
     lost = [3]
     base, _ = _shard_set(tmp_path, "rs_10_4", lost)
     maps = []
-    real_map = ec_files._map_readonly
+    real_map = ec_files._map_lazy
 
-    def map_spy(fd, size):
-        maps.append(real_map(fd, size))
+    def map_spy(fd):
+        maps.append(real_map(fd))
         return maps[-1]
 
-    monkeypatch.setattr(ec_files, "_map_readonly", map_spy)
+    monkeypatch.setattr(ec_files, "_map_lazy", map_spy)
     kwargs: dict = {}
     if how == "cancel":
         seen = []

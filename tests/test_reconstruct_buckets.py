@@ -275,11 +275,11 @@ def _spied_rebuild(monkeypatch, base, tag, lost):
     monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", BATCH)
     maps, calls, staged = [], [], []
     real_map, real_seam, real_staged = (
-        ec_files._map_readonly, ec_files._dispatch_reconstruct,
+        ec_files._map_lazy, ec_files._dispatch_reconstruct,
         dispatch._staged)
 
-    def map_spy(fd, size):
-        maps.append(real_map(fd, size))
+    def map_spy(fd):
+        maps.append(real_map(fd))
         return maps[-1]
 
     def seam_spy(codec, rows, ids, wanted, **kw):
@@ -294,7 +294,7 @@ def _spied_rebuild(monkeypatch, base, tag, lost):
         staged.append(len(rows[0]))
         return real_staged(rows, order, width)
 
-    monkeypatch.setattr(ec_files, "_map_readonly", map_spy)
+    monkeypatch.setattr(ec_files, "_map_lazy", map_spy)
     monkeypatch.setattr(ec_files, "_dispatch_reconstruct", seam_spy)
     monkeypatch.setattr(dispatch, "_staged", staged_spy)
     stats: dict = {}

@@ -347,7 +347,8 @@ def test_finalize_shards_cuts_a_longer_tmp_and_extends_with_a_hole(
 _GONE = ("submit_s", "complete_s", "submit_workers", "complete_workers",
          "aio_mode", "aio_direct_bytes", "aio_degraded_engines")
 # the call's own stages, in every engine and under every codec (PR 36)
-_CALL_KEYS = {"open_s", "join_writers_s", "commit_s", "call_s", "wall_s"}
+_CALL_KEYS = {"open_s", "map_s", "join_writers_s", "commit_s", "call_s",
+              "wall_s"}
 _ENCODE_KEYS = {
     "jax": {"read_s", "encode_s", "h2d_s", "dispatch_s", "d2h_s",
             "device_wait_s", "d2h_copy_s", "write_data_s",

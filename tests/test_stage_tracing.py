@@ -40,11 +40,13 @@ SEAM = {"codec.h2d", "codec.dispatch", "codec.device_wait",
 # PR 36 the call's head, tail and waits are stages too
 ENGINE = {"encode": {"ec.encode.read", "ec.encode.write_data",
                      "ec.encode.write_parity", "ec.encode.open",
+                     "ec.encode.map",
                      "ec.encode.ship_data", "ec.encode.await_unit",
                      "ec.encode.await_parity", "ec.encode.join_drain",
                      "ec.encode.join_writers", "ec.encode.commit"},
           "rebuild": {"ec.rebuild.stage", "ec.rebuild.unstage",
                       "ec.rebuild.write", "ec.rebuild.open",
+                      "ec.rebuild.map",
                       "ec.rebuild.await_batch", "ec.rebuild.join_drain",
                       "ec.rebuild.join_writers", "ec.rebuild.commit"},
           "fleet": {"ec.fleet.read", "ec.fleet.write_data",
@@ -63,7 +65,7 @@ BULK = {"encode": ("ec_encode", "ec.encode"),
         "fleet_spans": ("fleet_convert", "ec.fleet")}
 # stages whose annotation carries no `unit`: a writer's batch spans
 # several, and the call's own stages belong to no unit
-NO_UNIT = (".write", ".open", ".await_", ".join_", ".commit")
+NO_UNIT = (".write", ".open", ".map", ".await_", ".join_", ".commit")
 # every key /admin/ec/progress `stages` carried before the seam's cut
 # (the parent commit's stats dicts of the same tiny runs)
 OLD_KEYS = {
@@ -95,6 +97,10 @@ for _kind, _more in (("encode", {"ship_data_s", "await_unit_s",
     OLD_KEYS[_kind] |= _more | {
         "call_s", "open_s", "join_drain_s", "join_writers_s", "commit_s",
         "inflight_max", "inflight_avg", "inflight_ge2_frac"}
+# PR 37: the single-volume engines map their sources in a stage of its
+# own and count the spans they select there
+for _kind in ("encode", "rebuild"):
+    OLD_KEYS[_kind] |= {"map_s", "spans_mapped"}
 
 
 @pytest.fixture(autouse=True)
@@ -394,19 +400,27 @@ def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
     """On the thread that makes the call its stages follow one another
     from the job's first line to its last.  A slowed map and a slowed
     rename (the head and the tail) make the call long beside what lies
-    between two stages; the program itself sleeps nowhere."""
-    real_map, real_replace = ec_files._map_readonly, os.replace
+    between two stages; the program itself sleeps nowhere.  The fleet maps
+    its volumes inside `open` (`_map_readonly`); the single-volume engines
+    map their sources in a stage of its own, `map` (`_map_lazy`), after
+    `open` on the calling thread."""
+    real_map, real_lazy, real_replace = (
+        ec_files._map_readonly, ec_files._map_lazy, os.replace)
 
     def slow_map(fd, size):
         time.sleep(0.05)
         return real_map(fd, size)
+
+    def slow_lazy(fd):
+        time.sleep(0.05)
+        return real_lazy(fd)
 
     def slow_replace(src, dst):
         time.sleep(0.005)
         return real_replace(src, dst)
 
     op = prepare(kind, tmp_path)
-    monkeypatch.setattr(ec_files, "_map_readonly", slow_map)
+    monkeypatch.setattr(ec_files, "_map_lazy", slow_lazy)
     monkeypatch.setattr(fleet_convert, "_map_readonly", slow_map)
     monkeypatch.setattr(os, "replace", slow_replace)
     booked = []
@@ -426,7 +440,12 @@ def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
     total = sum(secs for _, secs in mine)
     assert total == pytest.approx(stats["call_s"], rel=0.05), (
         stats["call_s"], sorted(mine))
-    assert stats["open_s"] >= 0.05 and stats["commit_s"] >= 0.005
+    # the slowed map: the fleet's inside `open`, the others' in `map`,
+    # on this thread and so inside the sum above
+    slowed = "open" if BULK[kind][0] == "fleet_convert" else "map"
+    assert stats[slowed + "_s"] >= 0.05 and stats["commit_s"] >= 0.005
+    assert (slowed in {name for name, _ in mine}) and \
+        ("map_s" in stats) == (slowed == "map")
 
 
 def test_occupancy_states_max_mean_and_share_at_two_or_more(monkeypatch):
