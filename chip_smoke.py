@@ -7,8 +7,9 @@ it the way a user would — HTTP and `python -m seaweedfs_tpu shell` — at the
 size BASELINE.json calls its first configuration: one RS(10,4) volume of
 1 GB (the source's 1 GB / 1 MB block sizes, so 96 small-block rows, each a
 [10, 1 MiB] dispatch), filled with needles of 4 KiB - 1 MiB (log-uniform,
-BASELINE.json configuration 4's object mix) drawn from --seed.  The > 10 GB
-large-block path and the 30 GB volume are ROADMAP R3, not this script.
+BASELINE.json configuration 4's object mix) drawn from --seed.  Large-block
+rows are the benchmark's (`vol30g.encode`, a 30 GB volume's row structure
+at 1/32; ROADMAP R9), not this script's.
 
 No file past 1 GiB: a machine may refuse one (RLIMIT_FSIZE: EFBIG from the
 append, a 500 to the client), so on every machine the volume is sealed at

@@ -72,8 +72,9 @@ def _device_call(job, unit, kernel: str, nbytes: int, put, run,
     up (`h2d`), `run(placed)` enqueues the work on them (`dispatch`).  The
     result comes back un-materialised; `_to_host` holds the other two.
     `nbytes` are the caller's useful bytes, `h2d_bytes` what crosses where
-    that is more (a padded put)."""
-    with _stage(job, "h2d", unit, bytes=nbytes) as h2d:
+    that is more (a padded put).  `attrs` ride both stages' events (an
+    encode unit's `rows` and `block`)."""
+    with _stage(job, "h2d", unit, bytes=nbytes, **attrs) as h2d:
         placed = put()
     with _stage(job, "dispatch", unit, backend="device", kernel=kernel,
                 bytes=nbytes, **attrs) as disp:
@@ -205,9 +206,13 @@ def geometry(codec) -> dict:
             "alpha": getattr(codec, "alpha", 1)}
 
 
-def _note_matrix(kernel: str, codec) -> None:
-    """What a device entry point's parity matrix is, on its /perf row."""
+def _note_matrix(kernel: str, codec, stripes: int) -> None:
+    """What a device entry point's parity matrix is, and how many stripe
+    rows the units it ran held, each count once (1: a column cut of a
+    large-block row; 0: a 2-D array), on its /perf row."""
+    seen = set(KERNELS.notes(f"{kernel}[device]").get("stripes", ()))
     KERNELS.note(kernel, "device", **geometry(codec),
+                 stripes=sorted(seen | {stripes}),
                  tile=getattr(getattr(codec, "inner", codec), "tile", None))
 
 
@@ -221,7 +226,8 @@ def _unstriped(spans, k: int, stripes: int) -> np.ndarray:
 
 
 @codec_entry("encode_parity")
-def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
+def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0,
+                    block: int = 0):
     """Dispatch the parity of one unit, [k, B] -> [m, B].  JAX backends
     return device arrays WITHOUT materialising them (`materialize` is the
     sync point); host backends compute eagerly.
@@ -243,7 +249,10 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
     rows; PERF.md, PR 31), else as one array.  The runtime reads a span
     after its put returns, so the spans stay alive and unchanged until the
     result is materialised (a sealed `.dat`'s map, or the engine's staged
-    last row, held by the unit's queue item).  Every other codec (a host
+    last row, held by the unit's queue item).  `block` is the block size of
+    the unit's rows, said on its `h2d` and `dispatch` stage events beside
+    `rows` (a column cut of a large-block row: one row, k spans a block
+    apart).  Every other codec (a host
     shell, the bare numpy reference, the column-sharded mesh encoder) gets
     its `[k, B]` array built from the spans on the host (`_unstriped`),
     which the job counts as `rows_staged`."""
@@ -259,11 +268,11 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0):
                 for a in runs:  # see materialize
                     a.copy_to_host_async()
                 return runs
-            _note_matrix("encode_parity", codec)
+            _note_matrix("encode_parity", codec, stripes)
             return _device_call(
                 job, unit, "encode_parity", nbytes,
                 lambda: tuple(jnp.asarray(s) for s in spans), run,
-                stripes=stripes)
+                rows=stripes, block=block)
         batch = _unstriped(spans, codec.k, stripes)
         if job is not None and (stripes > 1 or len(spans) > 1):
             job.count("rows_staged", stripes)
@@ -347,11 +356,11 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
                 for a in runs:
                     a.copy_to_host_async()
             return parity
-        _note_matrix("fleet_encode", codec)
+        _note_matrix("fleet_encode", codec, stripes)
         return _device_call(
             job, unit, "fleet_encode",
             sum(p.nbytes for u in filter(None, units) for p in u),
-            lambda: codec.place_units(units), run, stripes=stripes)
+            lambda: codec.place_units(units), run, rows=stripes)
     nbytes = units.nbytes
     if _is_numpy_ref(codec):
         def batched(us):
@@ -361,7 +370,7 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
             lambda us: np.stack([codec.encode_parity(u) for u in us]))
     else:
         import jax.numpy as jnp
-        _note_matrix("fleet_encode", codec)
+        _note_matrix("fleet_encode", codec, stripes)
         return _device_call(job, unit, "fleet_encode", nbytes,
                             lambda: getattr(codec, "place",
                                             jnp.asarray)(units),

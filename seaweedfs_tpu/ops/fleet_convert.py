@@ -77,7 +77,8 @@ from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
     DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
     _iter_spans, _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
-    _state_overlap, _unit_coverage, _unit_spans, _unit_steps, write_vif)
+    _state_overlap, _unit_coverage, _unit_spans, _unit_steps, block_geometry,
+    write_vif)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -195,7 +196,9 @@ class _VolumeJob:
             for fd in self.out_fds:
                 os.close(fd)
             self.out_fds = []
-            write_vif(self.base, self.dat_size, codec=self.tag)
+            write_vif(self.base, self.dat_size, codec=self.tag,
+                      large_block=self.large_block,
+                      small_block=self.small_block)
             for i, p in enumerate(self.tmp_paths):
                 os.replace(p, self.base + layout.to_ext(i))
         self.committed = True
@@ -254,7 +257,12 @@ def convert_volumes(bases: list[str], *,
     plus units/volumes counters, the code's tag (`codec`), its files a
     volume (`shard_files`) and its sub-rows a file (`alpha`).  The code is
     the codec's: `codec_tag` names it where no `codec` is handed in
-    (`fleet_codec`), and k, m and the stripe width follow from it."""
+    (`fleet_codec`), and k, m and the stripe width follow from it.
+    `large_block` / `small_block` are the block sizes every volume of the
+    run is cut with, as `write_ec_files` takes them: each volume's `.vif`
+    records them, `stats` says them with the run's rows of each kind
+    (`large_rows`, `small_rows`, `large_row_share`, over all volumes) and
+    its units of each (`units_column`, `units_rows`)."""
     if not bases:
         return {"volumes": {}, "bytes": 0}
     codec = codec if codec is not None else fleet_codec(tag=codec_tag)
@@ -282,6 +290,7 @@ def convert_volumes(bases: list[str], *,
     stats["unit_batch"] = U
     stats.update(codec=spec.tag, shard_files=spec.n, alpha=spec.alpha)
     stats["rows_staged"] = 0  # stripe rows copied on the host (pjob.count)
+    stats.update(units_column=0, units_rows=0)  # units that carried data
     # class=convert on THIS thread and (contextvars are per-thread) re-
     # stamped inside each pipeline thread, so any hop made on the
     # conversion's behalf — wherever it runs — books as convert
@@ -305,6 +314,8 @@ def convert_volumes(bases: list[str], *,
                     for i, b in enumerate(bases)]
             stats["bytes"] = sum(j.dat_size for j in jobs)
             st.set(bytes=stats["bytes"])
+            stats.update(block_geometry((j.dat_size for j in jobs),
+                                        large_block, small_block, k))
     except BaseException as e:  # a volume that cannot be opened: no run
         pjob.finish(e)
         raise
@@ -369,6 +380,7 @@ def convert_volumes(bases: list[str], *,
                     np.copyto(slot[j, :n], job.view[off:off + n])
                 slot[j, n:] = 0
         pjob.count("rows_staged", staged)
+        pjob.count("units_column" if step != block else "units_rows", 1)
         return pieces, (job, shard_off, rows * step), unit
 
     def ship_data(job, unit):

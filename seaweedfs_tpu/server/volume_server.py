@@ -1106,15 +1106,36 @@ class VolumeServer:
                     return cand
         return None
 
+    @staticmethod
+    def _request_blocks(body: dict) -> tuple[int, int]:
+        """(large, small) block sizes a conversion request asks the set to
+        be cut with: its `large_block_bytes` / `small_block_bytes`, each
+        upstream's constant where the body has none.  The pair is the
+        volume's from then on (the `.vif` records it).  One the layout
+        cannot be cut with raises ValueError: a 400, nothing written."""
+        large = body.get("large_block_bytes", layout.LARGE_BLOCK_SIZE)
+        small = body.get("small_block_bytes", layout.SMALL_BLOCK_SIZE)
+        ec_files.check_blocks(large, small)
+        return large, small
+
     async def handle_ec_generate(self, req: web.Request) -> web.Response:
         """VolumeEcShardsGenerate (volume_grpc_erasure_coding.go:38): .dat ->
-        .ec00-13 + .ecx, parity computed by the TPU codec."""
+        .ec00-13 + .ecx, parity computed by the TPU codec.  The body may
+        say the block sizes the set is cut with (`large_block_bytes`,
+        `small_block_bytes`; upstream's 1 GB / 1 MB else); the job's
+        `stages` and the answer say them back."""
         body = await req.json()
         vid = body["volume"]
         v = self.store.get_volume(vid)
         if v is None:
             return web.json_response({"error": "volume not found"}, status=404)
         base = v._base
+        from seaweedfs_tpu.ops import codecs as _codecs
+        spec = _codecs.parse_tag(body.get("codec") or _codecs.default_tag())
+        try:
+            large, small = self._request_blocks(body)
+        except ValueError as e:
+            return web.json_response({"error": str(e)}, status=400)
         if self._ec_jobs.get(vid, {}).get("state") == "running":
             return web.json_response({"error": "encode already running"},
                                      status=409)
@@ -1127,15 +1148,12 @@ class VolumeServer:
                "cancel": False, "error": None, "started": time.time(),
                "stages": stages}
         self._ec_jobs[vid] = job
-
-        from seaweedfs_tpu.ops import codecs as _codecs
-        spec = _codecs.parse_tag(body.get("codec") or _codecs.default_tag())
         job["codec"] = spec.tag
 
         def gen():
             v.nm.flush()
             ec_files.write_ec_files(
-                base,
+                base, large_block=large, small_block=small,
                 progress=lambda n: job.__setitem__("bytes_done", n),
                 cancel=lambda: job["cancel"],
                 stats=stages, codec_tag=spec.tag)
@@ -1163,7 +1181,9 @@ class VolumeServer:
         job["state"] = "done"
         job["bytes_done"] = job["total"]
         return web.json_response({"shards": list(range(spec.n)),
-                                  "codec": spec.tag})
+                                  "codec": spec.tag,
+                                  "large_block_bytes": large,
+                                  "small_block_bytes": small})
 
     async def handle_ec_fleet_convert(self, req: web.Request
                                       ) -> web.Response:
@@ -1179,7 +1199,9 @@ class VolumeServer:
         freeze.  Each volume registers under the shared per-vid job
         table, so /admin/ec/progress observes it and /admin/ec/cancel on
         ANY participating vid aborts the whole run (uncommitted volumes
-        roll back to their previous state)."""
+        roll back to their previous state).  `large_block_bytes` /
+        `small_block_bytes` in the body are the block sizes every listed
+        volume is cut with, as /admin/ec/generate takes them."""
         body = await req.json()
         vids: list[int] = []
         for v_ in (body.get("volumes") or [])[:64]:  # bounded fan-in
@@ -1209,7 +1231,8 @@ class VolumeServer:
         try:
             codec = await asyncio.to_thread(_fleet.fleet_codec, None,
                                             body.get("codec"))
-        except _codecs.CodecUnsupported as e:
+            large, small = self._request_blocks(body)
+        except (_codecs.CodecUnsupported, ValueError) as e:
             return web.json_response({"error": str(e)}, status=400)
         # freeze writes for the duration (the same contract as shell
         # ec.encode's readonly step): a needle appended after the .dat
@@ -1234,6 +1257,7 @@ class VolumeServer:
                 #            must hold every committed needle
             rep = _fleet.convert_volumes(
                 [v._base for _, v in vols], codec=codec,
+                large_block=large, small_block=small,
                 progress=lambda n: shared.__setitem__("bytes_done", n),
                 cancel=lambda: shared["cancel"],
                 stats=stages)
@@ -1276,7 +1300,8 @@ class VolumeServer:
         return web.json_response(
             {"converted": [vid for vid, _ in vols], "skipped": skipped,
              "bytes": report["bytes"], "units": report["units"],
-             "devices": report["devices"], "wall_s": report["wall_s"]})
+             "devices": report["devices"], "wall_s": report["wall_s"],
+             "large_block_bytes": large, "small_block_bytes": small})
 
     async def handle_ec_progress(self, req: web.Request) -> web.Response:
         """Observability for a long-running encode (weak spot the reference
@@ -2387,11 +2412,8 @@ class VolumeServer:
         # forces reconstruction whether or not it is local.
         try:
             dat_off, size = ev.find_needle(nid)
-            intervals = layout.locate_data(
-                ev.large_block, ev.small_block, ev.dat_size, dat_off,
-                t.actual_size(size, ev.version))
-            planned = sorted({iv.to_shard_id_and_offset(
-                ev.large_block, ev.small_block)[0] for iv in intervals})
+            planned = sorted({sid for sid, _off, _n in ev.locate(
+                dat_off, t.actual_size(size, ev.version))})
         except KeyError:
             planned = []
         if not planned:
@@ -2456,9 +2478,12 @@ class VolumeServer:
                 ec_files.rebuild_ec_files(base, codec_tag=old.tag)
             dat_size = ec_files.find_dat_file_size(base)
             job["total"] = dat_size
+            # the blocks are the volume's: the new set keeps the old one's
+            large, small = ec_files.volume_blocks(base)
             ec_files.write_dat_file(base, dat_size, out_path=tmp_dat)
             ec_files.write_ec_files(
-                base, dat_path=tmp_dat,
+                base, dat_path=tmp_dat, large_block=large,
+                small_block=small,
                 progress=lambda n: job.__setitem__("bytes_done", n),
                 cancel=lambda: job["cancel"],
                 stats=stages, codec_tag=to.tag)

@@ -310,11 +310,18 @@ def cmd_ec_encode(env: CommandEnv, args, out):
     command_ec_encode.go:58-321).  With -volumeId, encodes that volume;
     without it, scans the topology for candidates that are at least
     -fullPercent full (default 95) and write-quiet for -quietFor
-    (default 1h) — the reference's fleet-wide operational loop."""
+    (default 1h) — the reference's fleet-wide operational loop.
+    -largeBlockBytes / -smallBlockBytes give the block sizes the sets are
+    cut with (upstream's 1 GB / 1 MB where not given); a set's .vif
+    records them and every later read, rebuild and decode takes them
+    from there."""
     env.require_lock()
     flags = parse_flags(args)
     collection = flags.get("collection", "")
     codec = flags.get("codec", "")
+    blocks = {field: int(flags[flag]) for flag, field in
+              (("largeBlockBytes", "large_block_bytes"),
+               ("smallBlockBytes", "small_block_bytes")) if flag in flags}
     if "volumeId" in flags:
         vids = [int(flags["volumeId"])]
     else:
@@ -325,11 +332,12 @@ def cmd_ec_encode(env: CommandEnv, args, out):
         print(f"{len(vids)} volume(s) ≥{full_percent}% full and quiet "
               f"for {quiet:.0f}s: {vids}", file=out)
     for vid in vids:
-        _ec_encode_one(env, vid, collection, out, codec=codec)
+        _ec_encode_one(env, vid, collection, out, codec=codec,
+                       blocks=blocks)
 
 
 def _ec_encode_one(env: CommandEnv, vid: int, collection: str, out,
-                   codec: str = ""):
+                   codec: str = "", blocks: dict | None = None):
     locations = env.volume_locations(vid)
     if not locations:
         raise RuntimeError(f"volume {vid} not found")
@@ -339,12 +347,16 @@ def _ec_encode_one(env: CommandEnv, vid: int, collection: str, out,
     for url in locations:
         env.vs_post(url, "/admin/volume/readonly", {"volume": vid, "readonly": True})
     # 2. generate shards on the source (TPU codec); -codec picks the
-    # erasure-code family (rs/lrc/msr tag), default per WEEDTPU_CODEC_*
+    # erasure-code family (rs/lrc/msr tag), default per WEEDTPU_CODEC_*;
+    # `blocks` the set's block sizes where the operator gave any (the
+    # server's default, upstream's 1 GB / 1 MB, else): the set's .vif
+    # carries them to every node a shard is copied to
     from seaweedfs_tpu.ops import codecs as _codecs
     spec = _codecs.parse_tag(codec or _codecs.default_tag())
     env.vs_post(source, "/admin/ec/generate",
                 {"volume": vid, "collection": collection,
-                 **({"codec": spec.tag} if codec else {})})
+                 **({"codec": spec.tag} if codec else {}),
+                 **(blocks or {})})
     print(f"generated {spec.n} {spec.tag} shards of volume {vid} "
           f"on {source}", file=out)
 

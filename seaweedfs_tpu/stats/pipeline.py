@@ -47,7 +47,9 @@ stage bounds throughput, and what was the call doing meanwhile?":
   ring/queue), bytes, items, and queue-depth high-water marks.
   Finished jobs land in a bounded ring; running jobs are observable
   live.  A tracked job opens a ``jax.profiler.TraceAnnotation`` named
-  ``job.<span>`` (``job.ec.encode``) carrying its id, on the thread that
+  ``job.<span>`` (``job.ec.encode``) carrying its id and its meta (an
+  encode's block sizes, rows of each kind and ``large_row_share``), on
+  the thread that
   tracks it, and closes it in ``finish()``, under the stages' gate (an
   open profiler session): the call itself is on the profiler's trace,
   from the first file opened to the last rename, and a reader of the
@@ -130,6 +132,11 @@ IDLE_STAGES = ("stall", "blocked", "idle")
 
 _trace_annotation = None  # jax.profiler.TraceAnnotation, once jax is loaded
 
+# attributes of a stage that ride its profiler annotation beside the ids:
+# what kind of unit it worked on (its stripe rows and their block size; a
+# column cut of a large-block row is rows 1)
+UNIT_GEOMETRY = ("rows", "block")
+
 
 def _profiler_annotation():
     """``jax.profiler.TraceAnnotation`` while a profiler session is open
@@ -179,6 +186,8 @@ class Stage:
             ids = self._job.annotation_ids()
             if self._unit is not None:
                 ids["unit"] = self._unit
+            ids.update((k, self._attrs[k]) for k in UNIT_GEOMETRY
+                       if k in self._attrs)
             ann = ann(self._span_name, **ids)
             ann.__enter__()
         self._ann = ann
@@ -240,7 +249,10 @@ class PipelineJob:
         # `codec.` names: a reader of stages must not take it for one
         ann = _profiler_annotation() if register else None
         if ann is not None:
-            ann = ann("job." + self.span, **self.annotation_ids())
+            # with the job's meta: what the call works on, said once
+            ann = ann("job." + self.span, **self.annotation_ids(),
+                      **{k: v for k, v in self.meta.items()
+                         if isinstance(v, (int, float, str))})
             ann.__enter__()
         self._ann = ann
         self._registered = register and perf_obs_enabled()

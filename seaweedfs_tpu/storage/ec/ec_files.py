@@ -149,9 +149,16 @@ def write_ec_files(base: str, dat_path: str | None = None,
     """Encode `<base>.dat` (or dat_path) into the shard files of the
     volume's code (`codec_tag`: `.ec00` .. `.ec13` under rs_10_4, `.ec15`
     under lrc_12_2_2), plus a `<base>.vif` volume-info sidecar recording
-    the codec tag, the encode-time dat size and version (the reference's .vif, volume_info.go:16-40, as JSON):
-    the layout was cut from the FILE size, which later lookups cannot
-    reliably re-derive from the index once tail needles get deleted.
+    the codec tag, the encode-time dat size, the version and the two block
+    sizes the layout was cut with (the reference's .vif,
+    volume_info.go:16-40, as JSON): the layout was cut from the FILE size,
+    which later lookups cannot reliably re-derive from the index once tail
+    needles get deleted, and with `large_block` / `small_block`, which are
+    the volume's from here on: given here (upstream's 1 GB / 1 MB where
+    the caller gives none), recorded in the `.vif`, and read back from it
+    by every reader of the set (`volume_blocks`: EcVolume, write_dat_file,
+    a recode).  The servers hold a request's pair to `check_blocks`
+    first.
 
     `progress(bytes_done)` is called per batch with ACTUAL volume bytes
     consumed and `cancel()` (returning True) aborts mid-stream — a 30GB
@@ -186,9 +193,11 @@ def write_ec_files(base: str, dat_path: str | None = None,
     # renders, so every encode is observable.  The job is the call, from
     # the first tmp file opened to the last rename
     stats = stats if stats is not None else {}
+    geometry = block_geometry([dat_size], large_block, small_block, spec.k)
+    stats.update(geometry, units_column=0, units_rows=0)
     pjob = _pipeline.track("ec_encode", stats, dat_size,
-                           meta={"mode": "pipelined"}, span="ec.encode",
-                           sums=ENCODE_SUMS)
+                           meta={"mode": "pipelined", **geometry},
+                           span="ec.encode", sums=ENCODE_SUMS)
     out_fds: list[int] = []
     ok = False
     try:
@@ -206,7 +215,9 @@ def write_ec_files(base: str, dat_path: str | None = None,
                 for fd in out_fds:
                     os.close(fd)
                 if ok:
-                    write_vif(base, dat_size, codec=spec.tag)
+                    write_vif(base, dat_size, codec=spec.tag,
+                              large_block=large_block,
+                              small_block=small_block)
                     for i, p_ in enumerate(tmp_paths):
                         os.replace(p_, base + layout.to_ext(i))
                 else:
@@ -219,6 +230,46 @@ def write_ec_files(base: str, dat_path: str | None = None,
             _state_overlap(stats)
             pjob.finish(None if ok else
                         (sys.exc_info()[1] or "encode failed"))
+
+
+def check_blocks(large_block, small_block,
+                 batch_size: int = DEFAULT_BATCH) -> None:
+    """Raise ValueError for a pair of block sizes a conversion request
+    may not name: both a positive number of bytes, small <= large, and
+    each a whole number of the column steps `_iter_units` cuts it in
+    (`min(batch_size, block)`).  The servers hold a request's
+    `large_block_bytes` / `small_block_bytes` to this before anything is
+    opened."""
+    for name, block in (("large", large_block), ("small", small_block)):
+        if not isinstance(block, int) or isinstance(block, bool) \
+                or block <= 0:
+            raise ValueError(f"{name} block size {block!r}: not a "
+                             f"positive number of bytes")
+        if block % min(batch_size, block):
+            raise ValueError(f"{name} block {block}: not a whole number "
+                             f"of {batch_size}-byte columns")
+    if small_block > large_block:
+        raise ValueError(f"small block {small_block} > large block "
+                         f"{large_block}")
+
+
+def block_geometry(dat_sizes, large_block: int, small_block: int,
+                   data_shards: int) -> dict:
+    """What a bulk job says of the layout it cuts (/admin/ec/progress
+    `stages`, the job's meta): the two block sizes, the rows of each kind
+    over the job's volumes (`dat_sizes`: one, or a fleet's), and the share
+    of their bytes that lie in large-block rows."""
+    sizes = list(dat_sizes)
+    total = sum(sizes)
+    geometry = (large_block, small_block, data_shards)
+    rows = sum(layout.n_large_rows(n, *geometry) for n in sizes)
+    return {"large_block": large_block, "small_block": small_block,
+            "large_rows": rows,
+            "small_rows": sum(layout.n_small_rows(n, *geometry)
+                              for n in sizes),
+            "large_row_share": round(
+                rows * large_block * data_shards / total, 4)
+            if total else 0.0}
 
 
 def _iter_units(dat_size: int, large_block: int, small_block: int,
@@ -410,7 +461,9 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
         their writers, and parity rides a small buffer ring.
       - device codecs (Pallas/XLA/mesh/numpy): a unit is a span of the
         mmap, up to batch_size bytes of every shard (_iter_spans: sixteen
-        1 MiB stripe rows at the served sizes), selected as views and put
+        1 MiB stripe rows at the served sizes; in a large-block row, which
+        a volume past ten large blocks has, a column cut: k spans of
+        batch_size bytes a block apart), selected as views and put
         from where it lies (no staging copy either: only the volume's
         last, short row is copied, `rows_staged`); JAX dispatch is async
         so the device round-trip overlaps host I/O, and only parity
@@ -425,7 +478,9 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     fills): `map` for the .dat's map here (_map_lazy: no page is made
     ready before the first put; `spans_mapped` counts the units selected
     from it), `commit` for the cut to size; `wall_s` runs from before the
-    map to after the pipeline's last join."""
+    map to after the pipeline's last join.  The job says which layout it
+    cut (`block_geometry`) and how many units of each kind carried data
+    (`units_column`, `units_rows`)."""
     stats = pjob.stats
     stats["bytes"] = dat_size
     stats["rows_staged"] = 0  # stripe rows copied on the host (job.count)
@@ -485,10 +540,11 @@ def _unit_steps(dat_size: int, large_block: int, small_block: int,
                 data_shards: int = layout.DATA_SHARDS) -> tuple[int, int]:
     """(min, max) column-batch step _iter_units will actually cut for this
     volume — min picks direct vs batched submission, max sizes the parity
-    ring buffers.  Sizing by the actual max matters: a small-block-only
-    volume (every production volume under 10x large_block) cuts 1MB units,
-    and ring buffers sized by the never-used large step would cycle an 8x
-    larger working set through the cache for nothing."""
+    ring buffers.  Sizing by the actual max matters: a volume of at most
+    one large row's bytes (10 GB under upstream's 1 GB blocks; a 30 GB
+    production volume has two large rows and a tail) cuts small-block
+    units only, and ring buffers sized by the never-used large step would
+    cycle an 8x larger working set through the cache for nothing."""
     k = data_shards
     row = large_block * k
     n_large = (dat_size - 1) // row if dat_size > row else 0
@@ -873,7 +929,9 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                codecs a unit is a span of the .dat's map (_iter_spans:
                up to batch_size // block consecutive stripe rows, which
                lie one after the other in the .dat and in every shard
-               file), and `read` is its selection as views
+               file; of a block wider than the batch, a large-block
+               row's, one column cut: k spans a block apart, rows == 1),
+               and `read` is its selection as views
                (_unit_spans): no byte moves but the volume's last, short
                row, copied into a zeroed buffer and counted
                (`rows_staged`, 0 or 1 a call).  At most PIPELINE_DEPTH
@@ -955,6 +1013,9 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 if not covered:
                     continue
                 pjob.count("spans_mapped", 1)
+                # a column cut of one (large) block row, or whole rows
+                pjob.count("units_column" if step != block else "units_rows",
+                           1)
                 if native_host:
                     # zero-copy: dispatch encodes off the mmap directly
                     q_read.put((unit, None, step, shard_off,
@@ -962,13 +1023,15 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 else:
                     with pjob.blocked("stall", unit=unit):
                         slots.acquire()
-                    with pjob.stage("read", unit=unit):
+                    with pjob.stage("read", unit=unit, rows=rows,
+                                    block=block):
                         spans, staged = _unit_spans(
                             dat_view, dat_size, k, row_start, block, col,
                             step, rows)
                         if staged:
                             pjob.count("rows_staged", staged)
-                    q_read.put((unit, spans, rows * step, shard_off, rows))
+                    q_read.put((unit, spans, rows * step, shard_off,
+                                (rows, block)))
                 done += covered
                 if progress is not None:
                     progress(done)
@@ -1051,7 +1114,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 break
             # stage-queue depth at the consume site
             pjob.queue("q_read", q_read.qsize(), PIPELINE_DEPTH)
-            # geom: a host unit's coverage, a device unit's stripe rows
+            # geom: a host unit's coverage, a device unit's stripe rows and
+            # their block size
             unit, spans, step, shard_off, geom = item
             if errors or writers.failed:  # stop dispatching, surface below
                 if spans is not None:
@@ -1072,7 +1136,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
             else:
                 try:
                     parity = _dispatch_parity(codec, spans, job=pjob,
-                                              unit=unit, stripes=geom)
+                                              unit=unit, stripes=geom[0],
+                                              block=geom[1])
                 except BaseException as e:
                     errors.append(e)  # the reader stops at its next unit
                     slots.release()
@@ -1665,18 +1730,20 @@ def rebuild_ec_reduced(base: str, lost: list[int], groups: list[dict],
 
 
 def write_dat_file(base: str, dat_size: int,
-                   large_block: int = layout.LARGE_BLOCK_SIZE,
-                   small_block: int = layout.SMALL_BLOCK_SIZE,
                    out_path: str | None = None,
                    data_shards: int | None = None) -> None:
     """Data shard files -> `<base>.dat` (row-major interleave copy).
     ``out_path`` redirects the output (the un-convert path decodes into
     a temp name and renames, so a crash mid-decode can never leave a
     half-written .dat a restart would mount as live data).  The stripe
-    width k comes from the volume's .vif codec tag unless overridden."""
+    width k comes from the volume's .vif codec tag unless overridden, the
+    block sizes from the .vif's record alone (`volume_blocks`): a set is
+    never un-striped with other blocks than it was cut with."""
+    vif = read_vif(base) or {}
+    large_block, small_block = volume_blocks(base, vif)
     if data_shards is None:
         from seaweedfs_tpu.ops import codecs as _codecs
-        data_shards = _codecs.parse_tag((read_vif(base) or {}).get("codec")).k
+        data_shards = _codecs.parse_tag(vif.get("codec")).k
     rows = layout.n_large_rows(dat_size, large_block, small_block,
                                data_shards=data_shards)
     ins = [open(base + layout.to_ext(i), "rb")
@@ -1743,9 +1810,16 @@ def write_idx_from_ecx(ecx_path: str, idx_path: str | None = None) -> None:
 
 def write_vif(base: str, dat_size: int,
               version: int = t.CURRENT_VERSION,
-              codec: str | None = None) -> None:
+              codec: str | None = None,
+              large_block: int = layout.LARGE_BLOCK_SIZE,
+              small_block: int = layout.SMALL_BLOCK_SIZE) -> None:
+    """The shard set's sidecar: what a reader cannot re-derive from the
+    files — the encode-time .dat size, the code's tag, and the block sizes
+    the layout was cut with (`large_block_bytes`, `small_block_bytes`)."""
     import json
-    doc: dict = {"version": version, "dat_file_size": dat_size}
+    doc: dict = {"version": version, "dat_file_size": dat_size,
+                 "large_block_bytes": large_block,
+                 "small_block_bytes": small_block}
     if codec:
         doc["codec"] = codec
     with open(base + ".vif", "w") as f:
@@ -1759,6 +1833,16 @@ def read_vif(base: str) -> dict | None:
             return json.load(f)
     except (OSError, ValueError):
         return None
+
+
+def volume_blocks(base: str, vif: dict | None = None) -> tuple[int, int]:
+    """(large, small) block sizes the shard set at `base` was cut with:
+    its .vif's record (`vif`, where the caller has read it already).  A
+    set from before the record, or one whose .vif is missing, was cut with
+    layout.py's defaults, upstream's 1 GB / 1 MB."""
+    vif = (read_vif(base) if vif is None else vif) or {}
+    return (int(vif.get("large_block_bytes", layout.LARGE_BLOCK_SIZE)),
+            int(vif.get("small_block_bytes", layout.SMALL_BLOCK_SIZE)))
 
 
 def volume_codec_tag(base: str) -> str:

@@ -84,10 +84,15 @@ def _read_pool() -> ThreadPoolExecutor:
 
 
 class EcVolume:
-    def __init__(self, base: str,
-                 large_block: int = layout.LARGE_BLOCK_SIZE,
-                 small_block: int = layout.SMALL_BLOCK_SIZE,
-                 version: int = t.CURRENT_VERSION):
+    """One mounted shard set.  Everything the layout depends on is the
+    set's own, read from its `.vif` at mount: the code (`codec`), the
+    encode-time `.dat` size and the two block sizes the set was cut with
+    (`ec_files.volume_blocks`: `large_block`, `small_block`; a `.vif` from
+    before that record means upstream's 1 GB / 1 MB).  No caller hands
+    block sizes in: a set is never located with other blocks than it was
+    cut with."""
+
+    def __init__(self, base: str, version: int = t.CURRENT_VERSION):
         self.base = base
         # the volume id this EC volume serves — the workload heat
         # tracker's key for degraded reads.  Base names are "<vid>" or
@@ -95,15 +100,14 @@ class EcVolume:
         # trailing id so a collection volume's reconstructions land on
         # the SAME heat key as its blob reads
         self.vid = os.path.basename(base).rsplit("_", 1)[-1]
-        self.large_block = large_block
-        self.small_block = small_block
-        vif = ec_files.read_vif(base)
-        self.version = vif.get("version", version) if vif else version
+        vif = ec_files.read_vif(base) or {}
+        self.large_block, self.small_block = ec_files.volume_blocks(base, vif)
+        self.version = vif.get("version", version)
         # the volume's erasure code, from its .vif tag (pre-tag volumes
         # and missing .vif mean RS — no flag-day): geometry (k/n/alpha)
         # and the degraded-read survivor policy both key off this
         from seaweedfs_tpu.ops import codecs as _codecs
-        self.spec = _codecs.parse_tag((vif or {}).get("codec"))
+        self.spec = _codecs.parse_tag(vif.get("codec"))
         self.codec_tag = self.spec.tag
 
         # replay any crash-left journal into the .ecx, as the reference
@@ -701,20 +705,23 @@ class EcVolume:
         with trace.span("ec.plan", needle=f"{needle_id:x}") as psp:
             dat_offset, size = self.find_needle(needle_id)
             length = t.actual_size(size, self.version)
-            intervals = layout.locate_data(
-                self.large_block, self.small_block, self.dat_size,
-                dat_offset, length, data_shards=self.spec.k)
-            plan = []
-            for iv in intervals:
-                sid, off = iv.to_shard_id_and_offset(self.large_block,
-                                                     self.small_block)
-                plan.append((sid, off, iv.size))
+            plan = self.locate(dat_offset, length)
             psp.set(intervals=len(plan), bytes=length)
         record = b"".join(self._read_ranges(plan, shard_reader))
         n = ndl.Needle.from_record(record, self.version)
         if n.id != needle_id:
             raise IOError(f"ec read returned needle {n.id:x}, wanted {needle_id:x}")
         return n
+
+    def locate(self, dat_offset: int, length: int) -> list[tuple]:
+        """[(shard id, offset in its file, size)] of the .dat's bytes
+        [dat_offset, dat_offset + length), under the set's own code and
+        blocks: the one place a mounted set's layout is asked."""
+        return [(*iv.to_shard_id_and_offset(self.large_block,
+                                            self.small_block), iv.size)
+                for iv in layout.locate_data(
+                    self.large_block, self.small_block, self.dat_size,
+                    dat_offset, length, data_shards=self.spec.k)]
 
     def shard_ids(self) -> list[int]:
         return sorted(self.shards)

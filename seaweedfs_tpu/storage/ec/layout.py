@@ -11,7 +11,9 @@ interoperate:
 
 - The .dat is consumed row-major. While more than one large row
   (k x 1GB) remains, a large row is cut into k large blocks; the rest is
-  cut into rows of k small (1MB) blocks, the final row zero-padded.
+  cut into rows of k small (1MB) blocks, the final row zero-padded.  The
+  two block sizes are the volume's own (its .vif's record; upstream's
+  1GB / 1MB by default): every function here takes them as arguments.
 - Shard j's file = its large blocks in row order, then its small blocks.
 - Parity shards k..k+m-1 hold the code's parity of each row, same block
   sizes.
@@ -31,6 +33,11 @@ TOTAL_SHARDS = DATA_SHARDS + PARITY_SHARDS
 # (msr_9_16 writes .ec17); pure filesystem probes use this instead of
 # TOTAL_SHARDS so a node holding only high shards still finds them
 MAX_TOTAL_SHARDS = 32
+# Upstream's two block sizes: the DEFAULTS of a volume's record.  A set is
+# cut with the pair its conversion was given (these where it gave none),
+# its .vif records the pair (ec_files.write_vif), and every reader of the
+# set takes it from there (ec_files.volume_blocks): these constants stand
+# in only for a .vif written before the record existed.
 LARGE_BLOCK_SIZE = 1024 * 1024 * 1024  # 1GB
 SMALL_BLOCK_SIZE = 1024 * 1024  # 1MB
 

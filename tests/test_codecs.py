@@ -304,7 +304,7 @@ def test_degraded_read_byte_identity_per_family(tmp_path, monkeypatch,
     assert os.path.exists(base + layout.to_ext(spec.n - 1))
     for sid in losses:
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, blocks[0], blocks[1])
+    ev = ec_volume.EcVolume(base)
     try:
         assert ev.codec_tag == tag  # identity from the .vif sidecar
         for nid, data in blobs.items():
@@ -323,7 +323,7 @@ def test_lrc_degraded_read_touches_one_local_group(tmp_path, monkeypatch):
     lost = 2
     os.remove(base + layout.to_ext(lost))
     code = lrc.get_code(10, 2, 2)
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     gathered: list[set[int]] = []
     orig = ev._gather_survivors
 

@@ -128,7 +128,7 @@ def test_ec_encode_roundtrip_all_needles(small_volume):
         assert os.path.getsize(base + layout.to_ext(i)) == \
             layout.shard_file_size(os.path.getsize(base + ".dat"), LARGE, SMALL)
 
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     for nid, data in blobs.items():
         n = ev.read_needle(nid)
         assert n.data == data, nid
@@ -142,7 +142,7 @@ def test_ec_degraded_read_with_missing_shards(small_volume):
     # lose 4 shards (2 data + 2 parity)
     for sid in (1, 7, 10, 13):
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     assert ev.shard_ids() == [0, 2, 3, 4, 5, 6, 8, 9, 11, 12]
     for nid, data in blobs.items():
         assert ev.read_needle(nid).data == data, nid
@@ -155,7 +155,7 @@ def test_ec_read_fails_below_k_shards(small_volume):
     encode_small(base)
     for sid in (0, 1, 2, 3, 10):
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     with pytest.raises(IOError, match="shards readable"):
         # any needle hitting shard 0..3 must fail with 9 shards left
         for nid in blobs:
@@ -181,7 +181,7 @@ def test_ec_delete_and_journal_replay(small_volume):
     tmp_path, blobs = small_volume
     base = str(tmp_path / "7")
     encode_small(base)
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     ev.delete_needle(5)
     ev.delete_needle(6)
     with pytest.raises(KeyError):
@@ -189,7 +189,7 @@ def test_ec_delete_and_journal_replay(small_volume):
     ev.close()
     assert ec_files.read_ecj(base + ".ecj") == [5, 6]
     # remount replays the journal and removes it
-    ev2 = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev2 = ec_volume.EcVolume(base)
     assert not os.path.exists(base + ".ecj")
     with pytest.raises(KeyError):
         ev2.read_needle(6)
@@ -206,7 +206,7 @@ def test_ec_decode_back_to_volume(small_volume):
     assert dat_size == len(golden_dat)
     os.remove(base + ".dat")
     os.remove(base + ".idx")
-    ec_files.write_dat_file(base, dat_size, LARGE, SMALL)
+    ec_files.write_dat_file(base, dat_size)
     ec_files.write_idx_from_ecx(base + ".ecx")
     assert open(base + ".dat", "rb").read() == golden_dat
     # reload as a normal volume and read everything
@@ -359,13 +359,16 @@ def test_encode_unit_is_a_span_of_the_dat_map(tmp_path, monkeypatch, tag,
     seen: list[int] = []
     real = ec_files._dispatch_parity
 
-    def spy(codec, spans, job=None, unit=None, stripes=0):
+    def spy(codec, spans, job=None, unit=None, stripes=0, block=0):
         # 1-D spans that hold `stripes` rows of k blocks, nothing 2-D
         assert all(s.ndim == 1 and s.dtype == np.uint8 for s in spans)
-        block, rest = divmod(sum(s.nbytes for s in spans), stripes * k)
-        assert rest == 0 and block in (small, large, batch), (block, rest)
+        step, rest = divmod(sum(s.nbytes for s in spans), stripes * k)
+        assert rest == 0 and step in (small, large, batch), (step, rest)
+        # the rows' block size rides along for the stage events
+        assert block in (small, large) and step <= block
         seen.append(stripes)
-        return real(codec, spans, job=job, unit=unit, stripes=stripes)
+        return real(codec, spans, job=job, unit=unit, stripes=stripes,
+                    block=block)
     monkeypatch.setattr(ec_files, "_dispatch_parity", spy)
     stats: dict = {}
     ec_files.write_ec_files(base, large_block=large, small_block=small,
@@ -440,7 +443,7 @@ def test_ec_reference_fixture_end_to_end(tmp_path):
     live = {nid: vol.read_needle(nid).data
             for nid, (off, sz) in vol.nm.items() if t.size_is_valid(sz)}
     vol.close()
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     for nid, data in live.items():
         assert ev.read_needle(nid).data == data, nid
     ev.close()

@@ -296,7 +296,8 @@ def test_convert_under_the_tag_byte_identity(tmp_path, monkeypatch, tag,
         assert got == _model_files(tag, raw), base
         assert ec_files.read_vif(base) == {
             "version": ec_files.read_vif(base)["version"],
-            "dat_file_size": len(raw), "codec": tag}
+            "dat_file_size": len(raw), "codec": tag,
+            "large_block_bytes": LARGE, "small_block_bytes": SMALL}
     if spans:  # only a volume's last, short row is copied on the host
         assert stats["rows_staged"] == sum(
             1 for n in sizes if n % (spec.k * SMALL))

@@ -295,7 +295,7 @@ def test_degraded_read_gathers_one_local_group(tmp_path, monkeypatch, kind,
     base, blobs = _needle_volume(tmp_path)
     for sid in lost:
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, 1 << 20, 4096)
+    ev = ec_volume.EcVolume(base)
     assert (ev.codec_tag, ev.spec.k, ev.spec.n) == (TAG, 12, 16)
     gathered: list[set[int]] = []
     orig = ev._gather_survivors
@@ -452,7 +452,10 @@ def test_generate_and_rebuild_under_the_tag(server):
     lost data shard and says so on /admin/ec/progress."""
     vs, base = server
     status, out = _call(vs.handle_ec_generate, {"volume": 3, "codec": TAG})
-    assert (status, out) == (200, {"shards": list(range(16)), "codec": TAG})
+    assert (status, out) == (200, {
+        "shards": list(range(16)), "codec": TAG,
+        "large_block_bytes": layout.LARGE_BLOCK_SIZE,
+        "small_block_bytes": layout.SMALL_BLOCK_SIZE})
     assert ec_files.read_vif(base)["codec"] == TAG
     with open(base + ".dat", "rb") as f:
         want = reference_files(f.read(), layout.LARGE_BLOCK_SIZE,

@@ -61,7 +61,7 @@ def test_syndrome_catches_single_flipped_bit_in_any_shard(tmp_path,
     dispatched syndrome is byte-identical to a python-backend recompute."""
     monkeypatch.setenv("WEEDTPU_EC_CODEC", "numpy")
     base, _ = _make_ec_volume(tmp_path)
-    ev = ec_volume.EcVolume(base, 1 << 40, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         assert scrub.syndrome_scan(ev, window=SMALL * 2) == []
 
@@ -102,7 +102,7 @@ def test_quarantined_range_served_via_reconstruction(tmp_path,
     with open(p, "r+b") as f:
         f.seek(64)
         f.write(b"\xff" * 128)
-    ev = ec_volume.EcVolume(base, 1 << 40, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         found = scrub.syndrome_scan(ev, window=SMALL)
         assert found and found[0]["shard"] == 2

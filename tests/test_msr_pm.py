@@ -174,7 +174,7 @@ def test_the_seam_takes_the_unit_linear_and_stages_nothing(kind):
     assert np.array_equal(np.stack(parity), ref.encode(files)[K:])
     assert "rows_staged" not in stats
     assert KERNELS.notes("encode_parity[device]") == {
-        "rows_in": 72, "rows_out": 72, "alpha": ALPHA,
+        "rows_in": 72, "rows_out": 72, "alpha": ALPHA, "stripes": [2],
         "tile": codec.inner.tile}
 
 
@@ -297,7 +297,7 @@ def test_degraded_read_on_a_mounted_volume(tmp_path, monkeypatch, lost):
     ec_files.write_sorted_ecx(base + ".idx")
     for sid in lost:
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, 1 << 20, 4096)
+    ev = ec_volume.EcVolume(base)
     assert (ev.codec_tag, ev.spec.k, ev.spec.n, ev.spec.alpha) == \
         (TAG, K, N, ALPHA)
     try:
@@ -318,7 +318,10 @@ def test_generate_rebuild_and_progress_under_the_tag(server):
     vs, base = server
     KERNELS.reset()
     status, out = _call(vs.handle_ec_generate, {"volume": 3, "codec": TAG})
-    assert (status, out) == (200, {"shards": list(range(N)), "codec": TAG})
+    assert (status, out) == (200, {
+        "shards": list(range(N)), "codec": TAG,
+        "large_block_bytes": layout.LARGE_BLOCK_SIZE,
+        "small_block_bytes": layout.SMALL_BLOCK_SIZE})
     with open(base + ".dat", "rb") as f:
         want = reference_files(f.read(), layout.LARGE_BLOCK_SIZE,
                                layout.SMALL_BLOCK_SIZE)

@@ -417,7 +417,7 @@ def test_ec_read_flow_account_books_stage_occupancy(tmp_path, monkeypatch):
     c_busy = metrics.PIPELINE_STAGE_SECONDS.labels("ec_read",
                                                    "local_pread")
     v0 = c_busy.value
-    ev = ecv.EcVolume(base, LARGE, SMALL)
+    ev = ecv.EcVolume(base)
     try:
         for nid, data in blobs.items():
             assert ev.read_needle(nid).data == data

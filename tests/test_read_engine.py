@@ -56,7 +56,7 @@ def test_degraded_read_byte_identity_across_codecs(tmp_path, monkeypatch,
     for sid in lost:
         os.remove(base + layout.to_ext(sid))
     monkeypatch.setenv("WEEDTPU_EC_CODEC", codec)
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         for nid, data in blobs.items():
             assert ev.read_needle(nid).data == data, nid
@@ -74,7 +74,7 @@ def test_concurrent_degraded_reads_one_volume(tmp_path, monkeypatch):
     base, blobs = _make_ec(tmp_path, n=40)
     for sid in (1, 8):
         os.remove(base + layout.to_ext(sid))
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     errors: list = []
 
     def worker(seed: int) -> None:
@@ -124,7 +124,7 @@ def test_coalesced_intervals_one_dispatch(tmp_path, monkeypatch):
         return real(codec, rows, ids, wanted, **kw)
 
     monkeypatch.setattr(ec_files, "_reconstruct_batch", counting)
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         assert ev.read_needle(1).data == big
         assert len(calls) == 1, calls  # one dispatch for the whole needle
@@ -276,7 +276,7 @@ def test_ec_read_stats_reach_metrics_registry(tmp_path, monkeypatch):
     monkeypatch.setenv("WEEDTPU_EC_CODEC", "numpy")
     base, blobs = _make_ec(tmp_path, n=10)
     os.remove(base + layout.to_ext(0))
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         for nid in blobs:
             ev.read_needle(nid)

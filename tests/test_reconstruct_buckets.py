@@ -168,7 +168,7 @@ def test_degraded_reads_build_a_program_a_bucket_not_a_length(
         return real_stack(stack, present, wanted, linear)
 
     codec.reconstruct_stack = stack_spy
-    ev = ec_volume.EcVolume(base, LARGE, SMALL)
+    ev = ec_volume.EcVolume(base)
     try:
         p0 = _programs()
         for nid in range(1, 49):

@@ -355,11 +355,11 @@ def test_gather_survivors_orders_remote_by_locality(shards, tmp_path,
     monkeypatch.setenv("WEEDTPU_EC_CODEC", "numpy")
     base = _write_shard_files(tmp_path, shards, list(range(0, 4)))
     from seaweedfs_tpu.storage.ec import ec_files as ecf
-    ecf.write_vif(base, CODE.k * L)
+    ecf.write_vif(base, CODE.k * L, large_block=1 << 40, small_block=L)
     with open(base + ".ecx", "wb") as f:
         f.write(b"")
     from seaweedfs_tpu.storage.ec.ec_volume import EcVolume
-    ev = EcVolume(base, large_block=1 << 40, small_block=L)
+    ev = EcVolume(base)
     try:
         order = []
         lock = __import__("threading").Lock()

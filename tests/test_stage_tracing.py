@@ -187,7 +187,7 @@ def prepare(kind: str, tmp_path):
         def op():
             root = trace.new_root()
             token = trace._current.set(root)
-            ev = ec_volume.EcVolume(base, LARGE, SMALL)
+            ev = ec_volume.EcVolume(base)
             try:
                 for nid, data in blobs.items():
                     assert ev.read_needle(nid).data == data
@@ -538,7 +538,7 @@ def test_regen_and_the_scrubber_book_the_seam_to_a_flow(kind, tmp_path):
     if kind == "ec_scrub":
         from seaweedfs_tpu.maintenance import scrub
         base, _ = _make_ec(tmp_path)
-        ev = ec_volume.EcVolume(base, LARGE, SMALL)
+        ev = ec_volume.EcVolume(base)
         try:
             assert scrub.syndrome_scan(ev, window=SMALL * 2) == []
         finally:

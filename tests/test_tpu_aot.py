@@ -104,11 +104,13 @@ WIDE = 16
     ("lrc_12_2_2", [0, 6], TILE, (2, 12)),
     ("rs_10_4", "unit", WIDE * MIB, (4, 10)),
     ("lrc_12_2_2", "unit", WIDE * MIB, (4, 12)),
+    ("rs_10_4", "column", WIDE * MIB, (4, 10)),
 ], ids=["encode_10_4", "rebuild_batch_1_row", "read_2_rows_smallest_bucket",
         "lrc_encode_4_12", "lrc_local_rebuild_batch_1_6",
         "lrc_local_read_smallest_bucket_1_6", "lrc_global_rebuild_2_12",
         "lrc_read_one_lost_in_each_group_2_12",
-        "encode_unit_16_rows_10_4", "lrc_encode_unit_16_rows_4_12"])
+        "encode_unit_16_rows_10_4", "lrc_encode_unit_16_rows_4_12",
+        "encode_unit_column_cut_10_4"])
 def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     """The served single-chip programs at TPU_TILE: [k, 1 MiB] under the
     parity matrix (the scrubber's 2-D window), the wide encode unit as the
@@ -120,10 +122,16 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     and a degraded read at the narrowest.  RS(10,4), and Azure LRC(12,2,2)
     (`lrc_12_2_2`): its [4, 12] parity, the [1, 6] of ones that rebuilds
     one lost data shard from its local group (k = 6 under PLANE_PAD 16)
-    and the [2, 12] of the global fallback, each as its basis stages it."""
+    and the [2, 12] of the global fallback, each as its basis stages it.
+    And the unit of a large-block row (a volume past ten large blocks: the
+    north star's 30 GB one, `vol30g.encode`'s at 1/32): a column cut, one
+    stripe row of ten 16 MiB pieces a block apart in the `.dat`, laid out
+    [10, 16 MiB] by the `stripes=1` program, the same kernel, four runs
+    back."""
     from seaweedfs_tpu.ops import codecs
     code = codecs._code_for(codecs.parse_tag(tag))
-    unit = wanted == "unit"
+    column = wanted == "column"
+    unit = wanted == "unit" or column
     if wanted is None or unit:
         C = code.parity_matrix
     else:
@@ -137,7 +145,14 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     # a wide stack as its ten rows, a narrow one as one array; an encode
     # unit as its stripe rows
     stripes = 0
-    if unit:
+    if column:
+        # dispatch.unit_pieces leaves the k spans of a column cut whole
+        pieces = dispatch.unit_pieces(
+            [np.empty(width, np.uint8) for _ in range(k)], 1)
+        assert [len(p) for p in pieces] == [width] * k
+        data = tuple(_spec((width,), jnp.uint8, one) for _ in range(k))
+        stripes = 1
+    elif unit:
         assert k * MIB >= dispatch.ROW_PUTS_FROM  # so: row by row
         data = tuple(_spec((k * MIB,), jnp.uint8, one) for _ in range(WIDE))
         stripes = WIDE
