@@ -106,7 +106,18 @@ def stacked(data, k: int, stripes: int = 0, alpha: int = 1) -> jax.Array:
     file is its byte set {t * alpha + a}, so each [W] file row is split
     into its alpha sub-rows of [W / alpha] here (`_subrows`), a
     byte-granular transpose inside the program where the host would copy
-    every byte."""
+    every byte.
+
+    Since PR 39 the Pallas apply lays out only what its kernel cannot
+    read where it lies (`pallas_gf.in_place_block`): a rebuild batch's
+    rows and an encode unit's pieces whose block is a tile multiple go
+    into the kernel as they were put, so this is what stacks a degraded
+    read's one array, a sub-packetised code's rows (`alpha` > 1), a
+    block that is no tile multiple (tests' tiny ones), the XLA shell's
+    every linear input, and, in its third form, the fleet's mesh
+    program's units (parallel/mesh.py).  On a v5e a u8 [k, W] is tiled
+    four rows a 32-bit word, so laying one out costs a strided write of
+    every byte (ten 16 MiB rows: 13 ms, PERF.md, PR 38)."""
     files = k // alpha
     if isinstance(data, (tuple, list)):
         data = jnp.concatenate(data) if stripes else jnp.stack(data, axis=0)
@@ -126,7 +137,10 @@ def unstacked(out: jax.Array, stripes: int = 0, alpha: int = 1):
     afresh every time and pays its first touch (TPU v5e's host: one
     64 MiB array back in 71-79 ms, four of 16 MiB in 6.5; PERF.md,
     PR 31).  `alpha` > 1: the m virtual rows are merged back into the
-    bytes of m / alpha files first (`stacked`'s split, undone)."""
+    bytes of m / alpha files first (`stacked`'s split, undone).  Where
+    the Pallas kernel read its input in place it writes these forms
+    itself (PR 39); this cuts the [m, W] of every input `stacked` laid
+    out."""
     if alpha > 1:
         out = _files(out, alpha)
     return tuple(out) if stripes else out.reshape(-1)
@@ -219,6 +233,18 @@ class RSCodecBase:
         rows' bytes of one shard (`unstacked`): the layout, the parity
         apply and the split are one program, and only 1-D arrays cross."""
         return self._parity(spans, True, stripes, alpha)
+
+    def in_place(self, lengths, stripes: int = 0, present=None,
+                 wanted=None) -> bool:
+        """Whether the linear apply of an encode unit (`stripes` >= 1) or
+        of the decode of `wanted` from `present` reads the 1-D pieces it is
+        handed, of `lengths` bytes, where they lie instead of through
+        `stacked` (the matrix apply's `in_place`; False for a backend
+        that has none)."""
+        mat = self._parity if wanted is None else \
+            self._cached_decode(tuple(sorted(present)), tuple(wanted))[1]
+        test = getattr(mat, "in_place", None)
+        return bool(test is not None and test(lengths, stripes))
 
     def encode_parity_batch(self, units: jax.Array) -> jax.Array:
         """[U, k, n] unit batch -> [U, m, n] parity in ONE device dispatch

@@ -267,6 +267,7 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0,
                 runs = codec.encode_parity_linear(placed, stripes)
                 for a in runs:  # see materialize
                     a.copy_to_host_async()
+                _count_in_place(job, codec, placed, stripes)
                 return runs
             _note_matrix("encode_parity", codec, stripes)
             return _device_call(
@@ -306,6 +307,17 @@ def materialize(parity, kernel: str = "encode_parity", job=None,
     if isinstance(parity, tuple):
         return _to_host(list(parity), job, unit, kernel)
     return _to_host([parity], job, unit, kernel)[0]
+
+
+def _count_in_place(job, codec, placed, stripes: int, **decode) -> None:
+    """Count a unit or batch whose program reads its 1-D pieces where
+    they lie (`in_place` on /admin/ec/progress `stages`, beside
+    `rows_staged`): the codec's `in_place`, where it has one, asked about
+    what was put."""
+    test = getattr(codec, "in_place", None)
+    if job is not None and test is not None and test(
+            [p.shape[0] for p in placed], stripes, **decode):
+        job.count("in_place", 1)
 
 
 def unit_pieces(spans, stripes: int) -> list:
@@ -597,6 +609,9 @@ def dispatch_reconstruct(codec, rows, ids: list[int], wanted: list[int],
     def run(placed):
         out = codec.reconstruct_stack(placed, ids, wanted, linear=True)
         out.copy_to_host_async()
+        if isinstance(placed, tuple):
+            _count_in_place(job, codec, placed, 0, present=ids,
+                            wanted=wanted)
         return out
 
     out = _device_call(job, unit, "reconstruct", nbytes, put, run,

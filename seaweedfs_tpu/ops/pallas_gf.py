@@ -1,10 +1,17 @@
 """Fused Pallas TPU kernel for the bit-sliced GF(2^8) matmul.
 
 The XLA path (`ops.gfmat_jax`) materialises the 8x bit-plane expansion in
-HBM; this kernel keeps it in VMEM. Each grid step DMAs a [k, TN] byte tile,
-unpacks bit-planes in VMEM, runs one int8 MXU dot against the pre-lifted
-coding matrix, folds parity-mask + repack into the epilogue, and writes only
-the [m, TN] output bytes — HBM traffic is the information-theoretic minimum.
+HBM; this kernel keeps it in VMEM. Each grid step has a [k, TN] byte block
+in VMEM, unpacks bit-planes there, runs one int8 MXU dot against the
+pre-lifted coding matrix, folds parity-mask + repack into the epilogue, and
+writes only the [m, TN] output bytes — HBM traffic is the
+information-theoretic minimum.  Two programs feed that body (`_gf_body`):
+a 2-D [k, n] array, or a linear input that `codec_base.stacked` lays out
+as one, is read a [k, TN] tile a step by the BlockSpec (`_gf_apply`); an
+encode unit's or a rebuild batch's 1-D pieces, as the seams put them, are
+read where they lie, each step DMAing the k shards' TN-byte tiles from
+their pieces and assembling the block in VMEM, and the parity is written
+as the seam wants it, m runs or one array (`_gf_apply_in_place`, PR 39).
 
 Throughput: not measured on current code (PERF.md keeps what the chip
 has shown).  The CPU comparison point is the klauspost/reedsolomon AVX2
@@ -134,13 +141,24 @@ GF_APPLY_BATCH = "_gf_apply_batch"
 def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
               tile: int, interpret: bool, linear: bool = False,
               stripes: int = 0, alpha: int = 1):
-    """`linear`: 1-D in and out, laid out in this program
-    (codec_base.stacked and unstacked; `stripes` rows of a `.dat`, whose
-    width need be no tile multiple: the pad and the cut are in this
-    program too; `alpha` > 1: the k and m rows are the byte-interleaved
-    sub-rows of k / alpha and m / alpha files, split and merged here)."""
+    """`linear`: 1-D in and out.  Rows put one by one (a tuple of 1-D
+    pieces: an encode unit, a rebuild batch) whose block is a tile
+    multiple (`in_place_block`) are read where they lie by the kernel
+    itself (`_gf_apply_in_place`); any other linear input is laid out in
+    this program (codec_base.stacked and unstacked; `stripes` rows of a
+    `.dat`, whose width need be no tile multiple: the pad and the cut are
+    in this program too; `alpha` > 1: the k and m rows are the
+    byte-interleaved sub-rows of k / alpha and m / alpha files, split and
+    merged here)."""
     cut = None
     if linear:
+        block = None
+        if alpha == 1 and isinstance(data, (tuple, list)):
+            block = in_place_block(tuple(p.shape[0] for p in data), k,
+                                   stripes, tile)
+        if block is not None:
+            return _gf_apply_in_place(bitmat, tuple(data), k, m, kpad, tile,
+                                      block, stripes, interpret)
         data = codec_base.stacked(data, k, stripes, alpha)
         if data.shape[1] % tile:
             cut = data.shape[1]
@@ -168,6 +186,190 @@ def _gf_apply(bitmat: jax.Array, data, k: int, m: int, kpad: int,
     if cut is not None:
         out = out[:, :cut]
     return codec_base.unstacked(out, stripes, alpha) if linear else out
+
+
+# The in-place path (PR 39).  A linear input that comes as pieces (an
+# encode unit's stripe rows or column cut, a rebuild batch's survivor
+# rows) is one stream of max(stripes, 1) rows of k blocks, the pieces
+# cutting it in order (codec_base.stacked's first and third forms).
+# Laid out [k, W] in HBM first, it cost 13 of a unit program's 15-17 ms
+# on a v5e: a u8 [k, W] is tiled with four rows a 32-bit word, so
+# writing one shard's row into it is a byte-strided scatter (PERF.md,
+# PR 38).  Instead each grid step DMAs tile c of every shard from where
+# it lies into VMEM (`_fetch_plan`), and the [k, tile] block `_gf_body`
+# reads is assembled there: a tile's bytes as [tile / 512, 128] 32-bit
+# words, shard j's run of them at rows j * (tile / 512) of a scratch,
+# so one strided load takes a word row of every shard, [k, 128]; its
+# four bytes go to four 128-column runs of the block (byte b of word row
+# r to columns b * tile / 4 + r * 128).  The parity's columns come back
+# in the same order into words, and each parity shard's tile is written
+# where its run wants it.  The matrix apply is column-local, so columns
+# in any order that is the same for every row give the same bytes.
+# 4096: a tile of one shard fills [8, 128] words a whole number of times.
+IN_PLACE_QUANTUM = 4096
+LINE = 128  # bytes a row of a piece's [n / 128, 128] view: free on a TPU
+# word rows a loop step of the assembly: its loop is unrolled by hand
+# (Mosaic takes a fori_loop unrolled by 1 or whole)
+IN_PLACE_UNROLL = 8
+
+
+def in_place_block(lengths: tuple, k: int, stripes: int,
+                   tile: int) -> int | None:
+    """The block of a linear input put as pieces of `lengths` bytes, if
+    `_gf_apply` reads it where it lies: max(stripes, 1) rows of k blocks
+    in all, every piece whole blocks, the block a multiple of the tile
+    and the tile of IN_PLACE_QUANTUM.  None: the input is laid out by
+    codec_base.stacked."""
+    rows = max(stripes, 1)
+    total = sum(lengths)
+    if tile % IN_PLACE_QUANTUM or not total or total % (k * rows):
+        return None
+    block = total // (k * rows)
+    if block % tile or any(n % block for n in lengths):
+        return None
+    return block
+
+
+def _fetch_plan(lengths: tuple, k: int, rows: int) -> tuple:
+    """-> ((piece, row, first shard, shards, first block of the piece's
+    that they are), ...): for each stripe row, the pieces its k blocks
+    lie in, a run of consecutive shards in each (one piece a shard in a
+    column cut or a rebuild batch, one a row in a unit of rows)."""
+    plan, first = [], 0
+    for q, n in enumerate(lengths):
+        blocks = range(first, first + n * rows * k // sum(lengths))
+        for r in range(rows):
+            js = [j for j in range(k) if r * k + j in blocks]
+            if js:
+                plan.append((q, r, js[0], len(js), r * k + js[0] - first))
+        first = blocks.stop
+    return tuple(plan)
+
+
+def _in_place_kernel(bitmat_ref, *refs, k: int, m: int, kpad: int,
+                     tile: int, per_row: int, stripe_rows: int, plan: tuple,
+                     pieces_n: int, runs: bool):
+    pieces = refs[:pieces_n]
+    outs = refs[pieces_n:pieces_n + (m if runs else 1)]
+    fetched, sem, words, x, y, ywords = refs[pieces_n + len(outs):]
+    lines, rows = tile // LINE, tile // (4 * LINE)  # a tile of one shard
+    quarter = tile // 4
+    step, steps = pl.program_id(0), pl.num_programs(0)
+
+    def copies(c, slot, act):
+        """`act` on the DMAs of tile c of every shard into `slot`: a run
+        of shards' tiles from a piece is one DMA, strided a block apart."""
+        for q, r, j, n, b in plan:
+            def each(q=q, j=j, n=n, b=b):
+                at = pl.multiple_of((c % per_row) * lines, lines)
+                act(pltpu.make_async_copy(
+                    pieces[q].at[pl.ds(b, n), pl.ds(at, lines)],
+                    fetched.at[slot, pl.ds(j, n)], sem.at[slot]))
+            if stripe_rows == 1:
+                each()
+            else:
+                pl.when(c // per_row == r)(each)
+
+    slot = step % 2
+
+    @pl.when(step == 0)
+    def _():
+        copies(step, 0, lambda d: d.start())
+
+    @pl.when(step + 1 < steps)
+    def _():
+        copies(step + 1, 1 - slot, lambda d: d.start())
+
+    copies(step, slot, lambda d: d.wait())
+    for j in range(k):
+        words[pl.ds(j * rows, rows), :] = pltpu.bitcast(fetched[slot, j],
+                                                        jnp.uint32)
+
+    def columns(b, r):
+        return pl.ds(pl.multiple_of(b * quarter + r * LINE, LINE), LINE)
+
+    def gather(r0, carry):
+        for u in range(IN_PLACE_UNROLL):
+            r = r0 * IN_PLACE_UNROLL + u
+            row = words[pl.ds(r, k, stride=rows), :]  # [k, 128] words
+            for b in range(4):
+                x[:, columns(b, r)] = ((row >> (8 * b)) & 0xFF).astype(
+                    jnp.uint8)
+        return carry
+
+    def scatter(r0, carry):
+        for u in range(IN_PLACE_UNROLL):
+            r = r0 * IN_PLACE_UNROLL + u
+            row = y[:, columns(0, r)].astype(jnp.uint32)
+            for b in range(1, 4):
+                row |= y[:, columns(b, r)].astype(jnp.uint32) << (8 * b)
+            ywords[pl.ds(r, m, stride=rows), :] = row
+        return carry
+
+    jax.lax.fori_loop(0, rows // IN_PLACE_UNROLL, gather, 0)
+    y[...] = _gf_body(bitmat_ref[...], x[...], k=k, m=m, kpad=kpad)
+    jax.lax.fori_loop(0, rows // IN_PLACE_UNROLL, scatter, 0)
+    for i in range(m):
+        tile_i = pltpu.bitcast(ywords[pl.ds(i * rows, rows), :], jnp.uint8)
+        if runs:
+            outs[i][...] = tile_i
+        else:
+            outs[0][i] = tile_i
+
+
+def _gf_apply_in_place(bitmat, pieces: tuple, k: int, m: int, kpad: int,
+                       tile: int, block: int, stripes: int,
+                       interpret: bool):
+    """The kernel of a linear input that `in_place_block` takes, as the
+    one program: the pieces go in as they were put (a 1-D u8 array's
+    [blocks, block / 128, 128] view is the same bytes on a TPU), each grid
+    step fetches its k tiles (`_in_place_kernel`), and the parity goes out
+    as `unstacked` gives it, written by the kernel where it lies: m runs
+    of [W] for an encode unit (`stripes` >= 1), one [m * W] for a
+    decode."""
+    rows = max(stripes, 1)
+    width, lines = rows * block, tile // LINE
+    runs = stripes > 0
+    vma = jax.typeof(pieces[0]).vma
+    if runs:
+        out_specs = [pl.BlockSpec((lines, LINE), lambda c: (c, 0))] * m
+        out_shape = [jax.ShapeDtypeStruct((width // LINE, LINE), jnp.uint8,
+                                          vma=vma)] * m
+    else:
+        out_specs = pl.BlockSpec((m, lines, LINE), lambda c: (0, c, 0))
+        out_shape = jax.ShapeDtypeStruct((m, width // LINE, LINE), jnp.uint8,
+                                         vma=vma)
+    lengths = tuple(p.shape[0] for p in pieces)
+    kernel = functools.partial(
+        _in_place_kernel, k=k, m=m, kpad=kpad, tile=tile,
+        per_row=block // tile, stripe_rows=rows,
+        plan=_fetch_plan(lengths, k, rows),
+        pieces_n=len(pieces), runs=runs)
+    words = tile // (4 * LINE)
+    out = pl.pallas_call(
+        kernel,
+        grid=(width // tile,),
+        in_specs=[pl.BlockSpec((8 * m, 8 * kpad), lambda c: (0, 0))]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pieces),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[
+            pltpu.VMEM((2, k, lines, LINE), jnp.uint8),  # fetched tiles
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((k * words, LINE), jnp.uint32),  # ... as words
+            pltpu.VMEM((k, tile), jnp.uint8),  # the block _gf_body reads
+            pltpu.VMEM((m, tile), jnp.uint8),  # what it gives
+            pltpu.VMEM((m * words, LINE), jnp.uint32),  # ... as words
+        ],
+        # a step starts the next one's fetch: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=GF_APPLY,
+    )(bitmat, *(p.reshape(-1, block // LINE, LINE) for p in pieces))
+    if runs:
+        return tuple(o.reshape(-1) for o in out)
+    return out.reshape(-1)
 
 
 def _gf_apply_batch_kernel(bitmat_ref, x_ref, o_ref, *, k: int, m: int,
@@ -254,6 +456,13 @@ class PallasGFMatrix:
         out = _gf_apply(self.bitmat, data, self.k, self.m, self.kpad,
                         self.tile, self.interpret)
         return out[:, :n] if pad else out
+
+    def in_place(self, lengths, stripes: int = 0) -> bool:
+        """Whether a linear call (`alpha` 1) on pieces of `lengths` bytes
+        reads them where they lie (`_gf_apply`'s own test): what the
+        dispatch seam counts as `in_place`."""
+        return in_place_block(tuple(lengths), self.k, stripes,
+                              self.tile) is not None
 
     def apply_batch(self, data: jax.Array) -> jax.Array:
         """[U, k, n] unit batch -> [U, m, n] parity in one kernel launch

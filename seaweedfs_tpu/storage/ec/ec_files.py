@@ -484,6 +484,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     stats = pjob.stats
     stats["bytes"] = dat_size
     stats["rows_staged"] = 0  # stripe rows copied on the host (job.count)
+    stats["in_place"] = 0  # units read where they were put (the seam counts)
     stats["spans_mapped"] = 0  # units selected in the .dat's map (job.count)
     shard_size = layout.shard_file_size(dat_size, large_block, small_block,
                                         data_shards=codec.k)
@@ -1374,6 +1375,7 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
     stats["survivors"] = len(use)
     stats["basis"] = basis_kind(codec, use)
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
+    stats["in_place"] = 0  # batches read where they were put (the seam too)
     stats["spans_mapped"] = 0  # rows selected in the maps: batches x survivors
     stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
     # MSR sub-packetization: every chunk a codec's interleave must see is

@@ -176,6 +176,27 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
                         "gf_apply" if wanted is None or unit
                         else "gf_reconstruct",
                         "jit__gf_apply")
+    if unit or width >= dispatch.ROW_PUTS_FROM:
+        # rows put one by one are read where they lie (PR 39): no HBM
+        # layout round the kernel
+        assert pallas_gf.in_place_block(
+            tuple(d.shape[0] for d in data), k, stripes, tile) is not None
+        _assert_no_layout(compiled)
+
+
+# the opcodes with which a program lays rows out in HBM: a stack, the
+# stripe rows' turn, a row written into a tiled [k, W], its loop
+LAYOUT_OPS = re.compile(
+    r"= [^=]*? (concatenate|transpose|dynamic-update-slice|while)\(")
+
+
+def _assert_no_layout(compiled) -> None:
+    """The program is its kernel: no layout op outside the one Mosaic
+    custom call, whose own body (its backend config) is not HLO."""
+    ops = [ln.strip() for ln in compiled.as_text().splitlines()
+           if 'custom_call_target="tpu_custom_call"' not in ln
+           and LAYOUT_OPS.search(ln)]
+    assert not ops, ops[:3]
 
 
 @pytest.mark.parametrize("m, k", [(4, 10), (1, 10), (2, 10), (4, 12),
