@@ -1327,13 +1327,73 @@ class VolumeServer:
         job["cancel"] = True
         return web.json_response({"ok": True})
 
+    async def _run_ec_job(self, vids: list[int], job: dict, work,
+                          answer) -> web.Response:
+        """Run `work(progress, cancel)` on a thread as the EC job `job`,
+        registered under every vid of `vids`, so that /admin/ec/progress on
+        any of them observes it and /admin/ec/cancel on any of them stops
+        it.  The answer is `answer(result, None)`, or, where the work
+        raised, the job settled cancelled or failed and `answer(None, e)`
+        with the `error`: 409 for a cancel, 400 for a code the backend does
+        not carry (refused before any tmp file), 500 for anything else.
+        The job keeps the answer (`answer` on /admin/ec/progress), for a
+        client whose call timed out while the job went on."""
+        for vid in vids:
+            self._ec_jobs[vid] = job
+        from seaweedfs_tpu.ops import codecs as _codecs
+        try:
+            result = await asyncio.to_thread(
+                work, lambda n: job.__setitem__("bytes_done", n),
+                lambda: job["cancel"])
+        except Exception as e:
+            if isinstance(e, ec_files.EncodeCancelled):
+                status, error = 409, "cancelled"
+                job["state"] = "cancelled"
+            else:
+                status = 400 if isinstance(e, _codecs.CodecUnsupported) \
+                    else 500
+                error = job["error"] = str(e)
+                job["state"] = "failed"
+                if status == 500:
+                    log.warning("ec %s of volumes %s failed", job["kind"],
+                                vids, exc_info=True)
+            job["answer"] = dict(answer(None, e), error=error)
+            return web.json_response(job["answer"], status=status)
+        job["answer"] = answer(result, None)
+        job["state"] = "done"
+        job["bytes_done"] = job["total"]
+        return web.json_response(job["answer"])
+
+    @staticmethod
+    def _rebuild_job(kind: str, sets: list[tuple], stages: dict,
+                     **extra) -> dict:
+        """A rebuild's job record; `total` is the survivor bytes a decode
+        of each (base, code spec) in `sets` reads."""
+        total = 0
+        for base, spec in sets:
+            present = [i for i in range(spec.n)
+                       if os.path.exists(base + layout.to_ext(i))]
+            if present:
+                total += os.path.getsize(
+                    base + layout.to_ext(present[0])) * spec.k
+        return {"state": "running", "kind": kind, **extra, "bytes_done": 0,
+                "total": total, "cancel": False, "error": None,
+                "started": time.time(), "stages": stages}
+
     async def handle_ec_rebuild(self, req: web.Request) -> web.Response:
         """VolumeEcShardsRebuild (volume_grpc_erasure_coding.go:84).
 
         Registers under the same per-vid job state as encode, so
         /admin/ec/progress and /admin/ec/cancel observe and abort a
-        long-running rebuild identically."""
+        long-running rebuild identically.  `{"volumes": [...]}` is a
+        rebuilder's backlog in one call (`_handle_ec_rebuild_volumes`);
+        `{"volume": v}` is its case of one (`rebuild_ec_files`), answered
+        `{"rebuilt": [shard ids]}`.  The commit is by rename either way: a
+        failed or cancelled rebuild of one volume leaves its previous set
+        untouched."""
         body = await req.json()
+        if "volumes" in body:
+            return await self._handle_ec_rebuild_volumes(body)
         vid = body["volume"]
         base = self._ec_base(vid)
         if base is None:
@@ -1346,76 +1406,120 @@ class VolumeServer:
         # else the local .vif — a rebuilder holding copied shards but no
         # sidecar must still decode with the right matrix
         from seaweedfs_tpu.ops import codecs as _codecs
-        tag = body.get("codec") or \
-            (ec_files.read_vif(base) or {}).get("codec")
-        spec = _codecs.parse_tag(tag)
-        present = [i for i in range(spec.n)
-                   if os.path.exists(base + layout.to_ext(i))]
-        total = (os.path.getsize(base + layout.to_ext(present[0]))
-                 * spec.k) if present else 0
+        spec = _codecs.parse_tag(body.get("codec") or
+                                 (ec_files.read_vif(base) or {}).get("codec"))
         stages: dict = {}
-        job = {"state": "running",
-               "kind": "rebuild_reduced" if reduced else "rebuild",
-               "codec": spec.tag,
-               "bytes_done": 0, "total": total, "cancel": False,
-               "error": None, "started": time.time(), "stages": stages}
-        self._ec_jobs[vid] = job
+        job = self._rebuild_job(
+            "rebuild_reduced" if reduced else "rebuild", [(base, spec)],
+            stages, codec=spec.tag)
+        if not reduced:
+            return await self._run_ec_job(
+                [vid], job,
+                lambda progress, cancel: ec_files.rebuild_ec_files(
+                    base, progress=progress, cancel=cancel, stats=stages,
+                    codec_tag=spec.tag),
+                lambda rebuilt, e: {} if e else {"rebuilt": rebuilt})
+        # reduced-read path: no survivor copies land here — each helper
+        # node ships XOR-combinable partials instead
+        # (storage/ec/ec_files.rebuild_ec_reduced)
         from seaweedfs_tpu.ops import regen as _regen
-        try:
-            if reduced:
-                # reduced-read path: no survivor copies land here — each
-                # helper node ships XOR-combinable partials instead
-                # (storage/ec/ec_files.rebuild_ec_reduced)
-                lost = sorted(int(s) for s in reduced.get("lost", []))
-                groups = [g for g in (reduced.get("groups") or [])
-                          if g.get("node") and g["node"] != self.url]
-                if reduced.get("shard_size"):
-                    for g in groups:
-                        g.setdefault("shard_size",
-                                     reduced["shard_size"])
-                result = await asyncio.to_thread(
-                    ec_files.rebuild_ec_reduced, base, lost, groups,
-                    self._partial_fetcher(vid, alpha=spec.alpha),
-                    d=reduced.get("d"),
-                    progress=lambda n: job.__setitem__("bytes_done", n),
-                    cancel=lambda: job["cancel"],
-                    stats=stages, codec_tag=spec.tag)
-                job["state"] = "done"
-                job["bytes_done"] = job["total"]
-                await self._heartbeat_once()
-                return web.json_response(result)
-            rebuilt = await asyncio.to_thread(
-                ec_files.rebuild_ec_files, base,
-                progress=lambda n: job.__setitem__("bytes_done", n),
-                cancel=lambda: job["cancel"],
-                stats=stages, codec_tag=spec.tag)
-        except ec_files.EncodeCancelled:
-            job["state"] = "cancelled"
-            return web.json_response({"error": "cancelled"}, status=409)
-        except _codecs.CodecUnsupported as e:
-            job["state"] = "failed"
-            job["error"] = str(e)
-            return web.json_response({"error": str(e)}, status=400)
-        except _regen.HelperDied as e:
-            # re-planning exhausted its substitutes: the master retries /
-            # falls back to naive copies, and needs to know how hard we
-            # tried and who killed us — a bare 500 hides the replan story
-            job["state"] = "failed"
-            job["error"] = str(e)
-            return web.json_response(
-                {"error": str(e),
-                 "helper": e.node or "<local>",
-                 "helper_shards": list(e.shards),
-                 "replans": stages.get("replans", 0),
-                 "dead_helpers": stages.get("dead_helpers", [])},
-                status=500)
-        except Exception as e:
-            job["state"] = "failed"
-            job["error"] = str(e)
-            raise
-        job["state"] = "done"
-        job["bytes_done"] = job["total"]
-        return web.json_response({"rebuilt": rebuilt})
+        lost = sorted(int(s) for s in reduced.get("lost", []))
+        groups = [g for g in (reduced.get("groups") or [])
+                  if g.get("node") and g["node"] != self.url]
+        if reduced.get("shard_size"):
+            for g in groups:
+                g.setdefault("shard_size", reduced["shard_size"])
+
+        def answer(result, e) -> dict:
+            if isinstance(e, _regen.HelperDied):
+                # re-planning exhausted its substitutes: the master retries
+                # / falls back to naive copies, and needs to know how hard
+                # we tried and who killed us — a bare 500 hides the replan
+                # story
+                return {"helper": e.node or "<local>",
+                        "helper_shards": list(e.shards),
+                        "replans": stages.get("replans", 0),
+                        "dead_helpers": stages.get("dead_helpers", [])}
+            return {} if e else result
+
+        resp = await self._run_ec_job(
+            [vid], job,
+            lambda progress, cancel: ec_files.rebuild_ec_reduced(
+                base, lost, groups,
+                self._partial_fetcher(vid, alpha=spec.alpha),
+                d=reduced.get("d"), progress=progress, cancel=cancel,
+                stats=stages, codec_tag=spec.tag),
+            answer)
+        if resp.status == 200:
+            await self._heartbeat_once()
+        return resp
+
+    async def _handle_ec_rebuild_volumes(self, body: dict) -> web.Response:
+        """`ec.rebuild`'s loop on the rebuilder, in one call: every listed
+        volume's missing shards rebuilt in one pipeline
+        (ec_files.rebuild_ec_volumes), each under the code its `.vif`
+        names, each committed by rename as soon as its rows are written.
+        One job is registered under every vid it works on, as a fleet
+        conversion's is.  A volume with no shard files here, an EC job of
+        its own running, nothing missing or fewer survivors than its code's
+        k is answered under `skipped` with its files untouched, and the
+        others go on.  The answer: `volumes` (the list asked for),
+        `rebuilt` {vid: shard ids} of the volumes committed, `shard_files`
+        (files written), `skipped` {vid: why}.  A cancel (409) or a failure
+        (500) answers the same keys with `error` and `untouched`: the
+        volumes committed before it stay committed, the one in flight and
+        every one after it are as they were before the call."""
+        vids: list[int] = []
+        for v_ in body.get("volumes") or []:
+            try:
+                vid = int(v_)
+            except (TypeError, ValueError):
+                return web.json_response(
+                    {"error": f"not a volume id: {v_!r}"}, status=400)
+            if vid not in vids:
+                vids.append(vid)
+        if not vids:
+            return web.json_response({"error": "no volumes listed"},
+                                     status=400)
+        skipped: dict[str, str] = {}
+        bases: dict[str, int] = {}
+        for vid in vids:
+            base = self._ec_base(vid)
+            if base is None:
+                skipped[str(vid)] = "no shards here"
+            elif self._ec_jobs.get(vid, {}).get("state") == "running":
+                skipped[str(vid)] = "ec job already running"
+            else:
+                bases[base] = vid
+        from seaweedfs_tpu.ops import codecs as _codecs
+        stages: dict = {}
+        job = self._rebuild_job(
+            "rebuild",
+            [(base, _codecs.parse_tag(
+                (ec_files.read_vif(base) or {}).get("codec")))
+             for base in bases],
+            stages, volumes=list(bases.values()))
+
+        def answer(report, e) -> dict:
+            report = getattr(e, "report", {}) if e else report
+            rebuilt = {str(bases[b]): ids
+                       for b, ids in report.get("rebuilt", {}).items()}
+            skipped.update((str(bases[b]), why)
+                           for b, why in report.get("skipped", {}).items())
+            out = {"volumes": vids, "rebuilt": rebuilt,
+                   "shard_files": sum(map(len, rebuilt.values())),
+                   "skipped": skipped}
+            if e:
+                out["untouched"] = [v for v in bases.values()
+                                    if str(v) not in rebuilt and
+                                    str(v) not in skipped]
+            return out
+
+        return await self._run_ec_job(
+            list(bases.values()), job,
+            lambda progress, cancel: ec_files.rebuild_ec_volumes(
+                list(bases), progress=progress, cancel=cancel, stats=stages),
+            answer)
 
     async def handle_ec_mount(self, req: web.Request) -> web.Response:
         body = await req.json()

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import shlex
+import time
 import urllib.parse
 import urllib.request
 
@@ -38,7 +39,12 @@ class CommandEnv:
     # -- http helpers --------------------------------------------------
 
     def _call(self, url: str, body: dict | None = None,
-              method: str | None = None, timeout: float = 600.0) -> dict:
+              method: str | None = None, timeout: float = 600.0,
+              answer_errors: bool = False) -> dict:
+        """The JSON answer of one request; an error status raises
+        RuntimeError with the answer's `error`, unless `answer_errors` and
+        the answer is a JSON object with one: then it is returned (a 409 or
+        500 that says what was done before it)."""
         data = json.dumps(body).encode() if body is not None else None
         headers = {"Content-Type": "application/json"} \
             if body is not None else {}
@@ -55,9 +61,12 @@ class CommandEnv:
                 return json.loads(raw) if raw else {}
         except urllib.error.HTTPError as e:
             try:
-                err = json.loads(e.read()).get("error", str(e))
+                answer = json.loads(e.read())
+                err = answer.get("error", str(e))
             except Exception:
-                err = str(e)
+                answer, err = None, str(e)
+            if answer_errors and "error" in (answer or {}):
+                return answer
             raise RuntimeError(f"{url}: {err}") from None
 
     def master_get(self, path: str, **params) -> dict:
@@ -68,8 +77,8 @@ class CommandEnv:
         qs = ("?" + urllib.parse.urlencode(params)) if params else ""
         return self._call(f"{self.master}{path}{qs}", body or {})
 
-    def vs_post(self, url: str, path: str, body: dict) -> dict:
-        return self._call(f"{url}{path}", body)
+    def vs_post(self, url: str, path: str, body: dict, **kw) -> dict:
+        return self._call(f"{url}{path}", body, **kw)
 
     def master_get_raw(self, node_url: str, path: str, **params) -> dict:
         """GET a JSON endpoint on an arbitrary cluster node."""
@@ -403,21 +412,36 @@ def cmd_ec_rebuild(env: CommandEnv, args, out):
         _ec_rebuild_all(env, out)
 
 
+# how long `ec.rebuild` waits for a rebuilder's answer on one connection;
+# past it the job is followed on /admin/ec/progress until it ends
+REBUILD_CALL_S = 600.0
+REBUILD_POLL_S = 5.0
+
+
 def _ec_rebuild_all(env: CommandEnv, out) -> None:
+    """Every EC volume with shards missing gets a rebuilder (the node that
+    holds most of its shards) and the survivors it lacks are copied there;
+    then each rebuilder rebuilds its whole backlog in one
+    `/admin/ec/rebuild {"volumes": [...]}` call (one pipeline, each volume
+    committed as its rows are written: `_rebuild_backlog`), the borrowed
+    shards are deleted and every volume rebuilt is mounted.  One line a
+    volume, from the answer, skips included.  A rebuilder whose call
+    failed or was cancelled has its committed volumes mounted all the same,
+    and the next rebuilder is called; the command then fails, naming the
+    volumes left as they were."""
+    from seaweedfs_tpu.ops import codecs as _codecs
     topo = env.topology()
     ec_vids = {int(v) for node in topo["nodes"].values()
                for v in node["ec_shards"]}
+    try:
+        health = env.master_get("/maintenance/status").get("volumes", {})
+    except RuntimeError:
+        health = {}
+    backlog: dict[str, list[tuple[int, list[int]]]] = {}
     for vid in sorted(ec_vids):
         shard_locs = env.ec_shard_locations(vid)
         present = set(shard_locs)
-        from seaweedfs_tpu.ops import codecs as _codecs
-        try:
-            health = env.master_get("/maintenance/status")
-            spec = _codecs.parse_tag(
-                (health.get("volumes", {}).get(str(vid)) or
-                 {}).get("codec"))
-        except RuntimeError:
-            spec = _codecs.parse_tag(None)
+        spec = _codecs.parse_tag((health.get(str(vid)) or {}).get("codec"))
         missing = [s for s in range(spec.n) if s not in present]
         if not missing:
             continue
@@ -441,12 +465,66 @@ def _ec_rebuild_all(env: CommandEnv, out) -> None:
                         {"volume": vid, "source": locs[0], "shards": [s],
                          "copy_ecx": False})
             borrowed.append(s)
-        r = env.vs_post(rebuilder, "/admin/ec/rebuild", {"volume": vid})
-        env.vs_post(rebuilder, "/admin/ec/delete_shards",
-                    {"volume": vid, "shards": borrowed})
-        env.vs_post(rebuilder, "/admin/ec/mount", {"volume": vid})
-        print(f"volume {vid}: rebuilt {r.get('rebuilt')} on {rebuilder}",
-              file=out)
+        backlog.setdefault(rebuilder, []).append((vid, borrowed))
+    failed: list[int] = []
+    for rebuilder, vols in backlog.items():
+        r = _rebuild_backlog(env, rebuilder, [vid for vid, _ in vols], out)
+        if r is None:  # the rebuilder cannot be asked: its files as they are
+            failed += [vid for vid, _ in vols]
+            continue
+        rebuilt, skipped = r.get("rebuilt", {}), r.get("skipped", {})
+        for vid, borrowed in vols:
+            env.vs_post(rebuilder, "/admin/ec/delete_shards",
+                        {"volume": vid, "shards": borrowed})
+            if str(vid) in rebuilt:
+                env.vs_post(rebuilder, "/admin/ec/mount", {"volume": vid})
+                print(f"volume {vid}: rebuilt {rebuilt[str(vid)]} on "
+                      f"{rebuilder}", file=out)
+            elif str(vid) in skipped:
+                print(f"volume {vid}: not rebuilt on {rebuilder}: "
+                      f"{skipped[str(vid)]}", file=out)
+            else:
+                failed.append(vid)
+                print(f"volume {vid}: not rebuilt on {rebuilder}: "
+                      f"{r.get('error', 'no answer')}", file=out)
+    if failed:
+        raise RuntimeError(f"ec.rebuild: volumes {failed} not rebuilt")
+
+
+def _rebuild_backlog(env: CommandEnv, rebuilder: str, vids: list[int],
+                     out) -> dict | None:
+    """The answer of one list call to `rebuilder`: a 409 or 500 answer too,
+    which says the volumes committed before the cancel or failure.  Where
+    no answer comes within REBUILD_CALL_S the server goes on rebuilding,
+    and its job's answer is read from /admin/ec/progress under one of the
+    listed vids once the job has ended.  None where the rebuilder cannot
+    be asked."""
+    try:
+        return env.vs_post(rebuilder, "/admin/ec/rebuild", {"volumes": vids},
+                           timeout=REBUILD_CALL_S, answer_errors=True)
+    except TimeoutError:
+        print(f"{rebuilder}: no answer in {REBUILD_CALL_S:.0f} s, following "
+              f"its job", file=out)
+    except (RuntimeError, OSError) as e:
+        print(f"{rebuilder}: {e}", file=out)
+        return None
+    while True:
+        job = None
+        for vid in vids:
+            try:
+                j = env.master_get_raw(rebuilder, "/admin/ec/progress",
+                                       volumeId=str(vid))
+            except (RuntimeError, OSError):
+                continue  # no job under this vid: it was skipped
+            if j.get("kind") == "rebuild" and vid in j.get("volumes", ()):
+                job = j
+                break
+        if job is None:
+            print(f"{rebuilder}: no rebuild job of volumes {vids}", file=out)
+            return None
+        if job.get("state") != "running":
+            return job.get("answer")
+        time.sleep(REBUILD_POLL_S)
 
 
 @command("ec.codecs")

@@ -282,6 +282,20 @@ class PipelineJob:
         """What ties a profiler annotation back to this run."""
         return {"job": self.job_id}
 
+    def annotate(self, name: str, **attrs):
+        """A profiler annotation `name` with the job's ids and `attrs`,
+        entered now, or None while no profiler session is open: the caller
+        exits it, on the same thread, where what it marks ends.  For a part
+        of a call that is neither the job nor a stage (a volume of a
+        backlog rebuild): named outside `job.`, `ec.` and `codec.`, so that
+        no reader of the trace takes it for either."""
+        ann = _profiler_annotation()
+        if ann is None:
+            return None
+        ann = ann(name, **self.annotation_ids(), **attrs)
+        ann.__enter__()
+        return ann
+
     def _book(self, name: str, secs: float, nbytes: float, items: float,
               blocked: bool) -> None:
         with self._lock:

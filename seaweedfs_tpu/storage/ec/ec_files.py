@@ -20,6 +20,7 @@ Functions mirror the reference's capability surface:
 
 from __future__ import annotations
 
+import collections
 import mmap
 import os
 import queue
@@ -651,6 +652,12 @@ class _ShardWriterPool:
     def failed(self) -> bool:
         return bool(self.errors)
 
+    @property
+    def idle(self) -> bool:
+        """Every write queued so far has been written (asked without a
+        wait: a rebuild commits a volume mid-call only once it is)."""
+        return not any(q.unfinished_tasks for q in self._queues)
+
     def _q(self, shard: int) -> queue.Queue:
         return self._queues[shard % self._nworkers]
 
@@ -689,6 +696,7 @@ class _ShardWriterPool:
                 self._busy[shard] += st.seconds
             for rel in releases:
                 rel()
+            q.task_done()
 
     def _write_batch(self, shard: int, item: list, releases: list) -> None:
         """Write one queue item's jobs; every error is kept for close(),
@@ -742,6 +750,16 @@ class _ShardWriterPool:
     def flush(self) -> None:
         pass
 
+    def stop(self) -> None:
+        """Tell the workers that nothing more will be queued: each ends
+        once its queue is written.  Idempotent; close() joins them.  Many
+        pools stopped first and closed after wait for their threads side
+        by side, not one pool after the other."""
+        if not getattr(self, "_stopped", False):
+            self._stopped = True
+            for q in self._queues:
+                q.put(None)
+
     def close(self, unit: int | None = None) -> None:
         """Drain every queue, join the workers, fold the thread capacity
         behind each stage into stats.  Idempotent, and does not raise —
@@ -753,8 +771,7 @@ class _ShardWriterPool:
             return
         self._closed = True
         with self._job.blocked("join_writers", unit=unit):
-            for q in self._queues:
-                q.put(None)
+            self.stop()
             for t in self._threads:
                 t.join()
         if self._stats is not None:
@@ -1196,35 +1213,217 @@ def basis_kind(codec, use: list[int]) -> str:
 
 
 def _write_rows(writers: "_ShardWriterPool", opool: queue.Queue,
-                obuf: np.ndarray, n: int, off: int) -> None:
-    """Hand a rebuild batch's rows, `obuf[r, :n]` for lost shard r, to
-    their writers at `off`; the buffer goes back to the output ring once
-    every writer is done with its row."""
-    release = _countdown(len(obuf), lambda: opool.put(obuf))
-    for r in range(len(obuf)):
+                obuf: np.ndarray, rows: int, n: int, off: int) -> None:
+    """Hand a rebuild batch's rows, `obuf[r, :n]` for the volume's r-th
+    lost shard (r < rows), to their writers at `off`; the buffer goes back
+    to the output ring once every writer is done with its row."""
+    release = _countdown(rows, lambda: opool.put(obuf))
+    for r in range(rows):
         writers.put(r, obuf[r, :n], off, release=release)
 
 
-def _rebuild_pipelined(codec, views: dict, use: list[int],
-                       missing: list[int], shard_size: int, batch_size: int,
-                       writers: "_ShardWriterPool", opool: queue.Queue, pjob,
-                       progress=None, cancel=None) -> None:
-    """Rebuild's batches through the dispatch seam, encode's shape
-    (`_encode_pipelined`) with the reader and the dispatcher one thread: no
-    byte moves on the host before a put, so there is nothing to read ahead.
+# why a listed volume is answered under `skipped` with its files untouched
+NOTHING_MISSING = "no shard missing"
 
-      caller   walks the batches: waits for one of PIPELINE_DEPTH slots
-               (`stall`), selects the batch's rows in the maps (`stage`: no
-               byte moves) and enqueues them (the seam's `h2d` and
-               `dispatch`).  A device codec's result comes back
+
+class _RebuildVolume:
+    """One volume of a rebuild call: its code, the shards it lacks, the
+    survivors its decode reads (`use`) and, once opened, the survivor files
+    and their maps, its `.tmp` outputs and their writer pool.  A batch is
+    `batch` bytes of each survivor file at one offset.  `enqueued` is
+    moved by the calling thread alone and `drained` by the drain alone, so
+    `enqueued > drained` says, without a lock, that a batch of the volume
+    is out.  `state`: planned, open, committed or rolled back; opened,
+    committed and rolled back on the calling thread, which also holds the
+    volume's profiler annotation over that span."""
+
+    def __init__(self, base: str, spec, codec, present: list[int],
+                 missing: list[int], batch_size: int):
+        self.base, self.spec, self.codec = base, spec, codec
+        self.present, self.missing = present, missing
+        self.use = _survivor_basis(codec, present, missing)
+        self.shard_size = os.path.getsize(base + layout.to_ext(self.use[0]))
+        # MSR sub-packetization: every chunk a codec's interleave must see
+        # is an alpha multiple (shard files themselves are block-multiples)
+        self.batch = batch_size
+        if spec.alpha > 1:
+            self.batch = max(spec.alpha, batch_size - batch_size % spec.alpha)
+            if self.shard_size % spec.alpha:
+                raise ValueError(f"shard size {self.shard_size} not "
+                                 f"{spec.alpha}-aligned")
+        self.tmp_paths = {i: base + layout.to_ext(i) + ".tmp"
+                          for i in missing}
+        self.ins: dict[int, object] = {}
+        self.maps: dict = {}
+        self.views: dict = {}
+        self.out_fds: dict[int, int] = {}
+        self.writers: _ShardWriterPool | None = None
+        self.enqueued = self.drained = 0
+        self.state = "planned"
+        self._ann = None
+
+    def offsets(self) -> range:
+        return range(0, self.shard_size, self.batch)
+
+    def open(self, pjob) -> None:
+        """Survivors opened, tmp outputs created, the writer pool started
+        (`open`), the survivors mapped with no page made ready (`map`)."""
+        self.state = "open"
+        name = os.path.basename(self.base)
+        self._ann = pjob.annotate(
+            "rebuild.volume", volume=name, vid=name.rpartition("_")[2],
+            lost=",".join(map(str, self.missing)))
+        with pjob.stage("open", files=len(self.use) + len(self.missing)):
+            for i in self.use:
+                self.ins[i] = open(self.base + layout.to_ext(i), "rb")
+            for i, p_ in self.tmp_paths.items():
+                self.out_fds[i] = os.open(p_, os.O_RDWR | os.O_CREAT, 0o644)
+            self.writers = _ShardWriterPool(
+                [self.out_fds[i] for i in self.missing], None, pjob)
+        with pjob.stage("map", files=len(self.use),
+                        bytes=self.shard_size * len(self.use)):
+            for i, f in self.ins.items():
+                if self.shard_size:
+                    mm = _map_lazy(f.fileno())
+                    self.maps[i] = mm
+                    self.views[i] = np.frombuffer(mm, dtype=np.uint8)
+
+    def commit(self, pjob) -> None:
+        """The outputs cut to size and renamed into place (`commit`) once
+        the writer pool has written everything queued: at once where it is
+        `idle` (its threads are joined with the call's other pools at the
+        end), else after its close (`join_writers`).  The pool's first
+        error is raised instead, and the volume left for `roll_back`."""
+        if not self.writers.idle:
+            self.writers.close()
+        if self.writers.errors:
+            raise self.writers.errors[0]
+        with pjob.stage("commit"):
+            for fd in self.out_fds.values():
+                os.ftruncate(fd, self.shard_size)
+            self._release(commit=True)
+
+    def roll_back(self, pjob) -> None:
+        """Whatever was opened closed, the tmp outputs removed: the volume's
+        shard files are as they were before the call."""
+        if self.writers is not None:
+            self.writers.close()  # idempotent; the fds must outlive it
+        with pjob.stage("commit"):
+            self._release(commit=False)
+
+    def _release(self, commit: bool) -> None:
+        """The runtime reads a row after its put returns: every batch's
+        views died with its queue item before this is reached.  Where a
+        reference outlives the walk all the same (a traceback, the CPU
+        backend aliasing an aligned view of the read-only maps)
+        `mm.close()` raises BufferError, let pass: the mapping goes with
+        its last view."""
+        for f in self.ins.values():
+            f.close()
+        self.views.clear()
+        for mm in self.maps.values():
+            try:
+                mm.close()
+            except BufferError:
+                pass
+        for fd in self.out_fds.values():
+            os.close(fd)
+        if commit:
+            for i, p_ in self.tmp_paths.items():
+                os.replace(p_, self.base + layout.to_ext(i))
+        else:
+            for p_ in self.tmp_paths.values():
+                try:
+                    os.remove(p_)
+                except OSError:
+                    pass
+        self.state = "committed" if commit else "rolled back"
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+
+def _pool_failure(vols: list[_RebuildVolume]) -> BaseException | None:
+    """The first writer error of the first volume the walk left open."""
+    for vol in vols:
+        if vol.state == "open":
+            vol.writers.close()
+            return vol.writers.errors[0] if vol.writers.errors else None
+    return None
+
+
+def _boundaries(pjob, crossed: list[int]) -> None:
+    """`boundaries_in_flight`: of the call's volume boundaries the walk
+    reached, the share at which the next volume's first batch was enqueued
+    while a batch of the volume before was out (`crossed`: [those, all])."""
+    if crossed[1]:
+        pjob.stats["boundaries_in_flight"] = round(crossed[0] / crossed[1], 4)
+
+
+def _rebuild_host_serial(vols: list[_RebuildVolume], opool: queue.Queue,
+                         pjob, progress, cancel, commit) -> None:
+    """The native host codec's loop: volume after volume, each batch's
+    decode matmul straight off the maps into the output ring on the
+    calling thread (booked whole as `reconstruct`) while the writers have
+    the batch before; a volume commits when its rows are written."""
+    from seaweedfs_tpu import native
+    done = unit = 0
+    for j, vol in enumerate(vols):
+        vol.open(pjob)
+        dec_mat = vol.codec.code.decode_matrix(list(vol.present),
+                                               list(vol.missing))
+        rows_out = len(vol.missing)
+        for off in vol.offsets():
+            if cancel is not None and cancel():
+                raise EncodeCancelled("ec rebuild cancelled")
+            if vol.writers.failed:
+                break
+            n = min(vol.batch, vol.shard_size - off)
+            with pjob.blocked("stall", unit=unit):
+                obuf = opool.get()
+            with pjob.stage("reconstruct", unit=unit) as st:
+                rows = [vol.views[i][off:off + n] for i in vol.use]
+                native.gf_matmul_ptrs(dec_mat, rows, list(obuf[:rows_out]),
+                                      n)
+            pjob.count("spans_mapped", len(rows))
+            _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
+                                    nbytes=len(vol.use) * n)
+            _write_rows(vol.writers, opool, obuf, rows_out, n, off)
+            unit += 1
+            done += n * len(vol.use)
+            if progress is not None:
+                progress(done)
+        commit(vol)  # the writers' first error is raised here
+        _boundaries(pjob, [0, j])
+
+
+def _rebuild_pipelined(vols: list[_RebuildVolume], opool: queue.Queue,
+                       pjob, progress=None, cancel=None,
+                       commit=None) -> None:
+    """Rebuild's batches through the dispatch seam, encode's shape
+    (`_encode_pipelined`) with the reader and the dispatcher one thread,
+    and one pipeline over every volume of the call: no byte moves on the
+    host before a put, so there is nothing to read ahead.
+
+      caller   walks the volumes and their batches: opens and maps a
+               volume, then for each batch waits for one of
+               PIPELINE_DEPTH slots (`stall`), selects the batch's rows in
+               the volume's maps (`stage`: no byte moves) and enqueues them
+               with the volume's own survivors and lost shards (the seam's
+               `h2d` and `dispatch`).  A device codec's result comes back
                un-materialised, so batch N+1's rows go up while batch N is
-               out; a host codec behind the seam computes here
+               out, and the next volume's first batch while the last
+               batches of the one before are (no drain between volumes); a
+               host codec behind the seam computes here.  Between batches
+               it commits every volume whose rows the writers have all
+               written (`_ShardWriterPool.idle`: no wait)
       drain    materialises batch N (the seam's `device_wait` and
                `d2h_copy`), frees its slot, waits for a buffer of the output
                ring (`stall`), copies the rebuilt rows into it (`unstage`)
-               and hands each to its shard's writer
-      writers  `_ShardWriterPool`; a buffer returns to the ring once every
-               writer is done with its row
+               and hands each to its volume's writer for that shard; after a
+               volume's last batch it hands the volume back to the caller
+      writers  one `_ShardWriterPool` a volume; a buffer returns to the ring
+               once every writer is done with its row
 
     The six stages book to the one job from two threads and add up to
     `reconstruct` (REBUILD_SUMS), which may so pass the wall.  A batch's
@@ -1233,15 +1432,21 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
     ends (the last batch, `cancel`, a failed writer, an exception from
     either half of the seam), every batch that went up is waited for and
     the drain thread joined before this returns: the caller closes the maps
-    next.  The first error is raised here; a failed writer's is the
-    caller's to raise, after the pool's close.  The job's gauge `inflight`
-    counts the batches between enqueue and materialised result
-    (`stats["inflight_max"]`, `inflight_avg`, `inflight_ge2_frac`); the
-    drain's wait for the next one is `await_batch`, the caller's for the
-    drain to end `join_drain`."""
+    next.  Then every volume whose rows all reached its writers commits, in
+    order, up to the first whose writers failed; the rest are the caller's
+    to roll back.  The first error is raised here: what ended the walk,
+    else the drain's, else a commit's, else the first failed writer pool's.
+    The job's gauge `inflight` counts the batches between enqueue and
+    materialised result (`stats["inflight_max"]`, `inflight_avg`,
+    `inflight_ge2_frac`); the drain's wait for the next one is
+    `await_batch`, the caller's for the drain to end `join_drain`."""
     slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
     q_out: queue.Queue = queue.Queue()  # unbounded: `slots` is the bound
+    # volumes whose every row is with their writers, in order (the drain
+    # appends, the caller pops)
+    whole: collections.deque = collections.deque()
     errors: list[BaseException] = []
+    halted: list[bool] = []  # the drain hands no more rows or volumes on
 
     def drain() -> None:
         while True:
@@ -1249,273 +1454,296 @@ def _rebuild_pipelined(codec, views: dict, use: list[int],
                 item = q_out.get()
             if item is None:
                 return
-            unit, off, n, pending = item
+            vol, unit, off, n, last, pending = item
+            if pending is None:  # a volume of empty files: no batch
+                del item
+                if not halted:
+                    whole.append(vol)
+                continue
             try:
                 # a batch of a run that failed is waited for like any other:
                 # the device may be reading its rows in the maps
                 rebuilt = _materialize_rows(pending, job=pjob, unit=unit)
             except BaseException as e:  # raised by the caller's thread
                 errors.append(e)
+                halted.append(True)
                 continue
             finally:
                 del item, pending  # the device is done with the host memory
+                vol.drained += 1
                 pjob.occupancy("inflight", -1)
                 slots.release()
-            if errors or writers.failed:
+            if halted or vol.writers.failed:
+                halted.append(True)
                 continue
             with pjob.blocked("stall", unit=unit):
                 obuf = opool.get()
             with pjob.stage("unstage", unit=unit):
-                for r, i in enumerate(missing):
+                for r, i in enumerate(vol.missing):
                     np.copyto(obuf[r, :n], rebuilt[i])
             del rebuilt
-            _write_rows(writers, opool, obuf, n, off)
+            _write_rows(vol.writers, opool, obuf, len(vol.missing), n, off)
+            if last:
+                whole.append(vol)
+
+    failed_commit: list[bool] = []  # no volume commits after one failed
+
+    def commit_whole(wait: bool) -> None:
+        """Commit the volumes whose rows are all with their writers, in
+        order, until one fails; mid-walk (`wait` false) only those whose
+        writes are done, so that the caller is not held from the next
+        enqueue."""
+        while whole and not failed_commit and (wait or whole[0].writers.idle):
+            try:
+                commit(whole.popleft())
+            except Exception:
+                failed_commit.append(True)
+                raise
+
+    crossed = [0, 0]
+
+    def walk() -> None:
+        done = unit = 0
+        for j, vol in enumerate(vols):
+            commit_whole(False)
+            vol.open(pjob)
+            offsets = vol.offsets()
+            if not offsets:  # in order behind the batches before it
+                q_out.put((vol, None, 0, 0, True, None))
+            for b, off in enumerate(offsets):
+                if cancel is not None and cancel():
+                    raise EncodeCancelled("ec rebuild cancelled")
+                if errors or any(v.writers.failed for v in vols
+                                 if v.state == "open"):
+                    return
+                n = min(vol.batch, vol.shard_size - off)
+                with pjob.blocked("stall", unit=unit):
+                    slots.acquire()
+                try:
+                    with pjob.stage("stage", unit=unit):
+                        rows = [vol.views[i][off:off + n] for i in vol.use]
+                    pjob.count("spans_mapped", len(rows))
+                    pending = _dispatch_reconstruct(
+                        vol.codec, rows, vol.use, vol.missing, job=pjob,
+                        unit=unit)
+                except BaseException:
+                    slots.release()
+                    raise
+                if b == 0 and j:
+                    before = vols[j - 1]
+                    crossed[0] += before.enqueued > before.drained
+                    crossed[1] += 1
+                vol.enqueued += 1
+                pjob.occupancy("inflight", +1)
+                q_out.put((vol, unit, off, n, b == len(offsets) - 1,
+                           pending))
+                del rows, pending  # the queue item alone holds the views
+                unit += 1
+                done += n * len(vol.use)
+                if progress is not None:
+                    progress(done)
+                commit_whole(False)
 
     t_d = threading.Thread(target=drain, name="ec-rebuild-drain",
                            daemon=True)
     with pjob.stage("open"):
         t_d.start()
-    done = 0
+    failure = None
     try:
-        for unit, off in enumerate(range(0, shard_size, batch_size)):
-            if cancel is not None and cancel():
-                raise EncodeCancelled("ec rebuild cancelled")
-            if errors or writers.failed:
-                break
-            n = min(batch_size, shard_size - off)
-            with pjob.blocked("stall", unit=unit):
-                slots.acquire()
-            try:
-                with pjob.stage("stage", unit=unit):
-                    rows = [views[i][off:off + n] for i in use]
-                pjob.count("spans_mapped", len(rows))
-                pending = _dispatch_reconstruct(codec, rows, use, missing,
-                                                job=pjob, unit=unit)
-            except BaseException:
-                slots.release()
-                raise
-            pjob.occupancy("inflight", +1)
-            q_out.put((unit, off, n, pending))
-            del rows, pending  # the queue item alone holds a batch's views
-            done += n * len(use)
-            if progress is not None:
-                progress(done)
+        walk()
+    except BaseException as e:  # raised below, once the drain is joined
+        failure = e
     finally:
         with pjob.blocked("join_drain"):
             q_out.put(None)
             t_d.join()
-    if errors:
-        raise errors[0]
+        _boundaries(pjob, crossed)
+        for vol in vols:  # no row is handed on any more
+            if vol.writers is not None:
+                vol.writers.stop()
+    failure = failure or (errors[0] if errors else None)
+    try:
+        commit_whole(True)
+    except Exception as e:  # a failed writer pool's error, re-raised below
+        failure = failure or e
+    failure = failure or _pool_failure(vols)
+    if failure is not None:
+        raise failure
 
 
-def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
-                     progress=None, cancel=None, stats=None,
-                     codec_tag: str | None = None) -> list[int]:
-    """Regenerate whichever `.ecXX` files are missing from the present
-    ones, under the code the `.vif` names. Returns the rebuilt shard ids.
-    Only the survivors of the code's basis are opened and mapped
-    (`_survivor_basis`): any k for an MDS code, the r of one local group
-    for an LRC's one-lost repair; `stats` says how many and which kind
-    (`survivors`, `basis`).
+def rebuild_ec_volumes(bases: list[str], batch_size: int = DEFAULT_BATCH,
+                       progress=None, cancel=None, stats=None,
+                       codec_tags: list | None = None) -> dict:
+    """Regenerate whichever `.ecXX` files are missing from each set of
+    `bases` (a rebuilder's backlog: `ec.rebuild` after a server is lost),
+    each under the code its `.vif` names (or `codec_tags[i]`), in one call
+    and one pipeline.  -> the report: `rebuilt` {base: the shard ids
+    rebuilt} for every volume committed, `skipped` {base: why} for every
+    volume left as it was before anything was opened: nothing missing
+    (`NOTHING_MISSING`), or fewer survivors than the code's k.  A call that
+    raises hands the same report on as the exception's `report`.
+
+    *What commits when.*  A volume commits by rename as soon as its last
+    rows are written (the tmp outputs cut to size and renamed into place
+    on the calling thread, between batches of the volumes after it), not
+    at the end of the call: volumes already committed stay so whatever
+    happens later.  A call that ends early (`cancel()`, a failed writer, an
+    exception from either half of the dispatch seam, a vanished survivor)
+    raises what ended it once every batch that went up is back; by then
+    every volume whose rows had all reached its writers is committed, in
+    order, up to the first whose writers failed, and the volume in flight
+    and every one after it are untouched (their tmp outputs removed, no
+    file renamed).  The report's `rebuilt` says which volumes committed, on
+    either way out.
+
+    Per volume, only the survivors of the code's basis are opened and
+    mapped (`_survivor_basis`): any k for an MDS code, the r of one local
+    group for an LRC's one-lost repair; `stats` says how many a volume
+    reads and which kind (`survivors`, the most over the volumes; `basis`,
+    `mixed` where the volumes differ), how many volumes the call walked
+    (`volumes`) and how many rows a batch rebuilds (`lost_rows`, the most
+    over the volumes).
 
     The encode path's observability (`progress(bytes_done)` per batch over
-    survivor bytes, `cancel()` aborts, `stats` gets per-stage seconds +
-    overlap_frac) and its zero-copy reads: survivor shards are mmap'd
-    (`_map_lazy`, stage `map`: no page is made ready before the first put,
-    a batch's faults are taken by whoever reads its rows) and a
-    batch is its rows where they lie in the maps (`stats["spans_mapped"]`
-    counts them: batches x survivors), handed to the native
-    decode matmul by row pointer or to the dispatch seam as views, which a
-    device codec puts up uncopied where they are a whole bucket wide
-    (`ops/dispatch.ROW_PUTS_FROM`; `stats["rows_staged"]` counts the rows
-    copied first: a short last batch).  Rebuilt shards land in a
-    countdown-released buffer ring and stream to per-shard writer workers
-    into recycled `.tmp` inodes, committed by rename only on success
-    (reference: RebuildEcFiles, ec_encoder.go:237-291).
+    survivor bytes of the whole call, `cancel()` aborts, `stats` gets
+    per-stage seconds summed over the volumes + overlap_frac) and its
+    zero-copy reads: survivor shards are mmap'd (`_map_lazy`, stage `map`:
+    no page is made ready before the first put, a batch's faults are taken
+    by whoever reads its rows) and a batch is one volume's rows where they
+    lie in the maps (`stats["spans_mapped"]` counts them: batches x
+    survivors), handed to the native decode matmul by row pointer or to
+    the dispatch seam as views, which a device codec puts up uncopied where
+    they are a whole bucket wide (`ops/dispatch.ROW_PUTS_FROM`;
+    `stats["rows_staged"]` counts the rows copied first: a short last
+    batch).  Rebuilt shards land in a countdown-released buffer ring, one
+    for the call, and stream to each volume's per-shard writer workers into
+    recycled `.tmp` inodes (reference: RebuildEcFiles,
+    ec_encoder.go:237-291; the backlog is `ec.rebuild`'s loop,
+    command_ec_rebuild.go).
 
-    What overlaps follows from the codec.  The native host codec decodes
-    batch N on the calling thread while the writers have batch N-1
-    (`stats["mode"]` `host-serial`).  Every other codec goes through
-    encode's reader -> dispatch -> drain shape (`_rebuild_pipelined`,
-    `pipelined`): up to PIPELINE_DEPTH batches are between their put and
-    their materialised result, batch N+1's rows going up while batch N's
-    program, copy back, `unstage` and writes run; `stats["inflight_max"]`
-    says how many there were at most.
+    What overlaps follows from the codecs.  Where every volume's is the
+    native host codec, each batch is decoded on the calling thread while
+    the writers have the batch before, volume after volume (`stats["mode"]`
+    `host-serial`).  Otherwise every volume goes through encode's reader ->
+    dispatch -> drain shape (`_rebuild_pipelined`, `pipelined`): up to
+    PIPELINE_DEPTH batches, of one volume or of two, are between their put
+    and their materialised result, batch N+1's rows going up while batch
+    N's program, copy back, `unstage` and writes run;
+    `stats["inflight_max"]` says how many there were at most and
+    `boundaries_in_flight` at what share of the volume boundaries a batch
+    of the volume before was still out (1.0: no drain between volumes).
+    Each volume is on the profiler's trace as `rebuild.volume` (its name,
+    vid and lost shards) from its open to its commit."""
+    report: dict = {"rebuilt": {}, "skipped": {}}
+    try:
+        _rebuild_volumes(bases, batch_size, progress, cancel, stats,
+                         codec_tags, report)
+    except Exception as e:
+        e.report = report  # what committed before it, and what was skipped
+        raise
+    return report
 
-    The runtime reads a row after its put returns, so a batch's views and
-    device arrays must be dead before the maps close: they ride the
-    batch's queue item, which dies when its result is materialised, and
-    the drain thread is joined, whatever ended the loop, before this
-    function's `finally` closes anything.  Where a reference outlives the
-    loop all the same (a traceback, the CPU backend aliasing an aligned
-    view of the read-only maps) `mm.close()` raises `BufferError`, let
-    pass: the mapping goes with its last view."""
+
+def _rebuild_volumes(bases, batch_size, progress, cancel, stats, codec_tags,
+                     report: dict) -> None:
+    """`rebuild_ec_volumes`' body, filling its report's `rebuilt` and
+    `skipped` as it goes."""
     from seaweedfs_tpu.ops import codecs as _codecs
-    spec = _codecs.parse_tag(codec_tag or
-                             (read_vif(base) or {}).get("codec"))
-    present = [i for i in range(spec.n)
-               if os.path.exists(base + layout.to_ext(i))]
-    missing = [i for i in range(spec.n) if i not in present]
-    if not missing:
-        return []
-    if len(present) < spec.k:
-        raise ValueError(
-            f"need >= {spec.k} shards to rebuild, have {len(present)}")
-    # chaos hook: fail like a dying disk BEFORE tmp shard files exist
     from seaweedfs_tpu.maintenance import faults as _faults
-    _faults.check_shard_write(base)
-    codec = _get_codec(tag=spec.tag)
-    use = _survivor_basis(codec, present, missing)
-    shard_size = os.path.getsize(base + layout.to_ext(use[0]))
+    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
+    rebuilt, skipped = report["rebuilt"], report["skipped"]
+    vols: list[_RebuildVolume] = []
+    for base, tag in zip(bases, codec_tags or [None] * len(bases)):
+        spec = _codecs.parse_tag(tag or (read_vif(base) or {}).get("codec"))
+        present = [i for i in range(spec.n)
+                   if os.path.exists(base + layout.to_ext(i))]
+        missing = [i for i in range(spec.n) if i not in present]
+        if not missing:
+            skipped[base] = NOTHING_MISSING
+            continue
+        if len(present) < spec.k:
+            skipped[base] = (f"need >= {spec.k} shards to rebuild, "
+                             f"have {len(present)}")
+            continue
+        # chaos hook: fail like a dying disk BEFORE tmp shard files exist
+        _faults.check_shard_write(base)
+        vols.append(_RebuildVolume(base, spec, _get_codec(tag=spec.tag),
+                                   present, missing, batch_size))
+    if not vols:
+        return
     stats = stats if stats is not None else {}
-    stats["bytes"] = shard_size * len(use)
-    stats["codec"] = spec.tag
-    # what the rebuild reads: how many survivor files it stages and
+    tags = sorted({v.spec.tag for v in vols})
+    kinds = sorted({basis_kind(v.codec, v.use) for v in vols})
+    lost_rows = max(len(v.missing) for v in vols)
+    stats["bytes"] = sum(v.shard_size * len(v.use) for v in vols)
+    stats["codec"] = ",".join(tags)
+    stats["volumes"] = len(vols)
+    stats["lost_rows"] = lost_rows
+    # what the rebuild reads: how many survivor files a volume stages and
     # whether they are one local group (/admin/ec/progress `stages`)
-    stats["survivors"] = len(use)
-    stats["basis"] = basis_kind(codec, use)
+    stats["survivors"] = max(len(v.use) for v in vols)
+    stats["basis"] = kinds[0] if len(kinds) == 1 else "mixed"
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
     stats["in_place"] = 0  # batches read where they were put (the seam too)
     stats["spans_mapped"] = 0  # rows selected in the maps: batches x survivors
     stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
-    # MSR sub-packetization: every chunk a codec's interleave must see is
-    # an alpha multiple (shard files themselves are block-multiples)
-    if spec.alpha > 1:
-        batch_size = max(spec.alpha,
-                         batch_size - batch_size % spec.alpha)
-        if shard_size % spec.alpha:
-            raise ValueError(
-                f"shard size {shard_size} not {spec.alpha}-aligned")
-
-    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
-    native_host = isinstance(codec, NativeRSCodec)
+    native_host = all(isinstance(v.codec, NativeRSCodec) for v in vols)
     stats["mode"] = "host-serial" if native_host else "pipelined"
-    if native_host:
-        from seaweedfs_tpu import native
-        dec_mat = codec.code.decode_matrix(list(present), list(missing))
 
     # a rebuild IS repair work: unless a caller already declared a class
     # (the planner's header re-entered through the middleware), any
     # network hop made on this thread while we run — a remote
     # shard_reader for survivors not on local disk — books as repair
     _flow_token = _netflow.set_class(_netflow.current_class() or "repair")
-    pjob = _pipeline.track("ec_rebuild", stats,
-                           shard_size * len(use),
-                           meta={"missing": len(missing),
-                                 "codec": spec.tag},
+    pjob = _pipeline.track("ec_rebuild", stats, stats["bytes"],
+                           meta={"volumes": len(vols), "missing": lost_rows,
+                                 "codec": stats["codec"]},
                            span="ec.rebuild", sums=REBUILD_SUMS)
     # the job's own span, round the stages named after it
-    job_span = _trace.span("ec.rebuild", codec=spec.tag,
-                           missing=len(missing),
+    job_span = _trace.span("ec.rebuild", codec=stats["codec"],
+                           volumes=len(vols), missing=lost_rows,
                            survivors=stats["survivors"],
                            basis=stats["basis"])
     job_span.__enter__()
     t_wall = time.perf_counter()
-    ins: dict[int, object] = {}
-    maps = {}
-    views = {}
-    tmp_paths = {i: base + layout.to_ext(i) + ".tmp" for i in missing}
-    out_fds: dict[int, int] = {}
-    writers = None
     ok = False
-    # setup runs under the same finally that seals the job: a survivor
+
+    def commit(vol: _RebuildVolume) -> None:
+        vol.commit(pjob)
+        rebuilt[vol.base] = list(vol.missing)
+
+    # the walk runs under the same finally that seals the job: a survivor
     # deleted between the present-list and open (a racing repair), or
     # ENOSPC on the tmp outputs, must not leak a forever-"running"
     # ec_rebuild entry on /debug/pipeline
     try:
-        with pjob.stage("open", files=len(use) + len(missing)):
-            for i in use:
-                ins[i] = open(base + layout.to_ext(i), "rb")
-            for i, p_ in tmp_paths.items():
-                out_fds[i] = os.open(p_, os.O_RDWR | os.O_CREAT, 0o644)
-            # reconstruction writes ride the same per-shard writer pool as
-            # the encode path: rebuilding 4 lost shards streams them to 4
-            # concurrent workers while the next batch's decode matmul runs.
-            # Pooled output buffers (countdown-released once every shard
-            # writer is done with its row) keep the decode from racing its
-            # own in-flight writes.
-            writers = _ShardWriterPool([out_fds[i] for i in missing], None,
-                                       pjob)
+        with pjob.stage("open"):
+            # the output ring, one for the call: rows of the most a batch
+            # rebuilds, as wide as the widest batch
+            width = max(min(v.batch, max(v.shard_size, 1)) for v in vols)
             opool: queue.Queue = queue.Queue()
             for _ in range(PIPELINE_DEPTH):
-                opool.put(np.empty(
-                    (len(missing), min(batch_size, max(shard_size, 1))),
-                    dtype=np.uint8))
-        # no page of a survivor is made ready here (_map_lazy): the first
-        # put waits for the maps alone, a batch reads its own span of each
-        with pjob.stage("map", files=len(use), bytes=shard_size * len(use)):
-            for i, f in ins.items():
-                if shard_size:
-                    mm = _map_lazy(f.fileno())
-                    maps[i] = mm
-                    views[i] = np.frombuffer(mm, dtype=np.uint8)
-        if native_host:
-            # the matmul straight off the maps into the output ring, a
-            # batch booked whole as `reconstruct`
-            done = 0
-            for unit, off in enumerate(range(0, shard_size, batch_size)):
-                if cancel is not None and cancel():
-                    raise EncodeCancelled("ec rebuild cancelled")
-                if writers.failed:
-                    break
-                n = min(batch_size, shard_size - off)
-                with pjob.blocked("stall", unit=unit):
-                    obuf = opool.get()
-                with pjob.stage("reconstruct", unit=unit) as st:
-                    rows = [views[i][off:off + n] for i in use]
-                    native.gf_matmul_ptrs(dec_mat, rows, list(obuf), n)
-                pjob.count("spans_mapped", len(rows))
-                _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
-                                        nbytes=len(use) * n)
-                _write_rows(writers, opool, obuf, n, off)
-                done += n * len(use)
-                if progress is not None:
-                    progress(done)
-        else:
-            _rebuild_pipelined(codec, views, use, missing, shard_size,
-                               batch_size, writers, opool, pjob, progress,
-                               cancel)
-        writers.close()  # `join_writers`
-        if writers.errors:
-            raise writers.errors[0]
-        with pjob.stage("commit"):
-            for fd in out_fds.values():
-                os.ftruncate(fd, shard_size)
+                opool.put(np.empty((lost_rows, width), dtype=np.uint8))
+        walk = _rebuild_host_serial if native_host else _rebuild_pipelined
+        walk(vols, opool, pjob, progress, cancel, commit)
         stats["wall_s"] = time.perf_counter() - t_wall
-        _book_stage_bytes(pjob, stats,
-                          shard_size * len(use),
-                          shard_size * len(missing))
+        _book_stage_bytes(pjob, stats, stats["bytes"],
+                          sum(v.shard_size * len(v.missing) for v in vols))
         ok = True
     finally:
         _netflow.reset(_flow_token)
         error = None if ok else (sys.exc_info()[1] or "rebuild failed")
         try:
-            if writers is not None:
-                # idempotent; the fds must outlive the workers
-                writers.close()
-            with pjob.stage("commit"):
-                for f in ins.values():
-                    f.close()
-                for i in list(views):
-                    del views[i]
-                for mm in maps.values():
-                    try:
-                        mm.close()
-                    except BufferError:
-                        pass
-                for fd in out_fds.values():
-                    os.close(fd)
-                if ok:
-                    for i, p_ in tmp_paths.items():
-                        os.replace(p_, base + layout.to_ext(i))
-                else:
-                    for p_ in tmp_paths.values():
-                        try:
-                            os.remove(p_)
-                        except OSError:
-                            pass
+            for vol in vols:
+                if vol.writers is not None:
+                    vol.writers.stop()
+            for vol in vols:
+                if vol.state == "open":
+                    vol.roll_back(pjob)
+                elif vol.writers is not None:
+                    vol.writers.close()  # a pool committed idle
         finally:
-            # the job is the call: it is sealed after the commit, and
+            # the job is the call: it is sealed after the last commit, and
             # after close() folded the writer-pool busy seconds into
             # stats — finish() exports the stage counters, and a failed
             # rebuild must not export zero write-stage occupancy.  The
@@ -1525,7 +1753,23 @@ def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
             _state_overlap(stats)
             pjob.finish(error)
             job_span.__exit__(*sys.exc_info())
-    return missing
+
+
+def rebuild_ec_files(base: str, batch_size: int = DEFAULT_BATCH,
+                     progress=None, cancel=None, stats=None,
+                     codec_tag: str | None = None) -> list[int]:
+    """Regenerate whichever `.ecXX` files are missing from the present
+    ones, under the code the `.vif` names (or `codec_tag`): the one-volume
+    case of `rebuild_ec_volumes`, which says how, what commits when, and
+    what a cancel or a failure leaves.  Returns the rebuilt shard ids, []
+    where none is missing; fewer survivors than the code's k is a
+    ValueError, before anything is opened."""
+    report = rebuild_ec_volumes([base], batch_size, progress, cancel, stats,
+                                codec_tags=[codec_tag])
+    why = report["skipped"].get(base)
+    if why is not None and why != NOTHING_MISSING:
+        raise ValueError(why)
+    return report["rebuilt"].get(base, [])
 
 
 def rebuild_ec_reduced(base: str, lost: list[int], groups: list[dict],

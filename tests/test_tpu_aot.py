@@ -105,12 +105,15 @@ WIDE = 16
     ("rs_10_4", "unit", WIDE * MIB, (4, 10)),
     ("lrc_12_2_2", "unit", WIDE * MIB, (4, 12)),
     ("rs_10_4", "column", WIDE * MIB, (4, 10)),
+    ("rs_10_4", [3, 10], 16 * MIB, (2, 10)),
+    ("rs_10_4", [3, 10], 10 * MIB, (2, 10)),
 ], ids=["encode_10_4", "rebuild_batch_1_row", "read_2_rows_smallest_bucket",
         "lrc_encode_4_12", "lrc_local_rebuild_batch_1_6",
         "lrc_local_read_smallest_bucket_1_6", "lrc_global_rebuild_2_12",
         "lrc_read_one_lost_in_each_group_2_12",
         "encode_unit_16_rows_10_4", "lrc_encode_unit_16_rows_4_12",
-        "encode_unit_column_cut_10_4"])
+        "encode_unit_column_cut_10_4", "node7_rebuild_batch_2_rows",
+        "node7_rebuild_short_batch_2_rows"])
 def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     """The served single-chip programs at TPU_TILE: [k, 1 MiB] under the
     parity matrix (the scrubber's 2-D window), the wide encode unit as the
@@ -127,7 +130,12 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     north star's 30 GB one, `vol30g.encode`'s at 1/32): a column cut, one
     stripe row of ten 16 MiB pieces a block apart in the `.dat`, laid out
     [10, 16 MiB] by the `stripes=1` program, the same kernel, four runs
-    back."""
+    back.  And the decode a server lost from a seven-server cluster leaves
+    every volume with (`node7.rebuild_2lost`: data shard 3 and parity
+    shard 10 of each), in place at `[2, 10]`, two rows back as one
+    `[2 * W]` run: at 16 MiB, the bucket both of a 256 MiB volume's
+    batches run at (its short 10 MiB batch is staged into it), and at
+    10 MiB, the width that batch would take up unstaged."""
     from seaweedfs_tpu.ops import codecs
     code = codecs._code_for(codecs.parse_tag(tag))
     column = wanted == "column"
