@@ -76,7 +76,7 @@ from seaweedfs_tpu.stats import pipeline as _pipeline
 from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
     DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
-    _iter_spans, _iter_units, _map_readonly, _ShardFlusher, _ShardWriterPool,
+    _iter_spans, _iter_units, _map_lazy, _ShardFlusher, _ShardWriterPool,
     _state_overlap, _unit_coverage, _unit_spans, _unit_steps, block_geometry,
     write_vif)
 
@@ -110,7 +110,8 @@ def fleet_codec(kind: str | None = None, tag: str | None = None):
 
 class _VolumeJob:
     """One volume mid-conversion: source map, recycled .tmp shard fds,
-    its writer pool, and completion accounting."""
+    its writer pool, and completion accounting.  Construction opens the
+    files and starts the pool; `map` maps the `.dat`."""
 
     def __init__(self, index: int, base: str, dat_path: str | None,
                  large_block: int, small_block: int, batch_size: int, pjob,
@@ -129,17 +130,14 @@ class _VolumeJob:
         k, n = spec.k, spec.n
         self.shard_size = layout.shard_file_size(
             self.dat_size, large_block, small_block, data_shards=k)
+        self.dat_f = open(self.dat_path, "rb")
         self.tmp_paths = [base + layout.to_ext(i) + ".tmp"
                           for i in range(n)]
         self.out_fds = [os.open(p, os.O_RDWR | os.O_CREAT, 0o644)
                         for p in self.tmp_paths]
         self.highwater = [0] * n
-        self.dat_f = open(self.dat_path, "rb")
         self.mm = None
         self.view: np.ndarray | None = None
-        if self.dat_size:
-            self.mm = _map_readonly(self.dat_f.fileno(), self.dat_size)
-            self.view = np.frombuffer(self.mm, dtype=np.uint8)
         self.writers = _ShardWriterPool(
             self.out_fds, self.highwater, pjob,
             stage_of=lambda i: "write_data" if i < k else "write_parity")
@@ -163,6 +161,13 @@ class _VolumeJob:
         self.done_bytes = 0
         self.committed = False
         self._pjob = pjob
+
+    def map(self) -> None:
+        """Map a non-empty `.dat` with no page made ready (_map_lazy): a
+        unit's span is faulted in by whoever reads it first."""
+        if self.dat_size:
+            self.mm = _map_lazy(self.dat_f.fileno())
+            self.view = np.frombuffer(self.mm, dtype=np.uint8)
 
     def next_unit(self):
         try:
@@ -290,6 +295,7 @@ def convert_volumes(bases: list[str], *,
     stats["unit_batch"] = U
     stats.update(codec=spec.tag, shard_files=spec.n, alpha=spec.alpha)
     stats["rows_staged"] = 0  # stripe rows copied on the host (pjob.count)
+    stats["spans_mapped"] = 0  # units selected in the volumes' maps (ditto)
     stats.update(units_column=0, units_rows=0)  # units that carried data
     # class=convert on THIS thread and (contextvars are per-thread) re-
     # stamped inside each pipeline thread, so any hop made on the
@@ -298,7 +304,7 @@ def convert_volumes(bases: list[str], *,
     _flow_token = _netflow.set_class(flow_cls)
     t_wall = time.perf_counter()
     # stages as the single-volume encode names them: the caller's `open`,
-    # `await_unit`, `join_drain` and `commit`, the reader's `read`,
+    # `map`, `await_unit`, `join_drain` and `commit`, the reader's `read`,
     # `ship_data` and `stall`, the drain's `await_parity`, the writers'
     # `write_data` and `write_parity` and each volume's `join_writers` and
     # `commit` (its index as `unit`), and the dispatch seam's four, which
@@ -307,16 +313,24 @@ def convert_volumes(bases: list[str], *,
     pjob = _pipeline.track("fleet_convert", stats,
                            meta={"volumes": len(bases), "unit_batch": U},
                            span="ec.fleet", sums=ENCODE_SUMS)
+    jobs: list[_VolumeJob] = []
     try:
         with pjob.stage("open", files=len(bases) * (spec.n + 1)) as st:
-            jobs = [_VolumeJob(i, b, None, large_block, small_block,
-                               batch_size, pjob, spans, spec)
-                    for i, b in enumerate(bases)]
+            for i, b in enumerate(bases):
+                jobs.append(_VolumeJob(i, b, None, large_block, small_block,
+                                       batch_size, pjob, spans, spec))
             stats["bytes"] = sum(j.dat_size for j in jobs)
             st.set(bytes=stats["bytes"])
             stats.update(block_geometry((j.dat_size for j in jobs),
                                         large_block, small_block, k))
-    except BaseException as e:  # a volume that cannot be opened: no run
+        with pjob.stage("map", files=sum(1 for j in jobs if j.dat_size),
+                        bytes=stats["bytes"]):
+            for job in jobs:
+                job.map()
+    except BaseException as e:  # a volume that cannot be opened or mapped
+        for job in jobs:
+            job.abort()
+            job.release()
         pjob.finish(e)
         raise
 
@@ -380,6 +394,7 @@ def convert_volumes(bases: list[str], *,
                     np.copyto(slot[j, :n], job.view[off:off + n])
                 slot[j, n:] = 0
         pjob.count("rows_staged", staged)
+        pjob.count("spans_mapped", 1)
         pjob.count("units_column" if step != block else "units_rows", 1)
         return pieces, (job, shard_off, rows * step), unit
 

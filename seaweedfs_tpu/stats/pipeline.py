@@ -22,9 +22,8 @@ stage bounds throughput, and what was the call doing meanwhile?":
   counts as busy:
 
     caller   ``open`` (tmp outputs created, sources opened, writer
-             pools and pipeline threads started, rings allocated; the
-             fleet's volumes mapped and populated), ``map`` (the
-             single-volume engines' sources mapped, no page made
+             pools and pipeline threads started, rings allocated),
+             ``map`` (every engine's sources mapped, no page made
              ready), ``await_unit`` (b: the dispatcher waiting for
              the reader's next unit), the seam's ``h2d`` and
              ``dispatch``, rebuild's ``stall`` (b) and ``stage``,

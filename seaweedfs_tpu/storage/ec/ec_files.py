@@ -84,45 +84,10 @@ def _writer_threads(nshards: int) -> int:
     return max(2, min(nshards, os.cpu_count() or 2))
 
 
-def _map_readonly(fd: int, size: int):
-    """Read-only map of a volume's .dat for fleet conversion
-    (ops/fleet_convert._VolumeJob), the caller that is left: the
-    single-volume encode and rebuild engines map through _map_lazy.
-
-    When the file plausibly fits in RAM (or WEEDTPU_EC_PREFAULT=always)
-    the map is created MAP_POPULATE: one batched kernel pass sets up
-    every PTE, measurably faster than the ~256k/GiB demand faults a
-    fresh mapping otherwise takes while the writer threads are
-    saturating the cores.  A volume bigger than a quarter of RAM (or
-    WEEDTPU_EC_PREFAULT=never) streams with plain demand faulting +
-    MADV_SEQUENTIAL readahead instead — populating it upfront would
-    serialize the whole disk read ahead of the first encoded byte and
-    churn the page cache."""
-    import mmap as mmap_mod
-    flags = mmap_mod.MAP_SHARED
-    populate = getattr(mmap_mod, "MAP_POPULATE", 0)
-    mode = os.environ.get("WEEDTPU_EC_PREFAULT", "auto")
-    if populate and mode != "never":
-        if mode == "always":
-            flags |= populate
-        else:
-            try:
-                ram = os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
-            except (ValueError, OSError, AttributeError):
-                ram = 0
-            if ram and size <= ram // 4:
-                flags |= populate
-    mm = mmap_mod.mmap(fd, 0, flags=flags, prot=mmap_mod.PROT_READ)
-    try:
-        mm.madvise(mmap_mod.MADV_SEQUENTIAL)
-    except (AttributeError, OSError):
-        pass
-    return mm
-
-
 def _map_lazy(fd: int):
-    """Read-only map of a source file for the single-volume encode and
-    rebuild engines, with no page made ready: a batch or a unit reads only
+    """Read-only map of a source file, the one form every bulk engine
+    maps with (single-volume encode and rebuild, a rebuild's backlog, the
+    fleet stream), with no page made ready: a batch or a unit reads only
     its own span of the map, and whoever reads a page first takes its
     fault, which for a device codec is the runtime's copy threads, beside
     the stream.  On the chip machine those faults cost a tenth of a
@@ -130,9 +95,8 @@ def _map_lazy(fd: int):
     by a quarter of a call, and a populated map a span, a page touched
     ahead and MADV_POPULATE_READ (refused there) each cost more than the
     faults they save (PERF.md section 6, PR 37).  So nothing is scheduled
-    and no size rule or knob applies: what _map_readonly's `never` gives
-    (MADV_SEQUENTIAL readahead for a file the page cache does not hold),
-    at any file size."""
+    and no size rule or knob applies: MADV_SEQUENTIAL readahead for a file
+    the page cache does not hold, at any file size."""
     mm = mmap.mmap(fd, 0, flags=mmap.MAP_SHARED, prot=mmap.PROT_READ)
     try:
         mm.madvise(mmap.MADV_SEQUENTIAL)

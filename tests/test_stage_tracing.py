@@ -101,6 +101,8 @@ for _kind, _more in (("encode", {"ship_data_s", "await_unit_s",
 # own and count the spans they select there
 for _kind in ("encode", "rebuild"):
     OLD_KEYS[_kind] |= {"map_s", "spans_mapped"}
+# the fleet stream maps its volumes the same way
+OLD_KEYS["fleet"] |= {"map_s", "spans_mapped"}
 
 
 @pytest.fixture(autouse=True)
@@ -400,16 +402,10 @@ def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
     """On the thread that makes the call its stages follow one another
     from the job's first line to its last.  A slowed map and a slowed
     rename (the head and the tail) make the call long beside what lies
-    between two stages; the program itself sleeps nowhere.  The fleet maps
-    its volumes inside `open` (`_map_readonly`); the single-volume engines
-    map their sources in a stage of its own, `map` (`_map_lazy`), after
+    between two stages; the program itself sleeps nowhere.  Every engine
+    maps its sources in a stage of its own, `map` (`_map_lazy`), after
     `open` on the calling thread."""
-    real_map, real_lazy, real_replace = (
-        ec_files._map_readonly, ec_files._map_lazy, os.replace)
-
-    def slow_map(fd, size):
-        time.sleep(0.05)
-        return real_map(fd, size)
+    real_lazy, real_replace = ec_files._map_lazy, os.replace
 
     def slow_lazy(fd):
         time.sleep(0.05)
@@ -421,7 +417,7 @@ def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
 
     op = prepare(kind, tmp_path)
     monkeypatch.setattr(ec_files, "_map_lazy", slow_lazy)
-    monkeypatch.setattr(fleet_convert, "_map_readonly", slow_map)
+    monkeypatch.setattr(fleet_convert, "_map_lazy", slow_lazy)
     monkeypatch.setattr(os, "replace", slow_replace)
     booked = []
     real = pipeline.PipelineJob._book
@@ -440,12 +436,9 @@ def test_the_callers_stages_add_up_to_call_s(kind, tmp_path, monkeypatch):
     total = sum(secs for _, secs in mine)
     assert total == pytest.approx(stats["call_s"], rel=0.05), (
         stats["call_s"], sorted(mine))
-    # the slowed map: the fleet's inside `open`, the others' in `map`,
-    # on this thread and so inside the sum above
-    slowed = "open" if BULK[kind][0] == "fleet_convert" else "map"
-    assert stats[slowed + "_s"] >= 0.05 and stats["commit_s"] >= 0.005
-    assert (slowed in {name for name, _ in mine}) and \
-        ("map_s" in stats) == (slowed == "map")
+    # the slowed map, in `map` on this thread and so inside the sum above
+    assert stats["map_s"] >= 0.05 and stats["commit_s"] >= 0.005
+    assert "map" in {name for name, _ in mine}
 
 
 def test_occupancy_states_max_mean_and_share_at_two_or_more(monkeypatch):
