@@ -512,8 +512,10 @@ def apply_matrix(codec, C: np.ndarray, stack: np.ndarray, job=None,
 # its ten rows 16; at 1 MiB a row the two ways tie; PERF.md, PR 26): rows
 # this wide and wider go up one by one, narrower ones in one array.  A row
 # that goes up on its own needs no place in a stacked array: where it is
-# as wide as its bucket too it is put from where it lies, uncopied (a view
+# as wide as its program runs it is put from where it lies, uncopied (a view
 # of a shard file's map in a rebuild; PERF.md, PR 29).
+# A program that reads such rows in place runs at their own width, which
+# spares a volume's short last batch the copy (PERF.md, section 6).
 ROW_PUTS_FROM = 2 << 20
 
 
@@ -558,14 +560,23 @@ def dispatch_reconstruct(codec, rows, ids: list[int], wanted: list[int],
     the `{shard: row}` dict already.
 
     A device codec is handed the rows its decode matrix wants, in its
-    order and W wide, W the bucket of n (`codec_base.bucket`): one program
-    per (rows wanted, W) and not per n, with nothing but 1-D arrays
-    crossing (`codec_base.stacked`), and the cut back to n is a view on
-    the host.  How they go up rests on n and W, not on what `rows` is:
-    rows as wide as their bucket and at least `ROW_PUTS_FROM` (a rebuild
-    batch) are put one by one from where they lie; any others are stacked
-    on the host first (`_staged`: the one host copy of a degraded read, or
-    of a rebuild's short last batch), which the job counts as
+    order and W wide, with nothing but 1-D arrays crossing
+    (`codec_base.stacked`), and the cut back to n is a view on the host.
+    W is n itself where n is at least `ROW_PUTS_FROM` and the codec's own
+    `in_place` says its program reads rows of n bytes where they lie (the
+    Pallas shell: whole tiles): a rebuild batch, the short last one of a
+    volume too, which the job counts as `narrow` where n is below its
+    bucket.  That costs a program per such n and count of rows wanted
+    (under 1 MiB small blocks and 16 MiB batches at most 11 widths beyond
+    the buckets: 3, 5-7 and 9-15 MiB), each built once and then served
+    from the persistent compile cache.  Else W is the bucket of n (`codec_base.bucket`): one
+    program per (rows wanted, W) and not per n, which keeps a degraded
+    read's needle lengths, all under `ROW_PUTS_FROM`, to a few programs.
+    How the rows go up rests on n and W, not on what `rows` is: rows W
+    wide and at least `ROW_PUTS_FROM` are put one by one from where they
+    lie; any others are stacked on the host first (`_staged`: the one host
+    copy of a degraded read, or of a short rebuild batch whose rows the
+    program would not read in place), which the job counts as
     `rows_staged`.  The runtime reads a row after its put returns: what
     comes back holds `rows` and the stacked copy, and whoever holds it
     keeps them alive and unchanged until `materialize_rows` has returned.
@@ -592,6 +603,12 @@ def dispatch_reconstruct(codec, rows, ids: list[int], wanted: list[int],
     from seaweedfs_tpu.ops.codec_base import bucket
     order = [ids.index(i) for i in codec.decode_basis(ids, wanted)]
     width = bucket(n, codec.tile)
+    test = getattr(codec, "in_place", None)
+    if (n < width and n >= ROW_PUTS_FROM and test is not None
+            and test([n] * len(order), 0, present=ids, wanted=wanted)):
+        width = n
+        if job is not None:
+            job.count("narrow", 1)
     held = [rows]
 
     def put():

@@ -1577,11 +1577,14 @@ def rebuild_ec_volumes(bases: list[str], batch_size: int = DEFAULT_BATCH,
     lie in the maps (`stats["spans_mapped"]` counts them: batches x
     survivors), handed to the native decode matmul by row pointer or to
     the dispatch seam as views, which a device codec puts up uncopied where
-    they are a whole bucket wide (`ops/dispatch.ROW_PUTS_FROM`;
-    `stats["rows_staged"]` counts the rows copied first: a short last
-    batch).  Rebuilt shards land in a countdown-released buffer ring, one
-    for the call, and stream to each volume's per-shard writer workers into
-    recycled `.tmp` inodes (reference: RebuildEcFiles,
+    they are a whole bucket wide or its program reads them in place at
+    their own width (`ops/dispatch.ROW_PUTS_FROM`; `stats["narrow"]`
+    counts the batches put below their bucket, a volume's short last
+    one, and `stats["rows_staged"]` the rows copied first: such a batch
+    under a codec whose program does not read it in place).  Rebuilt
+    shards land in a countdown-released buffer ring, one for the call, and
+    stream to each volume's per-shard writer workers into recycled `.tmp`
+    inodes (reference: RebuildEcFiles,
     ec_encoder.go:237-291; the backlog is `ec.rebuild`'s loop,
     command_ec_rebuild.go).
 
@@ -1649,6 +1652,7 @@ def _rebuild_volumes(bases, batch_size, progress, cancel, stats, codec_tags,
     stats["basis"] = kinds[0] if len(kinds) == 1 else "mixed"
     stats["rows_staged"] = 0  # the dispatch seam counts (PipelineJob.count)
     stats["in_place"] = 0  # batches read where they were put (the seam too)
+    stats["narrow"] = 0  # batches put at their own width, below the bucket
     stats["spans_mapped"] = 0  # rows selected in the maps: batches x survivors
     stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
     native_host = all(isinstance(v.codec, NativeRSCodec) for v in vols)

@@ -9,17 +9,20 @@ CPU device codec (the XLA shell, through the dispatch seam's pipeline) and
 on the native host codec (its host-serial loop, volume after volume)."""
 
 import asyncio
+import gc
 import json
 import os
 import threading
 import types
+import weakref
 
 import numpy as np
 import pytest
 
 from seaweedfs_tpu.models import lrc as lrc_ref
 from seaweedfs_tpu.models import rs
-from seaweedfs_tpu.stats import pipeline
+from seaweedfs_tpu.ops import dispatch, pallas_gf
+from seaweedfs_tpu.stats import pipeline, profile
 from seaweedfs_tpu.storage.ec import ec_files, layout
 
 BATCH = 4096
@@ -147,6 +150,63 @@ def test_every_rebuilt_file_equals_the_plain_reference(
     if codec_kind == "jax":
         assert job["stages"]["unstage"]["items"] == batches
         assert 1 <= stats["inflight_max"] <= ec_files.PIPELINE_DEPTH
+
+
+# the Pallas shell at a tile of `pallas_gf.IN_PLACE_QUANTUM`, which no
+# other test builds programs for: a batch of eight tiles (a bucket), and
+# last batches of three and five, which are none
+TILE = 4096
+WIDE_BATCH = 8 * TILE
+TAILS = (3 * TILE, 5 * TILE)
+
+
+def test_short_last_batches_go_up_at_their_own_widths(tmp_path, monkeypatch):
+    """One list call over volumes whose last batches are three or five
+    whole tiles, or none: every tail goes up from the maps at its own
+    width (`narrow`, nothing staged), the programs built are the whole
+    batch's and one a distinct tail, a second call builds none, and no
+    map outlives either call."""
+    ec_files._get_codec("jax")  # notes a JAX backend: counting is on
+    codec = pallas_gf.PallasRSCodec(rs.get_code(10, 4), tile=TILE,
+                                    interpret=True)
+    monkeypatch.setattr(ec_files, "_get_codec",
+                        lambda kind=None, tag=None: codec)
+    monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", 2 * TILE)
+    maps = []
+    real_map = ec_files._map_lazy
+
+    def map_spy(fd):
+        mm = real_map(fd)
+        maps.append(weakref.ref(mm))
+        return mm
+
+    monkeypatch.setattr(ec_files, "_map_lazy", map_spy)
+    sizes = [WIDE_BATCH + TAILS[0], WIDE_BATCH + TAILS[1],
+             2 * WIDE_BATCH + TAILS[0], WIDE_BATCH]
+
+    def programs() -> int:
+        return profile.compiles_snapshot().get(
+            "reconstruct", {}).get("count", 0)
+
+    built = []
+    for call in range(2):
+        vols = [_volume(tmp_path, vid, "rs_10_4", NODE7_LOST, size,
+                        name=f"call{call}_{vid}")
+                for vid, size in enumerate(sizes, 1)]
+        stats: dict = {}
+        p0 = programs()
+        report = _in_thread(lambda: ec_files.rebuild_ec_volumes(
+            [b for b, _ in vols], batch_size=WIDE_BATCH, stats=stats))
+        built.append(programs() - p0)
+        assert len(report["rebuilt"]) == len(sizes)
+        for base, want in vols:
+            assert _files(base, 14) == want, base
+        assert (stats["narrow"], stats["rows_staged"]) == (3, 0)
+        assert stats["in_place"] == 8  # every batch, read where it lies
+        _no_leftovers(tmp_path)
+        gc.collect()
+        assert maps and all(ref() is None or ref().closed for ref in maps)
+    assert built == [1 + len(TAILS), 0]
 
 
 def test_the_pipeline_crosses_every_boundary_with_a_batch_out(

@@ -267,12 +267,13 @@ def _shard_set(tmp_path, tag: str, shard_size: int, lost: list[int]):
     return base, want
 
 
-def _spied_rebuild(monkeypatch, base, tag, lost):
-    """`rebuild_ec_files` at `ROW_PUTS_FROM` = one batch, with what the
-    seam was handed: per call the rows' length and whether every row
-    shares memory with a map the engine made, and the lengths `_staged`
-    was entered with."""
-    monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", BATCH)
+def _spied_rebuild(monkeypatch, base, tag, lost, batch=BATCH,
+                   row_puts=BATCH):
+    """`rebuild_ec_files` at `ROW_PUTS_FROM` = `row_puts` (one batch),
+    with what the seam was handed: per call the rows' length and whether
+    every row shares memory with a map the engine made, and the lengths
+    `_staged` was entered with."""
+    monkeypatch.setattr(dispatch, "ROW_PUTS_FROM", row_puts)
     maps, calls, staged = [], [], []
     real_map, real_seam, real_staged = (
         ec_files._map_lazy, ec_files._dispatch_reconstruct,
@@ -298,7 +299,7 @@ def _spied_rebuild(monkeypatch, base, tag, lost):
     monkeypatch.setattr(ec_files, "_dispatch_reconstruct", seam_spy)
     monkeypatch.setattr(dispatch, "_staged", staged_spy)
     stats: dict = {}
-    assert ec_files.rebuild_ec_files(base, batch_size=BATCH, stats=stats,
+    assert ec_files.rebuild_ec_files(base, batch_size=batch, stats=stats,
                                      codec_tag=tag) == lost
     return stats, calls, staged
 
@@ -324,16 +325,51 @@ def test_rebuild_puts_whole_buckets_from_the_maps(
             want[i].tobytes(), i
 
 
-@pytest.mark.parametrize("kind", SHELLS)
+# a last batch after three whole buckets of four tiles: (shell, tile, the
+# batch's length, `ROW_PUTS_FROM` in tiles); the tiles of 8,192 and 12,288
+# are multiples of `pallas_gf.IN_PLACE_QUANTUM`, so the Pallas program can
+# read rows of whole tiles where they lie, and no other test builds them
+TAILS = {
+    "jax": ("jax", 640, 700, 4),
+    "pallas": ("pallas", 640, 700, 4),
+    "pallas_whole_tiles": ("pallas", 12288, 3 * 12288, 2),
+    "jax_whole_tiles": ("jax", 12288, 3 * 12288, 2),
+    "pallas_tiles_and_700": ("pallas", 8192, 3 * 8192 + 700, 2),
+    "pallas_under_row_puts": ("pallas", 8192, 3 * 8192, 4),
+}
+
+
+@pytest.mark.parametrize("case", list(TAILS))
 def test_rebuild_stages_only_a_last_batch_short_of_its_bucket(
-        kind, tmp_path, serve, monkeypatch):
-    base, want = _shard_set(tmp_path, "rs_10_4", 3 * BATCH + 700, [3])
-    serve(_shell(kind, 640))
-    stats, calls, staged = _spied_rebuild(monkeypatch, base, "rs_10_4", [3])
-    assert calls == [(BATCH, True)] * 3 + [(700, True)]
-    assert staged == [700]  # that batch alone: 700 bytes in a 1,280 bucket
-    assert stats["rows_staged"] == 10
+        case, tmp_path, serve, monkeypatch):
+    """A last batch short of its bucket goes up from the maps at its own
+    width where it is at least `ROW_PUTS_FROM` and the codec's program
+    reads rows of its length in place (the Pallas shell, whole tiles):
+    nothing staged, `narrow` 1, its own program, no padding put.  Under
+    the XLA shell, or a tail that is no whole tiles or under
+    `ROW_PUTS_FROM`, it alone is staged into its bucket."""
+    kind, tile, tail, row_puts = TAILS[case]
+    batch = 4 * tile
+    narrow = case == "pallas_whole_tiles"
+    base, want = _shard_set(tmp_path, "rs_10_4", 3 * batch + tail, [3])
+    serve(_shell(kind, tile))
+    before = profile.KERNELS.snapshot().get("reconstruct[device]", {})
+    p0 = _programs()
+    stats, calls, staged = _spied_rebuild(monkeypatch, base, "rs_10_4", [3],
+                                          batch, row_puts * tile)
+    assert calls == [(batch, True)] * 3 + [(tail, True)]
     assert open(base + layout.to_ext(3), "rb").read() == want[3].tobytes()
+    width = tail if narrow else codec_base.bucket(tail, tile)
+    after = profile.KERNELS.snapshot()["reconstruct[device]"]
+    assert after["h2d_bytes"] - before.get("h2d_bytes", 0) == \
+        10 * (3 * batch + width)
+    if narrow:  # the bucket's program and the tail's
+        assert staged == [] and _programs() - p0 == 2
+        assert (stats["rows_staged"], stats["narrow"]) == (0, 1)
+        assert stats["in_place"] == 4
+    else:  # that batch alone, e.g. 700 bytes in a 1,280 bucket
+        assert staged == [tail]
+        assert (stats["rows_staged"], stats["narrow"]) == (10, 0)
 
 
 @pytest.mark.parametrize("kind", SHELLS)

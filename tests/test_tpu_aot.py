@@ -133,9 +133,9 @@ def test_gf_apply_compiles_for_v5e(v5e, tag, wanted, width, shape):
     back.  And the decode a server lost from a seven-server cluster leaves
     every volume with (`node7.rebuild_2lost`: data shard 3 and parity
     shard 10 of each), in place at `[2, 10]`, two rows back as one
-    `[2 * W]` run: at 16 MiB, the bucket both of a 256 MiB volume's
-    batches run at (its short 10 MiB batch is staged into it), and at
-    10 MiB, the width that batch would take up unstaged."""
+    `[2 * W]` run: at 16 MiB, a 256 MiB volume's whole batch, and at
+    10 MiB (80 tiles), its short last batch, which goes up from the maps
+    at its own width (`dispatch.dispatch_reconstruct`)."""
     from seaweedfs_tpu.ops import codecs
     code = codecs._code_for(codecs.parse_tag(tag))
     column = wanted == "column"
