@@ -137,20 +137,12 @@ def keep_freed_pages() -> None:
     mallopt(-2, 64 << 20)  # M_TOP_PAD: a thread arena's heap (HEAP_MAX_SIZE)
 
 
-def _host_classes():
-    from seaweedfs_tpu.models.rs import RSCode
-    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
-    return NativeRSCodec, RSCode
-
-
 def _is_host(codec) -> bool:
-    """Eager host backend: computes synchronously, numpy in/out.  The
-    native AVX2 shell plus anything flagged `host_backend` (the MSR
-    file wrapper and the registry's numpy shell propagate the flag so
-    wrapped codecs route like the shell they wrap)."""
-    NativeRSCodec, _ = _host_classes()
-    return isinstance(codec, NativeRSCodec) or getattr(
-        codec, "host_backend", False)
+    """Eager host backend: computes synchronously, numpy in/out.  Anything
+    flagged `host_backend`: the native AVX2 shell, the registry's numpy
+    shell, and the MSR file wrapper over either, which propagates the flag
+    so that a wrapped codec routes like the shell it wraps."""
+    return getattr(codec, "host_backend", False)
 
 
 def _is_numpy_ref(codec) -> bool:
@@ -252,10 +244,12 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0,
     last row, held by the unit's queue item).  `block` is the block size of
     the unit's rows, said on its `h2d` and `dispatch` stage events beside
     `rows` (a column cut of a large-block row: one row, k spans a block
-    apart).  Every other codec (a host
-    shell, the bare numpy reference, the column-sharded mesh encoder) gets
-    its `[k, B]` array built from the spans on the host (`_unstriped`),
-    which the job counts as `rows_staged`."""
+    apart).  A host shell with a linear apply (the native one, and
+    `MSRFileCodec` over it) takes the spans too and reads them by pointer
+    where they lie: its `[m, B]` parity, computed here, is the m runs.
+    Every other codec (the numpy shells, the column-sharded mesh encoder)
+    gets its `[k, B]` array built from the spans on the host
+    (`_unstriped`), which the job counts as `rows_staged`."""
     if not (isinstance(batch, np.ndarray) and batch.ndim == 2):
         spans = list(batch)
         if hasattr(codec, "encode_parity_linear") and not _is_host(codec):
@@ -274,6 +268,11 @@ def dispatch_parity(codec, batch, job=None, unit=None, stripes: int = 0,
                 job, unit, "encode_parity", nbytes,
                 lambda: tuple(jnp.asarray(s) for s in spans), run,
                 rows=stripes, block=block)
+        if _is_host(codec) and hasattr(getattr(codec, "inner", codec),
+                                       "encode_parity_linear"):
+            return _host_call(job, unit, "encode_parity",
+                              sum(s.nbytes for s in spans),
+                              codec.encode_parity_linear, spans, stripes)
         batch = _unstriped(spans, codec.k, stripes)
         if job is not None and (stripes > 1 or len(spans) > 1):
             job.count("rows_staged", stripes)
@@ -330,6 +329,13 @@ def unit_pieces(spans, stripes: int) -> list:
     return [s[o:o + row] for s in spans for o in range(0, len(s), row)]
 
 
+class _EncodeUnits(list):
+    """A fleet batch dispatched a unit at a time (`dispatch_parity`): its
+    sync point books to that entry point's kernel, as a single volume's
+    `materialize` does."""
+    kernel = "encode_parity"
+
+
 @codec_entry("fleet_encode")
 def dispatch_parity_batch(codec, units, job=None, unit=None,
                           stripes: int = 0):
@@ -351,15 +357,22 @@ def dispatch_parity_batch(codec, units, job=None, unit=None,
     the enqueue (see `materialize`).  What comes back is a list, slot by
     slot, of m device arrays or None.  The runtime reads a piece after
     its put returns, so the pieces stay alive and unchanged until the
-    parity is materialised.
+    parity is materialised.  For any other codec (a one-device shell, a
+    host shell, the numpy reference) each occupied slot is one unit of
+    `dispatch_parity`, by its rule and under its kernel: the list that
+    comes back (`_EncodeUnits`) holds, slot by slot, what that gave (m
+    device runs, or a host shell's `[m, B]`), or None.
 
-    Or `units` is a `[U, k, B]` host array (a host codec's staged batch,
-    a one-device codec's, a test's) -> `[U, m, B]`: a mesh encoder H2Ds
-    it through its matched in_sharding (`place`: each chip pulls exactly
-    its U/D units), host backends loop eagerly per unit (they have no
-    batch geometry to win; the pipeline's value there is the interleaved
-    I/O)."""
+    Or `units` is a `[U, k, B]` host array (a test's) -> `[U, m, B]`: a
+    mesh encoder H2Ds it through its matched in_sharding (`place`: each
+    chip pulls exactly its U/D units), host backends loop eagerly per
+    unit."""
     if not isinstance(units, np.ndarray):
+        if not hasattr(codec, "encode_units_linear"):
+            return _EncodeUnits(None if u is None else dispatch_parity(
+                codec, u, job=job, unit=unit, stripes=stripes)
+                for u in units)
+
         def run(placed):
             parity = [runs if u is not None else None for runs, u in
                       zip(codec.encode_units_linear(placed, stripes),
@@ -394,7 +407,7 @@ def parity_devices(parity) -> int:
     """How many devices a dispatched batch's parity lives on (0: a host
     codec returned numpy)."""
     if isinstance(parity, list):
-        return len({d for runs in filter(None, parity)
+        return len({d for runs in parity if isinstance(runs, (tuple, list))
                     for d in runs[0].devices()})
     sharding = getattr(parity, "sharding", None)
     return len(sharding.device_set) if sharding is not None else 0
@@ -410,11 +423,19 @@ def unit_parity_shards(parity, kernel: str = "fleet_encode", job=None,
     that went up as spans yields one unit a block, its m contiguous runs
     (their copies were asked for at the enqueue: `d2h_copy` is what is
     left of them), and nothing for an empty slot; a `[U, m, B]` device
-    array yields each device's `[U/D, m, B]`; host arrays yield one block
-    immediately.  No stage stays open across a yield: what the consumer
-    does with a block is its own."""
+    array yields each device's `[U/D, m, B]`; host arrays yield at once,
+    a list of them one unit a block.  No stage stays open across a yield:
+    what the consumer does with a block is its own.  The wait and the
+    copies book to `kernel`, or to the batch's own (`_EncodeUnits`)."""
+    kernel = getattr(parity, "kernel", kernel)
     if isinstance(parity, np.ndarray):
         yield 0, parity.shape[0], parity
+        return
+    if isinstance(parity, list) and all(
+            runs is None or isinstance(runs, np.ndarray) for runs in parity):
+        for slot, runs in enumerate(parity):
+            if runs is not None:
+                yield slot, slot + 1, [runs]
         return
     if isinstance(parity, list):
         with _stage(job, "device_wait", unit, kernel=kernel) as wait:

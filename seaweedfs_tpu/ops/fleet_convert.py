@@ -12,10 +12,8 @@ stripe width k, its sub-rows a file: RS(10,4)'s 10 + 4, LRC(12,2,2)'s
     reader      walks the volumes round-robin and fills batches of one
                 unit a slot (data shards go straight to each volume's
                 writer pool by in-kernel copy_file_range on the way — they
-                never touch the device).  For a codec that lays a unit out
-                on the device (`encode_units_linear`: the mesh's
-                FleetUnitEncoder) a unit is a span of the volume's `.dat`
-                map, as a single volume's encode has it
+                never touch the device).  A unit is a span of the
+                volume's `.dat` map, as a single volume's encode has it
                 (ec_files._iter_spans: up to batch_size // block
                 consecutive stripe rows, sixteen 1 MiB rows at the served
                 sizes, k blocks wide), selected as views
@@ -24,15 +22,15 @@ stripe width k, its sub-rows a file: RS(10,4)'s 10 + 4, LRC(12,2,2)'s
                 row, into a zeroed buffer of its own, counted as
                 `rows_staged`.  A batch holds units of one shape (one
                 program a shape); a slot left without one stays empty.
-                For every other codec (a host shell, a one-device codec)
-                a unit is one stripe row, copied into a pooled [U, k, W]
-                host batch and counted
-    dispatch    puts every unit's pieces 1-D to its own device from where
-                they lie and launches ONE mesh program a batch, which
-                lays the units out (and splits a sub-packetised code's
-                file rows into sub-rows) and runs the batched parity kernel
-                (ops/dispatch.dispatch_parity_batch; a staged batch goes
-                up 2-D through the encoder's matched in_sharding)
+    dispatch    (ops/dispatch.dispatch_parity_batch) for a codec that lays
+                a unit out on the device (`encode_units_linear`: the
+                mesh's FleetUnitEncoder) puts every unit's pieces 1-D to
+                its own device from where they lie and launches ONE mesh
+                program a batch, which lays the units out (and splits a
+                sub-packetised code's file rows into sub-rows) and runs
+                the batched parity kernel; any other codec (a one-device
+                or a host shell) gets each unit of the batch as one
+                `dispatch_parity`, a single volume's encode unit
     drain       waits, then takes each unit's parity as it comes off its
                 device (dispatch.unit_parity_shards: one contiguous run
                 of each of the m parity files a unit, their copies asked
@@ -76,9 +74,8 @@ from seaweedfs_tpu.stats import pipeline as _pipeline
 from seaweedfs_tpu.storage.ec import layout
 from seaweedfs_tpu.storage.ec.ec_files import (
     DEFAULT_BATCH, ENCODE_SUMS, EncodeCancelled, _book_stage_bytes,
-    _iter_spans, _iter_units, _map_lazy, _ShardFlusher, _ShardWriterPool,
-    _state_overlap, _unit_coverage, _unit_spans, _unit_steps, block_geometry,
-    write_vif)
+    _iter_spans, _map_lazy, _ShardFlusher, _ShardWriterPool, _state_overlap,
+    _unit_coverage, _unit_spans, block_geometry, write_vif)
 
 
 def _env_int(name: str, default: int) -> int:
@@ -115,7 +112,7 @@ class _VolumeJob:
 
     def __init__(self, index: int, base: str, dat_path: str | None,
                  large_block: int, small_block: int, batch_size: int, pjob,
-                 spans: bool, spec):
+                 spec):
         """`spec` (codecs.CodecSpec) is the code the volume goes under:
         its k-wide striping, its n shard files, its tag in the `.vif`.
         `index` is the volume's place in the run: the `unit` of its
@@ -148,11 +145,8 @@ class _VolumeJob:
         self.data_flusher = _ShardFlusher(self.writers, n)
         self.parity_flusher = _ShardFlusher(self.writers, n)
         # (row_start, block, col, step, shard_off, rows): spans of the map
-        # for a codec that lays a unit out on the device, else one stripe
-        # row (or a column cut of one) a unit
-        geometry = (self.dat_size, large_block, small_block, batch_size, k)
-        self.units = _iter_spans(*geometry) if spans else (
-            u + (1,) for u in _iter_units(*geometry))
+        self.units = _iter_spans(self.dat_size, large_block, small_block,
+                                 batch_size, k)
         self.held = None  # the next unit, selected and not yet in a batch
         self.units_read = 0
         self.units_total: int | None = None  # set when the iterator ends
@@ -286,8 +280,6 @@ def convert_volumes(bases: list[str], *,
     slots = getattr(codec, "unit_slots", None)
     if slots is not None:  # round to an even mesh split
         U = slots(U)
-    # a codec that lays a unit out on the device gets spans of the map
-    spans = hasattr(codec, "encode_units_linear")
 
     stats = stats if stats is not None else {}
     stats["mode"] = "fleet"
@@ -318,7 +310,7 @@ def convert_volumes(bases: list[str], *,
         with pjob.stage("open", files=len(bases) * (spec.n + 1)) as st:
             for i, b in enumerate(bases):
                 jobs.append(_VolumeJob(i, b, None, large_block, small_block,
-                                       batch_size, pjob, spans, spec))
+                                       batch_size, pjob, spec))
             stats["bytes"] = sum(j.dat_size for j in jobs)
             st.set(bytes=stats["bytes"])
             stats.update(block_geometry((j.dat_size for j in jobs),
@@ -335,18 +327,11 @@ def convert_volumes(bases: list[str], *,
         raise
 
     # depth+1 batches between selection and materialised parity, so the
-    # H2D and kernel of batch N+1 overlap the D2H and writes of batch N.
-    # Spans hold no buffer (the pool's items are tokens); a staged batch
-    # is a pooled [U, k, W] array, one width for every job (ragged tails
-    # zero-fill)
+    # H2D and kernel of batch N+1 overlap the D2H and writes of batch N:
+    # the pool's items are tokens (spans hold no buffer)
     pool: queue.Queue = queue.Queue()
-    W = 0 if spans else max(_unit_steps(
-        j.dat_size, large_block, small_block, batch_size, k)[1]
-        for j in jobs)
-    with pjob.stage("open"):
-        for _ in range(depth + 1):
-            pool.put(None if spans else
-                     np.empty((U, k, W), dtype=np.uint8))
+    for _ in range(depth + 1):
+        pool.put(None)
     q_read: queue.Queue = queue.Queue(maxsize=depth)
     q_disp: queue.Queue = queue.Queue()
     errors: list[BaseException] = []
@@ -354,45 +339,31 @@ def convert_volumes(bases: list[str], *,
 
     def peek(job):
         """The job's next unit that holds data, selected once: (unit,
-        pieces, rows staged, shape).  Spans: the unit as 1-D views of the
-        map (no byte moves but a volume's last, short row, into a zeroed
-        buffer of its own) cut into the pieces it goes up as, whose
-        lengths are the shape a batch shares.  None at the volume's end."""
+        pieces, rows staged, shape): the unit as 1-D views of the map (no
+        byte moves but a volume's last, short row, into a zeroed buffer of
+        its own) cut into the pieces it goes up as, whose lengths are the
+        shape a batch shares.  None at the volume's end."""
         while job.held is None:
             unit = job.next_unit()
             if unit is None:
                 return None
             row_start, block, col, step, _, rows = unit
-            if spans:
-                views, staged = _unit_spans(job.view, job.dat_size, k,
-                                            row_start, block, col, step,
-                                            rows)
-                if views:
-                    pieces = unit_pieces(views, rows)
-                    job.held = (unit, pieces, staged,
-                                (rows, *map(len, pieces)))
-            elif _unit_coverage(job.dat_size, row_start, block, col,
-                                step, k)[0]:
-                job.held = unit, None, 1, None
-            if job.held is None:
+            views, staged = _unit_spans(job.view, job.dat_size, k,
+                                        row_start, block, col, step, rows)
+            if views:
+                pieces = unit_pieces(views, rows)
+                job.held = (unit, pieces, staged, (rows, *map(len, pieces)))
+            else:
                 # a trailing column unit wholly beyond the .dat: nothing
                 # to encode or write
                 job.units_skipped += 1
         return job.held
 
-    def take(job, slot):
-        """Move the job's selected unit into a batch; a staged unit's k
-        blocks are copied into `slot`."""
+    def take(job):
+        """Move the job's selected unit into a batch."""
         unit, pieces, staged, _ = job.held
         job.held = None
-        row_start, block, col, step, shard_off, rows = unit
-        if slot is not None:
-            for j in range(k):
-                off = row_start + j * block + col
-                n = max(0, min(step, job.dat_size - off))
-                if n > 0:
-                    np.copyto(slot[j, :n], job.view[off:off + n])
-                slot[j, n:] = 0
+        _, block, _, step, shard_off, rows = unit
         pjob.count("rows_staged", staged)
         pjob.count("spans_mapped", 1)
         pjob.count("units_column" if step != block else "units_rows", 1)
@@ -442,8 +413,7 @@ def convert_volumes(bases: list[str], *,
                                 active.remove(job)
                             elif not metas or held[3] == shape:
                                 shape = held[3]
-                                pieces, meta, unit = take(
-                                    job, None if spans else buf[len(metas)])
+                                pieces, meta, unit = take(job)
                                 units.append(pieces)
                                 metas.append(meta)
                                 taken.append((job, unit))
@@ -460,9 +430,7 @@ def convert_volumes(bases: list[str], *,
                     # one closed because the volumes' next units differ
                     # in shape
                     units += [None] * (U - len(units))
-                    q_read.put((batch, buf, metas,
-                                units if spans else buf,
-                                shape[0] if spans else 0))
+                    q_read.put((batch, buf, metas, units, shape[0]))
                     batch += 1
                 else:
                     pool.put(buf)
@@ -497,8 +465,8 @@ def convert_volumes(bases: list[str], *,
                     if not released:
                         # the first yield implies block_until_ready has
                         # returned: the device is done with the host
-                        # memory (the staging buffer, or the spans, which
-                        # the queue item held until here) even though
+                        # memory (the spans, which the queue item held
+                        # until here) even though
                         # later shards are still transferring
                         pool.put(buf)
                         released = True
@@ -506,10 +474,9 @@ def convert_volumes(bases: list[str], *,
                     touched = []
                     for u in range(a, min(b, len(metas))):
                         job, shard_off, width = metas[u]
-                        # the m parity files' runs of a unit that went
-                        # up as spans, the rows of [m, W] of a staged
-                        # one: one contiguous run of each parity shard's
-                        # file either way
+                        # the m parity files' runs, or the rows of a host
+                        # shell's [m, W]: one contiguous run of each
+                        # parity shard's file either way
                         for i, run in enumerate(block[u - a]):
                             job.parity_flusher.put(k + i, run[:width],
                                                    shard_off)

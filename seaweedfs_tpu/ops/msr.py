@@ -279,8 +279,8 @@ class MSRFileCodec:
                                 self.m, self.alpha)
 
     def encode_parity_batch(self, units):
-        """[U, k, L] -> [U, m, L]: a staged batch of the fleet stream, one
-        stripe row of k file blocks a unit.  On a device shell with a
+        """[U, k, L] -> [U, m, L]: a batch of `dispatch_parity_batch`'s
+        host-array form, one stripe row of k file blocks a unit.  On a device shell with a
         linear apply each unit is one program that splits its rows into
         sub-rows and merges the product (a stripe row is
         `codec_base.stacked`'s third form; the eager [L / alpha, alpha]

@@ -269,7 +269,7 @@ def _iter_units(dat_size: int, large_block: int, small_block: int,
 
 def _iter_spans(dat_size: int, large_block: int, small_block: int,
                 batch_size: int, data_shards: int = layout.DATA_SHARDS):
-    """_iter_units' units as a device codec is handed them: yield
+    """_iter_units' units as every codec is handed them: yield
     (row_start, block, col, step, shard_off, rows).  Consecutive whole
     rows of one block size (step == block) are contiguous in the .dat
     (striping is row-major) and in every shard file, so up to
@@ -417,22 +417,19 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
     """Stream the .dat through the codec into the shard fds: one strategy,
     the overlapped reader -> dispatch -> drain -> writers pipeline
     (_encode_pipelined), every shard file written through the per-shard
-    writer pool (_ShardWriterPool) so all of them land concurrently.  What
-    differs by codec is chosen from the codec ops/codecs.resolve built for
-    the platform:
-      - host codec (native AVX2/GFNI): the GF matmul runs on the dispatch
-        thread straight off an mmap of the .dat via per-row pointers (no
-        staging copy), data shards move by in-kernel copy_file_range on
-        their writers, and parity rides a small buffer ring.
-      - device codecs (Pallas/XLA/mesh/numpy): a unit is a span of the
-        mmap, up to batch_size bytes of every shard (_iter_spans: sixteen
-        1 MiB stripe rows at the served sizes; in a large-block row, which
-        a volume past ten large blocks has, a column cut: k spans of
-        batch_size bytes a block apart), selected as views and put
-        from where it lies (no staging copy either: only the volume's
-        last, short row is copied, `rows_staged`); JAX dispatch is async
-        so the device round-trip overlaps host I/O, and only parity
-        rides the device, one contiguous run a shard a unit.
+    writer pool (_ShardWriterPool) so all of them land concurrently.  A
+    unit is a span of the mmap, up to batch_size bytes of every shard
+    (_iter_spans: sixteen 1 MiB stripe rows at the served sizes; in a
+    large-block row, which a volume past ten large blocks has, a column
+    cut: k spans of batch_size bytes a block apart), selected as views
+    and handed to the codec through the dispatch seam, whatever the codec
+    ops/codecs.resolve built for the platform: a device codec puts it
+    from where it lies, the native host codec reads it there by row
+    pointer (no staging copy either way: only the volume's last, short
+    row is copied, `rows_staged`).  JAX dispatch is async so the device
+    round-trip overlaps host I/O, and only parity rides the device, one
+    contiguous run a shard a unit; data shards move by in-kernel
+    copy_file_range on their writers.
 
     Rows wholly beyond the .dat are never read, encoded, or written: the
     parity of an all-zero row region is zero, so those regions become
@@ -489,7 +486,7 @@ def _encode_stream(codec, dat_path: str, dat_size: int, large_block: int,
 def _book_stage_bytes(pjob, stats: dict, data_bytes: int,
                       parity_bytes: int) -> None:
     """Attribute the run's bytes to whichever stages actually ran (a
-    host-codec encode has no read/d2h stage; booking bytes against a
+    host-codec encode has no d2h stage; booking bytes against a
     zero-second stage would invent infinite-GB/s rows)."""
     for key, nbytes in (("read_s", data_bytes), ("encode_s", data_bytes),
                         ("d2h_s", parity_bytes),
@@ -501,28 +498,17 @@ def _book_stage_bytes(pjob, stats: dict, data_bytes: int,
             pjob.add_bytes(key[:-2], nbytes)
 
 
-def _unit_steps(dat_size: int, large_block: int, small_block: int,
-                batch_size: int,
-                data_shards: int = layout.DATA_SHARDS) -> tuple[int, int]:
-    """(min, max) column-batch step _iter_units will actually cut for this
-    volume — min picks direct vs batched submission, max sizes the parity
-    ring buffers.  Sizing by the actual max matters: a volume of at most
-    one large row's bytes (10 GB under upstream's 1 GB blocks; a 30 GB
-    production volume has two large rows and a tail) cuts small-block
-    units only, and ring buffers sized by the never-used large step would
-    cycle an 8x larger working set through the cache for nothing."""
-    k = data_shards
-    row = large_block * k
+def _min_step(dat_size: int, large_block: int, small_block: int,
+              batch_size: int, data_shards: int = layout.DATA_SHARDS) -> int:
+    """The narrowest column step _iter_units will cut for this volume:
+    whether the encode's drain submits its parity runs directly or
+    through a _ShardFlusher (_make_sink)."""
+    row = large_block * data_shards
     n_large = (dat_size - 1) // row if dat_size > row else 0
-    remaining = dat_size - n_large * row
-    steps = []
-    if n_large:
-        steps.append(min(batch_size, large_block))
-    if remaining > 0:
+    steps = [min(batch_size, large_block)] if n_large else []
+    if dat_size - n_large * row > 0:
         steps.append(min(batch_size, small_block))
-    if not steps:
-        steps = [batch_size]
-    return min(steps), max(steps)
+    return min(steps, default=batch_size)
 
 
 def _unit_coverage(dat_size: int, row_start: int, block: int, col: int,
@@ -866,35 +852,6 @@ def _state_overlap(stats: dict) -> None:
         stats["overlap_frac"] = frac
 
 
-def _host_parity_unit(pjob, unit: int, codec, dat_view: np.ndarray,
-                      tailbuf: np.ndarray, pbuf: np.ndarray, row_start: int,
-                      block: int, col: int, step: int, nz: int,
-                      tail: int) -> None:
-    """Parity for one column unit of a stripe row — the job's `encode`
-    stage: gf_matmul_ptrs straight off the .dat mmap into pbuf's m rows.
-    A partial tail row is staged into the zeroed tailbuf first; a stripe
-    with nz < k populated rows uses a column-truncated generator."""
-    from seaweedfs_tpu import native
-    with pjob.stage("encode", unit=unit) as st:
-        rows = [dat_view[row_start + j * block + col:
-                         row_start + j * block + col + step]
-                for j in range(nz)]
-        if tail < step:
-            tailbuf[:tail] = rows[nz - 1][:tail]
-            tailbuf[tail:step] = 0
-            rows[nz - 1] = tailbuf
-            pjob.count("rows_staged", 1)
-        code = codec.code
-        mat = code.parity_matrix if nz == code.k else \
-            np.ascontiguousarray(code.parity_matrix[:, :nz])
-        native.gf_matmul_ptrs(mat, rows, list(pbuf), step)
-    # the zero-copy path bypasses ops/dispatch, so it feeds the kernel
-    # profile itself — otherwise host-encode time vanishes from
-    # /debug/pprof?format=table
-    _profile.KERNELS.record("encode_parity", wall_s=st.seconds,
-                            nbytes=nz * step)
-
-
 def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                       dat_size: int, large_block: int, small_block: int,
                       batch_size: int, out_fds, highwater, pjob,
@@ -908,7 +865,8 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
       reader   walks the unit iterator for unit N+1; data shards go to
                their shard writers by in-kernel copy_file_range on the
                way (they never round-trip the device).  For DEVICE
-               codecs a unit is a span of the .dat's map (_iter_spans:
+               codecs and host codecs alike a unit is a span of the
+               .dat's map (_iter_spans:
                up to batch_size // block consecutive stripe rows, which
                lie one after the other in the .dat and in every shard
                file; of a block wider than the batch, a large-block
@@ -918,19 +876,18 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                row, copied into a zeroed buffer and counted
                (`rows_staged`, 0 or 1 a call).  At most PIPELINE_DEPTH
                units are between selection and materialised parity;
-               waiting for a slot is `stall`.  HOST codecs walk
-               _iter_units' units and the dispatch stage encodes them
-               straight off the mmap.
+               waiting for a slot is `stall`.
       dispatch (caller's thread) launches the parity matmul for unit N
-               — asynchronous on JAX backends (the seam's `h2d`: the
+               through the seam: asynchronous on JAX backends (`h2d`: the
                spans put as 1-D arrays from where they lie, and
-               `dispatch`, which add up to `encode`), eager (ptr-matmul
-               off the mmap into a pooled parity ring: `encode`) for
-               native host codecs
+               `dispatch`, which add up to `encode`), eager for a host
+               codec (`dispatch`: the native shell reads the spans by
+               pointer where they lie)
       drain    materialises unit N-1's parity (the seam's `device_wait`
                and `d2h_copy`, which add up to `d2h`: the device sync
-               point), m runs of [W], and hands each parity shard's
-               writer its own at shard_off: m writes a unit
+               point; a host codec's is already here), m runs of [W], and
+               hands each parity shard's writer its own at shard_off: m
+               writes a unit
       writers  striped pwrite workers over the shard fds
                (_ShardWriterPool), so parity files land concurrently
                instead of serially
@@ -940,39 +897,26 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     zero-copy-aliased on CPU backends) host memory, so the unit's spans
     ride its queue item that long; the map itself outlives the call and
     a sealed .dat does not change.  Parity runs are the materialised
-    arrays, kept alive by the writer queue items (host-codec
-    parity rides a countdown-released ring instead).  `progress` and
+    arrays, kept alive by the writer queue items.  `progress` and
     `cancel` are called once a unit."""
-    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
-    native_host = isinstance(codec, NativeRSCodec)
     k, m = codec.k, codec.m
-    min_step, max_step = _unit_steps(dat_size, large_block, small_block,
-                                     batch_size, data_shards=k)
-    # native host codec: the parity ring; device codecs hold no buffer,
-    # `slots` bounds their units in flight
-    pool: queue.Queue = queue.Queue()
+    # no buffer is held: `slots` bounds the units in flight
     slots = threading.BoundedSemaphore(PIPELINE_DEPTH)
     q_read: queue.Queue = queue.Queue(maxsize=PIPELINE_DEPTH)
     # q_disp is unbounded: it carries at most one entry per in-flight
-    # unit (the slots / the ring are the real backpressure) plus FLUSH
-    # nudges
+    # unit (the slots are the real backpressure)
     q_disp: queue.Queue = queue.Queue()
-    # dispatch sends this when it runs dry on parity buffers: the drain's
-    # flusher may be sitting on the very jobs whose releases would refill
-    # the ring (blocking on pool.get() without the nudge deadlocks)
-    FLUSH = object()
     errors: list[BaseException] = []
     done = 0
 
     def reader() -> None:
         nonlocal done
         flusher = _ShardFlusher(writers, k)  # data shards only
-        geometry = (dat_size, large_block, small_block, batch_size, k)
-        units = _iter_spans(*geometry) if not native_host else (
-            u + (1,) for u in _iter_units(*geometry))
         try:
             for unit, (row_start, block, col, step, shard_off,
-                       rows) in enumerate(units):
+                       rows) in enumerate(_iter_spans(
+                           dat_size, large_block, small_block, batch_size,
+                           k)):
                 if errors or writers.failed:  # downstream died: stop
                     break
                 if cancel is not None and cancel():
@@ -998,22 +942,16 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 # a column cut of one (large) block row, or whole rows
                 pjob.count("units_column" if step != block else "units_rows",
                            1)
-                if native_host:
-                    # zero-copy: dispatch encodes off the mmap directly
-                    q_read.put((unit, None, step, shard_off,
-                                (row_start, block, col, nz, tail)))
-                else:
-                    with pjob.blocked("stall", unit=unit):
-                        slots.acquire()
-                    with pjob.stage("read", unit=unit, rows=rows,
-                                    block=block):
-                        spans, staged = _unit_spans(
-                            dat_view, dat_size, k, row_start, block, col,
-                            step, rows)
-                        if staged:
-                            pjob.count("rows_staged", staged)
-                    q_read.put((unit, spans, rows * step, shard_off,
-                                (rows, block)))
+                with pjob.blocked("stall", unit=unit):
+                    slots.acquire()
+                with pjob.stage("read", unit=unit, rows=rows, block=block):
+                    spans, staged = _unit_spans(
+                        dat_view, dat_size, k, row_start, block, col, step,
+                        rows)
+                    if staged:
+                        pjob.count("rows_staged", staged)
+                q_read.put((unit, spans, rows * step, shard_off,
+                            (rows, block)))
                 done += covered
                 if progress is not None:
                     progress(done)
@@ -1030,30 +968,18 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
         # time overlaps the next unit's d2h instead of queueing behind a
         # flush-group boundary.  Tiny units keep the batcher — per-unit
         # queue hops would cost more than the writes.
-        flusher = _make_sink(writers, k + m, min_step)
+        flusher = _make_sink(writers, k + m, _min_step(
+            dat_size, large_block, small_block, batch_size, k))
         while True:
             with pjob.blocked("await_parity"):
                 item = q_disp.get()
             if item is None:
                 flusher.flush()
                 return
-            if item is FLUSH:
-                flusher.flush()
-                continue
-            unit, spans, step, shard_off, parity, release = item
+            unit, spans, step, shard_off, parity = item
             if failed or errors or writers.failed:
-                if release is not None:
-                    for _ in range(m):
-                        release()
-                else:
-                    pjob.occupancy("inflight", -1)
-                    slots.release()
-                continue
-            if release is not None:  # host parity: already materialised
-                for i in range(m):
-                    flusher.put(k + i, parity[i, :step], shard_off,
-                                release=release)
-                flusher.account(step)
+                pjob.occupancy("inflight", -1)
+                slots.release()
                 continue
             try:
                 pnp = _materialize(parity, job=pjob, unit=unit)
@@ -1065,8 +991,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 del spans, item  # the device is done with the host memory
                 pjob.occupancy("inflight", -1)
                 slots.release()
-            # m runs from a linear apply, the rows of [m, step] from any
-            # other
+            # parity row i: a device shell's run, or row i of an [m, step]
             for i, run in enumerate(pnp):
                 flusher.put(k + i, run[:step], shard_off)
             flusher.account(step)
@@ -1074,15 +999,6 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
     t_r = threading.Thread(target=reader, name="ec-reader", daemon=True)
     t_d = threading.Thread(target=drain, name="ec-drain", daemon=True)
     with pjob.stage("open"):
-        if native_host:
-            tailbuf = np.zeros(max_step, dtype=np.uint8)
-            # the parity ring: the drain batches small units through a
-            # _ShardFlusher, whose writers release a buffer only once its
-            # flush group is written, so the ring must cover a whole
-            # unflushed flush group on top of the pipeline's own depth
-            for _ in range(PIPELINE_DEPTH
-                           + max(1, FLUSH_BYTES // max_step)):
-                pool.put(np.empty((m, max_step), dtype=np.uint8))
         writers = _ShardWriterPool(
             out_fds, highwater, pjob,
             stage_of=lambda i: "write_data" if i < k else "write_parity")
@@ -1096,37 +1012,21 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                 break
             # stage-queue depth at the consume site
             pjob.queue("q_read", q_read.qsize(), PIPELINE_DEPTH)
-            # geom: a host unit's coverage, a device unit's stripe rows and
-            # their block size
+            # geom: the unit's stripe rows and their block size
             unit, spans, step, shard_off, geom = item
             if errors or writers.failed:  # stop dispatching, surface below
-                if spans is not None:
-                    slots.release()
+                slots.release()
                 continue
-            if native_host:
-                row_start, block, col, nz, tail = geom
-                try:
-                    pbuf = pool.get_nowait()
-                except queue.Empty:
-                    q_disp.put(FLUSH)  # see FLUSH above: avoid deadlock
-                    with pjob.blocked("stall", unit=unit):
-                        pbuf = pool.get()
-                _host_parity_unit(pjob, unit, codec, dat_view, tailbuf, pbuf,
-                                  row_start, block, col, step, nz, tail)
-                release = _countdown(m, lambda b=pbuf: pool.put(b))
-                q_disp.put((unit, None, step, shard_off, pbuf, release))
-            else:
-                try:
-                    parity = _dispatch_parity(codec, spans, job=pjob,
-                                              unit=unit, stripes=geom[0],
-                                              block=geom[1])
-                except BaseException as e:
-                    errors.append(e)  # the reader stops at its next unit
-                    slots.release()
-                    raise
-                # out on the device until the drain has its parity
-                pjob.occupancy("inflight", +1)
-                q_disp.put((unit, spans, step, shard_off, parity, None))
+            try:
+                parity = _dispatch_parity(codec, spans, job=pjob, unit=unit,
+                                          stripes=geom[0], block=geom[1])
+            except BaseException as e:
+                errors.append(e)  # the reader stops at its next unit
+                slots.release()
+                raise
+            # out on the device until the drain has its parity
+            pjob.occupancy("inflight", +1)
+            q_disp.put((unit, spans, step, shard_off, parity))
             del item, spans  # the queue item alone holds a unit's views
     finally:
         with pjob.blocked("join_drain"):
@@ -1138,7 +1038,7 @@ def _encode_pipelined(codec, dat_fd: int, dat_view: np.ndarray,
                     item = q_read.get(timeout=0.05)
                 except queue.Empty:
                     continue
-                if item is not None and item[1] is not None:
+                if item is not None:
                     slots.release()
             t_r.join()
         # after the producers: no submission can block now (`join_writers`)
@@ -1204,7 +1104,7 @@ class _RebuildVolume:
     def __init__(self, base: str, spec, codec, present: list[int],
                  missing: list[int], batch_size: int):
         self.base, self.spec, self.codec = base, spec, codec
-        self.present, self.missing = present, missing
+        self.missing = missing
         self.use = _survivor_basis(codec, present, missing)
         self.shard_size = os.path.getsize(base + layout.to_ext(self.use[0]))
         # MSR sub-packetization: every chunk a codec's interleave must see
@@ -1324,43 +1224,6 @@ def _boundaries(pjob, crossed: list[int]) -> None:
         pjob.stats["boundaries_in_flight"] = round(crossed[0] / crossed[1], 4)
 
 
-def _rebuild_host_serial(vols: list[_RebuildVolume], opool: queue.Queue,
-                         pjob, progress, cancel, commit) -> None:
-    """The native host codec's loop: volume after volume, each batch's
-    decode matmul straight off the maps into the output ring on the
-    calling thread (booked whole as `reconstruct`) while the writers have
-    the batch before; a volume commits when its rows are written."""
-    from seaweedfs_tpu import native
-    done = unit = 0
-    for j, vol in enumerate(vols):
-        vol.open(pjob)
-        dec_mat = vol.codec.code.decode_matrix(list(vol.present),
-                                               list(vol.missing))
-        rows_out = len(vol.missing)
-        for off in vol.offsets():
-            if cancel is not None and cancel():
-                raise EncodeCancelled("ec rebuild cancelled")
-            if vol.writers.failed:
-                break
-            n = min(vol.batch, vol.shard_size - off)
-            with pjob.blocked("stall", unit=unit):
-                obuf = opool.get()
-            with pjob.stage("reconstruct", unit=unit) as st:
-                rows = [vol.views[i][off:off + n] for i in vol.use]
-                native.gf_matmul_ptrs(dec_mat, rows, list(obuf[:rows_out]),
-                                      n)
-            pjob.count("spans_mapped", len(rows))
-            _profile.KERNELS.record("reconstruct", wall_s=st.seconds,
-                                    nbytes=len(vol.use) * n)
-            _write_rows(vol.writers, opool, obuf, rows_out, n, off)
-            unit += 1
-            done += n * len(vol.use)
-            if progress is not None:
-                progress(done)
-        commit(vol)  # the writers' first error is raised here
-        _boundaries(pjob, [0, j])
-
-
 def _rebuild_pipelined(vols: list[_RebuildVolume], opool: queue.Queue,
                        pjob, progress=None, cancel=None,
                        commit=None) -> None:
@@ -1378,7 +1241,8 @@ def _rebuild_pipelined(vols: list[_RebuildVolume], opool: queue.Queue,
                un-materialised, so batch N+1's rows go up while batch N is
                out, and the next volume's first batch while the last
                batches of the one before are (no drain between volumes); a
-               host codec behind the seam computes here.  Between batches
+               host codec computes here (`dispatch`: the native shell
+               reads the rows by pointer where they lie).  Between batches
                it commits every volume whose rows the writers have all
                written (`_ShardWriterPool.idle`: no wait)
       drain    materialises batch N (the seam's `device_wait` and
@@ -1575,8 +1439,8 @@ def rebuild_ec_volumes(bases: list[str], batch_size: int = DEFAULT_BATCH,
     no page is made ready before the first put, a batch's faults are taken
     by whoever reads its rows) and a batch is one volume's rows where they
     lie in the maps (`stats["spans_mapped"]` counts them: batches x
-    survivors), handed to the native decode matmul by row pointer or to
-    the dispatch seam as views, which a device codec puts up uncopied where
+    survivors), handed to the dispatch seam as views, which the native
+    host codec reads by row pointer and a device codec puts up uncopied where
     they are a whole bucket wide or its program reads them in place at
     their own width (`ops/dispatch.ROW_PUTS_FROM`; `stats["narrow"]`
     counts the batches put below their bucket, a volume's short last
@@ -1588,11 +1452,9 @@ def rebuild_ec_volumes(bases: list[str], batch_size: int = DEFAULT_BATCH,
     ec_encoder.go:237-291; the backlog is `ec.rebuild`'s loop,
     command_ec_rebuild.go).
 
-    What overlaps follows from the codecs.  Where every volume's is the
-    native host codec, each batch is decoded on the calling thread while
-    the writers have the batch before, volume after volume (`stats["mode"]`
-    `host-serial`).  Otherwise every volume goes through encode's reader ->
-    dispatch -> drain shape (`_rebuild_pipelined`, `pipelined`): up to
+    Every volume goes through encode's reader -> dispatch -> drain shape
+    (`_rebuild_pipelined`, `stats["mode"]` `pipelined`), whatever its
+    codec (a host codec computes at the enqueue): up to
     PIPELINE_DEPTH batches, of one volume or of two, are between their put
     and their materialised result, batch N+1's rows going up while batch
     N's program, copy back, `unstage` and writes run;
@@ -1617,7 +1479,6 @@ def _rebuild_volumes(bases, batch_size, progress, cancel, stats, codec_tags,
     `skipped` as it goes."""
     from seaweedfs_tpu.ops import codecs as _codecs
     from seaweedfs_tpu.maintenance import faults as _faults
-    from seaweedfs_tpu.ops.native_codec import NativeRSCodec
     rebuilt, skipped = report["rebuilt"], report["skipped"]
     vols: list[_RebuildVolume] = []
     for base, tag in zip(bases, codec_tags or [None] * len(bases)):
@@ -1655,8 +1516,7 @@ def _rebuild_volumes(bases, batch_size, progress, cancel, stats, codec_tags,
     stats["narrow"] = 0  # batches put at their own width, below the bucket
     stats["spans_mapped"] = 0  # rows selected in the maps: batches x survivors
     stats["inflight_max"] = 0  # the job's gauge (_rebuild_pipelined) says
-    native_host = all(isinstance(v.codec, NativeRSCodec) for v in vols)
-    stats["mode"] = "host-serial" if native_host else "pipelined"
+    stats["mode"] = "pipelined"
 
     # a rebuild IS repair work: unless a caller already declared a class
     # (the planner's header re-entered through the middleware), any
@@ -1692,8 +1552,7 @@ def _rebuild_volumes(bases, batch_size, progress, cancel, stats, codec_tags,
             opool: queue.Queue = queue.Queue()
             for _ in range(PIPELINE_DEPTH):
                 opool.put(np.empty((lost_rows, width), dtype=np.uint8))
-        walk = _rebuild_host_serial if native_host else _rebuild_pipelined
-        walk(vols, opool, pjob, progress, cancel, commit)
+        _rebuild_pipelined(vols, opool, pjob, progress, cancel, commit)
         stats["wall_s"] = time.perf_counter() - t_wall
         _book_stage_bytes(pjob, stats, stats["bytes"],
                           sum(v.shard_size * len(v.missing) for v in vols))

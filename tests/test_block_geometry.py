@@ -112,9 +112,8 @@ def test_files_equal_the_reference_cut_with_the_same_blocks(
     assert stats["large_row_share"] == round(
         rows * spec.k * LARGE / len(raw), 4)
     assert stats["units_column"] == rows * (LARGE // BATCH)
-    native_host = kind == "cpp" and spec.family != "msr"
-    assert stats["units_rows"] == (
-        small_rows if native_host else -(-small_rows // (BATCH // SMALL)))
+    # span units under every codec: BATCH // SMALL small rows a unit
+    assert stats["units_rows"] == -(-small_rows // (BATCH // SMALL))
     _no_leftovers(base)
 
 

@@ -184,9 +184,9 @@ def test_span_batch_parity_runs_and_empty_slots(unit_mesh):
 
 # ---- the stream under the volumes' code ----------------------------------
 # k, the shard files a volume and the sub-rows a file come from the codec:
-# every tag a single volume's encode carries, on the mesh encoder (units
-# as spans of the maps), on a host codec and on a one-device device codec
-# (one stripe row a unit, staged [U, k, W]).
+# every tag a single volume's encode carries, on the mesh encoder, on a host
+# codec and on a one-device device codec: units as spans of the maps under
+# each, a batch one mesh program on the mesh and a dispatch a unit else.
 
 # block sizes that msr_9_16's eight sub-rows a file divide; a unit is up
 # to eight small rows, a large block is cut in sixteen columns
@@ -298,13 +298,68 @@ def test_convert_under_the_tag_byte_identity(tmp_path, monkeypatch, tag,
             "version": ec_files.read_vif(base)["version"],
             "dat_file_size": len(raw), "codec": tag,
             "large_block_bytes": LARGE, "small_block_bytes": SMALL}
-    if spans:  # only a volume's last, short row is copied on the host
-        assert stats["rows_staged"] == sum(
-            1 for n in sizes if n % (spec.k * SMALL))
+    # only a volume's last, short row is copied on the host, but under
+    # the numpy shells, which build every unit of more than one span or
+    # row into a [k, W] array (dispatch._unstriped)
+    assert stats["rows_staged"] == sum(1 for n in sizes
+                                       if n % (spec.k * SMALL)) + (
+        _unstriped_rows(sizes, spec.k) if kind == "numpy" else 0)
+    if spans:
         assert rep["devices"] > 1
     else:
-        assert stats["rows_staged"] == stats["units"]
+        assert rep["devices"] == {"jax": 1, "numpy": 0}[kind]
     assert not [p for p in os.listdir(tmp_path) if p.endswith(".tmp")]
+
+
+def _unstriped_rows(sizes, k: int) -> int:
+    """The stripe rows a codec without a span apply has copied into `[k,
+    W]` arrays: every row of a unit of more than one span or row."""
+    n = 0
+    for size in sizes:
+        dat = np.zeros(size, dtype=np.uint8)
+        for row_start, block, col, step, _, rows in ec_files._iter_spans(
+                size, LARGE, SMALL, BATCH, k):
+            views, _ = ec_files._unit_spans(dat, size, k, row_start, block,
+                                            col, step, rows)
+            if views and (rows > 1 or len(views) > 1):
+                n += rows
+    return n
+
+
+@pytest.mark.parametrize("kind", ["jax", "cpp"])
+@pytest.mark.parametrize("tag", ["rs_10_4", "lrc_12_2_2", "msr_9_16"])
+def test_one_device_fleet_takes_spans_under_every_code(tmp_path, monkeypatch,
+                                                      tag, kind):
+    """A fleet conversion with no mesh (the XLA shell on one device, the
+    native host shell) takes every unit as spans of the maps, each one
+    `dispatch_parity` by the single-volume rule: files byte-equal to the
+    plain reference, at most one row staged a volume (its last, short
+    one), and a span selected for every unit that holds data."""
+    if kind == "cpp":
+        from seaweedfs_tpu import native
+        if not native.available():
+            pytest.skip("no native codec here")
+    from seaweedfs_tpu.ops import codecs
+    spec = codecs.parse_tag(tag)
+    sizes = _tag_sizes(tag)
+    bases, payloads = _make_volumes(tmp_path, sizes, seed=44)
+    monkeypatch.setenv("WEEDTPU_CONVERT_CODEC", kind)
+    stats: dict = {}
+    rep = fleet_convert.convert_volumes(
+        bases, large_block=LARGE, small_block=SMALL, batch_size=BATCH,
+        codec_tag=tag, stats=stats)
+    assert stats["backend"] == {"jax": "JaxRSCodec",
+                                "cpp": "NativeRSCodec"}[kind]
+    for base, raw in zip(bases, payloads):
+        assert _files_of(base, spec.n) == _model_files(tag, raw), base
+    assert stats["rows_staged"] <= len(bases)
+    assert stats["rows_staged"] == sum(1 for n in sizes
+                                       if n % (spec.k * SMALL))
+    holding = sum(1 for size in sizes for row_start, _, col, *_ in
+                  ec_files._iter_spans(size, LARGE, SMALL, BATCH, spec.k)
+                  if row_start + col < size)
+    assert stats["spans_mapped"] == holding
+    assert rep["devices"] == (1 if kind == "jax" else 0)
 
 
 def test_convert_msr_on_a_four_device_mesh(tmp_path, monkeypatch):
@@ -400,8 +455,8 @@ def test_convert_books_class_convert(tmp_path):
 def test_convert_cancel_clean_abort(tmp_path, kind):
     """Cancel mid-stream: EncodeCancelled, NO partial .ecXX visible, no
     .tmp litter, and a previous valid shard set survives untouched —
-    whether units go up as spans of the maps (the mesh encoder) or are
-    staged into [U, k, W] batches (a host codec)."""
+    whether a batch is one mesh program (the mesh encoder) or one
+    dispatch a unit (the numpy reference)."""
     bases, _ = _make_volumes(tmp_path, [300_000, 280_000], seed=9)
     # volume 0 already has a valid shard set from an earlier encode
     os.environ["WEEDTPU_EC_CODEC"] = "numpy"

@@ -522,8 +522,10 @@ def test_fleet_convert_under_the_tag_equals_generate(server, tag, n):
     assert status == 200 and job["kind"] == "fleet_convert"
     assert (job["stages"]["codec"], job["stages"]["shard_files"],
             job["stages"]["alpha"]) == (spec.tag, n, spec.alpha)
+    # one device: each unit of the stream is the single-volume encode's
+    # program, on its /perf row
     row = next(r for r in pipeline.local_snapshot()["roofline"]["rows"]
-               if r["kernel"] == "fleet_encode")
+               if r["kernel"] == "encode_parity")
     assert (row["backend"], row["rows_in"], row["rows_out"], row["alpha"],
             row["tile"]) == ("device", spec.k * spec.alpha,
                              spec.m * spec.alpha, spec.alpha, 32768)

@@ -78,6 +78,75 @@ def test_native_codec_roundtrip():
         assert (rebuilt[i] == shards[i]).all(), i
 
 
+# (large block, small block, batch, .dat bytes over k, the unit: its place
+# in `_iter_spans`' walk) for the native shell's span apply
+SPAN_UNITS = {
+    # four whole small rows, one span of the map
+    "whole_rows": (4096, 256, 1024, lambda k: 9 * k * 256, 0),
+    # a large-block row's first column cut: k spans a block apart
+    "column_cut": (4096, 256, 1024, lambda k: 3 * k * 4096, 0),
+    # three whole rows and the short last one, staged into a zeroed buffer
+    "staged_last_row": (4096, 256, 1024, lambda k: 3 * k * 256 + 77, 0),
+}
+
+
+@pytest.mark.parametrize("tag", ["rs_10_4", "lrc_12_2_2", "msr_9_16"])
+@pytest.mark.parametrize("case", sorted(SPAN_UNITS))
+def test_native_span_apply_equals_the_numpy_reference(case, tag):
+    """`encode_parity_linear` of the native shell (and of the MSR file
+    codec over it) on a unit as the engines select it in a `.dat`'s map:
+    one `[m, W]` array whose rows are the parity files' runs, equal to
+    the numpy reference's parity of the unit laid out as `[k, W]`."""
+    from seaweedfs_tpu.ops import codecs, dispatch
+    from seaweedfs_tpu.storage.ec import ec_files
+    large, small, batch, size, at = SPAN_UNITS[case]
+    codec, ref = codecs.resolve(tag, "cpp"), codecs.resolve(tag, "numpy")
+    k = codec.k
+    dat = np.random.default_rng(6).integers(0, 256, size(k), dtype=np.uint8)
+    row_start, block, col, step, _, rows = list(ec_files._iter_spans(
+        len(dat), large, small, batch, k))[at]
+    spans, staged = ec_files._unit_spans(dat, len(dat), k, row_start, block,
+                                         col, step, rows)
+    assert (len(spans), rows, staged) == {
+        "whole_rows": (1, 4, 0), "column_cut": (k, 1, 0),
+        "staged_last_row": (2, 4, 1)}[case]
+    got = codec.encode_parity_linear(spans, rows)
+    data = np.concatenate(spans).reshape(rows, k, step).transpose(
+        1, 0, 2).reshape(k, rows * step)
+    want = dispatch.materialize(dispatch.dispatch_parity(ref, data))
+    assert isinstance(got, np.ndarray) and got.shape == (codec.m, rows * step)
+    assert np.array_equal(got, want)
+    assert all(got[i].flags.c_contiguous for i in range(codec.m))
+
+
+def test_native_reconstruct_reads_row_views_without_a_stack(monkeypatch):
+    """`NativeRSCodec.reconstruct` over views of one buffer, as a rebuild
+    hands it a batch's rows in the maps: the reference's rows, and no
+    `np.stack` on the way."""
+    from seaweedfs_tpu.ops import native_codec
+    codec = native_codec.get_codec(10, 4)
+    code = rs.get_code(10, 4)
+    data = np.random.default_rng(8).integers(0, 256, (10, 3000),
+                                             dtype=np.uint8)
+    shards = code.encode_numpy(data)
+    flat = shards.reshape(-1)  # each survivor a view of one mapping
+    n = shards.shape[1]
+    survivors = {i: flat[i * n:(i + 1) * n] for i in (0, 2, 3, 5, 6, 8, 9,
+                                                      10, 12, 13)}
+    want = code.reconstruct_numpy(dict(survivors), wanted=[1, 4, 11])
+
+    def no_stack(*a, **kw):
+        raise AssertionError("np.stack called")
+
+    monkeypatch.setattr(np, "stack", no_stack)
+    got = codec.reconstruct(survivors, wanted=[1, 4, 11])
+    monkeypatch.undo()
+    assert sorted(got) == [1, 4, 11]
+    for i in (1, 4, 11):
+        assert np.array_equal(got[i], want[i]), i
+        assert np.array_equal(got[i], shards[i]), i
+
+
 def test_crc32c_known_answer():
     assert native.crc32c(b"123456789") == 0xE3069283
     assert native.crc32c(b"") == 0

@@ -5,8 +5,8 @@ small sizes, where a batch is a few KiB so that each volume has one to
 three batches and the pipeline crosses volume boundaries with batches in
 flight; the skips; what a cancel and a failed writer leave, volume by
 volume and file by file; and the single form's answer, unchanged.  On the
-CPU device codec (the XLA shell, through the dispatch seam's pipeline) and
-on the native host codec (its host-serial loop, volume after volume)."""
+CPU device codec (the XLA shell) and on the native host codec, both
+through the dispatch seam's one pipeline."""
 
 import asyncio
 import gc
@@ -136,8 +136,7 @@ def test_every_rebuilt_file_equals_the_plain_reference(
     assert stats["volumes"] == len(spec)
     assert stats["lost_rows"] == max(len(lost) for _, lost, _ in spec)
     assert stats["codec"] == ",".join(sorted({t for t, _, _ in spec}))
-    assert stats["mode"] == ("host-serial" if codec_kind == "cpp"
-                             else "pipelined")
+    assert stats["mode"] == "pipelined"
     batches = sum(-(-size // BATCH) for _, _, size in spec)
     assert stats["spans_mapped"] == sum(
         -(-size // BATCH) * (6 if tag == "lrc_12_2_2" and len(lost) == 1
@@ -147,9 +146,8 @@ def test_every_rebuilt_file_equals_the_plain_reference(
     job = next(j for j in pipeline.jobs_snapshot()
                if j["kind"] == "ec_rebuild")
     assert job["state"] == "done"
-    if codec_kind == "jax":
-        assert job["stages"]["unstage"]["items"] == batches
-        assert 1 <= stats["inflight_max"] <= ec_files.PIPELINE_DEPTH
+    assert job["stages"]["unstage"]["items"] == batches
+    assert 1 <= stats["inflight_max"] <= ec_files.PIPELINE_DEPTH
 
 
 # the Pallas shell at a tile of `pallas_gf.IN_PLACE_QUANTUM`, which no
@@ -362,7 +360,7 @@ def test_a_writer_failing_in_volume_five(when, codec_kind, tmp_path,
     # batch (the walk's twelfth) is out, so that volume 5's commit fails
     # mid-walk with volume 6 still to come whole
     sixth_out = threading.Event()
-    if when != "commit" or codec_kind == "cpp":
+    if when != "commit":
         sixth_out.set()
     real_dispatch = ec_files._dispatch_reconstruct
 

@@ -354,17 +354,17 @@ _ENCODE_KEYS = {
             "device_wait_s", "d2h_copy_s", "write_data_s",
             "write_parity_s", "stall_s", "ship_data_s", "await_unit_s",
             "await_parity_s", "join_drain_s",
-            "write_data_workers", "write_parity_workers"} | _CALL_KEYS,
-    "cpp": {"encode_s", "write_data_s", "write_parity_s", "ship_data_s",
-            "await_unit_s", "await_parity_s", "join_drain_s",
             "write_data_workers", "write_parity_workers"} | _CALL_KEYS}
 _REBUILD_KEYS = {
     "jax": {"reconstruct_s", "stage_s", "h2d_s", "dispatch_s",
             "device_wait_s", "d2h_copy_s", "unstage_s", "write_s",
             "stall_s", "await_batch_s", "join_drain_s",
-            "write_workers"} | _CALL_KEYS,
-    "cpp": {"reconstruct_s", "write_s", "stall_s",
             "write_workers"} | _CALL_KEYS}
+# the native host codec rides the same pipeline: its whole computation is
+# the seam's `dispatch`, and nothing goes up or comes back
+_DEVICE_ONLY = {"h2d_s", "device_wait_s", "d2h_copy_s"}
+_ENCODE_KEYS["cpp"] = _ENCODE_KEYS["jax"] - _DEVICE_ONLY - {"d2h_s"}
+_REBUILD_KEYS["cpp"] = _REBUILD_KEYS["jax"] - _DEVICE_ONLY
 
 
 @pytest.mark.parametrize("codec", ["jax", "cpp"])
@@ -394,6 +394,12 @@ def test_job_stats_carry_exactly_the_documented_stage_keys(
     # a ring that never ran dry books no stall
     assert got - {"stall_s"} == want - {"stall_s"}, sorted(got ^ want)
     assert not [k for k in _GONE if k in stats]
+    if codec == "cpp":  # the host seam's `dispatch` is summed into the lump
+        lump, parts = (("encode_s", ["dispatch_s"]) if job == "encode" else
+                       ("reconstruct_s", ["stage_s", "dispatch_s",
+                                          "unstage_s"]))
+        assert stats["dispatch_s"] > 0
+        assert stats[lump] == pytest.approx(sum(stats[p] for p in parts))
 
 
 # ---- the three consumers on the one path -------------------------------
@@ -470,44 +476,36 @@ def test_fleet_convert_crash_safety_tmp_rename(tmp_path, monkeypatch):
 
 # ---- streaming drain: write_parity overlaps d2h -------------------------
 
-class _FakeShard:
-    def __init__(self, start, stop, data, log, idx):
-        self.index = (slice(start, stop),)
+class _FakeRun:
+    """Device-array stand-in for one parity run of one unit, whose copy
+    back (`np.asarray`) is logged, so the test can see writes interleave
+    with transfers."""
+
+    def __init__(self, data, log, idx):
+        self.nbytes = data.nbytes
         self._data = data
         self._log = log
         self._idx = idx
 
-    @property
-    def data(self):
-        self._log.append(("d2h", self._idx))
-        return self._data
+    def devices(self):
+        return {"fake"}
 
-
-class _FakeParity:
-    """Device-array stand-in: two addressable blocks whose .data access
-    is logged, so the test can see writes interleave with transfers."""
-
-    def __init__(self, parity, log):
-        self.nbytes = parity.nbytes
-        half = parity.shape[0] // 2
-        self._shards = [
-            _FakeShard(0, half, parity[:half], log, 0),
-            _FakeShard(half, parity.shape[0], parity[half:], log, 1),
-        ]
+    def copy_to_host_async(self):
+        pass
 
     def block_until_ready(self):
         return self
 
-    @property
-    def addressable_shards(self):
-        return self._shards
+    def __array__(self, dtype=None, copy=None):
+        self._log.append(("d2h", self._idx))
+        return self._data
 
 
 def test_drain_streams_parity_writes_per_d2h_block(tmp_path, monkeypatch):
-    """The fleet drain must fan out and SUBMIT each block's parity the
-    moment that block's d2h lands — a parity flush interleaved between
-    the two fake-shard transfers proves write_parity overlaps d2h
-    instead of serializing behind a full gather."""
+    """The fleet drain must fan out and SUBMIT each unit's parity the
+    moment that unit's d2h lands — a parity flush interleaved between
+    two units' transfers proves write_parity overlaps d2h instead of
+    serializing behind the whole batch's."""
     from seaweedfs_tpu.models import rs
     code = rs.get_code(10, 4)
     log: list = []
@@ -515,13 +513,21 @@ def test_drain_streams_parity_writes_per_d2h_block(tmp_path, monkeypatch):
     class StreamCodec:
         k, m = 10, 4
 
-        def place(self, units):
+        def place_units(self, units):
             return units
 
-        def encode_parity_batch(self, units):
-            par = np.stack([code.encode_numpy(units[u])[code.k:]
-                            for u in range(units.shape[0])])
-            return _FakeParity(par, log)
+        def encode_units_linear(self, placed, stripes):
+            out = []
+            for u in placed:
+                if u is None:
+                    out.append(None)
+                    continue
+                data = np.concatenate(u).reshape(stripes, 10, -1)
+                par = code.encode_numpy(
+                    data.transpose(1, 0, 2).reshape(10, -1))[code.k:]
+                out.append(tuple(_FakeRun(row, log, len(log))
+                                 for row in par))
+            return out
 
     orig_flush = ec_files._ShardFlusher.flush
 
@@ -542,9 +548,9 @@ def test_drain_streams_parity_writes_per_d2h_block(tmp_path, monkeypatch):
                                   codec=StreamCodec(), stats=stats)
     d2h = [i for i, e in enumerate(log) if e[0] == "d2h"]
     flushes = [i for i, e in enumerate(log) if e[0] == "flush"]
-    assert len(d2h) >= 4  # two blocks per dispatched batch
+    assert len(d2h) >= 8  # m runs a unit, units of two volumes
     # at least one parity flush lands BETWEEN two d2h events: the
-    # writers were already busy while a later block was still in flight
+    # writers were already busy while a later unit was still in flight
     assert any(d2h[j] < f < d2h[j + 1]
                for f in flushes for j in range(len(d2h) - 1)), log
     assert stats["d2h_s"] > 0  # the streamed next() was timed

@@ -162,8 +162,7 @@ def test_fleet_from_lazy_maps_equals_the_plain_reference(
     """One conversion of a volume of every shape: the plain reference's
     files, one map a non-empty volume made on the calling thread in
     `map`, and a unit selected from a map for every unit that holds data
-    (spans of the map on the mesh, one stripe row a unit under the XLA
-    shell, whose rows are copied from the map into the batch)."""
+    (spans of the map, on the mesh and under the XLA shell alike)."""
     monkeypatch.setenv("WEEDTPU_CONVERT_CODEC", kind)
     bases, raws, k = _fleet_dats(tmp_path, tag)
     stats: dict = {}
@@ -172,9 +171,9 @@ def test_fleet_from_lazy_maps_equals_the_plain_reference(
     for base, raw in zip(bases, raws):
         if raw:
             assert _files_of(base, n) == _model_files(tag, raw), base
-    units = ec_files._iter_spans if kind == "fleet" else ec_files._iter_units
-    holding = sum(1 for raw in raws for row_start, _, col, *_ in units(
-        len(raw), LARGE, SMALL, E_BATCH, k) if row_start + col < len(raw))
+    holding = sum(1 for raw in raws for row_start, _, col, *_ in
+                  ec_files._iter_spans(len(raw), LARGE, SMALL, E_BATCH, k)
+                  if row_start + col < len(raw))
     assert stats["spans_mapped"] == holding == \
         stats["units_column"] + stats["units_rows"]
     assert [(flags & mmap.MAP_POPULATE, length, thread)
